@@ -58,13 +58,14 @@ def artifact_key(entry_name: str, config) -> dict:
 
 
 def default_root() -> str:
-    """$OVERSIM_AOT_DIR, else a host-keyed sibling of the XLA persistent
-    cache (same machine-feature keying, same rationale)."""
+    """$OVERSIM_AOT_DIR, else ``aot/`` inside the XLA persistent cache
+    directory (hostcache.cache_dir: placed by $JAX_COMPILATION_CACHE_DIR,
+    else the fixed path in the checkout) — one directory holds both."""
     env = os.environ.get("OVERSIM_AOT_DIR")
     if env:
         return env
     from oversim_tpu import hostcache
-    return hostcache.cache_dir() + "_aot"
+    return os.path.join(hostcache.cache_dir(), "aot")
 
 
 class ArtifactStore:

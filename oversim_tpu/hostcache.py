@@ -1,13 +1,13 @@
-"""Host/device-keyed compile-cache setup shared by every runner script.
+"""Compile-cache setup shared by every runner script.
 
 Two jobs:
 
-* :func:`cache_dir` — the host-keyed persistent-compile-cache path.
-  XLA-CPU AOT executables embed machine features; an entry compiled on
-  a different host poisons the cache with load-time machine-feature
-  mismatches (the round-4 goldens-regen failure).  Keying the cache
-  directory on the CPU model + ISA flags makes a foreign entry simply
-  invisible instead of fatal.
+* :func:`cache_dir` — where the persistent compile cache lives.  The
+  directory is placed from OUTSIDE: ``$JAX_COMPILATION_CACHE_DIR`` when
+  set (and then no code of this repo sets another), else one fixed path
+  inside the checkout (``<repo>/.jax_cache``, git-ignored).  The path
+  never moves between runs — no /tmp, no pid, no time — so whoever
+  keeps the directory keeps the compiles.
 * :func:`enable` — the ONE compile-cache boilerplate block.  Before this
   helper existed, six scripts each carried the same zstandard poisoning
   + x64 + ``jax_compilation_cache_dir`` stanza (bench.py,
@@ -20,17 +20,20 @@ Two jobs:
 
 :func:`device_signature` keys the AOT export artifacts
 (oversim_tpu/aot/) on the accelerator actually visible at warm-up time;
-:func:`host_signature` is the raw CPU identity string the cache dir
-hashes.  Module import stays pure stdlib — safe before jax.
+:func:`host_signature` is the raw CPU identity string those keys carry
+(XLA-CPU executables embed machine features).  Module import stays pure
+stdlib — safe before jax.
 """
 
 from __future__ import annotations
 
-import hashlib
+import os
 import platform
 import sys
 
 _CPUINFO = "/proc/cpuinfo"
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def host_signature(cpuinfo_path: str = _CPUINFO) -> str:
@@ -48,10 +51,11 @@ def host_signature(cpuinfo_path: str = _CPUINFO) -> str:
     return sig
 
 
-def cache_dir(prefix: str = "/tmp/oversim_jax_cache", *,
-              cpuinfo_path: str = _CPUINFO) -> str:
-    sig = host_signature(cpuinfo_path)
-    return prefix + "_" + hashlib.sha1(sig.encode()).hexdigest()[:10]
+def cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else the fixed
+    ``<repo>/.jax_cache``.  The one function that decides it."""
+    return os.environ.get(CACHE_ENV) or os.path.join(_REPO_ROOT,
+                                                     ".jax_cache")
 
 
 def device_signature() -> str:
@@ -67,14 +71,13 @@ def device_signature() -> str:
 
 
 def enable(*, persistent: bool = True, min_compile_secs: float = 1.0,
-           prefix: str = "/tmp/oversim_jax_cache",
            x64: bool = True) -> str | None:
     """Configure jax's compile cache the one blessed way.
 
     Poisons the zstandard C extension (segfaults on this box), nulls the
     already-bound ``compilation_cache`` module references when jax beat
     us to the import, enables x64, then either points the persistent
-    cache at the host-keyed directory (``persistent=True``; returns the
+    cache at :func:`cache_dir` (``persistent=True``; returns the
     path) or disables persistence entirely (``persistent=False``; the
     CPU-tier opt-out — returns None).  Call AFTER platform env vars are
     final; safe whether or not jax is already imported.
@@ -90,7 +93,7 @@ def enable(*, persistent: bool = True, min_compile_secs: float = 1.0,
     if not persistent:
         jax.config.update("jax_enable_compilation_cache", False)
         return None
-    d = cache_dir(prefix)
+    d = cache_dir()
     jax.config.update("jax_compilation_cache_dir", d)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_secs)

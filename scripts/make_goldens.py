@@ -34,12 +34,10 @@ if "xla_cpu_max_isa" not in _flags:
 os.environ["XLA_FLAGS"] = _flags
 import jax
 
-from oversim_tpu.hostcache import cache_dir as _host_cache_dir
+from oversim_tpu import hostcache
 
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_enable_x64", True)
-jax.config.update("jax_compilation_cache_dir", _host_cache_dir())
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+hostcache.enable()
 
 import math
 

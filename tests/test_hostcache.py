@@ -1,52 +1,46 @@
-"""hostcache: host-keyed cache dirs, device signatures, enable()."""
+"""hostcache: cache dir placed from outside, device signatures, enable()."""
+
+import os
 
 import jax
 import pytest
 
 from oversim_tpu import hostcache
 
-FAKE_CPUINFO = """\
-processor\t: 0
-model name\t: FakeCPU 9000 @ 3.00GHz
-flags\t\t: fpu sse sse2 avx avx2
-"""
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FIXED = os.path.join(REPO, ".jax_cache")
 
 
-@pytest.fixture
-def cpuinfo(tmp_path):
-    p = tmp_path / "cpuinfo"
-    p.write_text(FAKE_CPUINFO)
-    return str(p)
+def test_cache_dir_is_the_variable_when_set(monkeypatch, tmp_path):
+    # placed from outside: $JAX_COMPILATION_CACHE_DIR wins, verbatim
+    want = str(tmp_path / "outside")
+    monkeypatch.setenv(hostcache.CACHE_ENV, want)
+    assert hostcache.cache_dir() == want
 
 
-def test_cache_dir_stable_for_same_host(cpuinfo):
-    a = hostcache.cache_dir("/tmp/x", cpuinfo_path=cpuinfo)
-    b = hostcache.cache_dir("/tmp/x", cpuinfo_path=cpuinfo)
-    assert a == b
-    assert a.startswith("/tmp/x_")
-    # a 10-hex-digit host hash suffix
-    suffix = a.rsplit("_", 1)[1]
-    assert len(suffix) == 10
-    assert int(suffix, 16) >= 0
+def test_cache_dir_unset_is_one_fixed_path_in_the_checkout(monkeypatch,
+                                                           tmp_path):
+    monkeypatch.delenv(hostcache.CACHE_ENV, raising=False)
+    a = hostcache.cache_dir()
+    # the path is part of the cache's key: it must not depend on the
+    # working directory, the pid or the time — two calls agree
+    monkeypatch.chdir(tmp_path)
+    assert hostcache.cache_dir() == a == FIXED
+    assert not a.startswith("/tmp")
 
 
-def test_cache_dir_rolls_when_isa_flags_change(tmp_path, cpuinfo):
-    before = hostcache.cache_dir("/tmp/x", cpuinfo_path=cpuinfo)
-    other = tmp_path / "cpuinfo2"
-    other.write_text(FAKE_CPUINFO.replace("avx2", "avx512f"))
-    after = hostcache.cache_dir("/tmp/x", cpuinfo_path=str(other))
-    # different machine features MUST land in a different cache dir —
-    # an AOT entry compiled for the other host would poison this one
-    assert before != after
+def test_cache_dir_empty_variable_counts_as_unset(monkeypatch):
+    monkeypatch.setenv(hostcache.CACHE_ENV, "")
+    assert hostcache.cache_dir() == FIXED
 
 
-def test_cache_dir_oserror_fallback(tmp_path):
-    # unreadable cpuinfo (non-Linux, restricted /proc) degrades to
-    # platform.processor(), never raises
-    missing = str(tmp_path / "does_not_exist")
-    d = hostcache.cache_dir("/tmp/x", cpuinfo_path=missing)
-    assert d.startswith("/tmp/x_")
-    assert d == hostcache.cache_dir("/tmp/x", cpuinfo_path=missing)
+def test_aot_store_root_follows_cache_dir(monkeypatch, tmp_path):
+    from oversim_tpu.aot import store
+    monkeypatch.delenv("OVERSIM_AOT_DIR", raising=False)
+    monkeypatch.setenv(hostcache.CACHE_ENV, str(tmp_path / "outside"))
+    assert store.default_root() == str(tmp_path / "outside" / "aot")
+    monkeypatch.delenv(hostcache.CACHE_ENV)
+    assert store.default_root() == os.path.join(FIXED, "aot")
 
 
 def test_device_signature_names_the_visible_set():
@@ -56,13 +50,20 @@ def test_device_signature_names_the_visible_set():
     assert sig.endswith(":x8")
 
 
-def test_enable_persistent_points_cache_at_host_dir(tmp_path):
+@pytest.mark.parametrize("env_set", [True, False],
+                         ids=["variable-set", "variable-unset"])
+def test_enable_persistent_points_cache_at_cache_dir(monkeypatch, tmp_path,
+                                                     env_set):
+    want = str(tmp_path / "outside") if env_set else FIXED
+    if env_set:
+        monkeypatch.setenv(hostcache.CACHE_ENV, want)
+    else:
+        monkeypatch.delenv(hostcache.CACHE_ENV, raising=False)
     prev_dir = jax.config.jax_compilation_cache_dir
     try:
-        d = hostcache.enable(persistent=True,
-                             prefix=str(tmp_path / "cache"))
-        assert d == hostcache.cache_dir(str(tmp_path / "cache"))
-        assert jax.config.jax_compilation_cache_dir == d
+        assert hostcache.enable(persistent=True) == want
+        # that directory and no other is set in code
+        assert jax.config.jax_compilation_cache_dir == want
         # enable() must NOT flip the cache enable flag back on — the
         # suite runs with it disabled (XLA-CPU serialize segfault,
         # conftest note) and only sets the directory
