@@ -1,4 +1,4 @@
-"""Message-kind taxonomy and approximate wire sizes.
+"""Message-kind table and approximate wire sizes.
 
 TPU-native stand-in for the reference's `.msg`-generated message classes
 (src/common/CommonMessages.msg + per-protocol *.msg files): every in-flight

@@ -118,8 +118,15 @@ def build_churn(ini: IniFile, config: str) -> churn_mod.ChurnParams:
         dm = ini.get("**.deadtimeMean", config)
         if dm is not None:
             kw["deadtime_mean"] = float(_value(dm))
+    # the reference draws each creation gap from truncnormal(interval,
+    # interval / 3) (NoChurn.cc handleMessage; LifetimeChurn.cc
+    # initialDeviation = initialMean / 3) — the deviation SCALES with
+    # the interval.  ChurnParams' absolute 0.1 s default would stretch
+    # a 4096-node fill at 20 s / 4096 per node to ~330 s (the mean of
+    # |N(0.005, 0.1)| is 0.08 s, not 0.005 s).
     return churn_mod.ChurnParams(
-        model=model, target_num=target, init_interval=init_interval, **kw)
+        model=model, target_num=target, init_interval=init_interval,
+        init_deviation=init_interval / 3.0, **kw)
 
 
 def build_underlay(ini: IniFile, config: str):

@@ -9,11 +9,10 @@ The production-operations counterpart to raw scale (ROADMAP item 5):
     re-established via ``NamedSharding`` over whatever mesh is available
     at restore time.  Surviving replicas are bit-identical across the
     reshape.
-  * :mod:`oversim_tpu.elastic.retry` — the failure taxonomy: device /
-    tunnel errors classified transient vs fatal, jittered exponential
-    backoff around device dispatch and backend acquisition, and a
-    graceful, loudly-annotated degradation to ``JAX_PLATFORMS=cpu``
-    when chip acquisition keeps failing.
+  * :mod:`oversim_tpu.elastic.retry` — the failure classes: device
+    errors classified transient vs fatal, jittered exponential backoff
+    around device dispatch and backend acquisition; exhausted attempts
+    raise (no degradation to another platform).
   * :mod:`oversim_tpu.elastic.fleet` — the host-side pieces of the
     fleet supervisor (``scripts/fleet_run.py``): replica-shard
     assignment, heartbeat files, seeded chaos schedules, and the

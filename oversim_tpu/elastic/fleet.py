@@ -110,7 +110,7 @@ def aggregate_heartbeats(docs: dict, now: float | None = None) -> dict:  # analy
     t = time.time() if now is None else now
     out = {"workers_total": len(docs), "workers_reporting": 0,
            "ticks_done": 0, "ticks_target": 0, "retries": 0,
-           "degraded_to_cpu": 0, "heartbeat_age_max_s": None,
+           "heartbeat_age_max_s": None,
            "per_worker": {}}
     ages = []
     for widx, doc in sorted(docs.items()):
@@ -124,14 +124,12 @@ def aggregate_heartbeats(docs: dict, now: float | None = None) -> dict:  # analy
         out["ticks_done"] += int(doc.get("ticks_done", 0))
         out["ticks_target"] += int(doc.get("ticks", 0))
         out["retries"] += int(doc.get("retries", 0))
-        out["degraded_to_cpu"] += 1 if doc.get("degraded_to_cpu") else 0
         out["per_worker"][str(widx)] = {
             "age_s": round(age, 3) if age is not None else None,
             "ticks_done": int(doc.get("ticks_done", 0)),
             "ticks": int(doc.get("ticks", 0)),
             "retries": int(doc.get("retries", 0)),
             "chunk_wall_s": doc.get("chunk_wall_s"),
-            "degraded_to_cpu": bool(doc.get("degraded_to_cpu", False)),
         }
     if ages:
         out["heartbeat_age_max_s"] = round(max(ages), 3)

@@ -13,7 +13,7 @@ runners already have at their host-sync points:
     events (retry/backoff, chaos kill, AOT hit/miss, contract verdict).
 
 ``statusz()`` assembles the ``/statusz`` snapshot — tick, window,
-replica shards, inbox_impl, degraded_to_cpu, checkpoint age — purely
+replica shards, inbox_impl, checkpoint age — purely
 from those host-side updates, so a scrape never touches the device.
 
 Typical runner wiring (scripts/service_run.py)::
@@ -151,7 +151,7 @@ class RunObserver:
     # -------------------------------------------------------- updates --
     def set_static(self, **fields) -> None:
         """Scrape-visible run facts that don't change per window:
-        inbox_impl, replicas, shards, degraded_to_cpu, ..."""
+        inbox_impl, replicas, shards, ..."""
         self._static.update(fields)
 
     def record(self, kind: str, **fields) -> None:

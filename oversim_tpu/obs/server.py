@@ -12,7 +12,7 @@ metrics registry (obs/metrics.py):
              and will return to ready when the backlog clears
   /statusz   JSON operational snapshot: server info merged with the
              runner-provided ``statusz`` callable (tick, window,
-             replica shards, inbox_impl, degraded_to_cpu, checkpoint
+             replica shards, inbox_impl, checkpoint
              age — see obs/runtime.py RunObserver.statusz)
 
 The ``statusz`` callable MUST be cheap and sync-free: it is invoked

@@ -130,8 +130,8 @@ def _worker_main(spec_path: str) -> int:
                          base_s=0.2, seed=widx,
                          max_total_seconds=spec.get("retry_budget_s"))
 
-    # backend bring-up under the retry policy; a persistent transient
-    # failure degrades to CPU with a loud manifest annotation
+    # backend bring-up under the retry policy; when the attempts run
+    # out the last error raises and the supervisor sees the worker die
     ann = acquire_backend(policy)
 
     # AOT pre-warm ($OVERSIM_AOT=1): every worker of a fleet runs the
@@ -162,19 +162,17 @@ def _worker_main(spec_path: str) -> int:
             "config_hash": cfg_hash,
             "campaign": camp.describe(),
             "fleet": {"ticks_done": ticks_done, "worker": widx,
-                      "retries": retries,
-                      "degraded_to_cpu": ann["degraded_to_cpu"]}})
+                      "retries": retries}})
 
     last_chunk_wall = None
 
     def heartbeat():
-        # chunk_wall_s/degraded_to_cpu feed the supervisor's fleet-level
+        # chunk_wall_s feeds the supervisor's fleet-level
         # metric rollup (fleet.aggregate_heartbeats → obs plane)
         fleet.write_heartbeat(spec["heartbeat"], worker=widx,
                               ticks_done=ticks_done, ticks=ticks,
                               retries=retries,
-                              chunk_wall_s=last_chunk_wall,
-                              degraded_to_cpu=ann["degraded_to_cpu"])
+                              chunk_wall_s=last_chunk_wall)
 
     heartbeat()
     delays = backoff_delays(policy)
@@ -309,8 +307,6 @@ def _supervise(args) -> int:
                                "summed transient-retry counts"),
             "age_max": r.gauge("oversim_fleet_heartbeat_age_max_s",
                                "oldest heartbeat age"),
-            "degraded": r.gauge("oversim_fleet_degraded_to_cpu",
-                                "workers running on the CPU fallback"),
         }
         print(json.dumps({"phase": "obs", "metrics_port": obs.start(),
                           "flight": args.flight}), flush=True)
@@ -359,7 +355,6 @@ def _supervise(args) -> int:
         fleet_gauges["ticks_done"].set(agg["ticks_done"])
         fleet_gauges["ticks_target"].set(agg["ticks_target"])
         fleet_gauges["retries"].set(agg["retries"])
-        fleet_gauges["degraded"].set(agg["degraded_to_cpu"])
         if agg["heartbeat_age_max_s"] is not None:
             fleet_gauges["age_max"].set(agg["heartbeat_age_max_s"])
         obs.set_static(fleet=agg)     # /statusz carries the full rollup
@@ -634,8 +629,6 @@ def _supervise(args) -> int:
                      for w in finished},
         "worker_retries": {Path(w.spec_path).stem: a["retries"]
                            for w, a in zip(finished, arts)},
-        "degraded_to_cpu": any(a["elastic"]["degraded_to_cpu"]
-                               for a in arts),
     }
     if autoscaler is not None:
         elastic_ann["autoscale"] = {**autoscaler.describe(),

@@ -148,7 +148,7 @@ def render(cur: dict, prev: dict | None) -> str:
     for key in ("role", "window", "tick", "t_sim", "alive",
                 "windows_done", "checkpoints_written",
                 "checkpoint_age_s", "inbox_impl", "replicas",
-                "degraded_to_cpu", "ingest_rate"):
+                "ingest_rate"):
         if key in st and st[key] is not None:
             lines.append(f"{key:22s} {st[key]}")
     if isinstance(st.get("requests"), dict):
