@@ -554,8 +554,8 @@ def child_main():
     tel_window = int(os.environ.get("OVERSIM_BENCH_TELEMETRY_WINDOW", "256"))
     # OVERSIM_BENCH_INBOX_IMPL: scatter (default) | pallas (fused
     # kernel plane, oversim_tpu/kernels/) | sort (oracle-only) —
-    # resolved like **.inboxImpl (pallas falls back to scatter when the
-    # plane is unavailable, sort warns)
+    # resolved like **.inboxImpl (pallas raises when the plane is
+    # unavailable, sort warns)
     from oversim_tpu.config import scenario as scenario_mod
     inbox_impl = scenario_mod.resolve_inbox_impl(
         os.environ.get("OVERSIM_BENCH_INBOX_IMPL", "scatter"))

@@ -635,8 +635,8 @@ def scenario_pins() -> list:
     (ROADMAP item 6); only an explicit ``**.inboxImpl = "sort"`` key may
     select it.  The kernel plane adds two pins: an explicit
     ``"pallas"`` key is honored when the plane is importable, and a
-    pallas request on a kernel-less install falls back to ``"scatter"``
-    (never to ``"sort"``, never an error).  Returns Finding rows
+    pallas request on a kernel-less install raises ``ScenarioError``
+    (never a quiet run on another path).  Returns Finding rows
     (empty = pinned)."""
     from oversim_tpu.analysis.findings import Finding
     from oversim_tpu.config import scenario
@@ -664,18 +664,21 @@ def scenario_pins() -> list:
             message="explicit **.inboxImpl = \"sort\" was not honored "
                     "— the oracle path became unreachable",
             measured=sim_sort.ep.inbox_impl, limit="sort"))
-    # kernel-plane availability fallback: a "pallas" request without
-    # the plane resolves to the scatter default, loudly but non-fatally
-    fallback = scenario.resolve_inbox_impl("pallas", available=False,
-                                           warn=False)
-    if fallback != "scatter":
+    # no quiet fallback: a "pallas" request without the plane is a
+    # ScenarioError, never a run on another path under the same name
+    try:
+        resolved = scenario.resolve_inbox_impl("pallas", available=False,
+                                               warn=False)
+    except scenario.ScenarioError:
+        resolved = None
+    if resolved is not None:
         out.append(Finding(
-            pass_name="hlo", rule="pallas-unavailable-fallback",
+            pass_name="hlo", rule="pallas-unavailable-raises",
             where="config/scenario.py",
             message="inboxImpl \"pallas\" on a kernel-less install "
-                    f"resolved to {fallback!r} — must fall back to "
-                    "the scatter default",
-            measured=fallback, limit="scatter"))
+                    f"resolved to {resolved!r} — must raise "
+                    "ScenarioError",
+            measured=resolved, limit="ScenarioError"))
     from oversim_tpu import kernels
     if kernels.available():
         pallas_ini = IniFile.loads(_DEFAULT_INI

@@ -40,8 +40,9 @@ def resolve_inbox_impl(value: str, *, available: bool | None = None,
 
     - ``"scatter"`` — the zero-sort scatter-min default.
     - ``"pallas"`` — the fused kernel plane (oversim_tpu/kernels/).
-      Falls back to ``"scatter"`` with a stderr note when the plane is
-      unimportable (``available`` overrides the probe for tests/pins).
+      Raises :class:`ScenarioError` when the plane is unimportable
+      (``available`` overrides the probe for tests/pins): a run that
+      asked for the kernels never quietly measures the scatter path.
     - ``"sort"`` — ORACLE-ONLY legacy full-pool sort; selecting it
       outside the test tier prints a stderr deprecation warning
       (suppressed under pytest and with ``warn=False``).
@@ -62,12 +63,9 @@ def resolve_inbox_impl(value: str, *, available: bool | None = None,
             from oversim_tpu import kernels
             available = kernels.available()
         if not available:
-            if not quiet:
-                print("oversim-tpu: inboxImpl \"pallas\" requested but "
-                      "the kernel plane is unavailable (no "
-                      "jax.experimental.pallas) — falling back to "
-                      "\"scatter\"", file=sys.stderr)
-            return "scatter"
+            raise ScenarioError(
+                "inboxImpl \"pallas\" requested but the kernel plane is "
+                "unavailable (jax.experimental.pallas does not import)")
     elif impl == "sort" and not quiet:
         print("oversim-tpu: inboxImpl \"sort\" is deprecated and "
               "oracle-only — it exists to pin the scatter/pallas paths "

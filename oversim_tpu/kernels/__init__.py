@@ -37,8 +37,8 @@ _AVAILABLE = None
 
 def available() -> bool:
     """True when the Pallas toolchain imports on this install — the
-    scenario layer falls back to ``"scatter"`` (with a stderr note)
-    when ``**.inboxImpl = "pallas"`` is requested without it."""
+    scenario layer raises ``ScenarioError`` when ``**.inboxImpl =
+    "pallas"`` is requested without it."""
     global _AVAILABLE
     if _AVAILABLE is None:
         try:

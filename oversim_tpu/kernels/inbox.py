@@ -90,7 +90,13 @@ def _inbox_kernel(occ_ref, due_ref, dst_ref, thi_ref, tlo_ref, *refs,
             # stable position: entries with key <= (hi, lo) stay ahead;
             # earlier pool indices inserted at equal t compare <= via lo
             le = (row_hi < hi) | ((row_hi == hi) & (row_lo <= lo))
-            pos = jnp.sum(le.astype(I32))
+            # NOT a plain jnp.sum to a scalar: Mosaic lowers a
+            # reduce-to-scalar by re-tracing jnp.sum, which under x64
+            # promotes the i32 lanes to i64 and is then refused
+            # ("64-bit types are not supported").  A keepdims reduce
+            # of a 2-D view stays i32.
+            pos = jnp.sum(le.astype(I32).reshape(1, r), axis=1,
+                          keepdims=True, promote_integers=False)[0, 0]
 
             @pl.when(pos < r)
             def _():
@@ -116,7 +122,7 @@ def _inbox_kernel(occ_ref, due_ref, dst_ref, thi_ref, tlo_ref, *refs,
 
         return carry
 
-    jax.lax.fori_loop(0, occ_ref[0], select_body, None)
+    jax.lax.fori_loop(I32(0), occ_ref[0], select_body, None)
 
     if gather:
         def gather_body(jv, carry):
@@ -127,7 +133,7 @@ def _inbox_kernel(occ_ref, due_ref, dst_ref, thi_ref, tlo_ref, *refs,
             gblk_ref[nn, rr, :] = blk_ref[jnp.maximum(ix, 0), :]
             return carry
 
-        jax.lax.fori_loop(0, n * r, gather_body, None)
+        jax.lax.fori_loop(I32(0), I32(n * r), gather_body, None)
 
 
 @functools.partial(jax.jit,

@@ -48,7 +48,7 @@ def _dest_kernel(valid_ref, want_ref, dest_ref, over_ref,
 
         return carry
 
-    jax.lax.fori_loop(0, p, free_body, None)
+    jax.lax.fori_loop(I32(0), I32(p), free_body, None)
     n_free = cnt_ref[0]
 
     def want_body(jv, carry):
@@ -68,7 +68,7 @@ def _dest_kernel(valid_ref, want_ref, dest_ref, over_ref,
 
         return carry
 
-    jax.lax.fori_loop(0, q, want_body, None)
+    jax.lax.fori_loop(I32(0), I32(q), want_body, None)
     over_ref[0] = jnp.maximum(cnt_ref[1] - n_free, 0)
 
 
@@ -128,7 +128,7 @@ def _compact_kernel(mask_ref, vals_ref, out_ref, count_ref, cnt_ref, *,
 
         return carry
 
-    jax.lax.fori_loop(0, m, body, None)
+    jax.lax.fori_loop(I32(0), I32(m), body, None)
     count_ref[0] = cnt_ref[0]
 
 

@@ -134,7 +134,7 @@ def main():
     ap.add_argument("--inbox-impl", default="scatter",
                     choices=["scatter", "pallas", "sort"],
                     help="inbox implementation (pallas = fused kernel "
-                    "plane; falls back to scatter when unavailable)")
+                    "plane; an error when unavailable)")
     ap.add_argument("--platform", default=None)
     ap.add_argument("--out", default=None, help="incremental atomic "
                     "report artifact path")

@@ -297,7 +297,7 @@ def main():
     ap.add_argument("--inbox-impl", default="scatter",
                     choices=["scatter", "pallas", "sort"],
                     help="inbox implementation (pallas = fused kernel "
-                    "plane; falls back to scatter when unavailable)")
+                    "plane; an error when unavailable)")
     ap.add_argument("--tick-impl", default="dense",
                     choices=["dense", "sparse"],
                     help="tick implementation (sparse = active-set "

@@ -147,12 +147,12 @@ def test_resolve_tick_impl():
 
 def test_resolve_inbox_impl_kernel_plane():
     """The pallas key resolves by kernel-plane availability: honored
-    when importable, a loud scatter fallback when not (never an
-    error, never sort)."""
+    when importable, a ScenarioError when not — never a quiet run on
+    the scatter path under the pallas name."""
     assert scenario.resolve_inbox_impl(
         "pallas", available=True, warn=False) == "pallas"
-    assert scenario.resolve_inbox_impl(
-        "pallas", available=False, warn=False) == "scatter"
+    with pytest.raises(scenario.ScenarioError, match="unavailable"):
+        scenario.resolve_inbox_impl("pallas", available=False, warn=False)
     assert scenario.resolve_inbox_impl('"scatter"') == "scatter"
     assert scenario.resolve_inbox_impl("sort") == "sort"
     with pytest.raises(scenario.ScenarioError):
