@@ -85,12 +85,29 @@ def report(tag: str, out: dict) -> None:
         f"engine {json.dumps(out['_engine'])}")
 
 
-def drive(run_to, summary, s, fill: float, n: int, tag: str):
+def build_sim(n: int | None = None):
+    """The deployment, through the normal entry points.  ``n`` overrides
+    the ini's node count for a rehearsal (same 20 s fill)."""
+    from oversim_tpu.config.ini import IniFile
+    from oversim_tpu.config.scenario import build_simulation
+    from oversim_tpu.engine.sim import EngineParams
+
+    ini = IniFile.load(INI)
+    config = "General"
+    if n is not None:
+        config = ini.with_overrides("General", {
+            "**.targetOverlayTerminalNum": n,
+            "**.initPhaseCreationInterval": 20.0 / n})
+    return build_simulation(ini, config, EngineParams(**ENGINE))
+
+
+def drive(run_to, sim, s, tag: str):
     """Three calls of ONE program: to fill+1 s (compile + first ticks),
     to fill+10 s (steady-window base), to the horizon.  ``run_to(s, t)``
-    returns the advanced, ready state.  Returns (final summary, health
-    failures)."""
+    returns the advanced state.  Returns (final state, final summary,
+    health failures)."""
     import jax
+    fill, summary = sim.cp.init_finished_time, sim.summary
     t0 = time.perf_counter()
     s = jax.block_until_ready(run_to(s, fill + FIRST_S))
     t1 = time.perf_counter()
@@ -107,7 +124,7 @@ def drive(run_to, summary, s, fill: float, n: int, tag: str):
         f"window {t3 - t2:.1f} s: {ticks} ticks, "
         f"{out['_t_sim'] - base['_t_sim']:.1f} sim-s)")
     report(tag, out)
-    return s, out, health(base, out, n)
+    return s, out, health(base, out, sim.n)
 
 
 def main(argv=None) -> int:
@@ -148,21 +165,13 @@ def main(argv=None) -> int:
         lambda ev, secs, **kw: compiles.append(secs)
         if ev == COMPILE_EVENT else None)
 
-    from oversim_tpu.config.ini import IniFile
-    from oversim_tpu.config.scenario import build_simulation
-    from oversim_tpu.engine.sim import NS, EngineParams
+    from oversim_tpu.engine.sim import NS
 
-    ini = IniFile.load(INI)
-    config = "General"
-    if args.rehearsal is not None:
-        config = ini.with_overrides("General", {
-            "**.targetOverlayTerminalNum": args.rehearsal,
-            "**.initPhaseCreationInterval": 20.0 / args.rehearsal})
-    sim = build_simulation(ini, config, EngineParams(**ENGINE))
-    n, fill = sim.n, sim.cp.init_finished_time
-    say(f"scenario {os.path.relpath(INI, HERE)} [{config}] n={n} "
-        f"fill {fill:.1f} s engine {json.dumps(ENGINE)} chunk {CHUNK} "
-        f"seed {args.seed}")
+    sim = build_sim(args.rehearsal)
+    n = sim.n
+    say(f"scenario {os.path.relpath(INI, HERE)} n={n} "
+        f"fill {sim.cp.init_finished_time:.1f} s engine "
+        f"{json.dumps(ENGINE)} chunk {CHUNK} seed {args.seed}")
 
     def init():
         t0 = time.perf_counter()
@@ -177,7 +186,7 @@ def main(argv=None) -> int:
     failures = []
     s = init()
     n_before = len(compiles)
-    s, solo, bad = drive(solo_run_to, sim.summary, s, fill, n, "one device")
+    s, solo, bad = drive(solo_run_to, sim, s, "one device")
     failures += bad
     big = [c for c in compiles[n_before:] if c >= 1.0]
     programs = type(sim)._run_until_device._cache_size()
@@ -208,8 +217,7 @@ def main(argv=None) -> int:
         def mesh_run_to(st, t):
             return run(st, jax.numpy.int64(int(t * NS)))
 
-        s4, quad, bad = drive(mesh_run_to, sim.summary, s4, fill, n,
-                              "four devices")
+        s4, quad, bad = drive(mesh_run_to, sim, s4, "four devices")
         failures += bad
         keys = ["_ticks", "_t_sim", "_alive", "kbr_sent", "kbr_delivered"]
         diff = [f"{k}: one {solo[k]} four {quad[k]}" for k in keys

@@ -62,34 +62,23 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(jitted, *args, **static):
-    return jitted.lower(*args, **static).compile()
-
-
 def test_kademlia_tick_compiles_for_v5e(one_chip):
     """The chip_smoke program (ini -> build_simulation ->
     _run_until_device) at N=128: compiles for the described v5e, with
     the donated state aliased and no Pallas kernel on the default
     path."""
     import chip_smoke
-    from oversim_tpu.config.ini import IniFile
-    from oversim_tpu.config.scenario import build_simulation
-    from oversim_tpu.engine.sim import EngineParams
 
-    ini = IniFile.load(chip_smoke.INI)
-    cfg = ini.with_overrides("General", {
-        "**.targetOverlayTerminalNum": 128,
-        "**.initPhaseCreationInterval": 20.0 / 128})
-    sim = build_simulation(ini, cfg, EngineParams(**chip_smoke.ENGINE))
+    sim = chip_smoke.build_sim(128)
     # Simulation.init cannot be shape-evaluated (_dedupe_buffers reads
     # buffer pointers); init_from_rng can
     shapes = jax.eval_shape(
         lambda: sim.init_from_rng(jax.random.PRNGKey(1)))
     state = jax.tree.map(
         lambda l: _shape(l.shape, l.dtype, one_chip), shapes)
-    compiled = _compile(type(sim)._run_until_device, sim, state,
-                        _shape((), jnp.int64, one_chip),
-                        chunk=chip_smoke.CHUNK)
+    compiled = type(sim)._run_until_device.lower(
+        sim, state, _shape((), jnp.int64, one_chip),
+        chunk=chip_smoke.CHUNK).compile()
     ma = compiled.memory_analysis()
     assert ma.generated_code_size_in_bytes > 0
     # the state is donated: (nearly) every argument byte is aliased
@@ -100,10 +89,6 @@ def test_kademlia_tick_compiles_for_v5e(one_chip):
 def _inbox_args(sh):
     vec = _shape((P,), I32, sh)
     return vec, vec, vec, vec, _shape((P, W), I32, sh)
-
-
-def _assert_mosaic(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 KERNEL_CASES = ["inbox-gather", "inbox-select", "outbox-dest",
@@ -138,4 +123,5 @@ def _lower_kernel(case, sh):
         strict=True, raises=ValueError, reason=REFUSED[c]))
     if c in REFUSED else c for c in KERNEL_CASES])
 def test_kernel_compiles_for_v5e(one_chip, case):
-    _assert_mosaic(_lower_kernel(case, one_chip).compile())
+    compiled = _lower_kernel(case, one_chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -10,6 +10,14 @@ from oversim_tpu.apps.ntree import NTreeApp, NTreeParams
 from oversim_tpu.engine import sim as sim_mod
 from oversim_tpu.overlay.chord import ChordLogic, READY
 
+# PR 22: moved to the slow tier.  Until PR 22 a donated-buffer bug
+# (churn.T_INF) made most simulation tests of a worker fail in
+# milliseconds, so tier-1 "fitted" its limit; with the bug fixed this
+# module's fixture alone runs for minutes (measured 282 s under the
+# suite's load) and the whole suite no longer fitted.  Run with
+# scripts/run_suite.sh or `pytest -m slow`.
+pytestmark = pytest.mark.slow
+
 N = 16
 
 

@@ -13,6 +13,14 @@ from oversim_tpu.engine import sim as sim_mod
 from oversim_tpu.overlay.pastry import (BambooLogic, PastryLogic,
                                         PastryParams, READY)
 
+# PR 22: moved to the slow tier.  Until PR 22 a donated-buffer bug
+# (churn.T_INF) made most simulation tests of a worker fail in
+# milliseconds, so tier-1 "fitted" its limit; with the bug fixed this
+# module's fixture alone runs for minutes (measured more than 1900 s under the
+# suite's load) and the whole suite no longer fitted.  Run with
+# scripts/run_suite.sh or `pytest -m slow`.
+pytestmark = pytest.mark.slow
+
 
 @pytest.fixture(scope="module", params=["pastry", "bamboo", "pastry-iter"])
 def pastry_run(request):
