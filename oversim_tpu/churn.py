@@ -116,14 +116,6 @@ class ChurnState:
     t_tick: jnp.ndarray    # [] i64 — next periodic churn tick (random model)
 
 
-def _t_inf():
-    """A FRESH T_INF scalar for a state leaf.  The module-level T_INF is
-    one device buffer shared by every caller; as a state leaf it would
-    be DONATED with the state by run_chunk/run_until_device and every
-    later init in the process would read a deleted buffer."""
-    return jnp.array(T_INF, copy=True)
-
-
 def _with_grace(state_kw, n):
     state_kw.setdefault("t_dead", jnp.full((n,), T_INF, I64))
     state_kw.setdefault("graceful", jnp.zeros((n,), bool))
@@ -179,7 +171,7 @@ def init(rng: jax.Array, p: ChurnParams, life_mean=None) -> ChurnState:
         return ChurnState(**_with_grace(dict(
             t_create=(t_create * NS).astype(I64),
             t_kill=jnp.full((n,), T_INF, I64),
-            l_mean=zeros(), d_mean=zeros(), t_tick=_t_inf()), n))
+            l_mean=zeros(), d_mean=zeros(), t_tick=T_INF), n))
     if p.model == "trace":
         # TraceChurn: the schedule IS the trace (GlobalTraceManager
         # createNode/deleteNode at the traced times)
@@ -190,7 +182,7 @@ def init(rng: jax.Array, p: ChurnParams, life_mean=None) -> ChurnState:
             [t * NS if t is not None else int(T_INF)
              for t in p.trace_kill], I64)
         return ChurnState(**_with_grace(dict(t_create=t_create, t_kill=t_kill,
-                          l_mean=zeros(), d_mean=zeros(), t_tick=_t_inf()), n))
+                          l_mean=zeros(), d_mean=zeros(), t_tick=T_INF), n))
     if p.model == "lifetime":
         fin = p.init_finished_time
         i = jnp.arange(tgt)
@@ -208,7 +200,7 @@ def init(rng: jax.Array, p: ChurnParams, life_mean=None) -> ChurnState:
         t_kill = jnp.maximum(t_kill - p.graceful_leave_delay, t_create)
         return ChurnState(**_with_grace(dict(t_create=(t_create * NS).astype(I64),
             t_kill=(t_kill * NS).astype(I64),
-            l_mean=zeros(), d_mean=zeros(), t_tick=_t_inf()), n))
+            l_mean=zeros(), d_mean=zeros(), t_tick=T_INF), n))
     if p.model == "pareto":
         # ParetoChurn.cc:66-126: per-slot individual mean life/dead times,
         # equilibrium init (alive w.p. availability), stretch to hit the
@@ -254,7 +246,7 @@ def init(rng: jax.Array, p: ChurnParams, life_mean=None) -> ChurnState:
         return ChurnState(**_with_grace(dict(t_create=(t_create * NS).astype(I64),
             t_kill=(t_kill * NS).astype(I64),
             l_mean=l_i.astype(jnp.float32), d_mean=d_i.astype(jnp.float32),
-            t_tick=_t_inf()), n))
+            t_tick=T_INF), n))
     if p.model == "random":
         # RandomChurn: start tgt nodes, then probabilistic create/remove
         # ticks every churnChangeInterval (step() drives the process)

@@ -132,11 +132,20 @@ SPARSE_COUNTERS = ("awake_nodes", "active_dst", "active_deferred")
 
 
 def _dedupe_buffers(state):
-    """Copy any state leaf that shares a device buffer with an earlier
-    leaf.  ``run_chunk``/``run_until_device`` DONATE the state; XLA
-    refuses to donate the same buffer twice, so a logic/churn init that
-    assigns one array object to two fields would poison every later
-    chunk.  One-time cost at init; no-op for alias-free states."""
+    """Make every state leaf own its device buffer.
+
+    ``run_chunk``/``run_until_device`` DONATE the state, so two things
+    must never be a leaf: a buffer that an earlier leaf already holds
+    (XLA refuses to donate the same buffer twice — a logic/churn init
+    that assigns one array object to two fields would poison every
+    later chunk), and a buffer that lives on outside the state.  The
+    second is the module-level scalar constants every overlay defines
+    (``T_INF``, ``NO_NODE``, ``UMAX`` ...): an init that writes
+    ``t_tick=T_INF`` or ``rp=NO_NODE`` hands the shared constant to the
+    donation, and every later eager use of it in the process reads a
+    deleted array.  All such constants are 0-d, so every 0-d leaf is
+    copied; larger leaves only when they share a buffer.  One-time cost
+    at init."""
     leaves, treedef = jax.tree_util.tree_flatten(state)
     seen, out = set(), []
     for leaf in leaves:
@@ -145,7 +154,7 @@ def _dedupe_buffers(state):
         except (AttributeError, ValueError):
             out.append(leaf)
             continue
-        if ptr in seen:
+        if leaf.ndim == 0 or ptr in seen:
             leaf = jnp.array(leaf, copy=True)
         else:
             seen.add(ptr)

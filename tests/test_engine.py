@@ -322,6 +322,30 @@ def test_run_chunk_donates_state():
     assert not s2.t_now.is_deleted()
 
 
+def test_init_never_hands_shared_scalars_to_donation():
+    """A state leaf must own its buffer: churn.init writes
+    ``t_tick=T_INF`` and overlays write ``rp=NO_NODE`` — module-level
+    device scalars.  Simulation.init copies every 0-d leaf
+    (_dedupe_buffers), so a donated run deletes neither the constants
+    nor the chance of a second init in the same process."""
+    from oversim_tpu.engine.sim import _dedupe_buffers
+    shared = jnp.int32(-1)
+    owned = _dedupe_buffers({"rp": shared, "x": jnp.zeros((4,), I32)})
+    assert (owned["rp"].unsafe_buffer_pointer()
+            != shared.unsafe_buffer_pointer())
+    assert int(owned["rp"]) == -1
+
+    sim = make_sim(n=8)
+    s = sim.init(seed=2)
+    assert (s.churn.t_tick.unsafe_buffer_pointer()
+            != churn_mod.T_INF.unsafe_buffer_pointer())
+    s = sim.run_chunk(s, 2)
+    jax.block_until_ready(s.t_now)
+    assert not churn_mod.T_INF.is_deleted()
+    s = sim.run_chunk(sim.init(seed=3), 2)    # second init, same process
+    assert int(s.tick) == 2
+
+
 def test_run_until_device_matches_host_loop_chord64():
     """The lax.while_loop device-resident runner must be bit-identical
     to the host chunk loop on a real overlay scenario (chord, N=64):

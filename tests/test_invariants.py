@@ -50,15 +50,23 @@ def test_detects_succ_compaction_hole(chord8):
 def test_detects_ring_order_breakage(chord8):
     _, st = chord8
     lg = st.logic
+    from oversim_tpu.core import keys as K
+    keys_int = [K.to_int(k) for k in np.asarray(st.node_keys)]
+    a, b, c, d = sorted(range(8), key=lambda i: keys_int[i])[:4]
     s0 = np.asarray(lg.succ[:, 0])
-    # swap two nodes' successors: the quiet-ring order check must fire
+    if [int(s0[a]), int(s0[b]), int(s0[c])] != [b, c, d]:
+        pytest.skip("ring not at its fixed point: the order check is "
+                    "quiet by design")
+    # rotate three consecutive nodes: a -> c -> b -> d.  succ0 stays ONE
+    # cycle over the ready set (the gate check_chord needs before it
+    # judges order; a plain swap of two successors splits the ring in
+    # two cycles and is taken for a transient), but in the wrong key
+    # order: the quiet-ring order check must fire
     succ = jnp.asarray(lg.succ)
-    succ = succ.at[0, 0].set(int(s0[1])).at[1, 0].set(int(s0[0]))
+    succ = succ.at[a, 0].set(c).at[c, 0].set(b).at[b, 0].set(d)
     broken = dataclasses.replace(
         st, logic=dataclasses.replace(lg, succ=succ))
-    if int(s0[0]) == int(s0[1]):
-        pytest.skip("degenerate draw: identical successors")
-    with pytest.raises(inv.InvariantViolation):
+    with pytest.raises(inv.InvariantViolation, match="chord_ring_order"):
         inv.check_state(broken)
 
 
