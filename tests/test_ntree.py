@@ -10,14 +10,6 @@ from oversim_tpu.apps.ntree import NTreeApp, NTreeParams
 from oversim_tpu.engine import sim as sim_mod
 from oversim_tpu.overlay.chord import ChordLogic, READY
 
-# PR 22: moved to the slow tier.  Until PR 22 a donated-buffer bug
-# (churn.T_INF) made most simulation tests of a worker fail in
-# milliseconds, so tier-1 "fitted" its limit; with the bug fixed this
-# module's fixture alone runs for minutes (measured 282 s under the
-# suite's load) and the whole suite no longer fitted.  Run with
-# scripts/run_suite.sh or `pytest -m slow`.
-pytestmark = pytest.mark.slow
-
 N = 16
 
 
@@ -30,10 +22,15 @@ def ntree_run():
                                event_interval=10.0))
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=N, init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.020, transition_time=80.0)
+    # sized for XLA-CPU: window 0.05 and chunk 128 bound the tick count,
+    # inbox_slots 4 (engine default 8) halves the handler unrolled over
+    # the inbox slots — a fifth message in one 50 ms window is deferred
+    # to the next tick, never lost
+    ep = sim_mod.EngineParams(window=0.050, transition_time=80.0,
+                              inbox_slots=4)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=41)
-    st = s.run_until(st, 400.0, chunk=512)
+    st = s.run_until(st, 260.0, chunk=128)
     return s, st
 
 

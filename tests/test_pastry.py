@@ -8,18 +8,21 @@ import numpy as np
 import pytest
 
 from oversim_tpu import churn as churn_mod
+from oversim_tpu.apps.kbrtest import KbrTestApp, KbrTestParams
 from oversim_tpu.core import keys as K
 from oversim_tpu.engine import sim as sim_mod
 from oversim_tpu.overlay.pastry import (BambooLogic, PastryLogic,
                                         PastryParams, READY)
 
-# PR 22: moved to the slow tier.  Until PR 22 a donated-buffer bug
-# (churn.T_INF) made most simulation tests of a worker fail in
-# milliseconds, so tier-1 "fitted" its limit; with the bug fixed this
-# module's fixture alone runs for minutes (measured more than 1900 s under the
-# suite's load) and the whole suite no longer fitted.  Run with
-# scripts/run_suite.sh or `pytest -m slow`.
-pytestmark = pytest.mark.slow
+
+# R, messages a node consumes per tick.  Pastry's handler is unrolled
+# over the R inbox slots, and on XLA-CPU the tick program's cost grows
+# faster than R: at N=8 the engine default R=8 compiles in 344 s and
+# runs 167 ms/tick, R=4 in 87 s and 37 ms/tick, R=2 in 43 s and
+# 17 ms/tick (PR 22, CPU test durations).  At these N a window rarely
+# holds more than 2 messages for one node, and a third is deferred to
+# the next tick, never lost.
+INBOX_SLOTS = 2
 
 
 @pytest.fixture(scope="module", params=["pastry", "bamboo", "pastry-iter"])
@@ -31,10 +34,11 @@ def pastry_run(request):
     else:
         logic = PastryLogic(params=PastryParams(routing_mode="iterative"))
     cp = churn_mod.ChurnParams(model="none", target_num=8, init_interval=1.0)
-    ep = sim_mod.EngineParams(window=0.010, transition_time=30.0)
+    ep = sim_mod.EngineParams(window=0.010, transition_time=30.0,
+                              inbox_slots=INBOX_SLOTS)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=17)
-    st = s.run_until(st, 300.0, chunk=512)
+    st = s.run_until(st, 300.0, chunk=128)
     return s, st
 
 
@@ -78,10 +82,15 @@ def pastry32():
     routing table, not just the leafset span)."""
     cp = churn_mod.ChurnParams(model="none", target_num=32,
                                init_interval=0.4)
-    ep = sim_mod.EngineParams(window=0.010, transition_time=60.0)
-    s = sim_mod.Simulation(PastryLogic(), cp, engine_params=ep)
+    # window 0.1: at N=32 nearly every 10 ms window holds an event, so
+    # 10 ms ticks would be tens of thousands; the ACK timeout is 1.5 s.
+    # One test per node per 20 s so 200 s send well over 100 lookups
+    ep = sim_mod.EngineParams(window=0.100, transition_time=60.0,
+                              inbox_slots=INBOX_SLOTS)
+    app = KbrTestApp(KbrTestParams(test_interval=20.0))
+    s = sim_mod.Simulation(PastryLogic(app=app), cp, engine_params=ep)
     st = s.init(seed=23)
-    st = s.run_until(st, 400.0, chunk=512)
+    st = s.run_until(st, 200.0, chunk=128)
     return s, st
 
 
@@ -92,7 +101,10 @@ def test_semirecursive_delivery_multihop(pastry32):
     out = s.summary(st)
     assert (np.asarray(st.logic.state) == READY).all()
     assert out["kbr_sent"] > 100
-    assert out["kbr_delivered"] == out["kbr_sent"]
+    # nothing failed or was dropped; the only lookups not delivered are
+    # the one or two still in flight when the run stops
+    assert out["kbr_sent"] - 2 <= out["kbr_delivered"] <= out["kbr_sent"]
+    assert out["kbr_lookup_failed"] == 0
     assert out["kbr_wrong_node"] == 0
     assert out["route_dropped"] == 0
     # prefix routing: mean hops small but multi-hop traffic exists
