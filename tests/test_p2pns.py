@@ -200,7 +200,9 @@ def test_i3_cross_server_continuation():
     i = routed[0]
     assert int(fields["d"][i]) == int(w.I3_PACKET)
     assert int(fields["a"][i]) == int(idb)
-    assert int(fields["c"][i]) == 1                    # chain depth
+    # c = chain depth (low 16 bits) | payload kind + 1 (high bits)
+    assert int(fields["c"][i]) & 0xFFFF == 1           # chain depth
+    assert int(fields["c"][i]) >> 16 == 1              # payload kind 0
     assert (fields["key"][i] == glob.trigger_ids[3]).all()
 
     # the route layer decapsulates at server 1 (kind := d) — replay the
