@@ -141,11 +141,17 @@ def test_xmlrpc_full_surface(dht_sim):
     s, st = dht_sim
     iface = XmlRpcInterface(s, st, injector_slot=0)
     key = "cd" * (s.spec.bits // 8)
-    # full lookup over real FINDNODE traffic resolves to the oracle's
-    # closest node
+    # full lookup over real FINDNODE traffic resolves to the node
+    # RESPONSIBLE for the key — on Chord its clockwise successor.  (The
+    # local_lookup oracle ranks by bidirectional ring distance; for this
+    # key it names the predecessor, one slot short of the successor.)
     sibs = iface.lookup(key, 2)
     assert sibs, "wire lookup found no sibling"
-    assert sibs[0] == iface.local_lookup(key, 1)[0]
+    from oversim_tpu.core import keys as K
+    kint = int(key, 16)
+    cw = [(K.to_int(k) - kint) % (1 << s.spec.bits)
+          for k in np.asarray(st.node_keys)]
+    assert sibs[0] == min(range(len(cw)), key=cw.__getitem__)
     # a put must become visible in the global dump
     iface.put(key, value=4242, ttl=600.0)
     dump = iface.dump_dht()
