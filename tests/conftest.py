@@ -7,10 +7,16 @@ is imported) and with jax.config.update after it (last update wins), so
 the unit tests stay local whatever the ambient selection is.  The one
 file that describes a chip to the TPU compiler is
 tests/test_chip_compile.py, from inside a fixture.
+
+The file also decides how xdist deals the suite out: one module is one
+unit of work, and the costliest modules go first (see the two hooks at
+the end).
 """
 
 import os
 import sys
+
+import pytest
 
 # jax's persistent compile cache compresses with the zstandard C
 # extension when importable; that extension segfaulted mid-write on
@@ -63,3 +69,53 @@ jax.config.update("jax_enable_x64", True)
 # cpu_aot_loader point at the same AOT path).  In-process jit caching
 # still dedupes within the run; only cross-session reuse is lost.
 jax.config.update("jax_enable_compilation_cache", False)
+
+
+# ---------------------------------------------------------------------------
+# How the suite is dealt to xdist workers.  Every simulation lives in a
+# module-scoped fixture (minutes of XLA-CPU compile and fill) and the
+# persistent compile cache is off, so a module whose tests land on two
+# workers builds its simulation twice.  The suite therefore states its
+# own unit of distribution, whatever --dist the command line names.
+# ---------------------------------------------------------------------------
+
+# The modules whose serial time is longest, in descending order (CPU
+# seconds of one whole run, PR 26; CHANGES.md has the table).  They are
+# collected first so the long fixtures start at second 0 and the tail of
+# the run is cheap tests; every other module follows as collected.
+COST_ORDER = (
+    "test_route_modes.py", "test_route_modes_epichord.py",
+    "test_mesh_2d.py", "test_kernels.py", "test_route_modes_broose.py",
+    "test_mesh_dryrun.py", "test_mesh.py", "test_vmap_campaign.py",
+    "test_pastry_multihop.py", "test_engine.py", "test_epichord.py",
+    "test_faults.py", "test_route_modes_koorde.py", "test_nice.py",
+    "test_kademlia_depth.py", "test_ncs.py", "test_parity.py",
+    "test_zz_sparse.py", "test_zz_service_resume.py",
+    "test_pastry_bamboo.py", "test_p2pns.py", "test_pastry.py",
+    "test_pastry_iterative.py", "test_koorde.py", "test_stack.py",
+    "test_gateway.py", "test_dht_variants.py", "test_churn.py",
+    "test_dht.py",
+)
+
+
+@pytest.hookimpl(optionalhook=True)   # xdist may not be loaded
+def pytest_xdist_make_scheduler(config, log):
+    """One module = one work unit, on one worker.
+
+    xdist's own implementation of this hook is ``trylast``, so this one
+    is taken under any ``--dist``.  The scheduler hands units out in
+    collection order, one to each free worker; its default of sorting
+    units by their number of tests would undo COST_ORDER, so it is
+    turned off here.
+    """
+    from xdist.scheduler import LoadFileScheduling
+
+    config.option.loadscopereorder = False
+    return LoadFileScheduling(config, log)
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(COST_ORDER)}
+    # stable: a module's tests keep their order, the unlisted modules theirs
+    items.sort(key=lambda it: rank.get(it.path.name, len(rank)))

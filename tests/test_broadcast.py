@@ -16,7 +16,8 @@ def test_broadcast_reaches_the_ring():
     app = BroadcastTestApp(BroadcastTestParams(interval=40.0))
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=N, init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.020, transition_time=120.0)
+    ep = sim_mod.EngineParams(window=0.050, transition_time=120.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=23)
     st = s.run_until(st, 420.0, chunk=512)

@@ -19,10 +19,11 @@ from oversim_tpu.overlay.chord import ChordLogic, READY
 def chord_run():
     logic = ChordLogic()
     cp = churn_mod.ChurnParams(model="none", target_num=8, init_interval=1.0)
-    ep = sim_mod.EngineParams(window=0.010, transition_time=20.0)
+    ep = sim_mod.EngineParams(window=0.050, transition_time=20.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=7)
-    st = s.run_until(st, 300.0, chunk=512)
+    st = s.run_until(st, 300.0, chunk=128)
     return s, st
 
 

@@ -88,7 +88,8 @@ def test_chord_under_churn_stays_consistent():
     tiny (reference KBRTestApp tolerates churn-window misses)."""
     cp = churn_mod.ChurnParams(model="lifetime", target_num=12,
                                init_interval=0.5, lifetime_mean=200.0)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=20.0)
+    ep = sim_mod.EngineParams(window=0.05, transition_time=20.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(ChordLogic(), cp, engine_params=ep)
     st = s.init(seed=5)
     st = s.run_until(st, 400.0, chunk=512)
@@ -111,7 +112,8 @@ def test_rejoin_context_preserves_identity():
     cp = churn_mod.ChurnParams(model="lifetime", target_num=12,
                                init_interval=0.5, lifetime_mean=60.0,
                                rejoin_context=True)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=20.0)
+    ep = sim_mod.EngineParams(window=0.05, transition_time=20.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=5)
     keys0 = np.asarray(st.node_keys).copy()

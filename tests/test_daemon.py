@@ -211,15 +211,19 @@ class _TcpClient:
     def send_raw(self, payload: bytes):
         self.sock.sendall(len(payload).to_bytes(4, "big") + payload)
 
-    def recv_frame(self):
+    def _recv_exact(self, n):
+        """n bytes, or an error: the socket's 5 s timeout bounds a
+        silent peer, and a closed one (recv gives b"") ends the read."""
         buf = b""
-        while len(buf) < 4:
-            buf += self.sock.recv(4 - len(buf))
-        ln = int.from_bytes(buf, "big")
-        data = b""
-        while len(data) < ln:
-            data += self.sock.recv(ln - len(data))
-        return _HDR.unpack_from(data)
+        while len(buf) < n:
+            chunk = self.sock.recv(n - len(buf))
+            assert chunk, "connection closed mid-frame"
+            buf += chunk
+        return buf
+
+    def recv_frame(self):
+        ln = int.from_bytes(self._recv_exact(4), "big")
+        return _HDR.unpack_from(self._recv_exact(ln))
 
     def close(self):
         self.sock.close()

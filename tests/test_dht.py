@@ -20,7 +20,8 @@ def dht_run():
                            test_ttl=600.0))
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=8, init_interval=1.0)
-    ep = sim_mod.EngineParams(window=0.030, transition_time=20.0)
+    ep = sim_mod.EngineParams(window=0.030, transition_time=20.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=23)
     st = s.run_until(st, 400.0, chunk=512)
@@ -71,10 +72,11 @@ def test_crash_kill_churn_replication():
     cp = churn_mod.ChurnParams(model="lifetime", target_num=16,
                                init_interval=0.5, lifetime_mean=150.0,
                                graceful_leave_probability=0.0)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=40.0)
+    ep = sim_mod.EngineParams(window=0.05, transition_time=40.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=31)
-    st = s.run_until(st, 400.0, chunk=512)
+    st = s.run_until(st, 400.0, chunk=128)
     out = s.summary(st)
     gets = out["dht_get_attempts"]
     assert gets > 20

@@ -20,11 +20,11 @@ def epichord_run():
     cp = churn_mod.ChurnParams(model="none", target_num=N,
                                init_interval=0.5)
     # sized for XLA-CPU: window 0.05 and chunk 128 bound the tick count,
-    # inbox_slots 4 (engine default 8) halves the handler unrolled over
-    # the inbox slots — a fifth message in one 50 ms window is deferred
+    # inbox_slots 2 (engine default 8) shrinks the handler unrolled over
+    # the inbox slots — a third message in one 50 ms window is deferred
     # to the next tick, never lost
     ep = sim_mod.EngineParams(window=0.050, transition_time=80.0,
-                              inbox_slots=4)
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=5)
     st = s.run_until(st, 400.0, chunk=128)

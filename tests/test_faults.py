@@ -28,7 +28,8 @@ def test_partition_and_heal():
     logic = ChordLogic(app=KbrTestApp(KbrTestParams(test_interval=15.0)))
     s = sim_mod.Simulation(logic, cp, up,
                            sim_mod.EngineParams(window=0.05,
-                                                transition_time=60.0))
+                                                transition_time=60.0,
+                                                inbox_slots=2))
     st = s.init(seed=9)
     # stop well short of the split: run_until overshoots by up to a chunk
     st = s.run_until(st, 140.0, chunk=64)
@@ -40,7 +41,7 @@ def test_partition_and_heal():
     mid = s.summary(st)
     assert mid["_engine"]["partition_lost"] > 0, mid["_engine"]
 
-    st = s.run_until(st, 450.0, chunk=256)
+    st = s.run_until(st, 450.0, chunk=64)
     after = s.summary(st)
     # healed: deliveries keep accumulating after the merge
     assert after["kbr_delivered"] > mid["kbr_delivered"] + 10, (
@@ -91,7 +92,8 @@ def test_dht_handover_under_churn():
                                             storage_slots=192)))
     s = sim_mod.Simulation(logic, cp,
                            engine_params=sim_mod.EngineParams(
-                               window=0.05, transition_time=60.0))
+                               window=0.05, transition_time=60.0,
+                               inbox_slots=2))
     st = s.init(seed=4)
     st = s.run_until(st, 650.0, chunk=256)
     out = s.summary(st)
@@ -118,7 +120,7 @@ def test_malicious_sibling_attack_degrades_lookups():
     s = sim_mod.Simulation(logic, cp,
                            engine_params=sim_mod.EngineParams(
                                window=0.05, transition_time=60.0,
-                               malicious=mp))
+                               malicious=mp, inbox_slots=2))
     st = s.init(seed=8)
     st = s.run_until(st, 300.0, chunk=256)
     out = s.summary(st)
@@ -155,7 +157,8 @@ def test_overlay_partition_merge():
                                           merge_interval=15.0))
     s = sim_mod.Simulation(logic, cp, up,
                            sim_mod.EngineParams(window=0.05,
-                                                transition_time=60.0))
+                                                transition_time=60.0,
+                                                inbox_slots=2))
     st = s.init(seed=17)
     st = s.run_until(st, 190.0, chunk=128)
 
@@ -170,6 +173,6 @@ def test_overlay_partition_merge():
 
     assert cycle_ok(st) > 0, "rings unexpectedly merged during split"
 
-    st = s.run_until(st, 700.0, chunk=256)
+    st = s.run_until(st, 700.0, chunk=128)
     bad = cycle_ok(st)
     assert bad == 0, f"{bad}/{n} successor pointers wrong after merge"

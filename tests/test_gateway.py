@@ -9,6 +9,7 @@ import socket
 import struct
 import xmlrpc.client
 
+import jax
 import numpy as np
 import pytest
 
@@ -28,15 +29,27 @@ def _ring_sim(app, n=4, seed=9):
     logic = MyOverlayLogic(params=MyOverlayParams(), app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=n,
                                init_interval=0.2)
-    ep = sim_mod.EngineParams(window=0.020)
+    ep = sim_mod.EngineParams(window=0.020, inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
+    # the gateway and the XML-RPC interface advance the simulation with
+    # sim.step, tick by tick: eager that is seconds a tick on XLA-CPU,
+    # compiled it is milliseconds
+    s.step = jax.jit(s.step)
     state = s.init(seed=seed)
     state = s.run_until(state, 10.0)
     return s, state
 
 
-def test_udp_echo_through_sim():
-    s, state = _ring_sim(RealworldEchoApp(transform=5))
+@pytest.fixture(scope="module")
+def echo_ring():
+    """One settled ring under RealworldEchoApp(transform=5) for the UDP
+    tests: each starts a gateway of its own from this state, and a
+    gateway steps functionally, so the state here is never consumed."""
+    return _ring_sim(RealworldEchoApp(transform=5))
+
+
+def test_udp_echo_through_sim(echo_ring):
+    s, state = echo_ring
     gw = RealtimeGateway(s, state, gw_slot=0)
     client = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     client.settimeout(0.3)
@@ -92,16 +105,17 @@ def test_tcp_echo_through_sim():
 
 @pytest.fixture(scope="module")
 def dht_sim():
-    # same shape as tests/test_dht.py so the compile cache is shared
     app = DhtApp(DhtParams(test_interval=20.0, num_test_keys=16,
                            test_ttl=600.0))
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=8,
                                init_interval=1.0)
-    ep = sim_mod.EngineParams(window=0.010, transition_time=20.0)
+    ep = sim_mod.EngineParams(window=0.050, transition_time=20.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
+    s.step = jax.jit(s.step)   # as in _ring_sim
     st = s.init(seed=31)
-    st = s.run_until(st, 60.0, chunk=512)
+    st = s.run_until(st, 60.0, chunk=128)
     return s, st
 
 
@@ -161,7 +175,7 @@ def test_xmlrpc_full_surface(dht_sim):
     assert iface.join_overlay() == -1
 
 
-def test_signed_gateway_rejects_unsigned(tmp_path):
+def test_signed_gateway_rejects_unsigned(tmp_path, echo_ring):
     """Real-crypto SingleHost path (CryptoModule.h:56 signMessage /
     verifyMessage with keyFile): an unsigned datagram is dropped, a
     signed one traverses the sim and the reply verifies under the
@@ -173,7 +187,7 @@ def test_signed_gateway_rejects_unsigned(tmp_path):
     cm2 = CryptoModule(key_file=kf)      # second load shares the secret
     assert cm.key == cm2.key
 
-    s, state = _ring_sim(RealworldEchoApp(transform=3), seed=12)
+    s, state = echo_ring
     gw = RealtimeGateway(s, state, gw_slot=0, crypto=cm)
     client = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     client.settimeout(0.25)
@@ -199,7 +213,7 @@ def test_signed_gateway_rejects_unsigned(tmp_path):
         stripped = cm2.verify_frame(data)
         assert stripped is not None, "reply auth block must verify"
         _, sid, b, c = _HDR.unpack_from(stripped)
-        assert b == 9 and c == 500 + 3
+        assert b == 9 and c == 500 + 5
         assert cm.num_sign >= 1
 
         # tampered: flip a payload byte, keep the block -> reject
@@ -211,7 +225,7 @@ def test_signed_gateway_rejects_unsigned(tmp_path):
         gw.close()
 
 
-def test_pluggable_packet_parser():
+def test_pluggable_packet_parser(echo_ring):
     """GenericPacketParser surface (src/common/GenericPacketParser.h:
     parserType-selected codec): a custom parser speaking a different
     external wire format (ascii "b:c" datagrams) drives the same sim
@@ -230,7 +244,7 @@ def test_pluggable_packet_parser():
         def encapsulate(self, sid, b, c):
             return f"{b}:{c}".encode("ascii")
 
-    s, state = _ring_sim(RealworldEchoApp(transform=11), seed=13)
+    s, state = echo_ring
     gw = RealtimeGateway(s, state, gw_slot=0, parser=AsciiParser())
     client = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     client.settimeout(0.25)
@@ -246,7 +260,7 @@ def test_pluggable_packet_parser():
             except socket.timeout:
                 continue
         assert data is not None, "no ascii echo from the gateway"
-        assert data == b"6:911", data   # 900 + transform 11
+        assert data == b"6:905", data   # 900 + transform 5
     finally:
         client.close()
         gw.close()

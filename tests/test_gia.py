@@ -14,10 +14,11 @@ def gia_run():
                                       token_interval=1.0))
     cp = churn_mod.ChurnParams(model="none", target_num=12,
                                init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.020, transition_time=60.0)
+    ep = sim_mod.EngineParams(window=0.050, transition_time=60.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=31)
-    st = s.run_until(st, 400.0, chunk=512)
+    st = s.run_until(st, 400.0, chunk=128)
     return s, st
 
 

@@ -23,7 +23,8 @@ def _run(app, t_end=200.0, seed=11):
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=N,
                                init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=40.0)
+    ep = sim_mod.EngineParams(window=0.05, transition_time=40.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=seed)
     st = s.run_until(st, t_end, chunk=256)
@@ -141,7 +142,8 @@ def test_stacked_trigger_cross_server_keeps_payload_kind():
     app.rcfg = logic.rcfg
     cp = churn_mod.ChurnParams(model="none", target_num=N,
                                init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=40.0)
+    ep = sim_mod.EngineParams(window=0.05, transition_time=40.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=11)
     st = s.run_until(st, 200.0, chunk=256)

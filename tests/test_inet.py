@@ -38,7 +38,8 @@ def test_chord_runs_over_inet_underlay():
     cp = churn_mod.ChurnParams(model="none", target_num=n,
                                init_interval=0.3)
     up = inet_mod.InetUnderlayParams(topology="rease", routers=8)
-    ep = sim_mod.EngineParams(window=0.020, transition_time=40.0)
+    ep = sim_mod.EngineParams(window=0.050, transition_time=40.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, up, ep, underlay_module=inet_mod)
     state = s.init(seed=2)
     state = s.run_until(state, 240.0)

@@ -20,7 +20,8 @@ def chord8():
     cp = churn_mod.ChurnParams(model="none", target_num=8,
                                init_interval=0.3)
     s = sim_mod.Simulation(logic, cp,
-                           engine_params=sim_mod.EngineParams(window=0.05))
+                           engine_params=sim_mod.EngineParams(window=0.05,
+                                                              inbox_slots=2))
     st = s.init(seed=3)
     # checker runs BETWEEN chunks for the whole convergence run
     st = s.run_until(st, 120.0, chunk=128, check_invariants=True)

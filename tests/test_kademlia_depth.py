@@ -23,10 +23,11 @@ def run_sim(n=16, sim_s=240.0, seed=5, churn=None, **kw):
     logic = KademliaLogic(**kw)
     cp = churn or churn_mod.ChurnParams(model="none", target_num=n,
                                         init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.020, transition_time=30.0)
+    ep = sim_mod.EngineParams(window=0.050, transition_time=30.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=seed)
-    st = s.run_until(st, sim_s, chunk=256)
+    st = s.run_until(st, sim_s, chunk=128)
     return s, st
 
 

@@ -5,7 +5,11 @@ conftest.py forces --xla_force_host_platform_device_count=8, the same
 GSPMD compilation path real TPU meshes take (parallel/mesh.py).  Same
 seed ⇒ the integer workload counters must match exactly (the math is
 identical; only reduction orders could differ, and those only touch the
-float stat accumulators)."""
+float stat accumulators).
+
+The 2D (replica x node) mesh is test_mesh_2d.py and the driver's
+multichip dryrun test_mesh_dryrun.py: a module is one unit of work on
+one xdist worker (tests/conftest.py)."""
 
 import jax
 import numpy as np
@@ -26,7 +30,8 @@ def _make_sim():
     logic = ChordLogic(app=KbrTestApp(KbrTestParams(test_interval=5.0)))
     cp = churn_mod.ChurnParams(model="none", target_num=N,
                                init_interval=0.2)
-    ep = sim_mod.EngineParams(window=0.020, transition_time=10.0)
+    ep = sim_mod.EngineParams(window=0.020, transition_time=10.0,
+                              inbox_slots=2)
     return sim_mod.Simulation(logic, cp, engine_params=ep)
 
 
@@ -77,184 +82,3 @@ def test_sharded_engine_counters(pair):
     plain, sharded, _, _ = pair
     for k, v in plain["_engine"].items():
         assert sharded["_engine"][k] == v, k
-
-
-# ---------------------------------------------------------------------------
-# 2D (replica x node) mesh — placement pins + sharded-tick bit-identity
-# ---------------------------------------------------------------------------
-
-N2D = 16   # churn target; engine headroom doubles it -> N=32, 4/shard
-K2D = 8
-TICKS_2D = 64
-
-
-def _make_churn_sim(overlay="chord", inbox_impl="scatter", n=N2D):
-    app = KbrTestApp(KbrTestParams(test_interval=1.0))
-    if overlay == "kademlia":
-        from oversim_tpu.overlay.kademlia import KademliaLogic
-        logic = KademliaLogic(app=app)
-    else:
-        logic = ChordLogic(app=app)
-    cp = churn_mod.ChurnParams(model="lifetime", target_num=n,
-                               init_interval=0.2, lifetime_mean=8.0)
-    ep = sim_mod.EngineParams(window=0.1, inbox_slots=4, pool_factor=4,
-                              inbox_impl=inbox_impl)
-    return sim_mod.Simulation(logic, cp, engine_params=ep)
-
-
-def test_make_mesh_2d_shape():
-    mesh = mesh_mod.make_mesh_2d(2, 4)
-    assert mesh.axis_names == (mesh_mod.REPLICA_AXIS, mesh_mod.NODE_AXIS)
-    assert mesh.shape[mesh_mod.REPLICA_AXIS] == 2
-    assert mesh.shape[mesh_mod.NODE_AXIS] == 4
-    assert mesh_mod.make_mesh_2d(1, 8).shape[mesh_mod.NODE_AXIS] == 8
-    with pytest.raises(ValueError):
-        mesh_mod.make_mesh_2d(4, 4)   # 16 > 8 virtual devices
-
-
-def test_state_pspecs_2d_placement():
-    sim = _make_churn_sim()
-    st = jax.eval_shape(sim.init_from_rng, jax.random.PRNGKey(0))
-    sp = mesh_mod.state_pspecs_2d(st)
-    P, NODE = mesh_mod.P, mesh_mod.NODE_AXIS
-    # the dominant bytes shard: every pool leaf on its leading [P] dim
-    for leaf in jax.tree.leaves(
-            jax.tree.map(lambda s: s, sp.pool)):
-        assert leaf[0] == NODE
-    # replication ledger: cross-indexed [N] planes + scalars replicated
-    assert sp.alive == P()
-    assert sp.node_keys == P()
-    assert sp.t_now == P()
-    # per-node logic rows shard; at least one leaf must
-    n = st.alive.shape[0]
-    node_leaves = [l for l, s in zip(jax.tree.leaves(sp.logic),
-                                     jax.tree.leaves(st.logic))
-                   if s.shape and s.shape[0] == n]
-    assert node_leaves and all(l[0] == NODE for l in node_leaves)
-
-
-def test_state_shardings_2d_refuses_indivisible():
-    sim = _make_churn_sim()   # target 16 -> N=32 with headroom
-    st = jax.eval_shape(sim.init_from_rng, jax.random.PRNGKey(0))
-    assert st.alive.shape[0] % 3 != 0
-    with pytest.raises(ValueError, match="not divisible"):
-        mesh_mod.state_shardings_2d(st, mesh_mod.make_mesh_2d(1, 3))
-
-
-def test_campaign_pspecs_2d_placement():
-    from oversim_tpu.campaign import Campaign, CampaignParams
-    sim = _make_churn_sim()
-    camp = Campaign(sim, CampaignParams(replicas=2, base_seed=7))
-    cs = camp.init()
-    sp = mesh_mod.campaign_state_pspecs_2d(cs)
-    P = mesh_mod.P
-    R, NODE = mesh_mod.REPLICA_AXIS, mesh_mod.NODE_AXIS
-    assert sp.alive == P(R)
-    assert sp.t_now == P(R)
-    for leaf in jax.tree.leaves(jax.tree.map(lambda s: s, sp.pool)):
-        assert leaf[0] == R and leaf[1] == NODE
-
-
-def test_reshard_place_2d():
-    from oversim_tpu.elastic import reshard
-    sim = _make_churn_sim()
-    st = sim.init(seed=3)
-    st2, _mesh = reshard.place_solo(st, node_shards=K2D)
-    want = mesh_mod.NamedSharding(
-        mesh_mod.make_mesh_2d(1, K2D), mesh_mod.P(mesh_mod.NODE_AXIS))
-    assert st2.pool.valid.sharding.is_equivalent_to(
-        want, st2.pool.valid.ndim)
-    # alive stays replicated across the node axis
-    assert st2.alive.sharding.is_equivalent_to(
-        mesh_mod.NamedSharding(mesh_mod.make_mesh_2d(1, K2D),
-                               mesh_mod.P()), st2.alive.ndim)
-    with pytest.raises(ValueError):
-        reshard.place_solo(st, node_shards=3)    # N=32, 32 % 3 != 0
-    with pytest.raises(ValueError):
-        reshard.place_solo(st, node_shards=16)   # only 8 devices
-
-
-def test_reshard_place_campaign_2d():
-    from oversim_tpu.campaign import Campaign, CampaignParams
-    from oversim_tpu.elastic import reshard
-    sim = _make_churn_sim()
-    camp = Campaign(sim, CampaignParams(replicas=2, base_seed=7))
-    cs = camp.init()
-    cs2, _mesh = reshard.place_campaign(cs, node_shards=4)
-    shd = cs2.pool.valid.sharding
-    assert shd.mesh.shape[mesh_mod.NODE_AXIS] == 4
-    assert shd.spec[0] == mesh_mod.REPLICA_AXIS
-    assert shd.spec[1] == mesh_mod.NODE_AXIS
-    with pytest.raises(ValueError):
-        reshard.place_campaign(cs, node_shards=5)   # N=32, 32 % 5 != 0
-
-
-@pytest.mark.parametrize("overlay", ["chord", "kademlia"])
-@pytest.mark.parametrize("inbox_impl", ["scatter", "pallas"])
-def test_sharded_tick_bit_identical(overlay, inbox_impl):
-    """THE 2D contract: 64 churned ticks through parallel/shard_tick.py
-    on the (1, 8) mesh reproduce the solo oracle BIT-IDENTICALLY on
-    every SimState leaf — churn joins/leaves, KBR traffic, pool
-    alloc/free and stats all crossing shard boundaries.  pallas runs
-    the fused-inbox kernel in interpret mode on CPU (same lowering
-    decisions as the sub-core guide's interpret contract)."""
-    from oversim_tpu.parallel.shard_tick import ShardedSim
-
-    sim = _make_churn_sim(overlay=overlay, inbox_impl=inbox_impl)
-    s = sim.init(seed=3)
-    step = jax.jit(sim.step)
-    for _ in range(TICKS_2D):
-        s = step(s)
-    solo = jax.device_get(s)
-
-    ssim = ShardedSim(sim, mesh_mod.make_mesh_2d(1, K2D))
-    sh = ssim.place(sim.init(seed=3))
-    sstep = jax.jit(ssim.step, in_shardings=(ssim.shardings,),
-                    out_shardings=ssim.shardings)
-    for _ in range(TICKS_2D):
-        sh = sstep(sh)
-    sharded = jax.device_get(sh)
-
-    bad = []
-
-    def cmp(path, a, b):
-        a, b = np.asarray(a), np.asarray(b)
-        if a.dtype != b.dtype or a.shape != b.shape:
-            bad.append((path, "meta", str(a.dtype), a.shape,
-                        str(b.dtype), b.shape))
-        elif not np.array_equal(a, b):
-            bad.append((path, "value", int((a != b).sum())))
-
-    jax.tree_util.tree_map_with_path(
-        lambda p, a, b: cmp(jax.tree_util.keystr(p), a, b), solo, sharded)
-    assert not bad, f"sharded tick diverged from solo oracle: {bad[:8]}"
-    # the workload actually exercised the engine across shards
-    assert int(solo.tick) == TICKS_2D
-
-
-def test_rich_dryrun_scenario():
-    """Mirror of the driver's dryrun_multichip (VERDICT r3 item #6):
-    Kademlia + LifetimeChurn + KBR/DHT tier stack sharded over the
-    8-device mesh — churn recycling, lookups, puts and gets crossing
-    shard boundaries, counters asserted inside the run.  Smaller per-
-    device node count than the driver run keeps CI time bounded."""
-    import importlib.util
-    import os
-    from pathlib import Path
-
-    spec = importlib.util.spec_from_file_location(
-        "graft_entry", Path(__file__).resolve().parent.parent
-        / "__graft_entry__.py")
-    mod = importlib.util.module_from_spec(spec)
-    # both tiers at CI size: the driver's 8x32 Chord tier alone is a
-    # quarter of an hour of 8-way sharded XLA-CPU ticks under the
-    # suite's load
-    sizes = {"OVERSIM_DRYRUN_NODES_PER_DEV": "8",
-             "OVERSIM_DRYRUN_T1_NODES_PER_DEV": "4"}
-    os.environ.update(sizes)
-    try:
-        spec.loader.exec_module(mod)
-        mod.dryrun_multichip(8)   # asserts delivery + overflow inside
-    finally:
-        for k in sizes:
-            os.environ.pop(k, None)

@@ -19,7 +19,7 @@ def _run(n, t_sim, seed=3, **pkw):
     cp = churn_mod.ChurnParams(model="none", target_num=n,
                                init_interval=0.5)
     ep = sim_mod.EngineParams(window=0.05, outbox_slots=64,
-                              transition_time=40.0, rmax=16)
+                              transition_time=40.0, rmax=16, inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     state = s.init(seed=seed)
     state = s.run_until(state, t_sim)
@@ -81,7 +81,7 @@ def test_survives_churn():
     cp = churn_mod.ChurnParams(model="lifetime", target_num=16,
                                lifetime_mean=120.0, init_interval=0.5)
     ep = sim_mod.EngineParams(window=0.05, outbox_slots=64,
-                              transition_time=40.0, rmax=16)
+                              transition_time=40.0, rmax=16, inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     state = s.init(seed=5)
     state = s.run_until(state, 240.0)

@@ -18,10 +18,11 @@ def p2pns_run():
                    num_slots=N)
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=N, init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.020, transition_time=80.0)
+    ep = sim_mod.EngineParams(window=0.050, transition_time=80.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=17)
-    st = s.run_until(st, 400.0, chunk=512)
+    st = s.run_until(st, 260.0, chunk=128)
     return s, st
 
 
@@ -52,8 +53,11 @@ def test_no_engine_losses(p2pns_run):
 def test_xmlrpc_register_resolve(p2pns_run):
     """External XML-RPC register/resolve through the P2PNS tier
     (XmlRpcInterface.h register/resolve → P2pns calls)."""
+    import jax
     from oversim_tpu.xmlrpcif import XmlRpcInterface
     s, st = p2pns_run
+    # the interface steps tick by tick: compiled (see test_gateway.py)
+    s.step = jax.jit(s.step)
     iface = XmlRpcInterface(s, st, injector_slot=0)
     assert iface.register("alice.example", 31337, ttl=900.0)
     assert iface.resolve("alice.example") == 31337

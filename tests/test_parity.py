@@ -27,16 +27,16 @@ def chord64():
     logic = ChordLogic(app=KbrTestApp(KbrTestParams(test_interval=20.0)))
     cp = churn_mod.ChurnParams(model="none", target_num=N,
                                init_interval=0.2)
-    # window 0.1 / 400 s: the tick count, not the compile, is this
+    # window 0.1 / 300 s: the tick count, not the compile, is this
     # fixture's cost on XLA-CPU (w=0.02 to 600 s was 18,944 ticks); the
     # bands below hold at any window well under the 1.5 s RPC timeout.
-    # inbox_slots 4 (engine default 8) halves the per-tick handler; a
-    # fifth message in one window is deferred a tick, never lost
+    # inbox_slots 2 (engine default 8) shrinks the per-tick handler; a
+    # third message in one window is deferred a tick, never lost
     ep = sim_mod.EngineParams(window=0.100, transition_time=150.0,
-                              inbox_slots=4)
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=42)
-    st = s.run_until(st, 400.0, chunk=128)
+    st = s.run_until(st, 300.0, chunk=128)
     return s, st
 
 

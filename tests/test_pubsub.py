@@ -16,8 +16,8 @@ def _run(n, t_sim, seed=7, **pkw):
     logic = PubSubMMOGLogic(params=PubSubParams(**pkw))
     cp = churn_mod.ChurnParams(model="none", target_num=n,
                                init_interval=0.3)
-    ep = sim_mod.EngineParams(window=0.020, outbox_slots=64,
-                              transition_time=20.0, rmax=16)
+    ep = sim_mod.EngineParams(window=0.050, outbox_slots=64,
+                              transition_time=20.0, rmax=16, inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     state = s.init(seed=seed)
     state = s.run_until(state, t_sim)
@@ -71,8 +71,8 @@ def test_survives_churn():
     logic = PubSubMMOGLogic()
     cp = churn_mod.ChurnParams(model="lifetime", target_num=12,
                                lifetime_mean=100.0, init_interval=0.3)
-    ep = sim_mod.EngineParams(window=0.020, outbox_slots=64,
-                              transition_time=20.0, rmax=16)
+    ep = sim_mod.EngineParams(window=0.050, outbox_slots=64,
+                              transition_time=20.0, rmax=16, inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     state = s.init(seed=11)
     state = s.run_until(state, 150.0)

@@ -16,7 +16,7 @@ def _sim(n=4, seed=9):
                            app=None)
     cp = churn_mod.ChurnParams(model="none", target_num=n,
                                init_interval=0.2)
-    ep = sim_mod.EngineParams(window=0.020)
+    ep = sim_mod.EngineParams(window=0.020, inbox_slots=2)
     return sim_mod.Simulation(logic, cp, engine_params=ep)
 
 

@@ -10,14 +10,15 @@ back along the reversed path — verify.ini's ChordSource config).
 Coverage here: Chord runs the full three-mode matrix (it exercises the
 shared engine: common/route.py); Koorde (de Bruijn ext riding the
 routed message), EpiChord and Broose (shift-routing ext) each prove
-their wiring on one recursive mode — those three rows are collected in
-test_route_modes_ext.py, a file of their own because `--dist loadfile`
-runs a file serially and the seven simulations are minutes of XLA-CPU
-each.  Kademlia's recursive hook
-(R/Kademlia) is covered by test_kademlia_depth, Pastry's semi-recursive
-default by test_pastry.  Each mode run drives the KBRTestApp one-way
-AND routed-RPC tests: the one-way exercises request forwarding, the
-RPC test the mode's reply transport.
+their wiring on one recursive mode — each of those three rows is
+collected in a module of its own (test_route_modes_koorde.py,
+_epichord.py, _broose.py): a module is one unit of work on one xdist
+worker (tests/conftest.py), and each simulation is minutes of XLA-CPU.
+Kademlia's recursive hook (R/Kademlia) is covered by
+test_kademlia_depth, Pastry's semi-recursive default by test_pastry.
+Each mode run drives the KBRTestApp one-way AND routed-RPC tests: the
+one-way exercises request forwarding, the RPC test the mode's reply
+transport.
 """
 
 import pytest
@@ -28,17 +29,16 @@ from oversim_tpu.common import route as rt_mod
 from oversim_tpu.engine import sim as sim_mod
 
 N = 32
-# R: the handlers are unrolled over the inbox slots; 4 (engine default
-# 8) halves the tick program on XLA-CPU.  A fifth message for one node
-# in one window is deferred a tick, never lost.
-INBOX_SLOTS = 4
-_cache = {}
+# R: the handlers are unrolled over the inbox slots, and the XLA-CPU
+# tick costs what its program holds; 2 (engine default 8).  A third
+# message for one node in one window is deferred a tick, never lost.
+INBOX_SLOTS = 2
+# measurement opens at 120 s; 80 s of it is four rounds of one test per
+# node per 20 s, 128 one-way and 128 RPC tests for the > 100 below
+RUN_S = 200.0
 
 
 def run_mode(overlay: str, mode: str, seed: int = 11):
-    key = (overlay, mode, seed)
-    if key in _cache:
-        return _cache[key]
     rcfg = rt_mod.RouteConfig(mode=mode)
     app = KbrTestApp(KbrTestParams(test_interval=20.0, rpc_test=True),
                      rcfg=rcfg)
@@ -66,19 +66,24 @@ def run_mode(overlay: str, mode: str, seed: int = 11):
                               inbox_slots=INBOX_SLOTS)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=seed)
-    st = s.run_until(st, 320.0, chunk=128)
-    _cache[key] = (s, st, s.summary(st))
-    return _cache[key]
+    st = s.run_until(st, RUN_S, chunk=128)
+    return s, st, s.summary(st)
 
 
-CONFIGS = [("chord", "semi"), ("chord", "full"), ("chord", "source")]
+MODES = ["semi", "full", "source"]
 
 
-@pytest.fixture(scope="module", params=CONFIGS,
-                ids=[f"{o}-{m}" for o, m in CONFIGS])
-def mode_run(request):
-    o, m = request.param
-    return o, m, run_mode(o, m)
+@pytest.fixture(scope="module")
+def chord_runs():
+    """Chord under each mode, built together: the latency test below
+    compares two of them."""
+    return {m: run_mode("chord", m) for m in MODES}
+
+
+@pytest.fixture(scope="module", params=MODES,
+                ids=[f"chord-{m}" for m in MODES])
+def mode_run(request, chord_runs):
+    return "chord", request.param, chord_runs[request.param]
 
 
 def test_oneway_delivery(mode_run):
@@ -108,11 +113,11 @@ def test_recursive_hops_bounded(mode_run):
     assert 1.0 <= mean <= 12.0, (overlay, mode, mean)
 
 
-def test_reply_latency_ordering():
+def test_reply_latency_ordering(chord_runs):
     """Full/source replies traverse the overlay (multi-hop) — their RPC
     RTT must exceed the semi-recursive direct reply's on average."""
-    _, _, sem = run_mode("chord", "semi")
-    _, _, src = run_mode("chord", "source")
+    _, _, sem = chord_runs["semi"]
+    _, _, src = chord_runs["source"]
     assert (src["kbr_rpc_rtt_s"]["mean"]
             > sem["kbr_rpc_rtt_s"]["mean"] * 1.2), (
         sem["kbr_rpc_rtt_s"], src["kbr_rpc_rtt_s"])
@@ -138,7 +143,7 @@ def test_prox_aware_iterative():
                               inbox_slots=INBOX_SLOTS)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=11)
-    st = s.run_until(st, 320.0, chunk=128)
+    st = s.run_until(st, RUN_S, chunk=128)
     out = s.summary(st)
     assert out["kbr_sent"] > 100, out
     assert out["kbr_delivered"] / out["kbr_sent"] > 0.95, out

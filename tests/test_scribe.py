@@ -19,10 +19,11 @@ def scribe_run():
                                  subscribe_refresh=15.0))
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=N, init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.020, transition_time=80.0)
+    ep = sim_mod.EngineParams(window=0.050, transition_time=80.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=13)
-    st = s.run_until(st, 400.0, chunk=512)
+    st = s.run_until(st, 240.0, chunk=128)
     return s, st
 
 

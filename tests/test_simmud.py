@@ -27,10 +27,10 @@ def simmud_run():
     # tree in one tick — size the pool for the burst (counted, never
     # silent; engine/pool.py docstring)
     ep = sim_mod.EngineParams(window=0.05, transition_time=80.0,
-                              pool_factor=16, outbox_slots=64)
+                              pool_factor=16, outbox_slots=64, inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=43)
-    st = s.run_until(st, 400.0, chunk=512)
+    st = s.run_until(st, 280.0, chunk=128)
     return s, st
 
 

@@ -83,7 +83,10 @@ def test_tun_bridge_packet_roundtrip():
     cp = churn_mod.ChurnParams(model="none", target_num=4,
                                init_interval=0.2)
     s = sim_mod.Simulation(logic, cp,
-                           engine_params=sim_mod.EngineParams(window=0.020))
+                           engine_params=sim_mod.EngineParams(window=0.020,
+                                                              inbox_slots=2))
+    import jax
+    s.step = jax.jit(s.step)   # the gateway steps tick by tick
     state = s.init(seed=9)
     state = s.run_until(state, 10.0)
     gw = RealtimeGateway(s, state, gw_slot=0)

@@ -28,10 +28,11 @@ def run_stack(overlay: str):
         logic = KademliaLogic(app=stack)
     cp = churn_mod.ChurnParams(model="none", target_num=N,
                                init_interval=1.0)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=30.0)
+    ep = sim_mod.EngineParams(window=0.05, transition_time=30.0,
+                              inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=17)
-    st = s.run_until(st, 260.0, chunk=512)
+    st = s.run_until(st, 200.0, chunk=128)
     return s, st, s.summary(st)
 
 

@@ -68,7 +68,8 @@ def test_trace_driven_run():
     ev = trace_mod.parse_trace(TRACE)
     cp = trace_mod.churn_from_trace(ev)
     s = sim_mod.Simulation(ChordLogic(), cp,
-                           engine_params=sim_mod.EngineParams(window=0.05))
+                           engine_params=sim_mod.EngineParams(window=0.05,
+                                                              inbox_slots=2))
     st = s.init(seed=3)
     st = s.run_until(st, 30.0, chunk=128)
     alive = np.asarray(st.alive)

@@ -19,7 +19,8 @@ def chord_state():
     cp = churn_mod.ChurnParams(model="none", target_num=N,
                                init_interval=0.5)
     s = sim_mod.Simulation(logic, cp,
-                           engine_params=sim_mod.EngineParams(window=0.05))
+                           engine_params=sim_mod.EngineParams(window=0.05,
+                                                              inbox_slots=2))
     st = s.init(seed=3)
     st = s.run_until(st, 120.0, chunk=256)
     return s, st

@@ -47,7 +47,7 @@ def make_overlay_sim(overlay, n=12):
                               lcfg=lk_mod.LookupConfig(slots=4, merge=True))
     cp = churn_mod.ChurnParams(model="lifetime", target_num=n,
                                init_interval=0.2, lifetime_mean=8.0)
-    ep = sim_mod.EngineParams(window=0.1, inbox_slots=4, pool_factor=4)
+    ep = sim_mod.EngineParams(window=0.1, inbox_slots=2, pool_factor=4)
     return sim_mod.Simulation(logic, cp, engine_params=ep)
 
 
@@ -137,7 +137,7 @@ def test_service_ingest_echo_end_to_end():
                            app=RealworldEchoApp(transform=5))
     cp = churn_mod.ChurnParams(model="none", target_num=4,
                                init_interval=0.2)
-    ep = sim_mod.EngineParams(window=0.020, ext_hold_slot=0)
+    ep = sim_mod.EngineParams(window=0.020, ext_hold_slot=0, inbox_slots=2)
     sim = sim_mod.Simulation(logic, cp, engine_params=ep)
     state = sim.run_until(sim.init(seed=9), 10.0)
 
