@@ -418,10 +418,10 @@ def run_activity_sweep(fracs, *, n, overlay, window, inbox, pool_f, slots,
         if "awake_nodes" in cur["counters"]:
             awake = (int(cur["counters"]["awake_nodes"])
                      - int(base["counters"]["awake_nodes"]))
-            deferred = (int(cur["counters"]["active_deferred"])
-                        - int(base["counters"]["active_deferred"]))
+            lanes = (int(cur["counters"]["lanes_stepped"])
+                     - int(base["counters"]["lanes_stepped"]))
             row["awake_frac"] = round(awake / max(ticks, 1) / n, 4)
-            row["deferred"] = deferred
+            row["lane_frac"] = round(lanes / max(ticks, 1) / n, 4)
         print(json.dumps(row), flush=True)
         sys.stderr.write("bench: activity %.4f -> %.3f ms/tick "
                          "(%d ticks)\n"

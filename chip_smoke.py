@@ -237,9 +237,12 @@ def main(argv=None) -> int:
         keys = ["_ticks", "_t_sim", "_alive", "kbr_sent", "kbr_delivered"]
         diff = [f"{k}: one {solo[k]} four {quad[k]}" for k in keys
                 if solo[k] != quad[k]]
+        # the mesh steps the dense sweep (mesh._gspmd_step), which
+        # leaves the awake-set plane's own tallies at 0
+        from oversim_tpu.engine.sim import SPARSE_COUNTERS
         diff += [f"_engine.{k}: one {v} four {quad['_engine'][k]}"
                  for k, v in solo["_engine"].items()
-                 if v != quad["_engine"][k]]
+                 if v != quad["_engine"][k] and k not in SPARSE_COUNTERS]
         say("four devices vs one device: "
             + ("counters equal" if not diff else "; ".join(diff)))
         failures += diff

@@ -166,7 +166,10 @@ def build_sim(ctx: EntryContext, *, inbox_impl: str = "scatter",
               tick_impl: str = "dense", active_cap: int = 0):
     """The bench-shaped Simulation every entry compiles (KbrTestApp over
     chord/kademlia, churn off — the same construction the historical
-    hlo_breakdown modes used)."""
+    hlo_breakdown modes used).  ``tick_impl`` is "dense" unless an
+    entry asks otherwise: the solo/fused/sharded pins are the dense
+    ORACLE's, and ``sparse_tick``/``sparse_chunk`` are the engine's
+    default plane for these logics (EngineParams.tick_impl "auto")."""
     from oversim_tpu import churn as churn_mod
     from oversim_tpu import telemetry as telemetry_mod
     from oversim_tpu.apps import kbrtest
@@ -333,7 +336,7 @@ def _build_fused_chunk(ctx):
 def _build_sparse_tick(ctx):
     import jax
     # a genuinely sparse lane count (cap < n) so the compiled graph has
-    # the [A]-shaped step, not a full-width alias of the dense tick
+    # the [A]-shaped round, not a full-width alias of the dense tick
     cap = max(8, ctx.n // 4)
     sim = build_sim(ctx, tick_impl="sparse", active_cap=cap)
     # donation REQUIRED by the contract: the sparse plane exists for the
@@ -537,24 +540,28 @@ DEFAULT_ENTRIES = (
         build=_build_fused_chunk),
     EntryPoint(
         name="sparse_tick",
-        doc="jit(sim.step, donate) with the sparse active-set plane "
-            "armed (tick_impl=\"sparse\"): donation required, zero "
-            "full-pool sorts, no new collectives, and a NEGATIVE "
-            "wide-gather delta vs solo_tick — the [A]-lane step must "
-            "actually replace the full [N, R, W] payload gather",
+        doc="jit(sim.step, donate) on the awake-set plane (tick_impl="
+            "\"sparse\", the engine's default for Kademlia and Chord "
+            "under KBRTestApp): donation required, zero full-pool "
+            "sorts, NO sort more than solo_tick (ONE node-step body: "
+            "the round loop's, not a sparse branch beside a dense "
+            "fallback), no new collectives, and a NEGATIVE wide-gather "
+            "delta vs solo_tick — the [A]-lane rounds must actually "
+            "replace the full [N, R, W] payload gather",
         contract=GraphContract(require_donation=True,
                                max_scatters=DEFAULT_MAX_SCATTERS + 128),
         build=_build_sparse_tick,
-        # scatter delta bounded, not negative: the A-lane scatter-backs
+        # scatter delta bounded, not negative: the A-lane write-backs
         # (logic-state leaves + outbox/event planes) are each one gated
         # drop-scatter; the REQUIRED reduction is the wide-gather one
+        # (max_sort_delta stays at its default 0)
         delta=DeltaContract(base="solo_tick", max_scatter_delta=128,
                             max_wide_gather_delta=-1)),
     EntryPoint(
         name="sparse_chunk",
-        doc="run_chunk with the sparse plane armed: donation must "
-            "survive the compacted step (the full-width state updates "
-            "in place across chunks)",
+        doc="run_chunk on the awake-set plane: donation must survive "
+            "the round loop (the full-width state updates in place "
+            "across rounds and chunks)",
         contract=GraphContract(require_donation=True,
                                max_scatters=DEFAULT_MAX_SCATTERS + 128),
         build=_build_sparse_chunk),

@@ -100,6 +100,11 @@ class KbrTestApp:
     mode dictates (rt_mod.reply) instead of direct UDP, mirroring
     BaseRpc's routingType-driven response transport."""
 
+    # with no message and neither timer due (``next_event``) the app
+    # leaves its state as it is: an overlay that is itself exact under
+    # the engine's awake-set plane stays so with this app on top
+    awake_set_exact = True
+
     def __init__(self, params: KbrTestParams = KbrTestParams(), rcfg=None):
         self.p = params
         self.rcfg = rcfg

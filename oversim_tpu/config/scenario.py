@@ -76,16 +76,21 @@ def resolve_inbox_impl(value: str, *, available: bool | None = None,
 
 
 def resolve_tick_impl(value: str) -> str:
-    """Resolve a raw ``**.tickImpl`` string to the tick plane the engine
-    runs — ``"dense"`` (the full-N vmapped sweep, the bit-identity
-    oracle) or ``"sparse"`` (the active-set plane: only awake nodes run
-    the logic step; engine/sim.py ``_step_sparse``).  Both planes are
-    pure-lax with Pallas variants, so there is no availability fallback
-    to resolve; anything else raises :class:`ScenarioError`."""
+    """Validate a raw ``**.tickImpl`` string — ``"auto"`` (the default,
+    also with no key at all: the awake-set plane for an overlay and app
+    that declare it exact, today Kademlia and Chord under KBRTestApp,
+    the dense sweep for every other), ``"dense"`` (the full-N vmapped
+    sweep, the bit-identity oracle) or ``"sparse"`` (the awake-set
+    plane by name: only awake nodes run the logic step, in rounds of
+    ``**.activeCap`` lanes, bit-identical to dense; engine/sim.py
+    ``_step_sparse``; refused for a logic without the declaration).
+    Which plane ``"auto"`` comes to is the engine's to say
+    (``sim_mod.resolve_tick_impl``); anything else raises
+    :class:`ScenarioError`."""
     impl = str(value).strip().strip('"')
-    if impl not in ("dense", "sparse"):
-        raise ScenarioError(f"unsupported tickImpl: {impl!r} "
-                            "(expected \"dense\" or \"sparse\")")
+    if impl not in ("auto", "dense", "sparse"):
+        raise ScenarioError(f"unsupported tickImpl: {impl!r} (expected "
+                            "\"auto\", \"dense\" or \"sparse\")")
     return impl
 
 
@@ -400,7 +405,7 @@ def build_simulation(ini: IniFile, config: str = "General",
     inbox_impl = resolve_inbox_impl(_value(
         ini.get("**.inboxImpl", config), "scatter"))
     tick_impl = resolve_tick_impl(_value(
-        ini.get("**.tickImpl", config), "dense"))
+        ini.get("**.tickImpl", config), "auto"))
     ep = engine_params or sim_mod.EngineParams(
         transition_time=float(_value(
             ini.get("**.transitionTime", config), 0.0)),
@@ -411,9 +416,11 @@ def build_simulation(ini: IniFile, config: str = "General",
         # oversim_tpu/kernels/) | "sort" (ORACLE-ONLY legacy full-pool
         # sort); this framework's ini extension, engine/pool.py
         inbox_impl=inbox_impl,
-        # **.tickImpl: "dense" (full-N oracle, default) | "sparse"
-        # (active-set plane); **.activeCap bounds the sparse lane count
-        # (0 = auto) — this framework's ini extension, engine/sim.py
+        # **.tickImpl: "auto" (default: the awake-set plane where the
+        # logic declares it exact, else dense) | "dense" (full-N
+        # oracle) | "sparse" (awake-set plane); **.activeCap sets the
+        # lanes a round (0 = auto; never moves a result) — this
+        # framework's ini extension, engine/sim.py
         tick_impl=tick_impl,
         active_cap=int(_value(ini.get("**.activeCap", config), 0)),
         malicious=mp,
@@ -605,7 +612,10 @@ def build_simulation(ini: IniFile, config: str = "General",
     else:
         raise ScenarioError(f"unsupported overlayType: {overlay_type!r}")
 
-    return sim_mod.Simulation(logic, cp, up, ep, underlay_module=ul_mod)
+    try:
+        return sim_mod.Simulation(logic, cp, up, ep, underlay_module=ul_mod)
+    except ValueError as e:     # tickImpl "sparse" for an undeclared logic
+        raise ScenarioError(str(e)) from None
 
 
 # -- campaign (multi-replica) configuration ---------------------------------

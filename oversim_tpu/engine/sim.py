@@ -67,18 +67,21 @@ class EngineParams:
                                    # oversim_tpu/kernels/ — also arms the
                                    # fused outbox allocator) | "sort"
                                    # (legacy full-pool sort, ORACLE-ONLY)
-    tick_impl: str = "dense"       # node-step execution: "dense" (vmapped
-                                   # full-N sweep, the bit-identity
-                                   # ORACLE) | "sparse" (active-set plane:
-                                   # compact the awake nodes into A dense
-                                   # lanes, step only those, scatter the
-                                   # results back — tick cost scales with
-                                   # traffic, not N)
-    active_cap: int = 0            # A — sparse active-set lane count;
-                                   # 0 = auto (min(n, max(64, n // 8))).
-                                   # Awake nodes past the cap DEFER to
-                                   # the next tick (never dropped; see
-                                   # _phase_active_compact)
+    tick_impl: str = "auto"        # node-step execution: "sparse" (the
+                                   # awake-set plane: the awake nodes
+                                   # are stepped in rounds of A
+                                   # compacted lanes, every one in the
+                                   # tick it is due — bit-identical to
+                                   # dense at any load) | "dense"
+                                   # (vmapped full-N sweep, the ORACLE)
+                                   # | "auto" (default: sparse where the
+                                   # logic declares ``awake_set_exact``,
+                                   # dense for every other logic)
+    active_cap: int = 0            # A — lanes a round of the awake-set
+                                   # plane steps; 0 = auto (a rule of N
+                                   # alone, Simulation.acap: N/32, at
+                                   # least 32).  Moves the cost of a
+                                   # tick, never its result
     outbox_slots: int = 16         # MOUT — msgs emitted per node per tick
     pool_factor: int = 8           # P = pool_factor * N message slots
     rmax: int = 16                 # node-list payload width
@@ -123,12 +126,34 @@ class SimState:
 ENGINE_COUNTERS = ("queue_lost", "bit_error_lost", "dest_unavailable_lost",
                    "partition_lost", "pool_overflow", "outbox_overflow",
                    "inbox_deferred")
-# sparse-plane accounting, carried in SimState.counters ONLY when
-# tick_impl == "sparse" (the dense SimState layout stays bit-identical
-# to the pre-sparse engine): cumulative awake-node and active-inbox-
-# destination lane counts per run, plus the count of awake nodes
-# deferred past ``active_cap`` (deferral, never loss)
-SPARSE_COUNTERS = ("awake_nodes", "active_dst", "active_deferred")
+# awake-set accounting, carried in SimState.counters ONLY under the
+# awake-set plane (the dense SimState layout stays bit-identical to the
+# pre-sparse engine), cumulative over the run: awake nodes, nodes with
+# inbox traffic, and lanes stepped (rounds x A — what the node step
+# paid, where the dense sweep pays ticks x N)
+SPARSE_COUNTERS = ("awake_nodes", "active_dst", "lanes_stepped")
+
+
+def resolve_tick_impl(tick_impl: str, logic) -> str:
+    """The plane a Simulation's tick runs: ``"auto"`` gives the
+    awake-set plane to a logic that declares ``awake_set_exact`` (a
+    node with no inbox message, no due ``next_event`` and no churn is a
+    fixed point of its ``step``, pinned against the dense sweep by an
+    identity test) and the dense sweep to every other; ``"sparse"``
+    asked by name for a logic without the declaration is refused, since
+    nothing says its idle nodes may be skipped."""
+    exact = bool(getattr(logic, "awake_set_exact", False))
+    if tick_impl == "auto":
+        return "sparse" if exact else "dense"
+    if tick_impl not in ("dense", "sparse"):
+        raise ValueError(f"unsupported tick_impl: {tick_impl!r} "
+                         "(expected \"auto\", \"dense\" or \"sparse\")")
+    if tick_impl == "sparse" and not exact:
+        raise ValueError(
+            f"tick_impl=\"sparse\" needs a logic that declares "
+            f"awake_set_exact; {type(logic).__name__} does not (no "
+            "identity test pins its idle nodes as fixed points)")
+    return tick_impl
 
 
 def _dedupe_buffers(state):
@@ -180,24 +205,44 @@ class Simulation:
         self.ep = engine_params or EngineParams()
         self.n = churn_params.num_slots
         self.spec = logic.key_spec
+        # "dense" | "sparse": what ep.tick_impl comes to for this logic
+        self.tick_impl = resolve_tick_impl(self.ep.tick_impl, logic)
+
+    def dense_unless_asked(self) -> "Simulation":
+        """This deployment with ``tick_impl="auto"`` settled as the
+        dense sweep: for a caller that vmaps the step (under vmap the
+        round loop runs every replica for the busiest one's rounds) or
+        lets GSPMD partition it (across node shards the compaction's
+        gathers are collectives)."""
+        if self.ep.tick_impl != "auto" or self.tick_impl == "dense":
+            return self
+        return Simulation(
+            self.logic, self.cp, self.up,
+            dataclasses.replace(self.ep, tick_impl="dense"), self.ul)
 
     @property
     def counter_names(self) -> tuple:
         """Counter keys carried in SimState.counters for this engine
-        config (the sparse plane rides its active-set accounting along;
-        the dense layout is untouched)."""
-        if self.ep.tick_impl == "sparse":
+        config (the awake-set plane rides its accounting along; the
+        dense layout is untouched)."""
+        if self.tick_impl == "sparse":
             return ENGINE_COUNTERS + SPARSE_COUNTERS
         return ENGINE_COUNTERS
 
     @property
     def acap(self) -> int:
-        """A — static sparse active-set capacity (lanes per tick).
-        ``active_cap=0`` auto-sizes: full-N at small n (bit-identity is
-        then unconditional), N/8 once n outgrows 8*64."""
+        """A — static lane count of one round of the awake-set plane.
+        ``active_cap=0`` sizes it from N alone: full-N up to 32 nodes,
+        N/32 once n outgrows 32*32.  On the chip a round costs by its
+        lanes (43 us a lane at N=1000 and N=4096 alike) and next to
+        nothing besides, so A wants to be no larger than a steady
+        tick's awake set: under KBRTestApp's upstream interval that is
+        2.2 to 2.4% of the nodes at either N (3.3% at most), and up to
+        35% while a network fills at 205 joins a second, which then
+        takes a dozen rounds a tick (PERF.md, PR 27)."""
         if self.ep.active_cap > 0:
             return min(self.ep.active_cap, self.n)
-        return min(self.n, max(64, self.n // 8))
+        return min(self.n, max(32, self.n // 32))
 
     # -- init ---------------------------------------------------------------
 
@@ -379,10 +424,11 @@ class Simulation:
 
     def _make_ctx(self, s: SimState, t_next, t_end, alive, pre_killed,
                   churn_state, node_keys, ul_state, logic_state, *, ov=None):
-        """Tick context shared by the dense and sparse node-step phases.
+        """Tick context shared by the dense and awake-set node-step
+        phases.
 
         The Ctx is always FULL-WIDTH — node handlers index the ready/
-        bootstrap vectors by true node id, so the sparse path can
+        bootstrap vectors by true node id, so the awake-set plane can
         broadcast the same ctx over its compacted lanes.  Returns
         ``(ctx, node_part, glob, measuring)``."""
         n, ep, up, cp = self.n, self.ep, self.up, self.cp
@@ -450,11 +496,11 @@ class Simulation:
         return (logic_state, out_fields, out_valid, out_overflow, events,
                 measuring)
 
-    # -- sparse active-set plane (tick_impl="sparse") -----------------------
+    # -- awake-set plane (tick_impl="sparse") -------------------------------
 
     def _phase_inbox_select_sparse(self, s: SimState, t_end, alive):
-        """Sparse phase 3: selection WITHOUT the full [N, R, W] payload
-        gather — the sparse step gathers only the A compacted rows.
+        """Awake-set phase 3: selection WITHOUT the full [N, R, W]
+        payload gather — each round gathers only its A compacted rows.
         Under ``inbox_impl="pallas"`` the fused kernel runs in
         select-only mode (occupancy-bounded walk, no gather pass)."""
         if self.ep.inbox_impl == "pallas":
@@ -465,93 +511,110 @@ class Simulation:
         return self._phase_inbox_select(s, t_end, alive)
 
     def _phase_active_compact(self, s: SimState, t_end, alive, pre_killed,
-                              logic_state, inbox, delivered):
-        """Sparse phase 4a: compact the awake node set into A dense
-        lanes (the ``pool.alloc`` cumsum-compaction idiom; the kernel
-        plane uses the serial-counting compaction in
-        kernels/outbox.py).
+                              logic_state, inbox):
+        """Awake-set phase 4a: the awake nodes' indices in ascending
+        order (the ``pool.alloc`` cumsum-compaction idiom; the kernel
+        plane uses the serial-counting compaction in kernels/outbox.py).
 
         A node is awake when it has inbox traffic this window
         (``inbox[:, 0] >= 0`` — the selectors fill slot 0 first), a due
         local timer (``logic.next_event < t_end`` — the same oracle the
         event horizon trusts), or churn touched its slot this tick
         (created, killed, or pre-killed).  Every other node is an exact
-        fixed point of ``_node_step``, pinned bit-for-bit against the
-        dense oracle by tests/test_zz_sparse.py and
-        scripts/sparse_gate.py.
+        fixed point of ``_node_step`` for a logic that declares
+        ``awake_set_exact``, pinned bit-for-bit against the dense oracle
+        by tests/test_zz_sparse.py and scripts/sparse_gate.py.
 
-        Awake nodes past the cap DEFER, never drop: their timers stay
-        due, their selected messages revert to "not delivered" (the
-        R-overflow retention mechanism), and the compaction walk starts
-        at a per-tick rotating offset so persistent overload
-        round-robins the active set instead of starving the tail.
-        Returns ``(act [A] i32 lane->node map (sentinel n), delivered
-        [P] bool trimmed to stepped destinations, active
-        (awake, active_dst, deferred) i64 tallies)``."""
+        Returns ``(order, rounds, active)``: ``order`` i32, ceil(N/A)
+        rounds of A lanes, the awake nodes first and sentinels (n and
+        up) after them, ascending and without repeats throughout, so a
+        round's write-back may tell XLA so; ``rounds`` i32, how many of
+        them hold an awake node; ``active`` the i64 tallies (awake
+        nodes, nodes with inbox traffic, lanes the rounds step)."""
         n, cap = self.n, self.acap
+        lanes = -(-n // cap) * cap
         has_msg = inbox[:, 0] >= 0
         timer_due = alive & (self.logic.next_event(logic_state) < t_end)
         churned = (alive ^ s.alive) | (pre_killed & alive)
         awake = has_msg | timer_due | churned
-        n_awake = jnp.sum(awake.astype(I32))
-        off = (s.tick % n).astype(I32)
-        perm = (jnp.arange(n, dtype=I32) + off) % n
-        aw_r = awake[perm]
+        node_idx = jnp.arange(n, dtype=I32)
+        sentinels = n + jnp.arange(lanes, dtype=I32)
         if self.ep.inbox_impl == "pallas":
             from oversim_tpu import kernels
-            act, _cnt = kernels.outbox.compact_indices(aw_r, perm, cap,
-                                                       sentinel=n)
+            order, n_awake = kernels.outbox.compact_indices(
+                awake, node_idx, lanes, sentinel=n)
+            order = jnp.where(order < n, order, sentinels)
         else:
-            aw_i = aw_r.astype(I32)
+            aw_i = awake.astype(I32)
             rank = jnp.cumsum(aw_i) - aw_i
-            act = jnp.full((cap,), n, I32).at[
-                jnp.where(aw_r & (rank < cap), rank, cap)].set(
-                    perm, mode="drop")
-        taken = jnp.zeros((n,), bool).at[act].set(True, mode="drop")
-        # messages selected for a deferred destination stay pooled with
-        # their original timestamps and are re-offered next tick
-        delivered = delivered & taken[jnp.clip(s.pool.dst, 0, n - 1)]
+            n_awake = jnp.sum(aw_i)
+            order = sentinels.at[jnp.where(awake, rank, lanes)].set(
+                node_idx, mode="drop")
+        rounds = ((n_awake + (cap - 1)) // cap).astype(I32)
         active = (n_awake.astype(I64),
                   jnp.sum(has_msg.astype(I32)).astype(I64),
-                  (n_awake - jnp.minimum(n_awake, cap)).astype(I64))
-        return act, delivered, active
+                  rounds.astype(I64) * cap)
+        return order, rounds, active
 
     def _phase_sparse_step(self, s: SimState, t_next, t_end, alive,
                            pre_killed, churn_state, node_keys, ul_state,
-                           logic_state, inbox, act, r_nodes, *, ov=None):
-        """Sparse phase 4b: the vmapped logic step over the COMPACTED
-        [A] lane set only, scattered back into full-width state.
+                           logic_state, inbox, order, rounds, r_nodes, *,
+                           ov=None):
+        """Awake-set phase 4b: the vmapped logic step over the awake
+        nodes only, A compacted lanes a round, until every awake node
+        has been stepped — once, in this tick, with the tick's one Ctx
+        and its own rng stream, so the result is the dense sweep's bit
+        for bit whatever the load and whatever A.  An idle tick runs no
+        round at all.
 
-        Sentinel lanes (``act == n``) clamp to node n-1 for the compute
-        and drop at every scatter-back; the outbox/event bases are
-        zeros, which is write-equivalent to the dense path's idle-lane
-        junk because every downstream consumer (send_batch, alloc,
-        stats.record) is mask-gated."""
-        n = self.n
+        The program holds ONE copy of the node step (the loop body).
+        Sentinel lanes (``order >= n``) clamp to node n-1 for the
+        compute and drop at every write-back; the outbox/event bases
+        are zeros, which is write-equivalent to the dense path's
+        idle-lane junk because every downstream consumer (send_batch,
+        alloc, stats.record) is mask-gated."""
+        n, cap = self.n, self.acap
         logic = self.logic
         ctx, node_part, glob, measuring = self._make_ctx(
             s, t_next, t_end, alive, pre_killed, churn_state, node_keys,
             ul_state, logic_state, ov=ov)
-        act_c = jnp.minimum(act, n - 1)
-        lane_ok = act < n
-        inbox_act = jnp.where(lane_ok[:, None], inbox[act_c], -1)
-        gblk = s.pool.blk[jnp.maximum(inbox_act, 0)]       # [A, R, W]
-        msgs = self._msgs_from_block(s, t_next, inbox_act, gblk)
-        part_act = jax.tree_util.tree_map(lambda x: x[act_c], node_part)
-        node_rngs = self._node_rngs(r_nodes, s.tick, act_c.astype(jnp.int_))
 
-        part_act, out_f, out_v, out_o, ev = jax.vmap(
-            self._node_step, in_axes=(None, 0, 0, 0, 0))(
+        def step_lanes(part, act):
+            act_c = jnp.minimum(act, n - 1)
+            inbox_act = jnp.where((act < n)[:, None], inbox[act_c], -1)
+            gblk = s.pool.blk[jnp.maximum(inbox_act, 0)]       # [A, R, W]
+            msgs = self._msgs_from_block(s, t_next, inbox_act, gblk)
+            part_act = jax.tree_util.tree_map(lambda x: x[act_c], part)
+            node_rngs = self._node_rngs(r_nodes, s.tick,
+                                        act_c.astype(jnp.int_))
+            return jax.vmap(self._node_step, in_axes=(None, 0, 0, 0, 0))(
                 ctx, part_act, msgs, node_rngs, act_c)
 
-        scat = lambda base, upd: base.at[act].set(upd, mode="drop")  # noqa: E731
-        node_part = jax.tree_util.tree_map(scat, node_part, part_act)
-        full = lambda x: jnp.zeros((n,) + x.shape[1:], x.dtype)  # noqa: E731
-        out_fields = jax.tree_util.tree_map(
-            lambda x: scat(full(x), x), out_f)
-        out_valid = scat(full(out_v), out_v)
-        out_overflow = scat(full(out_o), out_o)
-        events = jax.tree_util.tree_map(lambda x: scat(full(x), x), ev)
+        # full-width zero bases for what a round writes besides the rows
+        outs0 = jax.tree_util.tree_map(
+            lambda x: jnp.zeros((n,) + x.shape[1:], x.dtype),
+            jax.eval_shape(step_lanes, node_part, order[:cap])[1:])
+
+        def one_round(carry):
+            r, part, outs = carry
+            act = jax.lax.dynamic_slice(order, (r * cap,), (cap,))
+            part_act, *outs_act = step_lanes(part, act)
+            # a row scatter: ``order`` ascends without repeats, its
+            # sentinels too.  On the chip it costs what the inverse form
+            # (a [N] lane-of-node map, a gather and a select) does, and
+            # laying the leaves side by side for one wide scatter buys
+            # 2% at N=4096 (PERF.md, PR 27)
+            write = lambda base, upd: base.at[act].set(  # noqa: E731
+                upd, mode="drop", indices_are_sorted=True,
+                unique_indices=True)
+            return (r + 1,
+                    jax.tree_util.tree_map(write, part, part_act),
+                    jax.tree_util.tree_map(write, outs, tuple(outs_act)))
+
+        _, node_part, outs = jax.lax.while_loop(
+            lambda carry: carry[0] < rounds, one_round,
+            (jnp.zeros((), I32), node_part, outs0))
+        out_fields, out_valid, out_overflow, events = outs
 
         logic_state = (logic.merge(node_part, glob)
                        if hasattr(logic, "merge") else node_part)
@@ -605,13 +668,11 @@ class Simulation:
             (jnp.sum(s.pool.valid & (s.pool.t_deliver < t_end)) -
              jnp.sum(delivered | to_dead)).astype(jnp.int64))
         if active is not None:
-            # sparse-plane accounting (tick_impl="sparse" only): lane
-            # tallies from _phase_active_compact — cumulative like the
-            # loss counters, so the telemetry rings carry the series
-            n_awake, active_dst, n_deferred = active
-            counters["awake_nodes"] += n_awake
-            counters["active_dst"] += active_dst
-            counters["active_deferred"] += n_deferred
+            # awake-set accounting (SPARSE_COUNTERS): the tallies of
+            # _phase_active_compact — cumulative like the loss counters,
+            # so the telemetry rings carry the series
+            for name, tally in zip(SPARSE_COUNTERS, active):
+                counters[name] += tally
 
         # telemetry sample point (telemetry.py): END-of-tick snapshot of
         # the accumulators into the ring buffers, gated on the sampling
@@ -645,7 +706,7 @@ class Simulation:
         ``app.*`` key a handler reads via ``Ctx.ov_get``.  ``None``
         (the default everywhere) keeps the trace bit-identical to the
         pre-campaign engine."""
-        if self.ep.tick_impl == "sparse":
+        if self.tick_impl == "sparse":
             return self._step_sparse(s, ov=ov)
         t_next, t_end, rngs = self._phase_horizon(s, ov=ov)
         (rng, r_churn, r_keys, r_reset, r_nodes, r_mig, r_send) = rngs
@@ -663,13 +724,12 @@ class Simulation:
             out_valid, out_overflow, events, measuring)
 
     def _step_sparse(self, s: SimState, *, ov=None) -> SimState:
-        """One sparse tick: horizon/churn/alloc phases are shared with
-        the dense oracle; the inbox skips the full-width gather, the
-        awake set compacts into A lanes, and only those lanes run
-        ``_node_step``.  Bit-identical to ``step`` whenever the awake
-        count fits ``active_cap`` (unconditional at the auto cap for
-        n <= 64); beyond the cap, deterministic rotation-fair
-        deferral."""
+        """One tick of the awake-set plane: horizon/churn/alloc phases
+        are shared with the dense oracle; the inbox skips the full-width
+        gather, and ``_node_step`` runs over the awake nodes only, in
+        rounds of A compacted lanes.  Bit-identical to the dense
+        ``step`` at any load and any ``active_cap`` (but for the
+        SPARSE_COUNTERS it carries): nothing is ever deferred."""
         t_next, t_end, rngs = self._phase_horizon(s, ov=ov)
         (rng, r_churn, r_keys, r_reset, r_nodes, r_mig, r_send) = rngs
         (churn_state, alive, pre_killed, node_keys, ul_state,
@@ -677,12 +737,12 @@ class Simulation:
                                           r_reset, r_mig, ov=ov)
         inbox, delivered, to_dead = self._phase_inbox_select_sparse(
             s, t_end, alive)
-        act, delivered, active = self._phase_active_compact(
-            s, t_end, alive, pre_killed, logic_state, inbox, delivered)
+        order, rounds, active = self._phase_active_compact(
+            s, t_end, alive, pre_killed, logic_state, inbox)
         (logic_state, out_fields, out_valid, out_overflow, events,
          measuring) = self._phase_sparse_step(
             s, t_next, t_end, alive, pre_killed, churn_state, node_keys,
-            ul_state, logic_state, inbox, act, r_nodes, ov=ov)
+            ul_state, logic_state, inbox, order, rounds, r_nodes, ov=ov)
         return self._phase_alloc_stats(
             s, t_end, rng, r_send, alive, pre_killed, node_keys, ul_state,
             churn_state, logic_state, delivered, to_dead, out_fields,

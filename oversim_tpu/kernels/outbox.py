@@ -156,7 +156,7 @@ def compact_indices(mask, vals, cap: int, sentinel: int,
     active-set compaction (engine/sim.py ``_phase_active_compact``):
     lane k holds ``vals[i]`` for the k-th set ``mask`` bit, ``sentinel``
     beyond the active count; ``count`` is the total set-bit count (may
-    exceed ``cap`` — overflowed entries defer to the next tick).
+    exceed ``cap``; the engine passes a cap that holds every node).
     Bit-identical to the cumsum-compaction idiom from ``pool.alloc``,
     pinned in tests/test_kernels.py."""
     from oversim_tpu import kernels

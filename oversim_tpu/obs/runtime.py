@@ -70,21 +70,22 @@ class RunObserver:
                                    "simulated seconds at the last drain")
         self.alive = r.gauge("oversim_alive_nodes",
                              "alive overlay nodes at the last drain")
-        # sparse active-set plane (EngineParams.tick_impl="sparse"):
-        # cumulative per-tick active-set sizes — the live view of "tick
-        # cost scales with traffic, not N".  Only set when the engine
-        # carries the sparse counters (dense runs never touch them).
+        # awake-set plane (engine/sim.py SPARSE_COUNTERS): cumulative
+        # per-tick awake-set sizes and the lanes the node step paid for
+        # them — the live view of "tick cost scales with traffic, not
+        # N".  Only set when the engine carries the counters (dense
+        # runs never touch them).
         self.awake_nodes = r.gauge(
             "oversim_sparse_awake_nodes",
-            "cumulative awake nodes summed over ticks (sparse tick)")
+            "cumulative awake nodes summed over ticks (awake-set tick)")
         self.active_dst = r.gauge(
             "oversim_sparse_active_dst",
             "cumulative due-message destinations summed over ticks "
-            "(sparse tick)")
-        self.active_deferred = r.gauge(
-            "oversim_sparse_active_deferred",
-            "cumulative awake nodes deferred past the active_cap "
-            "(sparse tick; nonzero means the cap clipped a window)")
+            "(awake-set tick)")
+        self.lanes_stepped = r.gauge(
+            "oversim_sparse_lanes_stepped",
+            "cumulative node-step lanes (rounds x active_cap) summed "
+            "over ticks (awake-set tick; the dense sweep pays ticks x N)")
         self.window_wall = r.histogram(
             "oversim_window_wall_seconds",
             "wall seconds per drained window",
@@ -224,7 +225,7 @@ class RunObserver:
         if "awake_nodes" in eng:
             self.awake_nodes.set(eng["awake_nodes"])
             self.active_dst.set(eng.get("active_dst", 0))
-            self.active_deferred.set(eng.get("active_deferred", 0))
+            self.lanes_stepped.set(eng.get("lanes_stepped", 0))
         if self._last_wall_s is not None and wall_s >= self._last_wall_s:
             self.window_wall.observe(wall_s - self._last_wall_s)
         self._last_wall_s = wall_s

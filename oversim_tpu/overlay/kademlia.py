@@ -175,6 +175,16 @@ class KademliaLogic:
 
     # -- engine interface ---------------------------------------------------
 
+    @property
+    def awake_set_exact(self) -> bool:
+        """The engine may skip this logic's idle nodes (engine/sim.py
+        ``resolve_tick_impl``): a node with no inbox message, no due
+        ``next_event`` and no churn is a fixed point of ``step``, for
+        the overlay's own part and, where the app says the same of
+        itself, for the app's.  Pinned against the dense sweep by
+        tests/test_zz_sparse.py."""
+        return bool(getattr(self.app, "awake_set_exact", False))
+
     def split(self, st: KademliaState):
         return dataclasses.replace(st, app_glob=None), st.app_glob
 

@@ -309,6 +309,17 @@ def jit_campaign_run_until(camp, mesh: Mesh, chunk: int = 64,
                    donate_argnums=(0,) if donate else ())
 
 
+def _gspmd_step(sim):
+    """The step the GSPMD builders below partition: the dense sweep,
+    unless the Simulation asks for the awake-set plane by name.  That
+    plane's compaction gathers rows from the whole node axis, so across
+    shards every round is collectives; it has never been measured on a
+    mesh.  The state keeps the layout of ``sim.init()``: where that
+    carries the awake-set counters, the dense step passes them through
+    at 0."""
+    return sim.dense_unless_asked().step
+
+
 def jit_step(sim, mesh: Mesh, donate: bool = True):
     """jit the one-tick step with sharded in/out state.
 
@@ -317,7 +328,7 @@ def jit_step(sim, mesh: Mesh, donate: bool = True):
     """
     example = sim.init()
     shardings = state_shardings(example, mesh)
-    return jax.jit(sim.step, in_shardings=(shardings,),
+    return jax.jit(_gspmd_step(sim), in_shardings=(shardings,),
                    out_shardings=shardings,
                    donate_argnums=(0,) if donate else ())
 
@@ -327,10 +338,11 @@ def jit_run(sim, mesh: Mesh, n_ticks: int, donate: bool = True):
     whole run — the multi-chip equivalent of Simulation.run_chunk)."""
     example = sim.init()
     shardings = state_shardings(example, mesh)
+    step = _gspmd_step(sim)
 
     def run(s):
         def body(carry, _):
-            return sim.step(carry), None
+            return step(carry), None
         s, _ = jax.lax.scan(body, s, None, length=n_ticks)
         return s
 
@@ -350,6 +362,7 @@ def jit_run_until(sim, mesh: Mesh, chunk: int = 64, donate: bool = True):
     """
     example = sim.init()
     shardings = state_shardings(example, mesh)
+    step = _gspmd_step(sim)
 
     def run(s, target_ns):
         def cond(carry):
@@ -357,7 +370,7 @@ def jit_run_until(sim, mesh: Mesh, chunk: int = 64, donate: bool = True):
 
         def body(carry):
             def sbody(c, _):
-                return sim.step(c), None
+                return step(c), None
             c, _ = jax.lax.scan(sbody, carry, None, length=chunk)
             return c
 

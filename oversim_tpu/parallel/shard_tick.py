@@ -42,8 +42,11 @@ Replicated (full-width rng draws and cross-indexed small vectors):
   * `alive`/`node_keys`/`malicious` [N] — cross-indexed by every
     handler through the full-width Ctx (`ctx.keys[slot]`).
 
-The sparse active-set plane (tick_impl="sparse") compacts across the
-whole node axis and is NOT supported here — `ShardedSim` refuses it.
+The awake-set plane (tick_impl="sparse") compacts across the whole
+node axis and is NOT supported here: `ShardedSim` refuses a Simulation
+whose tick is that plane, by name or by the engine's default ("auto")
+for its logic; the caller builds it with `tick_impl="dense"` (the
+sharded state's layout is the dense one).
 """
 
 from __future__ import annotations
@@ -103,10 +106,13 @@ class ShardedSim:
         if mesh_mod.NODE_AXIS not in mesh.axis_names:
             raise ValueError(f"mesh {mesh.axis_names} has no "
                              f"{mesh_mod.NODE_AXIS!r} axis")
-        if sim.ep.tick_impl != "dense":
+        if sim.tick_impl != "dense":
             raise ValueError(
-                "sharded tick requires tick_impl='dense': the sparse "
-                "active-set plane compacts across the whole node axis")
+                "sharded tick requires EngineParams(tick_impl='dense'), "
+                f"asked by name (this Simulation's is {sim.ep.tick_impl!r}"
+                f", which runs {sim.tick_impl!r} for "
+                f"{type(sim.logic).__name__}): the awake-set plane "
+                "compacts across the whole node axis")
         if sim.ep.inbox_impl not in ("scatter", "pallas"):
             raise ValueError(
                 f"sharded tick supports inbox_impl 'scatter' or 'pallas', "

@@ -23,8 +23,8 @@ instead of silently attributing the kernel time to neither half.
 Under the sparse plane (``tick_impl="sparse"``) the layout is
 ``horizon / churn / inbox_select / active_compact / sparse_step /
 alloc_stats``: selection never gathers the full payload block,
-``active_compact`` packs the awake set into A lanes, and
-``sparse_step`` is the logic sweep over those lanes only (the report
+``active_compact`` orders the awake set into rounds of A lanes, and
+``sparse_step`` is the logic sweep over those rounds only (the report
 carries ``tick_impl`` so artifact readers can tell the layouts apart).
 
 Each phase is jitted SEPARATELY and timed with ``block_until_ready``
@@ -108,12 +108,12 @@ def _jit_phases(sim):
             lambda s, te, alive: sim._phase_inbox_select_sparse(
                 s, te, alive)),
         "active_compact": jax.jit(
-            lambda s, te, alive, pk, lg, inbox, dlv:
-            sim._phase_active_compact(s, te, alive, pk, lg, inbox, dlv)),
+            lambda s, te, alive, pk, lg, inbox:
+            sim._phase_active_compact(s, te, alive, pk, lg, inbox)),
         "sparse_step": jax.jit(
-            lambda s, tn, te, alive, pk, cs, nk, ul, lg, inbox, act, rn:
-            sim._phase_sparse_step(s, tn, te, alive, pk, cs, nk, ul, lg,
-                                   inbox, act, rn)),
+            lambda s, tn, te, alive, pk, cs, nk, ul, lg, inbox, order, nr,
+            rn: sim._phase_sparse_step(s, tn, te, alive, pk, cs, nk, ul,
+                                       lg, inbox, order, nr, rn)),
         "alloc_stats_sparse": jax.jit(
             lambda s, te, rng, rs, alive, pk, nk, ul, cs, lg, dlv, dead,
             of, ov, oo, ev, ms, act: sim._phase_alloc_stats(
@@ -148,9 +148,9 @@ def profile_ticks(sim, s, n_ticks: int = 4, fused_reference: bool = True,
     pays all phase compiles and is EXCLUDED from the averages.
     """
     fns = _jit_phases(sim)
-    sparse = sim.ep.tick_impl == "sparse"
+    sparse = sim.tick_impl == "sparse"
     fused_inbox = sim.ep.inbox_impl == "pallas" and not sparse
-    phases = phases_for(sim.ep.inbox_impl, sim.ep.tick_impl)
+    phases = phases_for(sim.ep.inbox_impl, sim.tick_impl)
     totals = {p: 0.0 for p in phases}
     compile_s = 0.0
     measured = 0
@@ -179,9 +179,9 @@ def profile_ticks(sim, s, n_ticks: int = 4, fused_reference: bool = True,
             dt_is = time.perf_counter() - t0
 
             t0 = time.perf_counter()
-            act, delivered, active = jax.block_until_ready(
+            order, rounds, active = jax.block_until_ready(
                 fns["active_compact"](s, t_end, alive, pre_killed,
-                                      logic_state, inbox, delivered))
+                                      logic_state, inbox))
             inbox_dts = (dt_is, time.perf_counter() - t0)
 
             t0 = time.perf_counter()
@@ -189,7 +189,8 @@ def profile_ticks(sim, s, n_ticks: int = 4, fused_reference: bool = True,
              measuring) = jax.block_until_ready(
                 fns["sparse_step"](s, t_next, t_end, alive, pre_killed,
                                    churn_state, node_keys, ul_state,
-                                   logic_state, inbox, act, r_nodes))
+                                   logic_state, inbox, order, rounds,
+                                   r_nodes))
             dt_n = time.perf_counter() - t0
 
             t0 = time.perf_counter()
@@ -251,7 +252,7 @@ def profile_ticks(sim, s, n_ticks: int = 4, fused_reference: bool = True,
         "metric": "tick_phase_breakdown",
         "n_ticks": measured,
         "inbox_impl": sim.ep.inbox_impl,
-        "tick_impl": sim.ep.tick_impl,
+        "tick_impl": sim.tick_impl,
         "kernel_plane": fused_inbox,
         "phase_ms_per_tick": phase_ms,
         "phase_frac": {p: round(totals[p] / max(sum(totals.values()), 1e-12),

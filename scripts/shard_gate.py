@@ -61,8 +61,10 @@ def _build(inbox_impl, n=16):
 
     cp = churn_mod.ChurnParams(model="lifetime", target_num=n,
                                init_interval=0.2, lifetime_mean=8.0)
+    # dense by name: ShardedSim refuses the awake-set plane, which the
+    # engine's default gives Chord under KBRTestApp
     ep = sim_mod.EngineParams(window=0.1, inbox_slots=4, pool_factor=4,
-                              inbox_impl=inbox_impl)
+                              inbox_impl=inbox_impl, tick_impl="dense")
     return sim_mod.Simulation(ChordLogic(), cp, engine_params=ep)
 
 

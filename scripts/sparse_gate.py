@@ -1,4 +1,4 @@
-"""Sparse active-set tick gate (run_suite.sh; engine/sim.py, ISSUE 16).
+"""Awake-set tick gate (run_suite.sh; engine/sim.py, ISSUE 16, ISSUE 27).
 
 Two checks on a small chord scenario under LifetimeChurn, CPU-only:
 
@@ -8,7 +8,10 @@ Two checks on a small chord scenario under LifetimeChurn, CPU-only:
      same rng consumption, same churn cascade — for BOTH inbox impls
      (scatter, and the fused kernel plane in interpret mode when
      available).  The sparse-only counters are stripped before the
-     compare (the dense layout never carries them).
+     compare (the dense layout never carries them).  Since PR 27 the
+     plane steps awake nodes past A in further rounds of the same tick,
+     so identity holds at any active_cap; tests/test_zz_sparse.py pins
+     the several-rounds cases.
   2. GATHER CENSUS: the compiled sparse tick must carry FEWER
      full-width gathers (result leading dim N or P —
      hlo_text.gather_counts) than the dense tick: compaction must

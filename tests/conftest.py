@@ -86,7 +86,8 @@ jax.config.update("jax_enable_compilation_cache", False)
 COST_ORDER = (
     "test_route_modes.py", "test_route_modes_epichord.py",
     "test_mesh_2d.py", "test_kernels.py", "test_route_modes_broose.py",
-    "test_mesh_dryrun.py", "test_mesh.py", "test_vmap_campaign.py",
+    "test_mesh_dryrun.py", "test_mesh.py", "test_zz_sparse_rounds.py",
+    "test_vmap_campaign.py",
     "test_pastry_multihop.py", "test_engine.py", "test_epichord.py",
     "test_faults.py", "test_route_modes_koorde.py", "test_nice.py",
     "test_kademlia_depth.py", "test_ncs.py", "test_parity.py",

@@ -143,26 +143,27 @@ def test_untelemetried_fake_state_fetch_has_no_telemetry_key():
 
 
 def test_sparse_counters_ride_the_single_fetch():
-    """The sparse active-set counters are ordinary SimState.counters
-    leaves: they come back inside the one per-window device_get and
-    land in the summary's _engine dict — no extra sync — and
-    active_deferred matches the bench health gate's ``"deferred" in k``
-    unhealthy-counter pattern (a capped window can't post a record)."""
+    """The awake-set counters are ordinary SimState.counters leaves:
+    they come back inside the one per-window device_get and land in the
+    summary's _engine dict — no extra sync — and none of them matches
+    the bench health gate's unhealthy-counter pattern (the plane never
+    defers: a busy tick takes more rounds, and ``lanes_stepped`` says
+    how many)."""
     st = FakeState(tick=8)
     st.counters = dict(st.counters, awake_nodes=np.int64(37),
                        active_dst=np.int64(21),
-                       active_deferred=np.int64(5))
+                       lanes_stepped=np.int64(64))
     leaves = bench._fetch_window_leaves(st)
     assert leaves["counters"]["awake_nodes"] == 37
     out = bench._summary_from_leaves(leaves)
     assert out["_engine"]["awake_nodes"] == 37
     assert out["_engine"]["active_dst"] == 21
+    assert out["_engine"]["lanes_stepped"] == 64
     # the health gate flags any positive-delta counter whose name
-    # contains "overflow" or "deferred" — deferral is in its net
+    # contains "overflow" or "deferred"
     flagged = {k for k in out["_engine"]
                if "overflow" in k or "deferred" in k}
-    assert "active_deferred" in flagged
-    assert "awake_nodes" not in flagged
+    assert not flagged & {"awake_nodes", "active_dst", "lanes_stepped"}
 
 
 def test_stop_event_finishes_in_flight_window_then_exits():

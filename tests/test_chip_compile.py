@@ -38,7 +38,7 @@ from jax.sharding import SingleDeviceSharding
 
 N, P, W, R = 4096, 32768, 31, 8
 MOUT = 16                    # EngineParams.outbox_slots
-ACAP = N // 8                # Simulation.acap at N=4096
+ACAP = N // 32               # Simulation.acap at N=4096
 I32 = jnp.int32
 
 

@@ -123,11 +123,13 @@ def test_scenario_inbox_impl_key(ini):
 
 def test_scenario_tick_impl_key(ini):
     """``**.tickImpl`` selects the tick implementation (dense full-N
-    oracle vs sparse active-set plane) and ``**.activeCap`` bounds the
-    compacted lane count; anything but dense/sparse is a config
-    error."""
+    oracle vs the awake-set plane; with no key the engine's "auto",
+    which gives Kademlia under KBRTestApp the awake-set plane) and
+    ``**.activeCap`` sets the lanes a round; anything but
+    auto/dense/sparse is a config error."""
     sim = scenario.build_simulation(ini, "Kad")
-    assert sim.ep.tick_impl == "dense"           # oracle default
+    assert sim.ep.tick_impl == "auto"            # the engine's default
+    assert sim.tick_impl == "sparse"             # ... for this logic
     assert sim.ep.active_cap == 0
     sim = scenario.build_simulation(ini, "KadSparseTick")
     assert sim.ep.tick_impl == "sparse"
@@ -138,7 +140,9 @@ def test_scenario_tick_impl_key(ini):
 
 def test_resolve_tick_impl():
     """No availability dimension here — sparse is pure XLA, so the
-    resolver is a straight validator."""
+    resolver is a straight validator (which plane "auto" comes to is
+    the engine's to say, per logic)."""
+    assert scenario.resolve_tick_impl("auto") == "auto"
     assert scenario.resolve_tick_impl("dense") == "dense"
     assert scenario.resolve_tick_impl('"sparse"') == "sparse"
     with pytest.raises(scenario.ScenarioError):

@@ -172,7 +172,7 @@ def test_alloc_dest_identity_randomized():
         assert int(over) == max(int(w.sum()) - len(free), 0), trial
 
 
-def _churn_sim(overlay, inbox_impl):
+def _churn_sim(overlay, inbox_impl, tick_impl="auto"):
     if overlay == "chord":
         from oversim_tpu.overlay.chord import ChordLogic
         logic = ChordLogic()
@@ -182,7 +182,7 @@ def _churn_sim(overlay, inbox_impl):
     cp = churn_mod.ChurnParams(model="lifetime", target_num=12,
                                init_interval=0.2, lifetime_mean=8.0)
     ep = EngineParams(window=0.1, inbox_slots=4, pool_factor=4,
-                      inbox_impl=inbox_impl)
+                      inbox_impl=inbox_impl, tick_impl=tick_impl)
     return Simulation(logic, cp, engine_params=ep)
 
 
@@ -219,12 +219,14 @@ def test_fused_tick_hlo_scatter_reduction():
     """The compiled fused tick must carry EXACTLY 2R+1 fewer scatter
     ops than the scatter tick (R scatter-min key rounds + R index
     rounds + the outbox fslot scatter fold into the kernels), zero
-    full-pool sorts, and — in interpret mode — zero custom-calls."""
+    full-pool sorts, and — in interpret mode — zero custom-calls.  The
+    pin is the dense oracle's (on the awake-set plane, Chord's default,
+    the kernel plane also folds the awake-set compaction's scatter)."""
     from oversim_tpu.analysis import hlo_text
 
     census = {}
     for impl in ("scatter", "pallas"):
-        sim = _churn_sim("chord", impl)
+        sim = _churn_sim("chord", impl, tick_impl="dense")
         s = sim.init(seed=3)
         txt = jax.jit(sim.step).lower(s).compile().as_text()
         m = hlo_text.hlo_op_counts(txt, sim.ep.pool_factor * sim.n)

@@ -26,8 +26,10 @@ def _make_churn_sim(overlay="chord", inbox_impl="scatter", n=N2D):
         logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="lifetime", target_num=n,
                                init_interval=0.2, lifetime_mean=8.0)
+    # dense by name: the hand-sharded tick refuses the awake-set plane,
+    # which the engine's default gives these logics
     ep = sim_mod.EngineParams(window=0.1, inbox_slots=4, pool_factor=4,
-                              inbox_impl=inbox_impl)
+                              inbox_impl=inbox_impl, tick_impl="dense")
     return sim_mod.Simulation(logic, cp, engine_params=ep)
 
 

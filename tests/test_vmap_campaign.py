@@ -148,8 +148,13 @@ def assert_leaves_identical(a, b, label):
 
 @pytest.mark.parametrize("overlay", ["chord", "kademlia"])
 def test_campaign_bit_identity_vs_solo_runs(overlay):
-    sim = make_overlay_sim(overlay)
-    camp = Campaign(sim, CampaignParams(replicas=4, base_seed=3))
+    camp = Campaign(make_overlay_sim(overlay),
+                    CampaignParams(replicas=4, base_seed=3))
+    # the campaign's own simulation: the vmapped step takes the dense
+    # sweep where the engine's default would give these logics the
+    # awake-set plane (same results, two more counters in the layout)
+    sim = camp.sim
+    assert sim.tick_impl == "dense"
     cs = camp.run_chunk(camp.init(), 64)
     for r in range(camp.s):
         solo = sim_mod._dedupe_buffers(

@@ -1,22 +1,26 @@
-"""Sparse active-set tick tests (engine/sim.py _step_sparse; ISSUE 16).
+"""Awake-set tick tests (engine/sim.py _step_sparse; ISSUE 16, ISSUE 27).
 
-The dense tick is the bit-identity ORACLE: with the auto active_cap
-(full-N at these sizes) every SimState leaf after 64 churned ticks must
-match the dense engine exactly — chord and kademlia, scatter and fused
-inbox, across active-set occupancy extremes (all-asleep windows, 100%
-awake, R-overflow pressure).  A sub-capacity active_cap is DEFERRAL,
-never loss: those runs are pinned for conservation and liveness, not
-identity.
+The dense tick is the bit-identity ORACLE: every SimState leaf after a
+churned run must match the dense engine exactly — chord and kademlia,
+scatter and fused inbox, across awake-set occupancy extremes (an idle
+tick, 100% awake, R-overflow pressure) and at ANY active_cap: awake
+nodes past one round's A lanes are stepped in further rounds of the
+same tick, never deferred.
 
-(Late-alphabet filename on purpose: these are the compile-heaviest
-tests in the suite and tier-1 runs files alphabetically.  Tier-1 keeps
-the scatter identity runs, the deferral-conservation pin and the
-compaction oracles; the remaining occupancy/pallas/window variants are
-marked slow — scripts/sparse_gate.py re-covers both inbox impls'
-identity in every run_suite pass.)
+(Late-alphabet filename on purpose: these are compile-heavy tests.
+Tier-1 keeps the scatter identity runs, the default resolution, the
+one-node-step-body pin and the compaction oracles here, and in
+test_zz_sparse_rounds.py (a module of its own: a module is one unit of
+work on one xdist worker) the cell's own deployment at N=128 under a
+cap that forces several rounds a tick and the all-awake and idle
+extremes; the remaining occupancy/pallas/window variants are marked
+slow — scripts/sparse_gate.py re-covers both inbox impls' identity in
+every run_suite pass.)
 """
 
 import dataclasses
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +52,30 @@ def _sim(overlay, inbox_impl="scatter", tick_impl="dense", active_cap=0,
     return Simulation(logic, cp, engine_params=ep)
 
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELL_N = 128
+
+
+def _cell_sim(tick_impl="auto", active_cap=0, n=CELL_N):
+    """``kademlia4096.kbr60``'s deployment as the benchmark builds it
+    (benchmark/program.py: the configuration file's own ini text and
+    engine sizes, the traffic file's overrides, ``build_simulation``)
+    at N=128 with the fill time kept."""
+    from oversim_tpu.config.ini import IniFile
+    from oversim_tpu.config.scenario import build_simulation
+    with open(os.path.join(ROOT, "benchmark/configs/kademlia4096.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(ROOT, "benchmark/traffic/kbr60.json")) as f:
+        pairs = dict(json.load(f)["overrides"])
+    pairs["**.targetOverlayTerminalNum"] = n
+    pairs["**.initPhaseCreationInterval"] = float(config["fill_s"]) / n
+    ini = IniFile.loads("\n".join(config["ini"]))
+    section = ini.with_overrides("General", pairs)
+    ep = EngineParams(**config["engine"], tick_impl=tick_impl,
+                      active_cap=active_cap)
+    return build_simulation(ini, section, ep), config
+
+
 def _strip_sparse(st):
     """Drop the sparse-only counters so the dense and sparse SimState
     pytrees become layout-comparable (the dense engine never carries
@@ -68,8 +96,9 @@ def _assert_tree_equal(a, b):
 
 
 def _identity_run(overlay, inbox_impl, n_ticks=64, seed=3, **kw):
-    """64 churned ticks full-step: sparse (auto cap = full-N here) must
-    land on the EXACT dense SimState, bit for bit."""
+    """64 churned ticks full-step: the awake-set plane (auto cap =
+    full-N here unless ``active_cap`` says less) must land on the EXACT
+    dense SimState, bit for bit."""
     finals = {}
     for tick_impl in ("dense", "sparse"):
         sim = _sim(overlay, inbox_impl=inbox_impl, tick_impl=tick_impl,
@@ -123,8 +152,7 @@ def test_sparse_identity_kademlia_pallas_under_churn():
 def test_sparse_identity_empty_active_set():
     """Near-empty windows: joins staggered ~50s out, so only the t=0
     bootstrap node ever wakes in the first 8 ticks (no messages at
-    all) — and a synthesized all-asleep window compacts to pure
-    sentinel lanes with zero tallies."""
+    all)."""
     finals = {}
     for tick_impl in ("dense", "sparse"):
         sim = _sim("chord", tick_impl=tick_impl, churn="none")
@@ -136,18 +164,6 @@ def test_sparse_identity_empty_active_set():
     assert int(finals["sparse"].counters["awake_nodes"]) <= 8
     assert int(finals["sparse"].counters["active_dst"]) == 0
 
-    # phase-level: a window where NOTHING is due (no inbox traffic, no
-    # churn flips, t_end before any timer) compacts to all-sentinel
-    n = sim.n
-    s0 = sim.init(seed=11)
-    inbox = jnp.full((n, sim.ep.inbox_slots), -1, jnp.int32)
-    dlv0 = jnp.zeros(s0.pool.valid.shape, bool)
-    act, dlv, active = sim._phase_active_compact(
-        s0, jnp.int64(0), s0.alive, jnp.zeros((n,), bool), s0.logic,
-        inbox, dlv0)
-    assert (np.asarray(act) == n).all()                # pure sentinels
-    assert int(active[0]) == 0 and int(active[2]) == 0
-    assert not np.asarray(dlv).any()
 
 
 @pytest.mark.slow
@@ -187,37 +203,103 @@ def test_sparse_identity_r_overflow_pressure():
     assert int(finals["dense"].counters["inbox_deferred"]) > 0
 
 
-# -- sub-capacity active_cap: deferral, never loss --------------------------
+# -- which plane a deployment gets ------------------------------------------
 
 
-def test_active_cap_defers_but_never_loses():
-    """active_cap=2 on a 12-node kbr run: the cap clips every busy
-    window (active_deferred climbs), but nothing is lost — unserved
-    inbox slots revert to pooled, unserved timers stay due — and the
-    app still makes progress."""
-    sim = _sim("chord", tick_impl="sparse", active_cap=2,
-               churn="none", interval=0.2)
-    assert sim.acap == 2
-    s = sim.init(seed=3)
-    out = jax.device_get(sim.run_chunk(s, 64))
-    assert int(out.counters["active_deferred"]) > 0
-    assert int(out.counters["pool_overflow"]) == 0
-    assert int(out.counters["queue_lost"]) == 0
-    assert int(np.sum(out.alive)) > 0
-    # liveness: deferred work drains — lookups still complete
-    assert int(out.stats["c:kbr_delivered"]) > 0
+PASTRY_INI = """
+[General]
+**.overlayType = "oversim.overlay.pastry.PastryModules"
+**.tier1Type = "oversim.applications.kbrtestapp.KBRTestAppModules"
+**.targetOverlayTerminalNum = 8
+"""
+
+
+def test_default_tick_plane_resolution():
+    """The engine's default: the awake-set plane for a logic that
+    declares it exact (the benchmark cells' deployment), the dense
+    sweep for every other overlay; the vmapped campaign runner and the
+    hand-sharded tick take dense for themselves; and no logic without
+    the declaration is given the plane, by default or by name."""
+    from oversim_tpu.campaign import Campaign
+    from oversim_tpu.config.ini import IniFile
+    from oversim_tpu.config.scenario import ScenarioError, build_simulation
+    from oversim_tpu.engine.sim import resolve_tick_impl
+    from oversim_tpu.parallel import mesh as mesh_mod
+    from oversim_tpu.parallel.shard_tick import ShardedSim
+
+    assert EngineParams().tick_impl == "auto"
+    cell, config = _cell_sim()
+    assert "tick_impl" not in config["engine"]
+    assert not any("tickImpl" in ln for ln in config["ini"])
+    assert cell.logic.awake_set_exact and cell.tick_impl == "sparse"
+    assert cell.acap == min(cell.n, max(32, cell.n // 32)) == 32
+    assert set(cell.counter_names) == set(ENGINE_COUNTERS + SPARSE_COUNTERS)
+
+    pastry = build_simulation(IniFile.loads(PASTRY_INI))
+    assert not getattr(pastry.logic, "awake_set_exact", False)
+    assert pastry.ep.tick_impl == "auto" and pastry.tick_impl == "dense"
+    assert pastry.counter_names == ENGINE_COUNTERS
+    assert pastry.dense_unless_asked() is pastry
+    with pytest.raises(ScenarioError, match="awake_set_exact"):
+        build_simulation(IniFile.loads(
+            PASTRY_INI + '**.tickImpl = "sparse"\n'))
+    with pytest.raises(ValueError, match="awake_set_exact"):
+        resolve_tick_impl("sparse", pastry.logic)
+    with pytest.raises(ValueError, match="unsupported"):
+        resolve_tick_impl("eager", cell.logic)
+
+    # an app that does not declare itself keeps its overlay on dense
+    from oversim_tpu.apps.dht import DhtApp
+    from oversim_tpu.overlay.kademlia import KademliaLogic
+    assert not KademliaLogic(app=DhtApp()).awake_set_exact
+    assert KademliaLogic().awake_set_exact
+
+    camp = Campaign(cell)
+    assert camp.sim is not cell and camp.sim.tick_impl == "dense"
+    assert camp.sim.counter_names == ENGINE_COUNTERS
+    asked, _ = _cell_sim(tick_impl="sparse")
+    assert Campaign(asked).sim is asked                # asked by name
+    # the hand-sharded tick runs dense alone, and has to be asked so
+    mesh = mesh_mod.make_mesh_2d(1, 2)
+    for refused in (cell, asked):
+        with pytest.raises(ValueError, match="tick_impl='dense'"):
+            ShardedSim(refused, mesh)
+    dense, _ = _cell_sim(tick_impl="dense")
+    assert ShardedSim(dense, mesh).sim is dense
+
+
+def test_default_kademlia_tick_holds_one_node_step_body():
+    """The default Kademlia tick program (the awake-set plane) holds
+    exactly as many sorts as the dense oracle's — one copy of the node
+    step, not a sparse branch beside a dense fallback — none of them
+    full-pool, and no wide [N, R, W] payload gather."""
+    from oversim_tpu.analysis import hlo_text
+    counts = {}
+    for tick_impl in ("dense", "auto"):
+        sim = _sim("kademlia", tick_impl=tick_impl, churn="none", n=64,
+                   active_cap=16 if tick_impl == "auto" else 0)
+        s = sim.init(seed=3)
+        txt = jax.jit(sim.step).lower(s).compile().as_text()
+        pool_dim = sim.ep.pool_factor * sim.n
+        counts[sim.tick_impl] = dict(
+            hlo_text.hlo_op_counts(txt, pool_dim),
+            **hlo_text.gather_counts(txt, wide_dims=(sim.n, pool_dim)))
+    dense, sparse = counts["dense"], counts["sparse"]
+    assert sparse["sort_count"] == dense["sort_count"] > 0, counts
+    assert sparse["full_pool_sort_count"] == 0
+    assert sparse["wide_gather_count"] < dense["wide_gather_count"]
 
 
 @pytest.mark.slow
 def test_active_cap_at_capacity_is_exact():
-    """cap == n is the auto-cap small-N case spelled explicitly: no
-    deferral, bit-identity to dense."""
+    """cap == n is the auto-cap small-N case spelled explicitly: one
+    round a tick, bit-identity to dense."""
     dense = _sim("chord", churn="none", interval=0.2)
     sparse = _sim("chord", tick_impl="sparse", active_cap=12,
                   churn="none", interval=0.2)
     a = jax.device_get(dense.run_chunk(dense.init(seed=5), 32))
     b = jax.device_get(sparse.run_chunk(sparse.init(seed=5), 32))
-    assert int(b.counters["active_deferred"]) == 0
+    assert int(b.counters["lanes_stepped"]) <= 32 * 12
     _assert_tree_equal(a, _strip_sparse(b))
 
 
@@ -228,7 +310,7 @@ def test_active_cap_at_capacity_is_exact():
 def test_compact_indices_randomized_oracle():
     """kernels.outbox.compact_indices == numpy nonzero-compaction:
     lane k holds the k-th set index, sentinel beyond, and count is the
-    TRUE set-bit total even past cap (the caller's deferral signal)."""
+    TRUE set-bit total even past cap."""
     rng = np.random.default_rng(23)
     for trial in range(25):
         m = int(rng.integers(1, 48))
@@ -303,4 +385,4 @@ def test_sparse_window_one_dispatch_one_fetch(monkeypatch):
     assert len(fetched) == 2                    # ONE device_get per window
     for leaves in fetched:
         assert "awake_nodes" in leaves["counters"]
-        assert "active_deferred" in leaves["counters"]
+        assert "lanes_stepped" in leaves["counters"]
