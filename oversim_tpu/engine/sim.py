@@ -130,7 +130,8 @@ ENGINE_COUNTERS = ("queue_lost", "bit_error_lost", "dest_unavailable_lost",
 # awake-set plane (the dense SimState layout stays bit-identical to the
 # pre-sparse engine), cumulative over the run: awake nodes, nodes with
 # inbox traffic, and lanes stepped (rounds x A — what the node step
-# paid, where the dense sweep pays ticks x N)
+# paid, where the dense sweep pays ticks x N: the dense step, handed a
+# state that carries the counter, adds the alive rows it swept)
 SPARSE_COUNTERS = ("awake_nodes", "active_dst", "lanes_stepped")
 
 
@@ -211,9 +212,10 @@ class Simulation:
     def dense_unless_asked(self) -> "Simulation":
         """This deployment with ``tick_impl="auto"`` settled as the
         dense sweep: for a caller that vmaps the step (under vmap the
-        round loop runs every replica for the busiest one's rounds) or
-        lets GSPMD partition it (across node shards the compaction's
-        gathers are collectives)."""
+        round loop runs every replica for the busiest one's rounds).
+        The GSPMD builders of parallel/mesh.py no longer take it: on
+        four chips the awake-set plane is the faster one (PERF.md,
+        PR 28)."""
         if self.ep.tick_impl != "auto" or self.tick_impl == "dense":
             return self
         return Simulation(
@@ -673,6 +675,12 @@ class Simulation:
             # so the telemetry rings carry the series
             for name, tally in zip(SPARSE_COUNTERS, active):
                 counters[name] += tally
+        elif "lanes_stepped" in counters:
+            # the dense sweep on a state laid out by the awake-set plane
+            # (tick_impl="dense" by name on another Simulation's init):
+            # every alive row was stepped, so a reader of lanes over
+            # rows gets 100% and never 0; the other two tallies stay
+            counters["lanes_stepped"] += jnp.sum(alive).astype(I64)
 
         # telemetry sample point (telemetry.py): END-of-tick snapshot of
         # the accumulators into the ring buffers, gated on the sampling

@@ -227,7 +227,8 @@ def jit_sharded_campaign_step(camp, mesh: Mesh, donate: bool = True):
 
 
 def state_shardings(state, mesh: Mesh):
-    """NamedSharding pytree for a SimState: leading axis of every array
+    """NamedSharding pytree for a SimState (arrays, or the shapes
+    ``jax.eval_shape`` gives): leading axis of every array
     whose first dim divides evenly over the mesh is sharded; scalars and
     ragged leaves are replicated.  Telemetry ring buffers (leading axis
     = the sample window W, not a node dimension) are always replicated —
@@ -237,9 +238,9 @@ def state_shardings(state, mesh: Mesh):
     replicated = NamedSharding(mesh, P())
 
     def spec(leaf):
-        leaf = jnp.asarray(leaf)
-        if leaf.ndim >= 1 and leaf.shape[0] % n_dev == 0 and leaf.shape[0] > 0:
-            return NamedSharding(mesh, P(NODE_AXIS, *([None] * (leaf.ndim - 1))))
+        shp = _shape(leaf)
+        if shp and shp[0] > 0 and shp[0] % n_dev == 0:
+            return NamedSharding(mesh, P(NODE_AXIS, *([None] * (len(shp) - 1))))
         return replicated
 
     sh = jax.tree.map(spec, state)
@@ -310,14 +311,32 @@ def jit_campaign_run_until(camp, mesh: Mesh, chunk: int = 64,
 
 
 def _gspmd_step(sim):
-    """The step the GSPMD builders below partition: the dense sweep,
-    unless the Simulation asks for the awake-set plane by name.  That
-    plane's compaction gathers rows from the whole node axis, so across
-    shards every round is collectives; it has never been measured on a
-    mesh.  The state keeps the layout of ``sim.init()``: where that
-    carries the awake-set counters, the dense step passes them through
-    at 0."""
-    return sim.dense_unless_asked().step
+    """The step the GSPMD builders below partition: the Simulation's
+    own (``sim.step``), so the plane ``EngineParams.tick_impl`` resolves
+    to — the awake-set plane for a logic that declares
+    ``awake_set_exact``, the dense sweep for every other or by name.
+
+    That plane's compaction gathers rows from the whole node axis, so
+    across shards every round is collectives, and still it is the one to
+    shard.  Read on four v5e chips at N = 16,384 (Kademlia under
+    KBRTestApp, 4,096 rows a device, same seed, six dispatches after the
+    fill; PERF.md, PR 28): the dense sweep ticks in 320.3 ms with 4.6 ms
+    of collectives, the awake-set plane in 171.4 ms with 6.1 ms, and it
+    is the awake-set plane that lands on the one-device run, leaf for
+    leaf (the dense sweep on the mesh does not, at that N on the chip).
+    A dense step handed a state that carries the awake-set counters
+    (``tick_impl="dense"`` by name on the layout of another Simulation's
+    ``init()``) adds the alive rows it swept to ``lanes_stepped`` and
+    leaves the other two at 0."""
+    return sim.step
+
+
+def _example_shardings(sim, mesh: Mesh):
+    """The shardings of this deployment's SimState on ``mesh``, from the
+    state's shapes alone (``jax.eval_shape``, as ``ShardedSim``): no
+    state is built to size them."""
+    example = jax.eval_shape(sim.init_from_rng, jax.random.PRNGKey(0))
+    return state_shardings(example, mesh)
 
 
 def jit_step(sim, mesh: Mesh, donate: bool = True):
@@ -326,8 +345,7 @@ def jit_step(sim, mesh: Mesh, donate: bool = True):
     Returns a compiled callable state -> state.  The sharding constraint is
     placed on the argument/result; everything inside is GSPMD-partitioned.
     """
-    example = sim.init()
-    shardings = state_shardings(example, mesh)
+    shardings = _example_shardings(sim, mesh)
     return jax.jit(_gspmd_step(sim), in_shardings=(shardings,),
                    out_shardings=shardings,
                    donate_argnums=(0,) if donate else ())
@@ -336,8 +354,7 @@ def jit_step(sim, mesh: Mesh, donate: bool = True):
 def jit_run(sim, mesh: Mesh, n_ticks: int, donate: bool = True):
     """jit a ``lax.scan`` of n_ticks sharded steps (one dispatch for the
     whole run — the multi-chip equivalent of Simulation.run_chunk)."""
-    example = sim.init()
-    shardings = state_shardings(example, mesh)
+    shardings = _example_shardings(sim, mesh)
     step = _gspmd_step(sim)
 
     def run(s):
@@ -358,10 +375,13 @@ def jit_run_until(sim, mesh: Mesh, chunk: int = 64, donate: bool = True):
     ``t_now >= target_ns``, so the whole run to a simulation-time target
     is ONE dispatch — no per-chunk host round-trip (the per-chunk sync
     in the host loop costs a full ICI/DCN drain at scale).  ``target_ns``
-    is an i64 scalar in engine ns (``t_sim * sim_mod.NS``), replicated.
+    is an i64 scalar in engine ns (``t_sim * sim_mod.NS``; a host
+    ``np.int64`` costs no device operation of its own), replicated and
+    traced: every target shares the ONE compiled program, whose count
+    the returned callable gives as ``_cache_size()``.  The state is
+    donated: rebind it.
     """
-    example = sim.init()
-    shardings = state_shardings(example, mesh)
+    shardings = _example_shardings(sim, mesh)
     step = _gspmd_step(sim)
 
     def run(s, target_ns):

@@ -95,8 +95,29 @@ COST_ORDER = (
     "test_pastry_bamboo.py", "test_p2pns.py", "test_pastry.py",
     "test_pastry_iterative.py", "test_koorde.py", "test_stack.py",
     "test_gateway.py", "test_dht_variants.py", "test_churn.py",
-    "test_dht.py",
+    "test_dht.py", "test_mesh_run_until.py",
 )
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drop_compiled_programs():
+    """After a module, let go of everything it compiled.
+
+    Every XLA-CPU executable holds its own memory mappings (13,000 for
+    one module's simulations), a worker keeps every module's in jax's
+    caches, and the sandbox allows a process 65,530 (vm.max_map_count).
+    At PR 27 the fullest worker of a whole run peaked at 58,900; with
+    PR 28's mesh programs one worker in each of three whole runs crossed
+    the limit and died inside a compile (``Segmentation fault`` or
+    ``Aborted`` in backend_compile_and_load, in whatever module it had
+    reached).  Cleared, a worker is back at 660 mappings after every
+    module; no simulation is shared between modules, so what is compiled
+    again is the eager init's small operations.
+    """
+    yield
+    import gc
+    jax.clear_caches()
+    gc.collect()
 
 
 @pytest.hookimpl(optionalhook=True)   # xdist may not be loaded

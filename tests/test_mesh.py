@@ -79,15 +79,11 @@ def test_sharded_run_matches_unsharded(pair):
 
 
 def test_sharded_engine_counters(pair):
-    """Every engine counter agrees.  The unsharded run is on Chord's
-    default plane (the awake-set tick) and the GSPMD builders step the
-    dense sweep (mesh._gspmd_step), so this is also dense against
-    awake-set on 1500 ticks; the awake-set plane's own tallies are the
-    one difference: the dense step passes them through at 0."""
+    """Every engine counter agrees, the awake-set plane's own tallies
+    among them: since PR 28 the GSPMD builders step the plane the
+    Simulation resolves (mesh._gspmd_step), here Chord's default, the
+    awake-set tick, on both sides over 1500 ticks."""
     plain, sharded, _, _ = pair
     assert plain["_engine"]["lanes_stepped"] > 0
     for k, v in plain["_engine"].items():
-        if k in sim_mod.SPARSE_COUNTERS:
-            assert sharded["_engine"][k] == 0, k
-        else:
-            assert sharded["_engine"][k] == v, k
+        assert sharded["_engine"][k] == v, k

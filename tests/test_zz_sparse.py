@@ -114,6 +114,29 @@ def _identity_run(overlay, inbox_impl, n_ticks=64, seed=3, **kw):
     return finals
 
 
+def test_dense_step_counts_its_rows_on_an_awake_set_layout():
+    """The dense step handed a state that carries the awake-set counters
+    (``tick_impl="dense"`` by name on the layout of another
+    Simulation's ``init()``): ``lanes_stepped`` gains the alive rows of
+    every tick, the other two tallies stay 0, and every other leaf is
+    the dense run's, bit for bit."""
+    dense = _sim("kademlia", tick_impl="dense")
+    sparse = _sim("kademlia", tick_impl="sparse")
+    step = jax.jit(dense.step)
+    s, ref = sparse.init(seed=3), dense.init(seed=3)
+    assert set(s.counters) == set(ENGINE_COUNTERS + SPARSE_COUNTERS)
+    rows = 0
+    for _ in range(48):
+        s, ref = step(s), step(ref)
+        rows += int(np.sum(s.alive))
+    assert rows > 0
+    assert int(s.counters["lanes_stepped"]) == rows
+    assert int(s.counters["awake_nodes"]) == 0
+    assert int(s.counters["active_dst"]) == 0
+    assert set(ref.counters) == set(ENGINE_COUNTERS)
+    _assert_tree_equal(jax.device_get(ref), _strip_sparse(jax.device_get(s)))
+
+
 # -- bit-identity under lifetime churn: overlays x inbox impls --------------
 
 
