@@ -11,10 +11,17 @@ opening's counters, and one warm-up of the read-back the window makes.
 The window then repeats ONE dispatch of ``ticks_per_dispatch`` ticks of
 the same compiled program until ``seconds`` of wall time have passed,
 and stops on a dispatch boundary.  After each dispatch it copies the
-message pool to the host (a few MB): that read-back is part of the
-window and of every rate.  The comparison with the plain reference runs
-once the window has closed, the peak has been read and the state is
-freed.
+message pool and the four KBR counters to the host (a few MB): that
+read-back is part of the window and of every rate.  The comparison with
+the plain reference runs once the window has closed, the peak has been
+read and the state is freed.
+
+Both rates, ``tick_ms`` and every number of ``correct`` are read over
+the whole window.  The result line's ``attempted`` and ``failed`` are
+not: they count the window's first ``failures_over_sim_s`` simulated
+seconds (the configuration's; ``window.counted_stretch``), the same
+lookups on one seed however far a tree's window reaches, so that a
+faster tree is not held against failures its parent never got to.
 """
 
 from __future__ import annotations
@@ -45,6 +52,10 @@ def run_cell(prog, cell: dict, seed: int, seconds: float, *, t_proc: float,
     t1 = clock()
     s = prog.run_to(s, 1)                     # one dispatch: compiles
     t2 = clock()
+    # the run's own baseline: a process may hold tick programs of other
+    # simulations (a sweep's earlier seeds, a test's); what this run may
+    # not do is compile another after its first dispatch
+    programs_before = prog.tick_programs() - 1
     spans["first_dispatch_s"] = t2 - t1
     big = [c for c in prog.compiles[n_before:] if c >= 1.0]
     spans["compile_s"] = sum(big)
@@ -101,12 +112,15 @@ def run_cell(prog, cell: dict, seed: int, seconds: float, *, t_proc: float,
     tables = prog.tables(s)
     peaks = prog.memory_peaks()               # one per chip used
     peak = max((p for p in peaks if p is not None), default=None)
-    programs = prog.tick_programs()
+    programs = prog.tick_programs() - programs_before
     del s                                     # the state is freed
 
     rates = window_mod.window_rates(opening, close, dispatches, t_open,
                                     skip_gaps_before=after_profiler)
     look = rates["lookups"]
+    stretch = window_mod.counted_stretch(opening, snaps, close,
+                                         config["failures_over_sim_s"])
+    tenths = window_mod.by_tenth(opening, snaps)
     took = sorted(done - call for call, done in dispatches)
     mid = took[len(took) // 2]
     say(f"dispatch seconds: least {took[0]:.4f} median "
@@ -124,6 +138,15 @@ def run_cell(prog, cell: dict, seed: int, seconds: float, *, t_proc: float,
         f"{look['delivered']} in flight {look['in_flight_open']} -> "
         f"{look['in_flight_close']}; tick programs {programs}; "
         f"peak_bytes_in_use {peaks}")
+    if not stretch["reached"]:
+        say(f"counted stretch: reached {stretch['sim_s']:.1f} of "
+            f"{stretch['over_sim_s']} simulated seconds")
+    say(f"counted stretch: {stretch['over_sim_s']} sim-s, "
+        f"{stretch['dispatches']} dispatches, ended {stretch['attempted']} "
+        f"failed {stretch['failed']}; whole window ended "
+        f"{look['attempted']} failed {look['failed']}")
+    say("failed by tenth: " + " ".join(map(str, tenths["failed"]))
+        + "; ended by tenth: " + " ".join(map(str, tenths["ended"])))
 
     # -- the comparison ---------------------------------------------------
     t4 = clock()
@@ -134,7 +157,11 @@ def run_cell(prog, cell: dict, seed: int, seconds: float, *, t_proc: float,
     spans["reference_s"] = clock() - t4
     return {"spans": spans, "rates": rates, "readings": readings,
             "rows": rows, "correct": all(r[4] for r in rows) and bool(rows),
-            "attempted": look["attempted"], "failed": look["failed"],
+            # the result line's: over the counted stretch
+            "attempted": stretch["attempted"], "failed": stretch["failed"],
+            "stretch": stretch, "failed_by_tenth": tenths,
+            "window_attempted": look["attempted"],
+            "window_failed": look["failed"],
             "peak_bytes": peak, "state_bytes": state_bytes, "chips": chips,
             "traced": traced, "dispatches": len(dispatches),
             "ticks_per_dispatch":

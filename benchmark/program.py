@@ -37,6 +37,12 @@ SURFACE = (
     "underlay.coords", "underlay.channel",
     "logic.state", "logic.sib", "logic.buckets",
 )
+# the four KBR counters (scalars of the ``stats`` dict) that ride every
+# read-back of the window, so that the lookups ended and failed can be
+# counted up to any dispatch boundary (``window.counted_stretch``)
+KBR_COUNTERS = ("stats.c:kbr_sent", "stats.c:kbr_delivered",
+                "stats.c:kbr_lookup_failed", "stats.c:kbr_wrong_node")
+SURFACE += KBR_COUNTERS
 POOL_VIEWS = ("src", "dst", "kind", "size_b", "key")
 
 
@@ -45,12 +51,13 @@ class SurfaceError(AttributeError):
 
 
 def leaf(s, path: str):
-    """``s.<path>``, or a ``SurfaceError`` that names the leaf."""
+    """``s.<path>`` (an attribute, or a key where the state holds a
+    dict), or a ``SurfaceError`` that names the leaf."""
     at = s
     for part in path.split("."):
         try:
-            at = getattr(at, part)
-        except AttributeError:
+            at = at[part] if isinstance(at, dict) else getattr(at, part)
+        except (AttributeError, KeyError):
             raise SurfaceError(
                 f"benchmark/program.py reads the state leaf {path!r} for "
                 f"the comparison, and the program's state has no "
@@ -194,16 +201,17 @@ class Program:
 
     def payloads(self, s) -> dict:
         """The message pool as the engine holds it (valid mask, deliver
-        times, the packed 32-bit block) and the lookups' pending RPCs."""
+        times, the packed 32-bit block), the lookups' pending RPCs and
+        the four KBR counters."""
         if self._cols is None:            # set-up's warming call
             self._cols = pool_columns(leaf(s, "pool"))
         col = self._cols
-        # one read for all seven leaves: the copies overlap
-        valid, blk, t_deliver, rpc_dst, rpc_t_sent, rpc_active, t_now = (
-            self.jax.device_get(tuple(leaf(s, k) for k in (
-                "pool.valid", "pool.blk", "pool.t_deliver",
-                "logic.lk.pending_dst", "logic.lk.t_sent",
-                "logic.lk.active", "t_now"))))
+        # one read for all eleven leaves: the copies overlap
+        (valid, blk, t_deliver, rpc_dst, rpc_t_sent, rpc_active, t_now,
+         *kbr) = self.jax.device_get(tuple(leaf(s, k) for k in (
+             "pool.valid", "pool.blk", "pool.t_deliver",
+             "logic.lk.pending_dst", "logic.lk.t_sent",
+             "logic.lk.active", "t_now") + KBR_COUNTERS))
         # only the slots that hold a message come to the host's record
         rows = np.nonzero(np.asarray(valid))[0]
         blk = np.asarray(blk)[rows]
@@ -216,6 +224,8 @@ class Program:
             "key": np.ascontiguousarray(
                 blk[:, col["key"]]).view(np.uint32),
             "t_now_ns": int(t_now),
+            "stats": {k.partition(".")[2]: int(v)
+                      for k, v in zip(KBR_COUNTERS, kbr)},
             # the lookups' pending RPCs: whom each asked, and when
             "rpc_dst": np.asarray(rpc_dst),
             "rpc_t_sent": np.asarray(rpc_t_sent),

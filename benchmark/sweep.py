@@ -61,6 +61,11 @@ def sweep(cell: dict, prog, device: dict, args, say) -> int:
                "control_readings": {k: c_read[k] for k in
                                     ("timer_off_lattice", "delay_early_ns")
                                     if k in c_read},
+               # over the counted stretch, and over the whole window
+               "stretch": rec["stretch"],
+               "window_attempted": rec["window_attempted"],
+               "window_failed": rec["window_failed"],
+               "failed_by_tenth": rec["failed_by_tenth"],
                "rates": {k: v for k, v in rec["rates"].items()
                          if k != "lookups"},
                "spans": rec["spans"], "peak_bytes": rec["peak_bytes"]}

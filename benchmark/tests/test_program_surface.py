@@ -73,6 +73,28 @@ def test_a_missing_leaf_fails_loudly_by_name(state, path):
     assert repr(path) in str(err.value) and repr(last) in str(err.value)
 
 
+def test_every_read_back_carries_the_four_kbr_counters(state):
+    """The window's read-back brings the counters the counted stretch is
+    read from, as ``counters`` reads them, and a state without one fails
+    with its name."""
+    prog, s = state
+    assert program.KBR_COUNTERS == (
+        "stats.c:kbr_sent", "stats.c:kbr_delivered",
+        "stats.c:kbr_lookup_failed", "stats.c:kbr_wrong_node")
+    assert set(program.KBR_COUNTERS) <= set(program.SURFACE)
+    snap, full = prog.payloads(s), prog.counters(s)
+    assert set(snap["stats"]) == {k.partition(".")[2]
+                                  for k in program.KBR_COUNTERS}
+    for name, value in snap["stats"].items():
+        assert isinstance(value, int) and value == int(full["stats"][name])
+    assert snap["t_now_ns"] == full["t_now_ns"]
+    broken = types.SimpleNamespace(stats={
+        k: v for k, v in s.stats.items() if k != "c:kbr_wrong_node"})
+    with pytest.raises(program.SurfaceError) as err:
+        program.leaf(broken, "stats.c:kbr_wrong_node")
+    assert "'stats.c:kbr_wrong_node'" in str(err.value)
+
+
 def test_a_pool_without_a_view_fails_loudly_by_name():
     @dataclasses.dataclass
     class Repacked:

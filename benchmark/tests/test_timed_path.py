@@ -27,6 +27,7 @@ from conftest import HERE
 # 10 s, from no source): enough calls in flight at this size
 TRAFFIC = os.path.join(HERE, "data", "kbr10.json")
 N = 128
+STRETCH_SIM_S = 8.0
 
 
 @pytest.fixture(scope="module")
@@ -40,6 +41,9 @@ def setup():
     lim = cell["config"]["limits"]
     lim["payload_far_share"] = ["max", 0.5]       # N/16 is 8 nodes here
     lim["lookup_failed_share"] = ["max", 0.1]     # some 50 lookups end
+    # the counted stretch: five dispatches, which the shortest run here
+    # passes (the cell's own is 0.8 of what 51 s cover on the chip)
+    cell["config"]["failures_over_sim_s"] = STRETCH_SIM_S
     import program
     prog = program.Program(cell["config"], cell["traffic"], 1, n=N,
                            persistent_cache=False)
@@ -56,6 +60,17 @@ def drive(setup, prog, seed=7):
     result, lines = run.drive(bench, cell, prog, args_for(name, seed),
                               prog.device_record(), None)
     return result, lines
+
+
+@pytest.fixture
+def recs(monkeypatch):
+    """The records ``run.drive`` gets from ``cellrun.run_cell``."""
+    kept = []
+    real = cellrun.run_cell
+    monkeypatch.setattr(
+        cellrun, "run_cell",
+        lambda *a, **kw: kept.append(real(*a, **kw)) or kept[-1])
+    return kept
 
 
 class Wrapped:
@@ -82,6 +97,58 @@ def test_a_sound_run_is_correct_and_reports_the_contract_keys(setup):
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert any(line.startswith("compare timer_off_lattice = 0 ")
                for line in lines)
+
+
+def test_two_lengths_of_one_seed_count_the_same_stretch(setup, recs):
+    """A parent and an exact change twice as fast, stood in for by one
+    seed run for two lengths: the result line's ``attempted`` and
+    ``failed`` are the same two integers, the whole window's are not."""
+    bench, name, cell, prog = setup
+    lines = []
+    for seconds in (2.0, 5.0):
+        args = args_for(name, 13)
+        args.seconds = seconds
+        result, _ = run.drive(bench, cell, prog, args,
+                              prog.device_record(), None)
+        lines.append((result["attempted"], result["failed"]))
+    short, long_ = recs
+    assert short["stretch"]["reached"] and long_["stretch"]["reached"]
+    assert short["stretch"] == long_["stretch"]
+    assert short["stretch"]["sim_s"] == STRETCH_SIM_S
+    assert lines[0] == lines[1] == (short["attempted"], short["failed"])
+    assert lines[0][0] > 0
+    assert long_["dispatches"] > short["dispatches"]
+    assert long_["window_attempted"] > short["window_attempted"] \
+        >= short["attempted"]
+    # the rates and the comparison still read the whole window
+    assert long_["rates"]["lookups"]["attempted"] == \
+        long_["window_attempted"]
+    assert sum(long_["failed_by_tenth"]["ended"]) == \
+        long_["window_attempted"]
+
+
+def test_lookups_cut_short_fail_more_over_the_stretch(setup, recs):
+    """The control G2 as the chip runs it (``run.py --ini
+    '**.overlay.kademlia.lookupRedundantNodes=1'``): its failed share
+    over the counted stretch is many times the sound run's."""
+    bench, name, cell, prog = setup
+    drive(setup, prog, seed=11)
+    cut = dict(cell["traffic"], overrides=dict(
+        cell["traffic"]["overrides"],
+        **{"**.overlay.kademlia.lookupRedundantNodes": 1}))
+    import program
+    broken = program.Program(cell["config"], cut, 1, n=N,
+                             persistent_cache=False)
+    result, _ = run.drive(bench, dict(cell, traffic=cut), broken,
+                          args_for(name, 11), broken.device_record(), None)
+    sound, control = recs
+    assert sound["stretch"]["reached"] and control["stretch"]["reached"]
+    assert result["correct"] is False
+    assert (result["attempted"], result["failed"]) == (
+        control["attempted"], control["failed"])
+    share = control["failed"] / control["attempted"]
+    assert share >= 0.05
+    assert share >= 3 * sound["failed"] / sound["attempted"]
 
 
 def test_the_control_at_test_size_is_not_correct(setup):

@@ -32,6 +32,42 @@ def lookups(opening: dict, close: dict) -> dict:
             "delivery": delivered / max(sent, ended, 1)}
 
 
+def counted_stretch(opening: dict, snaps: list, close: dict,
+                    over_sim_s: float) -> dict:
+    """What the result line's ``attempted`` and ``failed`` count: the
+    lookups that ended in the window's first ``over_sim_s`` simulated
+    seconds, the configuration's ``failures_over_sim_s``.  ``snaps`` are
+    the read-backs after each dispatch, each with ``t_now_ns`` and the
+    KBR counters; the stretch ends at the FIRST whose ``t_now_ns`` less
+    the opening's is at least that long.  The program is deterministic
+    in its seed, so a faster tree reads the same two integers there, and
+    a share of failures is compared between parent and change over the
+    same lookups.  A window that never gets that far counts over what it
+    reached, up to the close, and says so (``reached`` False)."""
+    over_ns = int(round(float(over_sim_s) * 1e9))
+    t_open = opening["t_now_ns"]
+    at = next((i for i, snap in enumerate(snaps)
+               if snap["t_now_ns"] - t_open >= over_ns), None)
+    end = close if at is None else snaps[at]
+    look = lookups(opening, end)
+    return {"over_sim_s": float(over_sim_s), "reached": at is not None,
+            "sim_s": (end["t_now_ns"] - t_open) / 1e9,
+            "dispatches": len(snaps) if at is None else at + 1,
+            "attempted": look["attempted"], "failed": look["failed"]}
+
+
+def by_tenth(opening: dict, snaps: list) -> dict:
+    """WHEN in simulated time lookups fail: the lookups that ended and
+    that failed in each tenth of the window's dispatches (a dispatch is
+    a fixed stretch of simulated time).  Held to no limit."""
+    cum = [(0, 0)] + [(look["attempted"], look["failed"]) for look in
+                      (lookups(opening, snap) for snap in snaps)]
+    edges = [(k * len(snaps)) // 10 for k in range(11)]
+    tenths = list(zip(edges, edges[1:]))
+    return {"ended": [cum[b][0] - cum[a][0] for a, b in tenths],
+            "failed": [cum[b][1] - cum[a][1] for a, b in tenths]}
+
+
 def window_rates(opening: dict, close: dict, dispatches: list,
                  t_open: float, skip_gaps_before=()) -> dict:
     """Rates over all the work and all the time of the window: from its
