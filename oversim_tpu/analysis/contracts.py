@@ -39,8 +39,16 @@ DEFAULT_DTYPES = frozenset({
 })
 
 # measured at -O0/inbox=8: kademlia 151 / chord 123 scatters per tick
-# (mostly small per-node logic scatters; engine share 8 + 2*inbox) — 200
-# catches gross regressions while zero-full-pool-sorts stays the sharp pin
+# before PR 34 (mostly small per-node logic scatters; engine share
+# 8 + 2*inbox).  Since PR 34 the default inbox selection holds its
+# rounds twice — 2*inbox D-update scatter-mins over the due messages'
+# compacted lanes plus ONE D-update write-back of ``delivered``, and
+# behind a lax.cond the 2*inbox P-wide scatter-mins for a tick whose due
+# messages outnumber the lanes — so the engine share is
+# 8 + 4*inbox + 1 and the ticks hold 2*inbox + 1 = 17 more (168 / 140);
+# no sort came with it (the lanes are compacted by a prefix sum and a
+# binary search, and selected by the same rounds).  200 still catches
+# gross regressions while zero-full-pool-sorts stays the sharp pin
 DEFAULT_MAX_SCATTERS = 200
 
 
@@ -523,13 +531,15 @@ DEFAULT_ENTRIES = (
             "(inbox_impl=\"pallas\"; interpret mode off-TPU): zero "
             "full-pool sorts, Mosaic-custom-calls only, and a NEGATIVE "
             "scatter delta vs solo_tick — the fused kernel must "
-            "actually replace the 2R scatter-min rounds + fslot "
-            "compaction",
+            "actually replace the scatter-min rounds (both copies: the "
+            "D-lane branch and the P-wide one) + fslot compaction",
         contract=_FUSED_TICK,
         build=_build_fused_tick,
         # negative bound = a REQUIRED reduction: the fused tick must
         # carry at least 2 fewer scatters than solo_tick (measured:
-        # 2R+1 fewer; tests/test_kernels.py pins the exact count)
+        # 4R+2 fewer since PR 34 — 2R rounds in each branch of the
+        # default selection, its ``delivered`` write-back and the fslot
+        # scatter; tests/test_kernels.py pins the exact count)
         delta=DeltaContract(base="solo_tick", max_scatter_delta=-2)),
     EntryPoint(
         name="fused_chunk",

@@ -91,10 +91,11 @@ class Campaign:
     """
 
     def __init__(self, sim, params: CampaignParams | None = None):
-        # the vmapped step takes the dense sweep unless the awake-set
-        # plane was asked for by name: under vmap its round loop runs
-        # every replica for the busiest replica's rounds
-        self.sim = sim.dense_unless_asked() if sim is not None else sim
+        # the vmapped step takes the dense sweep (unless the awake-set
+        # plane was asked for by name) and the P-wide inbox selection:
+        # under vmap the round loop runs every replica for the busiest
+        # replica's rounds, and a lax.cond runs both its branches
+        self.sim = sim.for_vmap() if sim is not None else sim
         self.p = params or CampaignParams()
         if self.p.replicas < 1:
             raise ValueError("campaign needs at least one replica")

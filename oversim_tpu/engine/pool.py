@@ -8,12 +8,15 @@ simulation tick:
   * the due messages (deliver time inside the tick window) are grouped by
     destination into a fixed-width inbox index table.  The default
     ``scatter`` implementation runs R rounds of deterministic scatter-min
-    selection: each round one [P]→[N] scatter-min on t_deliver picks every
-    destination's earliest remaining due message (a second scatter-min on
-    the pool index breaks t_deliver ties exactly like the old stable
-    sort), the winners are masked out, and R rounds fill the [N, R] table
-    in O(R·P) work — ZERO full-pool sorts in the tick graph
-    (tests/test_engine.py pins sort and scatter counts on the HLO).  The
+    selection: each round one scatter-min on t_deliver over the
+    destination axis picks every destination's earliest remaining due
+    message (a second scatter-min on the pool index breaks t_deliver
+    ties exactly like the old stable sort), the winners are masked out,
+    and R rounds fill the [N, R] table.  The rounds run over the tick's
+    due messages, compacted into D = P/32 lanes, and over all P slots
+    only in a tick with more due messages than lanes: exact at any
+    load, ZERO full-pool sorts in the tick graph (tests/test_engine.py
+    pins sort and scatter counts on the HLO).  The
     legacy ``sort`` implementation (one lexicographic (dst, t_deliver)
     ``lax.sort``, O(P log P)) stays selectable via
     ``EngineParams.inbox_impl`` / the ``**.inboxImpl`` ini key; both
@@ -204,38 +207,48 @@ def build_inbox_sort(pool: MsgPool, n: int, r: int, t_end, alive,
     return inbox, delivered, to_dead
 
 
-def build_inbox_scatter(pool: MsgPool, n: int, r: int, t_end, alive,
-                        hold=None, *, axis_name=None, base=0, p_total=None):
-    """Zero-sort inbox grouping: R rounds of deterministic scatter-min.
+def inbox_lanes(p: int) -> int:
+    """D — the static lane count of the compacted inbox selection, from
+    P alone (as ``Simulation.acap`` is from N): P/32, at least 32.  On
+    the chip a scatter costs by its UPDATES (134 ns a slot a round at
+    N=1000 and N=4096 alike), a steady tick of the KBR cells has under a
+    hundred due messages among 8,000 to 131,072 slots, and a tick with
+    more than D takes the P-wide rounds, so D moves the cost of a tick
+    and never its result (PERF.md, PR 34)."""
+    return min(p, max(32, p // 32))
 
-    Round k scatter-mins t_deliver over the destination axis to find each
-    row's earliest remaining due message, then scatter-mins the POOL INDEX
-    over the messages matching that minimum — reproducing the stable
-    sort's exact (t_deliver, idx) tie-break — and masks the winners out.
-    O(R·P) work, 2R small [P]→[N] scatters, no full-pool sort.
-    Bit-identical to :func:`build_inbox_sort` (pinned by the identity
-    tests in tests/test_engine.py).
 
-    Under explicit node sharding (parallel/shard_tick.py) ``pool`` is
-    one shard's contiguous tile: pass the shard_map ``axis_name``, the
-    tile's ``base`` pool offset and the global ``p_total``.  Each round's
-    two scatter-mins then run on the LOCAL tile and merge across shards
-    with ``lax.pmin`` — the local-select + all-reduce:min form this
-    selection was designed for.  The per-round global minimum over
-    (t_deliver, pool index) is the min of the per-shard minima, so the
-    sharded table is bit-identical to the solo one; ``delivered`` /
-    ``to_dead`` come back tile-local.  Defaults leave the solo path
-    byte-for-byte unchanged.
-    """
+def _lanes(p: int, lanes) -> int:
+    return inbox_lanes(p) if lanes is None else min(lanes, p)
+
+
+def _fits(due, d: int):
+    """The tick's due messages fit the D compacted lanes."""
+    return jnp.sum(due.astype(I32)) <= d
+
+
+def lanes_swept(pool: MsgPool, n: int, t_end, alive, hold=None,
+                lanes=None):
+    """Candidates a round of :func:`build_inbox_scatter` sweeps in this
+    tick (i32): ``lanes`` when the due messages fit them, else all P.
+    What the engine's ``inbox_lanes`` counter adds; in one program with
+    the selection the due mask is computed once (same operands)."""
     p = pool.capacity
-    pt = p if p_total is None else p_total
-    due, to_dead = _due_masks(pool, n, t_end, alive, hold)
+    d = _lanes(p, lanes)
+    if d >= p:
+        return jnp.int32(p)
+    due, _ = _due_masks(pool, n, t_end, alive, hold)
+    return jnp.where(_fits(due, d), d, p).astype(I32)
 
-    idx = base + jnp.arange(p, dtype=I32)  # GLOBAL pool indices
-    dstc = jnp.clip(pool.dst, 0, n - 1)
-    # remaining-candidate key; winners flip to T_INF between rounds
-    tkey = jnp.where(due, pool.t_deliver, T_INF)
-    cols, delivered = [], jnp.zeros((p,), bool)
+
+def _scatter_rounds(tkey, dstc, idx, n: int, r: int, pt: int,
+                    axis_name=None):
+    """R rounds of deterministic scatter-min over L candidates (all P
+    pool slots, or the D compacted lanes): ``tkey`` [L] i64 deliver time
+    (T_INF = no candidate), ``dstc`` [L] clipped destination, ``idx``
+    [L] GLOBAL pool index.  Returns the [N, R] table and the [L] mask of
+    candidates placed in it."""
+    cols, taken = [], jnp.zeros(tkey.shape, bool)
     for _ in range(r):
         min_t = jnp.full((n,), T_INF, I64).at[dstc].min(tkey)
         if axis_name is not None:
@@ -246,13 +259,83 @@ def build_inbox_scatter(pool: MsgPool, n: int, r: int, t_end, alive,
             win = jax.lax.pmin(win, axis_name)
         cols.append(jnp.where(win < pt, win, NO_NODE))
         is_win = cand & (idx == win[dstc])
-        delivered |= is_win
+        taken |= is_win
         tkey = jnp.where(is_win, T_INF, tkey)
-    return jnp.stack(cols, axis=1), delivered, to_dead
+    return jnp.stack(cols, axis=1), taken
+
+
+def build_inbox_scatter(pool: MsgPool, n: int, r: int, t_end, alive,
+                        hold=None, *, lanes=None, axis_name=None, base=0,
+                        p_total=None):
+    """Zero-sort inbox grouping: R rounds of deterministic scatter-min,
+    over the tick's DUE messages compacted into D lanes.
+
+    Round k scatter-mins t_deliver over the destination axis to find each
+    row's earliest remaining due message, then scatter-mins the POOL INDEX
+    over the messages matching that minimum — reproducing the stable
+    sort's exact (t_deliver, idx) tie-break — and masks the winners out.
+    Bit-identical to :func:`build_inbox_sort` (pinned by the identity
+    tests in tests/test_engine.py).
+
+    A scatter costs by its updates, and a tick's due messages are a few
+    of the pool's P slots: so the due slots' pool indices are compacted,
+    ascending, into ``lanes`` static lanes (None: :func:`inbox_lanes`,
+    a rule of P alone), the rounds run over those, and ``delivered`` is
+    written back at full width — O(P) elementwise work and 2R+1
+    D-update scatters.  A tick with more due messages than lanes (a
+    fill, a saturated mix, flooding) takes the P-wide rounds through a
+    ``lax.cond``, 2R [P]→[N] scatters and O(R·P) work, for the same
+    answer: exact at any load, nothing deferred that the P-wide rounds
+    would deliver.  ``lanes >= P`` is the P-wide rounds alone (what a
+    caller that vmaps the step wants: under vmap a cond runs both
+    branches).
+
+    Under explicit node sharding (parallel/shard_tick.py) ``pool`` is
+    one shard's contiguous tile: pass the shard_map ``axis_name``, the
+    tile's ``base`` pool offset and the global ``p_total``.  Each round's
+    two scatter-mins then run on the LOCAL tile, P-wide, and merge across
+    shards with ``lax.pmin`` — the local-select + all-reduce:min form
+    this selection was designed for.  The per-round global minimum over
+    (t_deliver, pool index) is the min of the per-shard minima, so the
+    sharded table is bit-identical to the solo one; ``delivered`` /
+    ``to_dead`` come back tile-local.
+    """
+    p = pool.capacity
+    pt = p if p_total is None else p_total
+    due, to_dead = _due_masks(pool, n, t_end, alive, hold)
+
+    idx = base + jnp.arange(p, dtype=I32)  # GLOBAL pool indices
+    dstc = jnp.clip(pool.dst, 0, n - 1)
+
+    def wide(_):
+        # remaining-candidate key; winners flip to T_INF between rounds
+        return _scatter_rounds(jnp.where(due, pool.t_deliver, T_INF), dstc,
+                               idx, n, r, pt, axis_name)
+
+    d = _lanes(p, lanes)
+    if axis_name is not None or d >= p:
+        inbox, delivered = wide(None)
+        return inbox, delivered, to_dead
+
+    def compacted(_):
+        # lane j holds the pool index of the (j+1)-th due slot: the first
+        # slot whose inclusive due-count reaches j+1 (p past the last)
+        li = jnp.searchsorted(jnp.cumsum(due.astype(I32)),
+                              jnp.arange(1, d + 1, dtype=I32),
+                              side="left").astype(I32)
+        lic = jnp.minimum(li, p - 1)
+        inbox, taken = _scatter_rounds(
+            jnp.where(li < p, pool.t_deliver[lic], T_INF), dstc[lic], li,
+            n, r, pt)
+        return inbox, jnp.zeros((p,), bool).at[
+            jnp.where(taken, li, p)].set(True, mode="drop")
+
+    inbox, delivered = jax.lax.cond(_fits(due, d), compacted, wide, None)
+    return inbox, delivered, to_dead
 
 
 def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
-                impl: str = "scatter", hold=None):
+                impl: str = "scatter", hold=None, lanes=None):
     """Group due messages by destination into an index table.
 
     ``impl`` selects the grouping algorithm: ``"scatter"`` (default,
@@ -262,7 +345,8 @@ def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
     ``"sort"`` (legacy full-pool lexicographic sort, ORACLE-ONLY).  All
     three return bit-identical results.
     ``hold`` ([P] bool) excludes messages from delivery entirely — see
-    :func:`_due_masks`.
+    :func:`_due_masks`.  ``lanes`` is the scatter selection's static
+    lane count (None: :func:`inbox_lanes`); the others take no notice.
 
     Returns:
       inbox: [N, R] i32 pool indices, -1 for empty slots, ordered by
@@ -274,7 +358,8 @@ def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
     if impl == "sort":
         return build_inbox_sort(pool, n, r, t_end, alive, hold)
     if impl == "scatter":
-        return build_inbox_scatter(pool, n, r, t_end, alive, hold)
+        return build_inbox_scatter(pool, n, r, t_end, alive, hold,
+                                   lanes=lanes)
     if impl == "pallas":
         from oversim_tpu import kernels
         inbox, delivered, to_dead, _gblk = kernels.inbox.fused_inbox(

@@ -133,6 +133,16 @@ ENGINE_COUNTERS = ("queue_lost", "bit_error_lost", "dest_unavailable_lost",
 # paid, where the dense sweep pays ticks x N: the dense step, handed a
 # state that carries the counter, adds the alive rows it swept)
 SPARSE_COUNTERS = ("awake_nodes", "active_dst", "lanes_stepped")
+# inbox-selection accounting, carried where SPARSE_COUNTERS are (so the
+# dense layout — the oracle's, the campaign's, shard_tick's — stays what
+# it was; a state without them runs the same selection, uncounted),
+# cumulative: candidates a round of the selection swept (D in a tick
+# whose due messages fit the D compacted lanes, P in a tick that took
+# the P-wide rounds; engine/pool.py build_inbox_scatter) and the pool
+# slots the P-wide rounds would have swept (P every tick)
+INBOX_COUNTERS = ("inbox_lanes", "inbox_pool_slots")
+# what a state of the awake-set plane carries beside ENGINE_COUNTERS
+PLANE_COUNTERS = SPARSE_COUNTERS + INBOX_COUNTERS
 
 
 def resolve_tick_impl(tick_impl: str, logic) -> str:
@@ -194,7 +204,7 @@ class Simulation:
     def __init__(self, logic, churn_params: churn_mod.ChurnParams,
                  underlay_params=None,
                  engine_params: EngineParams | None = None,
-                 underlay_module=None):
+                 underlay_module=None, *, inbox_lanes: int | None = None):
         # the underlay is a strategy module (init/migrate/send_batch/
         # connection_matrix): underlay.simple (SimpleUnderlay, default)
         # or underlay.inet (InetUnderlay/ReaSEUnderlay router topology)
@@ -208,27 +218,42 @@ class Simulation:
         self.spec = logic.key_spec
         # "dense" | "sparse": what ep.tick_impl comes to for this logic
         self.tick_impl = resolve_tick_impl(self.ep.tick_impl, logic)
+        # D — lanes the scatter selection compacts a tick's due messages
+        # into (engine/pool.py inbox_lanes: a rule of P alone); P = the
+        # P-wide rounds only, and what every other inbox_impl sweeps.
+        # Code's to pass (``for_vmap``, a test), never a user's: it
+        # moves the cost of a tick, not its result
+        p = self.ep.pool_factor * self.n
+        if inbox_lanes is None:
+            inbox_lanes = (pool_mod.inbox_lanes(p)
+                           if self.ep.inbox_impl == "scatter" else p)
+        self.inbox_lanes = min(inbox_lanes, p)
 
-    def dense_unless_asked(self) -> "Simulation":
-        """This deployment with ``tick_impl="auto"`` settled as the
-        dense sweep: for a caller that vmaps the step (under vmap the
-        round loop runs every replica for the busiest one's rounds).
-        The GSPMD builders of parallel/mesh.py no longer take it: on
-        four chips the awake-set plane is the faster one (PERF.md,
-        PR 28)."""
-        if self.ep.tick_impl != "auto" or self.tick_impl == "dense":
+    def for_vmap(self) -> "Simulation":
+        """This deployment as a caller that vmaps the step wants it
+        (campaign/runner.py): ``tick_impl="auto"`` settled as the dense
+        sweep (under vmap the round loop runs every replica for the
+        busiest one's rounds) and the inbox selection P-wide (under vmap
+        a ``lax.cond`` runs both branches, so the compacted lanes would
+        come on top of the P-wide rounds).  Same results either way.
+        The GSPMD builders of parallel/mesh.py do not take it: on four
+        chips the awake-set plane is the faster one (PERF.md, PR 28)."""
+        p = self.ep.pool_factor * self.n
+        dense = self.ep.tick_impl != "auto" or self.tick_impl == "dense"
+        if dense and self.inbox_lanes == p:
             return self
-        return Simulation(
-            self.logic, self.cp, self.up,
-            dataclasses.replace(self.ep, tick_impl="dense"), self.ul)
+        ep = self.ep if dense else dataclasses.replace(
+            self.ep, tick_impl="dense")
+        return Simulation(self.logic, self.cp, self.up, ep, self.ul,
+                          inbox_lanes=p)
 
     @property
     def counter_names(self) -> tuple:
         """Counter keys carried in SimState.counters for this engine
-        config (the awake-set plane rides its accounting along; the
-        dense layout is untouched)."""
+        config (the awake-set plane rides its accounting and the inbox
+        selection's along; the dense layout is untouched)."""
         if self.tick_impl == "sparse":
-            return ENGINE_COUNTERS + SPARSE_COUNTERS
+            return ENGINE_COUNTERS + PLANE_COUNTERS
         return ENGINE_COUNTERS
 
     @property
@@ -359,11 +384,13 @@ class Simulation:
 
     def _phase_inbox_select(self, s: SimState, t_end, alive):
         """Phase 3a: pick each destination's R earliest due messages
-        (scatter-min rounds by default — zero full-pool sorts; see
-        engine/pool.py and ``EngineParams.inbox_impl``)."""
+        (scatter-min rounds over the due messages' compacted lanes by
+        default — zero full-pool sorts; see engine/pool.py and
+        ``EngineParams.inbox_impl``)."""
         return pool_mod.build_inbox(
             s.pool, self.n, self.ep.inbox_slots, t_end, alive,
-            impl=self.ep.inbox_impl, hold=self._hold_mask(s))
+            impl=self.ep.inbox_impl, hold=self._hold_mask(s),
+            lanes=self.inbox_lanes)
 
     def _msgs_from_block(self, s: SimState, t_next, inbox, blk,
                          t_deliver=None, stamp=None):
@@ -681,6 +708,13 @@ class Simulation:
             # every alive row was stepped, so a reader of lanes over
             # rows gets 100% and never 0; the other two tallies stay
             counters["lanes_stepped"] += jnp.sum(alive).astype(I64)
+        if "inbox_lanes" in counters:
+            # INBOX_COUNTERS: what this tick's selection swept a round
+            # over what the P-wide rounds sweep
+            counters["inbox_lanes"] += pool_mod.lanes_swept(
+                s.pool, self.n, t_end, alive, self._hold_mask(s),
+                self.inbox_lanes).astype(I64)
+            counters["inbox_pool_slots"] += s.pool.capacity
 
         # telemetry sample point (telemetry.py): END-of-tick snapshot of
         # the accumulators into the ring buffers, gated on the sampling
@@ -737,7 +771,7 @@ class Simulation:
         gather, and ``_node_step`` runs over the awake nodes only, in
         rounds of A compacted lanes.  Bit-identical to the dense
         ``step`` at any load and any ``active_cap`` (but for the
-        SPARSE_COUNTERS it carries): nothing is ever deferred."""
+        PLANE_COUNTERS it carries): nothing is ever deferred."""
         t_next, t_end, rngs = self._phase_horizon(s, ov=ov)
         (rng, r_churn, r_keys, r_reset, r_nodes, r_mig, r_send) = rngs
         (churn_state, alive, pre_killed, node_keys, ul_state,

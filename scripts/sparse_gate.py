@@ -66,10 +66,10 @@ def _build(tick_impl, inbox_impl, n=12, active_cap=0):
 def _strip_sparse(st):
     import dataclasses
 
-    from oversim_tpu.engine.sim import SPARSE_COUNTERS
+    from oversim_tpu.engine.sim import PLANE_COUNTERS
     return dataclasses.replace(
         st, counters={k: v for k, v in st.counters.items()
-                      if k not in SPARSE_COUNTERS})
+                      if k not in PLANE_COUNTERS})
 
 
 def main() -> int:

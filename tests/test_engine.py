@@ -11,6 +11,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from oversim_tpu import churn as churn_mod
 from oversim_tpu import stats as stats_mod
@@ -102,6 +103,136 @@ def test_inbox_impl_identity_randomized_pool():
         b = scat_j(pool, n=n, r=r, t_end=t_end, alive=alive)
         for x, y, name in zip(a, b, ("inbox", "delivered", "dropped_dead")):
             assert (np.asarray(x) == np.asarray(y)).all(), (trial, name)
+
+
+T_END = 1000       # the selection cases' window end (ns)
+
+
+def _case_pool(seed, n, p, n_due, *, t_due=(0, T_END), later=0.3,
+               dst=None):
+    """A [P] pool with exactly ``n_due`` slots valid and due before
+    T_END at random places, and a share ``later`` of the others valid
+    but due after it; destinations random in [0, n) unless given."""
+    rng = np.random.default_rng(seed)
+    due = np.zeros(p, bool)
+    due[rng.choice(p, size=n_due, replace=False)] = True
+    valid = due | (rng.random(p) < later)
+    t = np.where(due, rng.integers(*t_due, size=p),
+                 rng.integers(T_END, 2 * T_END, size=p)).astype(np.int64)
+    if dst is None:
+        dst = rng.integers(0, n, size=p)
+    base = pool_mod.empty(p, key_lanes=5, rmax=4)
+    return dataclasses.replace(
+        base, valid=jnp.asarray(valid),
+        t_deliver=jnp.where(jnp.asarray(valid), jnp.asarray(t),
+                            pool_mod.T_INF),
+        blk=base.blk.at[:, pool_mod._COL["dst"]].set(
+            jnp.asarray(dst, np.int32)))
+
+
+# name -> (n, p, r, n_due, lanes asked (None: the rule of P), what else)
+N_A, P_A, D_A = 16, 128, 32            # inbox_lanes(128) == 32
+SELECT_CASES = {
+    "due_0": (N_A, P_A, 3, 0, None, {}),
+    "due_1": (N_A, P_A, 3, 1, None, {}),
+    "due_d_minus_1": (N_A, P_A, 3, D_A - 1, None, {}),
+    "due_d": (N_A, P_A, 3, D_A, None, {}),
+    "due_d_plus_1": (N_A, P_A, 3, D_A + 1, None, {}),
+    "due_p": (N_A, P_A, 3, P_A, None, {}),
+    # two deliver times only: nearly every pick is a tie on t_deliver,
+    # broken by the pool index
+    "ties": (N_A, P_A, 3, 30, None, {"t_due": (5, 7)}),
+    # every due message for ONE destination: R go, the rest wait
+    "over_r": (N_A, P_A, 3, 20, None, {"dst": np.full(P_A, 5)}),
+    "dead_dst": (N_A, P_A, 3, 40, 64, {"dead": (2, 5, 11)}),
+    "hold": (N_A, P_A, 3, 30, None, {"hold_every": 3}),
+    # dst outside [0, n) is clipped into the end rows, as the P-wide
+    # rounds do (the sort oracle wraps or drops such rows: no oracle here)
+    "dst_out_of_range": (N_A, P_A, 3, 30, None,
+                         {"dst": np.arange(P_A) % (N_A + 6) - 3,
+                          "oracle": "wide"}),
+    "lanes_asked_5": (N_A, P_A, 3, 5, 5, {}),
+    "lanes_asked_5_over": (N_A, P_A, 3, 6, 5, {}),
+    # no power of two anywhere: N=1000, P=8000, D=250
+    "n1000_under": (1000, 8000, 8, 250, None, {}),
+    "n1000_over": (1000, 8000, 8, 251, None, {}),
+    "n1000_ties_over_r": (1000, 8000, 8, 200, None,
+                          {"t_due": (5, 8),
+                           "dst": np.arange(8000) % 7}),
+}
+_SELECTORS = {}
+
+
+def _selectors(lanes):
+    """(sort oracle, P-wide rounds, default selection, lanes swept),
+    jitted once for each lane count asked."""
+    if lanes not in _SELECTORS:
+        static = ("n", "r")
+        _SELECTORS[lanes] = (
+            jax.jit(pool_mod.build_inbox_sort, static_argnames=static),
+            jax.jit(lambda pool, **kw: pool_mod.build_inbox_scatter(
+                pool, lanes=pool.capacity, **kw), static_argnames=static),
+            jax.jit(lambda *a, **kw: pool_mod.build_inbox_scatter(
+                *a, lanes=lanes, **kw), static_argnames=static),
+            jax.jit(lambda pool, n, t_end, alive, hold:
+                    pool_mod.lanes_swept(pool, n, t_end, alive, hold,
+                                         lanes), static_argnums=(1,)))
+    return _SELECTORS[lanes]
+
+
+@pytest.mark.parametrize("name", list(SELECT_CASES))
+def test_inbox_select_over_due_lanes_equals_sort(name):
+    """The default selection (the due messages compacted into D lanes,
+    the P-wide rounds when they do not fit) equals the sort oracle on
+    ``inbox``, ``delivered`` and ``to_dead`` at every load around D, as
+    the P-wide rounds alone do, and sweeps D lanes exactly when the due
+    messages fit them."""
+    n, p, r, n_due, lanes, extra = SELECT_CASES[name]
+    extra = dict(extra)
+    dead = extra.pop("dead", ())
+    hold_every = extra.pop("hold_every", 0)
+    oracle = extra.pop("oracle", "sort")
+    pool = _case_pool(sum(map(ord, name)), n, p, n_due, **extra)
+    alive = jnp.ones((n,), bool).at[jnp.asarray(dead, I32)].set(False)
+    hold = (jnp.arange(p) % hold_every == 0) if hold_every else None
+    t_end = jnp.int64(T_END)
+    sort_j, wide_j, sel_j, swept_j = _selectors(lanes)
+    kw = dict(n=n, r=r, t_end=t_end, alive=alive, hold=hold)
+    want, got = wide_j(pool, **kw), sel_j(pool, **kw)
+    oracles = [want] + ([sort_j(pool, **kw)] if oracle == "sort" else [])
+    for ref in oracles:
+        for x, y, leaf in zip(ref, got, ("inbox", "delivered", "to_dead")):
+            assert x.dtype == y.dtype and x.shape == y.shape, leaf
+            assert (np.asarray(x) == np.asarray(y)).all(), leaf
+    # the selection's own count of due messages: live destination, not
+    # held
+    live = np.asarray(pool.valid & (pool.t_deliver < t_end)
+                      & ~want[2])
+    if hold is not None:
+        live = live & ~np.asarray(hold)
+    if not dead and hold is None:
+        assert live.sum() == n_due
+    d = pool_mod.inbox_lanes(p) if lanes is None else lanes
+    assert int(swept_j(pool, n, t_end, alive, hold)) == (
+        d if live.sum() <= d else p)
+    # slot 0 is filled first (what _phase_active_compact reads)
+    inbox = np.asarray(got[0])
+    assert ((inbox[:, 1:] < 0) | (inbox[:, :-1] >= 0)).all()
+
+
+def test_inbox_lanes_rule_and_wide_forms():
+    """D follows from P alone; ``lanes >= P`` and the tiled
+    (``axis_name``) form hold no branch: the P-wide rounds only."""
+    assert [pool_mod.inbox_lanes(p) for p in (8, 32, 128, 8000, 32768,
+                                              131072)] == \
+        [8, 32, 32, 250, 1024, 4096]
+    pool = _case_pool(1, N_A, P_A, 10)
+    args = (pool, N_A, 3, jnp.int64(T_END), jnp.ones((N_A,), bool))
+    txt = str(jax.make_jaxpr(
+        lambda: pool_mod.build_inbox_scatter(*args, lanes=P_A))())
+    assert "cond" not in txt and "cumsum" not in txt
+    assert "cond" in str(jax.make_jaxpr(
+        lambda: pool_mod.build_inbox_scatter(*args))())
 
 
 def test_inbox_overflow_keeps_earliest_r():
@@ -281,17 +412,20 @@ def test_tick_hlo_zero_sorts_bounded_scatters():
     """The default scatter-min inbox leaves the tick graph with ZERO
     full-pool sorts, and the scatter count stays within the engine
     budget (8 baseline scatters — outbox alloc, stat hists, misc — plus
-    2 per inbox round), pinned via scripts/hlo_breakdown.py's counting
-    helpers so the --budget CLI and this test share one definition.
-    n=24 makes the pool dimension P = 24*8 = 192 distinctive in shape
-    strings."""
+    2 per inbox round in EACH branch of the selection: over the due
+    messages' D = 32 compacted lanes with one more to write
+    ``delivered`` back, and P-wide for a tick whose due messages
+    outnumber the lanes), pinned via scripts/hlo_breakdown.py's
+    counting helpers so the --budget CLI and this test share one
+    definition.  n=24 makes the pool dimension P = 24*8 = 192
+    distinctive in shape strings."""
     from scripts.hlo_breakdown import check_budget
     sim = make_sim(n=24)
     s = sim.init(seed=1)
     txt = jax.jit(lambda st: sim.step(st)).lower(s).compile().as_text()
     ok, counts = check_budget(
         txt, pool_dim=192, max_full_pool_sorts=0,
-        max_scatters=8 + 2 * sim.ep.inbox_slots)
+        max_scatters=8 + 4 * sim.ep.inbox_slots + 1)
     assert ok, counts
     assert counts["full_pool_sort_count"] == 0, counts
 

@@ -188,12 +188,19 @@ def _churn_sim(overlay, inbox_impl, tick_impl="auto"):
 
 def _fused_identity_run(overlay, n_ticks=64, seed=3):
     """64 churned ticks, full-step: every SimState leaf after the run
-    must be bit-identical between the fused and scatter engines."""
+    must be bit-identical between the fused and scatter engines, but
+    for ``inbox_lanes``, each selection's own count of what it swept
+    (P every tick for the kernel, D or P for the scatter rounds)."""
     finals = {}
     for impl in ("scatter", "pallas"):
         sim = _churn_sim(overlay, impl)
         s = sim.init(seed=seed)
         finals[impl] = jax.device_get(sim.run_chunk(s, n_ticks))
+    p = sim.ep.pool_factor * sim.n
+    swept = {impl: int(st.counters.pop("inbox_lanes"))
+             for impl, st in finals.items()}
+    assert swept["scatter"] < swept["pallas"] == p * n_ticks \
+        == int(finals["pallas"].counters["inbox_pool_slots"])
     la, ta = jax.tree_util.tree_flatten(finals["scatter"])
     lb, tb = jax.tree_util.tree_flatten(finals["pallas"])
     assert ta == tb
@@ -216,10 +223,14 @@ def test_fused_tick_identity_kademlia_under_churn():
 
 
 def test_fused_tick_hlo_scatter_reduction():
-    """The compiled fused tick must carry EXACTLY 2R+1 fewer scatter
+    """The compiled fused tick must carry EXACTLY 4R+2 fewer scatter
     ops than the scatter tick (R scatter-min key rounds + R index
-    rounds + the outbox fslot scatter fold into the kernels), zero
-    full-pool sorts, and — in interpret mode — zero custom-calls.  The
+    rounds in EACH branch of the default selection — over the due
+    messages' D compacted lanes, and P-wide behind its ``lax.cond`` —
+    the D-lane branch's ``delivered`` write-back and the outbox fslot
+    scatter all fold into the kernels), zero full-pool sorts, no sort
+    the scatter tick lacks, and — in interpret mode — zero
+    custom-calls.  The
     pin is the dense oracle's (on the awake-set plane, Chord's default,
     the kernel plane also folds the awake-set compaction's scatter)."""
     from oversim_tpu.analysis import hlo_text
@@ -235,7 +246,7 @@ def test_fused_tick_hlo_scatter_reduction():
         r = sim.ep.inbox_slots
     drop = census["scatter"]["scatter_count"] \
         - census["pallas"]["scatter_count"]
-    assert drop == 2 * r + 1, census
+    assert drop == 4 * r + 2, census
     assert census["pallas"]["full_pool_sort_count"] == 0
     assert census["pallas"]["custom_calls"] == {}
     assert census["pallas"]["sort_count"] \

@@ -97,11 +97,16 @@ def test_four_devices_equal_one_device(solo, meshed, tick_impl):
     for k, v in solo.stats.items():
         if np.issubdtype(np.asarray(v).dtype, np.integer):
             assert np.array_equal(s4.stats[k], v), k
-    # the dense layout carries no awake-set tallies; all else is equal,
-    # and on the awake-set plane those too (rounds x A lanes)
+    # the dense layout carries no awake-set tallies and no account of
+    # the inbox selection; all else is equal, and on the awake-set plane
+    # those too (rounds x A lanes; D lanes or P slots a tick)
     for k, v in solo.counters.items():
-        if tick_impl != "dense" or k not in sim_mod.SPARSE_COUNTERS:
+        if tick_impl != "dense" or k not in sim_mod.PLANE_COUNTERS:
             assert int(s4.counters[k]) == int(v), k
+    if tick_impl != "dense":
+        p = sim_mod.EngineParams().pool_factor * N
+        assert int(s4.counters["inbox_pool_slots"]) == p * int(s4.tick)
+        assert 0 < int(s4.counters["inbox_lanes"]) < p * int(s4.tick)
     for name in ("t_test", "seq"):
         assert np.array_equal(getattr(s4.logic.app, name),
                               getattr(solo.logic.app, name)), name

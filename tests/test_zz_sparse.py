@@ -31,7 +31,7 @@ from oversim_tpu import churn as churn_mod
 from oversim_tpu import kernels
 from oversim_tpu.apps.kbrtest import KbrTestApp, KbrTestParams
 from oversim_tpu.engine.sim import (
-    ENGINE_COUNTERS, SPARSE_COUNTERS, EngineParams, Simulation)
+    ENGINE_COUNTERS, PLANE_COUNTERS, EngineParams, Simulation)
 
 
 def _sim(overlay, inbox_impl="scatter", tick_impl="dense", active_cap=0,
@@ -56,7 +56,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL_N = 128
 
 
-def _cell_sim(tick_impl="auto", active_cap=0, n=CELL_N):
+def _cell_sim(tick_impl="auto", active_cap=0, n=CELL_N, **engine):
     """``kademlia4096.kbr60``'s deployment as the benchmark builds it
     (benchmark/program.py: the configuration file's own ini text and
     engine sizes, the traffic file's overrides, ``build_simulation``)
@@ -71,8 +71,8 @@ def _cell_sim(tick_impl="auto", active_cap=0, n=CELL_N):
     pairs["**.initPhaseCreationInterval"] = float(config["fill_s"]) / n
     ini = IniFile.loads("\n".join(config["ini"]))
     section = ini.with_overrides("General", pairs)
-    ep = EngineParams(**config["engine"], tick_impl=tick_impl,
-                      active_cap=active_cap)
+    ep = EngineParams(**{**config["engine"], **engine},
+                      tick_impl=tick_impl, active_cap=active_cap)
     return build_simulation(ini, section, ep), config
 
 
@@ -82,7 +82,7 @@ def _strip_sparse(st):
     them — sim.counter_names)."""
     return dataclasses.replace(
         st, counters={k: v for k, v in st.counters.items()
-                      if k not in SPARSE_COUNTERS})
+                      if k not in PLANE_COUNTERS})
 
 
 def _assert_tree_equal(a, b):
@@ -106,9 +106,10 @@ def _identity_run(overlay, inbox_impl, n_ticks=64, seed=3, **kw):
         s = sim.init(seed=seed)
         finals[tick_impl] = jax.device_get(sim.run_chunk(s, n_ticks))
     # counter layout: dense stays pre-sparse, sparse rides its three
+    # and the inbox selection's two
     assert set(finals["dense"].counters) == set(ENGINE_COUNTERS)
     assert set(finals["sparse"].counters) \
-        == set(ENGINE_COUNTERS + SPARSE_COUNTERS)
+        == set(ENGINE_COUNTERS + PLANE_COUNTERS)
     _assert_tree_equal(finals["dense"], _strip_sparse(finals["sparse"]))
     assert int(finals["dense"].tick) == n_ticks
     return finals
@@ -124,7 +125,7 @@ def test_dense_step_counts_its_rows_on_an_awake_set_layout():
     sparse = _sim("kademlia", tick_impl="sparse")
     step = jax.jit(dense.step)
     s, ref = sparse.init(seed=3), dense.init(seed=3)
-    assert set(s.counters) == set(ENGINE_COUNTERS + SPARSE_COUNTERS)
+    assert set(s.counters) == set(ENGINE_COUNTERS + PLANE_COUNTERS)
     rows = 0
     for _ in range(48):
         s, ref = step(s), step(ref)
@@ -256,13 +257,16 @@ def test_default_tick_plane_resolution():
     assert not any("tickImpl" in ln for ln in config["ini"])
     assert cell.logic.awake_set_exact and cell.tick_impl == "sparse"
     assert cell.acap == min(cell.n, max(32, cell.n // 32)) == 32
-    assert set(cell.counter_names) == set(ENGINE_COUNTERS + SPARSE_COUNTERS)
+    assert set(cell.counter_names) == set(ENGINE_COUNTERS + PLANE_COUNTERS)
 
     pastry = build_simulation(IniFile.loads(PASTRY_INI))
     assert not getattr(pastry.logic, "awake_set_exact", False)
     assert pastry.ep.tick_impl == "auto" and pastry.tick_impl == "dense"
     assert pastry.counter_names == ENGINE_COUNTERS
-    assert pastry.dense_unless_asked() is pastry
+    twin = pastry.for_vmap()
+    assert twin.tick_impl == "dense" and twin.ep == pastry.ep
+    assert twin.inbox_lanes == pastry.ep.pool_factor * pastry.n
+    assert twin.for_vmap() is twin
     with pytest.raises(ScenarioError, match="awake_set_exact"):
         build_simulation(IniFile.loads(
             PASTRY_INI + '**.tickImpl = "sparse"\n'))
@@ -280,8 +284,14 @@ def test_default_tick_plane_resolution():
     camp = Campaign(cell)
     assert camp.sim is not cell and camp.sim.tick_impl == "dense"
     assert camp.sim.counter_names == ENGINE_COUNTERS
+    # ... and the P-wide inbox selection (under vmap a cond runs both
+    # its branches); the cell compacts the due messages into P/32 lanes
+    p = cell.ep.pool_factor * cell.n
+    assert cell.inbox_lanes == p // 32 and camp.sim.inbox_lanes == p
     asked, _ = _cell_sim(tick_impl="sparse")
-    assert Campaign(asked).sim is asked                # asked by name
+    twin = Campaign(asked).sim                         # asked by name
+    assert twin.tick_impl == "sparse" and twin.ep == asked.ep
+    assert twin.inbox_lanes == p
     # the hand-sharded tick runs dense alone, and has to be asked so
     mesh = mesh_mod.make_mesh_2d(1, 2)
     for refused in (cell, asked):

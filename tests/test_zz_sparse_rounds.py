@@ -6,7 +6,11 @@ for bit at any active_cap.
 Pinned here: the benchmark cell's own deployment at N=128 through fill,
 settling and 100 ticks of window at A=4 (most ticks take several
 rounds), ``lanes_stepped`` against the recorded awake counts, every
-node awake (N/A rounds a tick), and an idle tick (no round).  The
+node awake (N/A rounds a tick), and an idle tick (no round).  The same
+recorded run pins the inbox selection over the due messages' D lanes
+(engine/pool.py build_inbox_scatter; ISSUE 34): its oracle selects by
+the full-pool sort, and ``inbox_lanes`` adds D in a tick whose due
+messages fit the lanes and P in a tick that fell back.  The
 helpers are test_zz_sparse.py's; a module of its own because a module
 is one unit of work on one xdist worker (tests/conftest.py).
 """
@@ -19,7 +23,8 @@ import numpy as np
 import pytest
 
 from oversim_tpu import churn as churn_mod
-from oversim_tpu.engine.sim import SPARSE_COUNTERS
+from oversim_tpu.engine import pool as pool_mod
+from oversim_tpu.engine.sim import INBOX_COUNTERS, SPARSE_COUNTERS
 
 from test_zz_sparse import (
     CELL_N, _assert_tree_equal, _cell_sim, _sim, _strip_sparse)
@@ -31,23 +36,41 @@ CELL_CAP = 4          # A: most ticks of the run below take 2+ rounds
 @pytest.fixture(scope="module")
 def cell_run():
     """The cell's deployment at N=128 through its fill (20 s), its
-    settling (20 s) and 100 ticks of window, tick by tick, under
-    ``tick_impl="dense"`` and under the awake-set plane at A=4; the
-    plane's counters after every tick are the recorded run."""
+    settling (20 s) and 100 ticks of window, tick by tick, under the
+    oracle of both planes (``tick_impl="dense"``, every row swept, and
+    ``inbox_impl="sort"``, the full-pool sort) and under the engine's
+    defaults at A=4 (the awake-set plane; the inbox selected over the
+    due messages' D lanes); the plane's counters after every tick, and
+    the due messages each tick's selection met, are the recorded run."""
     sparse, config = _cell_sim(active_cap=CELL_CAP)
-    dense, _ = _cell_sim(tick_impl="dense")
+    dense, _ = _cell_sim(tick_impl="dense", inbox_impl="sort")
     assert sparse.tick_impl == "sparse" and sparse.acap == CELL_CAP
-    assert dense.tick_impl == "dense"
+    assert sparse.ep.inbox_impl == "scatter"
+    assert dense.tick_impl == "dense" and dense.ep.inbox_impl == "sort"
     ticks = int(round((config["fill_s"] + config["settle_s"])
                       / config["engine"]["window"])) + 100
+
+    @jax.jit
+    def due_met(s):
+        """The due messages the tick about to run selects among."""
+        t_next, t_end, rngs = sparse._phase_horizon(s)
+        alive = sparse._phase_churn(s, t_next, t_end, rngs[1], rngs[2],
+                                    rngs[3], rngs[5])[1]
+        return jnp.sum(pool_mod._due_masks(
+            s.pool, sparse.n, t_end, alive)[0])
+
     sd, ss = dense.init(seed=5), sparse.init(seed=5)
-    rec = []
+    rec, n_due = [], []
     for _ in range(ticks):
+        n_due.append(int(due_met(ss)))
         ss = sparse.run_chunk(ss, 1)
         rec.append(jax.device_get(ss.counters))   # ss is donated next
     sd = dense.run_chunk(sd, ticks)
     return dict(dense=jax.device_get(sd), sparse=jax.device_get(ss),
-                rec=rec, ticks=ticks, n=sparse.n)
+                rec=rec, n_due=np.asarray(n_due), ticks=ticks, n=sparse.n,
+                p=sparse.ep.pool_factor * sparse.n, d=sparse.inbox_lanes,
+                fill_ticks=int(round(config["fill_s"]
+                                     / config["engine"]["window"])))
 
 
 def test_rounds_identity_on_the_cells_deployment(cell_run):
@@ -83,6 +106,26 @@ def test_lanes_stepped_counts_the_rounds(cell_run):
     assert lanes[-100:].sum() < 0.5 * 100 * n       # the window's share
 
 
+def test_inbox_lanes_counts_d_under_d_and_p_over_it(cell_run):
+    """On the recorded run (whose every leaf equals the sort oracle's,
+    above): ``inbox_pool_slots`` adds P every tick; ``inbox_lanes`` adds
+    D in a tick whose due messages fit the D lanes and P in a tick that
+    fell back to the P-wide rounds; the fill overflows D, the window's
+    steady ticks do not."""
+    rec, p, d = cell_run["rec"], cell_run["p"], cell_run["d"]
+    n_due, fill = cell_run["n_due"], cell_run["fill_ticks"]
+    assert d == pool_mod.inbox_lanes(p) == 32 and p == 8 * CELL_N
+    slots = np.diff([0] + [int(c["inbox_pool_slots"]) for c in rec])
+    lanes = np.diff([0] + [int(c["inbox_lanes"]) for c in rec])
+    assert (slots == p).all()
+    assert (lanes == np.where(n_due <= d, d, p)).all()
+    over = n_due > d
+    assert over[:fill].any() and (~over[:fill]).any()   # both branches ran
+    assert n_due.max() < p and n_due.min() == 0
+    assert not over[-100:].any()                         # the window
+    assert lanes[-100:].sum() == 100 * d
+
+
 def test_every_node_awake_takes_n_over_a_rounds():
     """The dense-equivalent load: a KBRTest interval shorter than the
     window keeps every ready node awake in every tick; at A = N/8 that
@@ -114,7 +157,8 @@ def test_every_node_awake_takes_n_over_a_rounds():
 
 def test_idle_tick_runs_no_round():
     """With nothing due anywhere the tick runs zero rounds and leaves
-    every leaf but the clock, the tick count and the rng as it was."""
+    every leaf but the clock, the tick count, the rng and the inbox
+    selection's two counters as it was."""
     sim = _sim("kademlia", tick_impl="sparse", churn="none")
     s0 = sim.init(seed=11)
     s0 = dataclasses.replace(s0, churn=dataclasses.replace(
@@ -131,5 +175,14 @@ def test_idle_tick_runs_no_round():
     assert int(after.tick) == 1 and int(after.t_now) > int(before.t_now)
     assert not np.array_equal(jax.random.key_data(after.rng),
                               jax.random.key_data(before.rng))
-    same = dict(t_now=before.t_now, tick=before.tick, rng=before.rng)
+    # ... and the inbox selection's account of the tick: no message was
+    # due, so its rounds swept the D empty lanes, of the pool's P slots
+    p = sim.ep.pool_factor * n
+    assert int(after.counters["inbox_lanes"]) == sim.inbox_lanes < p
+    assert int(after.counters["inbox_pool_slots"]) == p
+    same = dict(t_now=before.t_now, tick=before.tick, rng=before.rng,
+                counters=before.counters)
     _assert_tree_equal(dataclasses.replace(after, **same), before)
+    assert all(int(after.counters[k]) == int(v)
+               for k, v in before.counters.items()
+               if k not in INBOX_COUNTERS)
