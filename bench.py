@@ -341,7 +341,7 @@ def run_measurement_windows(sim, s, *, start_sim_t, window_sim_s,
 # ---------------------------------------------------------------------------
 
 def run_activity_sweep(fracs, *, n, overlay, window, inbox, pool_f, slots,
-                       inbox_impl, tick_impl, active_cap, chunk, platform,
+                       tick_impl, active_cap, chunk, platform,
                        warm_extra=5.0, reps=3):
     """ms/tick vs activity fraction — the sparse plane's success metric
     (ISSUE 16: steady ms/tick at N=65k with 1% activity within 2x of
@@ -383,7 +383,6 @@ def run_activity_sweep(fracs, *, n, overlay, window, inbox, pool_f, slots,
                                    init_deviation=2.0 / n)
         ep = sim_mod.EngineParams(window=window, inbox_slots=inbox,
                                   pool_factor=pool_f,
-                                  inbox_impl=inbox_impl,
                                   tick_impl=tick_impl,
                                   active_cap=active_cap)
         sim = sim_mod.Simulation(logic, cp, engine_params=ep)
@@ -410,7 +409,6 @@ def run_activity_sweep(fracs, *, n, overlay, window, inbox, pool_f, slots,
             "overlay": overlay,
             "window": window,
             "tick_impl": tick_impl,
-            "inbox_impl": inbox_impl,
             "active_cap": active_cap,
             "platform": platform,
             "warm_wall_s": round(warm_wall, 1),
@@ -552,13 +550,7 @@ def child_main():
     # is emitted as a telemetry_series side-channel line after the run
     tel_ticks = int(os.environ.get("OVERSIM_BENCH_TELEMETRY", "0"))
     tel_window = int(os.environ.get("OVERSIM_BENCH_TELEMETRY_WINDOW", "256"))
-    # OVERSIM_BENCH_INBOX_IMPL: scatter (default) | pallas (fused
-    # kernel plane, oversim_tpu/kernels/) | sort (oracle-only) —
-    # resolved like **.inboxImpl (pallas raises when the plane is
-    # unavailable, sort warns)
     from oversim_tpu.config import scenario as scenario_mod
-    inbox_impl = scenario_mod.resolve_inbox_impl(
-        os.environ.get("OVERSIM_BENCH_INBOX_IMPL", "scatter"))
     # OVERSIM_BENCH_TICK_IMPL: dense (full-N oracle, default) | sparse
     # (active-set plane — tick cost bounded by traffic, not N;
     # engine/sim.py _step_sparse).  OVERSIM_BENCH_ACTIVE_CAP bounds the
@@ -576,7 +568,6 @@ def child_main():
     from oversim_tpu import telemetry as telemetry_mod
     ep = sim_mod.EngineParams(window=window, inbox_slots=inbox,
                               pool_factor=pool_f,
-                              inbox_impl=inbox_impl,
                               tick_impl=tick_impl,
                               active_cap=active_cap,
                               telemetry=telemetry_mod.TelemetryParams(
@@ -598,7 +589,7 @@ def child_main():
 
     # Mesh layout string ("RxK") for the manifest and every artifact
     # row — ladder rows from different mesh shapes must never silently
-    # merge (scripts/scale_smoke.py keys its cache on this too).
+    # merge.
     if node_shards > 1:
         _avail = len(jax.devices())
         if replicas >= 1:
@@ -647,8 +638,7 @@ def child_main():
             role="bench",
             port=int(metrics_port) if metrics_port is not None else None,
             flight_path=flight_path)
-        obs.set_static(n=n, overlay=overlay, inbox_impl=inbox_impl,
-                       tick_impl=tick_impl,
+        obs.set_static(n=n, overlay=overlay, tick_impl=tick_impl,
                        replicas=int(os.environ.get(
                            "OVERSIM_BENCH_REPLICAS", "0")),
                        node_shards=node_shards)
@@ -662,8 +652,6 @@ def child_main():
     print(json.dumps(telemetry_mod.run_manifest(
         config={"n": n, "overlay": overlay, "interval": interval,
                 "window": window, "inbox": inbox, "pool_factor": pool_f,
-                "inbox_impl": inbox_impl,
-                "kernel_plane": inbox_impl == "pallas",
                 "tick_impl": tick_impl, "active_cap": active_cap,
                 "chunk": chunk, "slots": slots,
                 "telemetry_sample_ticks": tel_ticks,
@@ -685,8 +673,8 @@ def child_main():
         fracs = [float(x) for x in activity_env.split(",") if x.strip()]
         run_activity_sweep(
             fracs, n=n, overlay=overlay, window=window, inbox=inbox,
-            pool_f=pool_f, slots=slots, inbox_impl=inbox_impl,
-            tick_impl=tick_impl, active_cap=active_cap, chunk=chunk,
+            pool_f=pool_f, slots=slots, tick_impl=tick_impl,
+            active_cap=active_cap, chunk=chunk,
             platform=dev.platform)
         if obs is not None:
             obs.close()
@@ -795,7 +783,6 @@ def child_main():
                 f"delivery {delivered}/{sent}, {out['_ticks']} ticks, "
                 f"{wall:.1f}s wall)")
         extra = {"delivery": round(delivery, 4),
-                 "inbox_impl": inbox_impl,
                  "tick_impl": tick_impl,
                  "node_shards": node_shards,
                  "mesh": mesh_layout,

@@ -26,7 +26,6 @@ from oversim_tpu.analysis.contracts import (      # noqa: F401
     REGISTRY,
     entries,
     register_entry,
-    scenario_pins,
 )
 from oversim_tpu.analysis.findings import (       # noqa: F401
     Finding,

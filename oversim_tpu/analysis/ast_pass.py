@@ -4,7 +4,7 @@ The compiled-graph contracts (hlo_pass.py) catch a regression AFTER it
 reaches XLA; this pass catches the source patterns that cause them —
 host numpy / ``.item()`` / ``float()`` / ``jax.device_get`` /
 ``time.time()`` inside the hot-path modules, ``lax.sort`` family calls
-outside the allowlisted ``inbox_impl="sort"`` oracle, un-donated ``jit``
+that no ``allow(sort-call)`` marker owns up to, un-donated ``jit``
 decorators on state-carrying functions, and silent host reads of
 SimState leaves anywhere in the package.
 

@@ -65,14 +65,6 @@ class GraphContract:
     # whose census is mesh-dependent.
     allowed_collectives: frozenset = frozenset()
     collectives_enforced: bool = True
-    # custom-call allowlist (hlo_text.custom_call_census targets).  The
-    # kernel plane's entries enforce it: on TPU the fused Pallas
-    # kernels appear as Mosaic ``tpu_custom_call`` ops and NOTHING else
-    # may — under interpret mode (CPU CI) the census is empty, so the
-    # allowlist is an upper bound both backends satisfy.  Off by
-    # default: pre-kernel entries never audited their custom-calls.
-    allowed_custom_calls: frozenset = frozenset()
-    custom_calls_enforced: bool = False
     max_host_transfers: int = 0
     # donation: the optimized module header must carry input→output
     # buffer aliases (may-/must-alias) — dropped donation round-trips
@@ -169,13 +161,13 @@ class EntryPoint:
 # builders (import jax lazily — the registry itself stays import-safe)
 # ---------------------------------------------------------------------------
 
-def build_sim(ctx: EntryContext, *, inbox_impl: str = "scatter",
-              telemetry_ticks: int = 0, ext_hold_slot: int = -1,
-              tick_impl: str = "dense", active_cap: int = 0):
+def build_sim(ctx: EntryContext, *, telemetry_ticks: int = 0,
+              ext_hold_slot: int = -1, tick_impl: str = "dense",
+              active_cap: int = 0):
     """The bench-shaped Simulation every entry compiles (KbrTestApp over
     chord/kademlia, churn off — the same construction the historical
     hlo_breakdown modes used).  ``tick_impl`` is "dense" unless an
-    entry asks otherwise: the solo/fused/sharded pins are the dense
+    entry asks otherwise: the solo/sharded pins are the dense
     ORACLE's, and ``sparse_tick``/``sparse_chunk`` are the engine's
     default plane for these logics (EngineParams.tick_impl "auto")."""
     from oversim_tpu import churn as churn_mod
@@ -198,9 +190,8 @@ def build_sim(ctx: EntryContext, *, inbox_impl: str = "scatter",
                                init_deviation=2.0 / ctx.n)
     ep = sim_mod.EngineParams(
         window=ctx.window, inbox_slots=ctx.inbox,
-        pool_factor=ctx.pool_factor, inbox_impl=inbox_impl,
-        ext_hold_slot=ext_hold_slot, tick_impl=tick_impl,
-        active_cap=active_cap,
+        pool_factor=ctx.pool_factor, ext_hold_slot=ext_hold_slot,
+        tick_impl=tick_impl, active_cap=active_cap,
         telemetry=telemetry_mod.TelemetryParams(
             sample_ticks=telemetry_ticks))
     return sim_mod.Simulation(logic, cp, engine_params=ep)
@@ -318,29 +309,6 @@ def _build_resharded_resume(ctx):
               "devices": n_dev})
 
 
-def _build_fused_tick(ctx):
-    import jax
-    sim = build_sim(ctx, inbox_impl="pallas")
-    fn = jax.jit(sim.step)
-    s0 = sim.init(seed=7)
-    return EntryBuild(fn=fn, make_args=lambda: (s0,),
-                      pool_dim=sim.ep.pool_factor * ctx.n,
-                      info={"n": ctx.n, "overlay": ctx.overlay,
-                            "inbox_impl": "pallas"})
-
-
-def _build_fused_chunk(ctx):
-    sim = build_sim(ctx, inbox_impl="pallas")
-    # same static-self discipline as solo_chunk: ONE sim instance, the
-    # unbound class-level jit, fresh donated state per call
-    return EntryBuild(
-        fn=type(sim).run_chunk,
-        make_args=lambda: (sim, sim.init(seed=7), ctx.chunk),
-        pool_dim=sim.ep.pool_factor * ctx.n,
-        info={"n": ctx.n, "overlay": ctx.overlay, "n_ticks": ctx.chunk,
-              "inbox_impl": "pallas"})
-
-
 def _build_sparse_tick(ctx):
     import jax
     # a genuinely sparse lane count (cap < n) so the compiled graph has
@@ -359,7 +327,7 @@ def _build_sparse_tick(ctx):
 def _build_sparse_chunk(ctx):
     cap = max(8, ctx.n // 4)
     sim = build_sim(ctx, tick_impl="sparse", active_cap=cap)
-    # same static-self discipline as solo_chunk/fused_chunk
+    # same static-self discipline as solo_chunk
     return EntryBuild(
         fn=type(sim).run_chunk,
         make_args=lambda: (sim, sim.init(seed=7), ctx.chunk),
@@ -470,19 +438,6 @@ def _build_daemon_window(ctx):
 _TICK = GraphContract()
 _DONATED = GraphContract(require_donation=True)
 
-# the only custom-call the kernel plane may introduce: the Mosaic
-# lowering of pl.pallas_call on TPU.  Interpret mode (CPU CI) lowers
-# the kernels inline — zero custom-calls — so the allowlist holds on
-# both backends (oversim_tpu/kernels/).
-KERNEL_CUSTOM_CALLS = frozenset({"tpu_custom_call"})
-_FUSED_TICK = GraphContract(
-    custom_calls_enforced=True,
-    allowed_custom_calls=KERNEL_CUSTOM_CALLS)
-_FUSED_CHUNK = GraphContract(
-    require_donation=True,
-    custom_calls_enforced=True,
-    allowed_custom_calls=KERNEL_CUSTOM_CALLS)
-
 DEFAULT_ENTRIES = (
     EntryPoint(
         name="solo_tick",
@@ -525,29 +480,6 @@ DEFAULT_ENTRIES = (
             "program, donated, zero cross-replica collectives",
         contract=_DONATED,
         build=_build_daemon_window),
-    EntryPoint(
-        name="fused_tick",
-        doc="jit(sim.step) with the Pallas kernel plane armed "
-            "(inbox_impl=\"pallas\"; interpret mode off-TPU): zero "
-            "full-pool sorts, Mosaic-custom-calls only, and a NEGATIVE "
-            "scatter delta vs solo_tick — the fused kernel must "
-            "actually replace the scatter-min rounds (both copies: the "
-            "D-lane branch and the P-wide one) + fslot compaction",
-        contract=_FUSED_TICK,
-        build=_build_fused_tick,
-        # negative bound = a REQUIRED reduction: the fused tick must
-        # carry at least 2 fewer scatters than solo_tick (measured:
-        # 4R+2 fewer since PR 34 — 2R rounds in each branch of the
-        # default selection, its ``delivered`` write-back and the fslot
-        # scatter; tests/test_kernels.py pins the exact count)
-        delta=DeltaContract(base="solo_tick", max_scatter_delta=-2)),
-    EntryPoint(
-        name="fused_chunk",
-        doc="run_chunk with the kernel plane armed: donation must "
-            "survive the pallas path (the pool block stays in-place "
-            "across chunks)",
-        contract=_FUSED_CHUNK,
-        build=_build_fused_chunk),
     EntryPoint(
         name="sparse_tick",
         doc="jit(sim.step, donate) on the awake-set plane (tick_impl="
@@ -633,79 +565,3 @@ def entries(names=None) -> list:
         raise KeyError(f"unknown entries: {', '.join(missing)} "
                        f"(known: {', '.join(REGISTRY)})")
     return [REGISTRY[n] for n in names]
-
-
-# ---------------------------------------------------------------------------
-# scenario pins (config-level contracts — no compilation needed)
-# ---------------------------------------------------------------------------
-
-_DEFAULT_INI = """
-[General]
-**.overlayType = "oversim.overlay.kademlia.KademliaModules"
-**.targetOverlayTerminalNum = 16
-"""
-
-
-def scenario_pins() -> list:
-    """Config-level contract: the DEFAULT scenario resolution must never
-    pick ``inbox_impl="sort"`` — the legacy sort path is oracle-only
-    (ROADMAP item 6); only an explicit ``**.inboxImpl = "sort"`` key may
-    select it.  The kernel plane adds two pins: an explicit
-    ``"pallas"`` key is honored when the plane is importable, and a
-    pallas request on a kernel-less install raises ``ScenarioError``
-    (never a quiet run on another path).  Returns Finding rows
-    (empty = pinned)."""
-    from oversim_tpu.analysis.findings import Finding
-    from oversim_tpu.config import scenario
-    from oversim_tpu.config.ini import IniFile
-
-    out = []
-    ini = IniFile.loads(_DEFAULT_INI)
-    sim = scenario.build_simulation(ini, "General")
-    if sim.ep.inbox_impl != "scatter":
-        out.append(Finding(
-            pass_name="hlo", rule="default-inbox-impl",
-            where="config/scenario.py",
-            message="default scenario resolved inbox_impl="
-                    f"{sim.ep.inbox_impl!r} — the sort path is "
-                    "oracle-only and must require an explicit "
-                    "**.inboxImpl key",
-            measured=sim.ep.inbox_impl, limit="scatter"))
-    sort_ini = IniFile.loads(_DEFAULT_INI
-                             + '\n**.inboxImpl = "sort"\n')
-    sim_sort = scenario.build_simulation(sort_ini, "General")
-    if sim_sort.ep.inbox_impl != "sort":
-        out.append(Finding(
-            pass_name="hlo", rule="inbox-impl-override",
-            where="config/scenario.py",
-            message="explicit **.inboxImpl = \"sort\" was not honored "
-                    "— the oracle path became unreachable",
-            measured=sim_sort.ep.inbox_impl, limit="sort"))
-    # no quiet fallback: a "pallas" request without the plane is a
-    # ScenarioError, never a run on another path under the same name
-    try:
-        resolved = scenario.resolve_inbox_impl("pallas", available=False,
-                                               warn=False)
-    except scenario.ScenarioError:
-        resolved = None
-    if resolved is not None:
-        out.append(Finding(
-            pass_name="hlo", rule="pallas-unavailable-raises",
-            where="config/scenario.py",
-            message="inboxImpl \"pallas\" on a kernel-less install "
-                    f"resolved to {resolved!r} — must raise "
-                    "ScenarioError",
-            measured=resolved, limit="ScenarioError"))
-    from oversim_tpu import kernels
-    if kernels.available():
-        pallas_ini = IniFile.loads(_DEFAULT_INI
-                                   + '\n**.inboxImpl = "pallas"\n')
-        sim_k = scenario.build_simulation(pallas_ini, "General")
-        if sim_k.ep.inbox_impl != "pallas":
-            out.append(Finding(
-                pass_name="hlo", rule="inbox-impl-override",
-                where="config/scenario.py",
-                message="explicit **.inboxImpl = \"pallas\" was not "
-                        "honored despite an available kernel plane",
-                measured=sim_k.ep.inbox_impl, limit="pallas"))
-    return out

@@ -26,7 +26,6 @@ def measure_entry(txt: str, pool_dim: int, wide_dims=()) -> dict:
     m = dict(hlo_text.hlo_op_counts(txt, pool_dim))
     m.update(hlo_text.gather_counts(txt, wide_dims))
     m["collectives"] = hlo_text.collective_census(txt)
-    m["custom_calls"] = hlo_text.custom_call_census(txt)
     m["host_transfers"] = hlo_text.host_transfer_count(txt)
     m["donated_leaves"] = hlo_text.donated_leaf_count(txt)
     m["dtypes"] = hlo_text.dtype_census(txt)
@@ -65,16 +64,6 @@ def check_contract(name: str, contract, m: dict) -> list:
                    "for replica-sharded entries this means the "
                    "partitioner found a cross-replica data dependency",
                    bad, sorted(contract.allowed_collectives))
-    if contract.custom_calls_enforced:
-        bad = {k: v for k, v in m["custom_calls"].items()
-               if k not in contract.allowed_custom_calls}
-        if bad:
-            breach("custom-calls",
-                   "custom-calls outside the kernel allowlist — an "
-                   "unvetted external call entered the compiled tick "
-                   "(the fused Pallas kernels may only appear as "
-                   "Mosaic tpu_custom_call ops)",
-                   bad, sorted(contract.allowed_custom_calls))
     if m["host_transfers"] > contract.max_host_transfers:
         breach("host-transfers",
                "infeed/outfeed/send/recv/host-callback ops inside the "
@@ -241,15 +230,12 @@ def run(ctx, selected=None, *, progress=None, builds=None,
                         "scatter_count", "collective_count",
                         "gather_count", "wide_gather_count")},
             "collectives": m["collectives"],
-            "custom_calls": m["custom_calls"],
             "host_transfers": m["host_transfers"],
             "donated_leaves": m["donated_leaves"],
             "compile_seconds": timing,
             "info": built.info,
             **({"delta": delta_info} if delta_info else {}),
         }
-    findings.extend(contracts_mod.scenario_pins())
     summary = {"entries": entries_summary,
-               "scenario_pins": "checked",
                "findings": len(findings)}
     return findings, summary

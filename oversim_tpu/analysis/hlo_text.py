@@ -185,30 +185,6 @@ def _combiner_by_region(txt: str) -> dict:
     return out
 
 
-_CUSTOM_TARGET = re.compile(r'custom_call_target="([^"]+)"')
-
-
-def custom_call_census(txt: str) -> dict:
-    """Per-target custom-call census: ``{custom_call_target: count}``.
-
-    The contract language for the kernel plane (oversim_tpu/kernels/):
-    on TPU the fused Pallas kernels lower to Mosaic ``tpu_custom_call``
-    ops — the ``fused_tick`` allowlist pins that nothing ELSE enters
-    the graph as an unvetted external call.  Under
-    ``pallas_call(interpret=True)`` (the CPU CI path) the kernels
-    discharge to inline HLO and the census is empty — the allowlist is
-    an upper bound, so both backends pass the same contract.  Targets
-    missing the ``custom_call_target=`` attribute count as
-    ``"<unknown>"``.
-    """
-    out = collections.Counter()
-    for ln in txt.splitlines():
-        if " custom-call(" in ln or " custom-call-start(" in ln:
-            m = _CUSTOM_TARGET.search(ln)
-            out[m.group(1) if m else "<unknown>"] += 1
-    return dict(out)
-
-
 def host_transfer_count(txt: str) -> int:
     """Ops that reach the host mid-execution: infeed/outfeed/send/recv
     plus python-callback custom-calls (io_callback/pure_callback/debug

@@ -154,8 +154,6 @@ class Campaign:
             "replica_ids": list(self.ids),
             "s": self.s,
             "total": self.total,
-            "inbox_impl": (self.sim.ep.inbox_impl
-                           if self.sim is not None else None),
         }
 
     # -- init ---------------------------------------------------------------
@@ -261,7 +259,6 @@ class Campaign:
             "replicas": self.p.replicas,
             "grid": self.grid,
             "s": self.s,
-            "inbox_impl": self.sim.ep.inbox_impl,
             "replica_ids": list(self.ids),
             "base_seed": self.p.base_seed,
             "confidence": confidence,

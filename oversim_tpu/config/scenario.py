@@ -34,47 +34,6 @@ class ScenarioError(ValueError):
     pass
 
 
-def resolve_inbox_impl(value: str, *, available: bool | None = None,
-                       warn: bool = True) -> str:
-    """Resolve a raw ``**.inboxImpl`` string to the impl the engine runs.
-
-    - ``"scatter"`` — the zero-sort scatter-min default.
-    - ``"pallas"`` — the fused kernel plane (oversim_tpu/kernels/).
-      Raises :class:`ScenarioError` when the plane is unimportable
-      (``available`` overrides the probe for tests/pins): a run that
-      asked for the kernels never quietly measures the scatter path.
-    - ``"sort"`` — ORACLE-ONLY legacy full-pool sort; selecting it
-      outside the test tier prints a stderr deprecation warning
-      (suppressed under pytest and with ``warn=False``).
-
-    Anything else raises :class:`ScenarioError`.
-    """
-    import os
-    import sys
-
-    impl = str(value).strip().strip('"')
-    if impl not in ("scatter", "sort", "pallas"):
-        raise ScenarioError(f"unsupported inboxImpl: {impl!r} "
-                            "(expected \"scatter\", \"pallas\" or "
-                            "\"sort\")")
-    quiet = not warn or "PYTEST_CURRENT_TEST" in os.environ
-    if impl == "pallas":
-        if available is None:
-            from oversim_tpu import kernels
-            available = kernels.available()
-        if not available:
-            raise ScenarioError(
-                "inboxImpl \"pallas\" requested but the kernel plane is "
-                "unavailable (jax.experimental.pallas does not import)")
-    elif impl == "sort" and not quiet:
-        print("oversim-tpu: inboxImpl \"sort\" is deprecated and "
-              "oracle-only — it exists to pin the scatter/pallas paths "
-              "bit-identical in tests, not to run simulations; use "
-              "\"scatter\" (default) or \"pallas\" (kernel plane)",
-              file=sys.stderr)
-    return impl
-
-
 def resolve_tick_impl(value: str) -> str:
     """Validate a raw ``**.tickImpl`` string — ``"auto"`` (the default,
     also with no key at all: the awake-set plane for an overlay and app
@@ -402,8 +361,6 @@ def build_simulation(ini: IniFile, config: str = "General",
         cp = build_churn(ini, config)
     ap = build_app(ini, config, spec, trace=workload)
     mp = build_malicious(ini, config)
-    inbox_impl = resolve_inbox_impl(_value(
-        ini.get("**.inboxImpl", config), "scatter"))
     tick_impl = resolve_tick_impl(_value(
         ini.get("**.tickImpl", config), "auto"))
     ep = engine_params or sim_mod.EngineParams(
@@ -411,11 +368,6 @@ def build_simulation(ini: IniFile, config: str = "General",
             ini.get("**.transitionTime", config), 0.0)),
         measurement_time=float(_value(
             ini.get("**.measurementTime", config), -1.0)),
-        # **.inboxImpl: inbox grouping algorithm — "scatter" (zero-sort
-        # scatter-min rounds, default) | "pallas" (fused kernel plane,
-        # oversim_tpu/kernels/) | "sort" (ORACLE-ONLY legacy full-pool
-        # sort); this framework's ini extension, engine/pool.py
-        inbox_impl=inbox_impl,
         # **.tickImpl: "auto" (default: the awake-set plane where the
         # logic declares it exact, else dense) | "dense" (full-N
         # oracle) | "sparse" (awake-set plane); **.activeCap sets the

@@ -6,21 +6,19 @@ in-flight packets live in one structure-of-arrays pool of P slots; each
 simulation tick:
 
   * the due messages (deliver time inside the tick window) are grouped by
-    destination into a fixed-width inbox index table.  The default
-    ``scatter`` implementation runs R rounds of deterministic scatter-min
-    selection: each round one scatter-min on t_deliver over the
-    destination axis picks every destination's earliest remaining due
-    message (a second scatter-min on the pool index breaks t_deliver
-    ties exactly like the old stable sort), the winners are masked out,
-    and R rounds fill the [N, R] table.  The rounds run over the tick's
-    due messages, compacted into D = P/32 lanes, and over all P slots
-    only in a tick with more due messages than lanes: exact at any
-    load, ZERO full-pool sorts in the tick graph (tests/test_engine.py
-    pins sort and scatter counts on the HLO).  The
-    legacy ``sort`` implementation (one lexicographic (dst, t_deliver)
-    ``lax.sort``, O(P log P)) stays selectable via
-    ``EngineParams.inbox_impl`` / the ``**.inboxImpl`` ini key; both
-    produce bit-identical inboxes (identity tests in tests/test_engine.py);
+    destination into a fixed-width inbox index table by R rounds of
+    deterministic scatter-min selection: each round one scatter-min on
+    t_deliver over the destination axis picks every destination's
+    earliest remaining due message (a second scatter-min on the pool
+    index breaks t_deliver ties exactly like a stable sort), the
+    winners are masked out, and R rounds fill the [N, R] table.  The
+    rounds run over the tick's due messages, compacted into D = P/32
+    lanes, and over all P slots only in a tick with more due messages
+    than lanes: exact at any load, ZERO full-pool sorts in the tick
+    graph (tests/test_engine.py pins sort and scatter counts on the
+    HLO).  Its oracle, one lexicographic (dst, t_deliver) full-pool
+    ``lax.sort``, lives with the tests (tests/oracles.py), which hold
+    the two bit-identical;
   * delivered slots are freed, and the tick's outbox is written into free
     slots with a sort-free cumsum allocation (prefix sum over the free
     mask + one scatter).
@@ -169,7 +167,8 @@ def next_deliver_time(pool: MsgPool):
 
 
 def _due_masks(pool: MsgPool, n: int, t_end, alive, hold=None):
-    """(due, to_dead) masks shared by both inbox implementations.
+    """(due, to_dead) masks of the inbox selection (and of its oracle
+    under tests/).
 
     ``hold`` ([P] bool or None) marks messages that are NEVER due: the
     service/gateway plane parks ``EXT_OUT`` responses in the pool until
@@ -180,31 +179,6 @@ def _due_masks(pool: MsgPool, n: int, t_end, alive, hold=None):
         due = due & ~hold
     to_dead = due & ~alive[jnp.clip(pool.dst, 0, n - 1)]
     return due & ~to_dead, to_dead
-
-
-def build_inbox_sort(pool: MsgPool, n: int, r: int, t_end, alive,
-                     hold=None):  # analysis: allow(sort-call)
-    """Legacy inbox grouping: one lexicographic (dst, t_deliver) full-pool
-    stable sort, O(P log P).  Kept selectable (``inbox_impl="sort"``) so
-    the scatter path stays identity-testable against it."""
-    p = pool.capacity
-    due, to_dead = _due_masks(pool, n, t_end, alive, hold)
-
-    dst_k = jnp.where(due, pool.dst, n).astype(I32)
-    t_k = jnp.where(due, pool.t_deliver, T_INF)
-    idx = jnp.arange(p, dtype=I32)
-    dst_s, _, idx_s = jax.lax.sort((dst_k, t_k, idx), dimension=0, num_keys=2)
-
-    # rank of each message within its destination group
-    first = jnp.searchsorted(dst_s, dst_s, side="left").astype(I32)
-    rank = jnp.arange(p, dtype=I32) - first
-    take = (dst_s < n) & (rank < r)
-
-    rows = jnp.where(take, dst_s, n)  # row n is out-of-bounds -> dropped
-    inbox = jnp.full((n, r), NO_NODE, I32).at[rows, jnp.minimum(rank, r - 1)].set(
-        idx_s, mode="drop")
-    delivered = jnp.zeros((p,), bool).at[idx_s].set(take)
-    return inbox, delivered, to_dead
 
 
 def inbox_lanes(p: int) -> int:
@@ -229,7 +203,7 @@ def _fits(due, d: int):
 
 def lanes_swept(pool: MsgPool, n: int, t_end, alive, hold=None,
                 lanes=None):
-    """Candidates a round of :func:`build_inbox_scatter` sweeps in this
+    """Candidates a round of :func:`build_inbox` sweeps in this
     tick (i32): ``lanes`` when the due messages fit them, else all P.
     What the engine's ``inbox_lanes`` counter adds; in one program with
     the selection the due mask is computed once (same operands)."""
@@ -264,18 +238,21 @@ def _scatter_rounds(tkey, dstc, idx, n: int, r: int, pt: int,
     return jnp.stack(cols, axis=1), taken
 
 
-def build_inbox_scatter(pool: MsgPool, n: int, r: int, t_end, alive,
+def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
                         hold=None, *, lanes=None, axis_name=None, base=0,
                         p_total=None):
-    """Zero-sort inbox grouping: R rounds of deterministic scatter-min,
-    over the tick's DUE messages compacted into D lanes.
+    """Group due messages by destination into an index table: R rounds
+    of deterministic scatter-min (no sort), over the tick's DUE messages
+    compacted into D lanes.
 
     Round k scatter-mins t_deliver over the destination axis to find each
     row's earliest remaining due message, then scatter-mins the POOL INDEX
-    over the messages matching that minimum — reproducing the stable
+    over the messages matching that minimum — reproducing a stable
     sort's exact (t_deliver, idx) tie-break — and masks the winners out.
-    Bit-identical to :func:`build_inbox_sort` (pinned by the identity
-    tests in tests/test_engine.py).
+    Bit-identical to the full-pool sort oracle (tests/oracles.py
+    ``build_inbox_sort``; pinned by the identity tests in
+    tests/test_engine.py).  ``hold`` ([P] bool) excludes messages from
+    delivery entirely — see :func:`_due_masks`.
 
     A scatter costs by its updates, and a tick's due messages are a few
     of the pool's P slots: so the due slots' pool indices are compacted,
@@ -299,6 +276,13 @@ def build_inbox_scatter(pool: MsgPool, n: int, r: int, t_end, alive,
     (t_deliver, pool index) is the min of the per-shard minima, so the
     sharded table is bit-identical to the solo one; ``delivered`` /
     ``to_dead`` come back tile-local.
+
+    Returns:
+      inbox: [N, R] i32 pool indices, -1 for empty slots, ordered by
+             (deliver time, pool index) within each row.
+      delivered: [P] bool — messages placed into the inbox this tick.
+      dropped_dead: [P] bool — messages due for a dead node (freed, counted;
+             reference drops these as "dest unavailable", SimpleUDP.cc:307).
     """
     p = pool.capacity
     pt = p if p_total is None else p_total
@@ -334,41 +318,6 @@ def build_inbox_scatter(pool: MsgPool, n: int, r: int, t_end, alive,
     return inbox, delivered, to_dead
 
 
-def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
-                impl: str = "scatter", hold=None, lanes=None):
-    """Group due messages by destination into an index table.
-
-    ``impl`` selects the grouping algorithm: ``"scatter"`` (default,
-    zero-sort scatter-min rounds), ``"pallas"`` (the fused kernel-plane
-    selection, oversim_tpu/kernels/inbox.py — the fused payload gather
-    is dropped here; the engine's fused phase consumes it directly) or
-    ``"sort"`` (legacy full-pool lexicographic sort, ORACLE-ONLY).  All
-    three return bit-identical results.
-    ``hold`` ([P] bool) excludes messages from delivery entirely — see
-    :func:`_due_masks`.  ``lanes`` is the scatter selection's static
-    lane count (None: :func:`inbox_lanes`); the others take no notice.
-
-    Returns:
-      inbox: [N, R] i32 pool indices, -1 for empty slots, ordered by
-             (deliver time, pool index) within each row.
-      delivered: [P] bool — messages placed into the inbox this tick.
-      dropped_dead: [P] bool — messages due for a dead node (freed, counted;
-             reference drops these as "dest unavailable", SimpleUDP.cc:307).
-    """
-    if impl == "sort":
-        return build_inbox_sort(pool, n, r, t_end, alive, hold)
-    if impl == "scatter":
-        return build_inbox_scatter(pool, n, r, t_end, alive, hold,
-                                   lanes=lanes)
-    if impl == "pallas":
-        from oversim_tpu import kernels
-        inbox, delivered, to_dead, _gblk = kernels.inbox.fused_inbox(
-            pool, n, r, t_end, alive, hold)
-        return inbox, delivered, to_dead
-    raise ValueError(f"unknown inbox_impl: {impl!r} "
-                     "(expected 'scatter', 'pallas' or 'sort')")
-
-
 def free(pool: MsgPool, mask) -> MsgPool:
     return dataclasses.replace(
         pool,
@@ -376,7 +325,7 @@ def free(pool: MsgPool, mask) -> MsgPool:
         t_deliver=jnp.where(mask, T_INF, pool.t_deliver))
 
 
-def alloc(pool: MsgPool, out: dict, want, impl: str = "scatter"):
+def alloc(pool: MsgPool, out: dict, want):
     """Write the tick's outbox into free pool slots — SORT-FREE.
 
     ``out`` maps field name -> [Q, ...] flattened outbox arrays;
@@ -389,39 +338,30 @@ def alloc(pool: MsgPool, out: dict, want, impl: str = "scatter"):
     O(P log P) full-pool sorts, the dominant per-tick cost at P = 8N.
     The payload write stays one gather + one scatter of the packed
     [·, W] block plus the two i64 fields and the valid mask.
-
-    ``impl="pallas"`` computes the destination mapping with the fused
-    compaction kernel (oversim_tpu/kernels/outbox.py) instead of the
-    cumsum/fslot-scatter trio — bit-identical destinations and
-    overflow count; the payload write is shared.
     """
     p = pool.capacity
-    if impl == "pallas":
-        from oversim_tpu import kernels
-        dest, overflow = kernels.outbox.alloc_dest(pool.valid, want)
-    else:
-        n_want = jnp.sum(want.astype(I32))
-        free = ~pool.valid
-        n_free = jnp.sum(free.astype(I32))
+    n_want = jnp.sum(want.astype(I32))
+    free = ~pool.valid
+    n_free = jnp.sum(free.astype(I32))
 
-        # rank of each free slot among free slots / of each wanted
-        # message among wanted messages (exclusive prefix sums)
-        free_i = free.astype(I32)
-        free_rank = jnp.cumsum(free_i) - free_i            # [P]
-        want_i = want.astype(I32)
-        want_rank = jnp.cumsum(want_i) - want_i            # [Q]
+    # rank of each free slot among free slots / of each wanted
+    # message among wanted messages (exclusive prefix sums)
+    free_i = free.astype(I32)
+    free_rank = jnp.cumsum(free_i) - free_i            # [P]
+    want_i = want.astype(I32)
+    want_rank = jnp.cumsum(want_i) - want_i            # [Q]
 
-        # compact free-slot list: fslot[j] = index of the j-th free slot
-        # (p elsewhere, which scatters/reads as "dropped")
-        fslot = jnp.full((p,), p, I32).at[
-            jnp.where(free, free_rank, p)].set(
-            jnp.arange(p, dtype=I32), mode="drop")
-        # destination slot per outbox message; p (out of bounds,
-        # dropped) for unwanted messages and for wanted ones past the
-        # free supply
-        dest = jnp.where(want & (want_rank < n_free),
-                         fslot[jnp.minimum(want_rank, p - 1)], p)
-        overflow = jnp.maximum(n_want - n_free, 0)
+    # compact free-slot list: fslot[j] = index of the j-th free slot
+    # (p elsewhere, which scatters/reads as "dropped")
+    fslot = jnp.full((p,), p, I32).at[
+        jnp.where(free, free_rank, p)].set(
+        jnp.arange(p, dtype=I32), mode="drop")
+    # destination slot per outbox message; p (out of bounds,
+    # dropped) for unwanted messages and for wanted ones past the
+    # free supply
+    dest = jnp.where(want & (want_rank < n_free),
+                     fslot[jnp.minimum(want_rank, p - 1)], p)
+    overflow = jnp.maximum(n_want - n_free, 0)
 
     out_blk = pack_block(out, pool.kl, pool.rmax)
     new_pool = dataclasses.replace(

@@ -61,12 +61,6 @@ class EngineParams:
 
     window: float = 0.010          # tick window (s)
     inbox_slots: int = 8           # R — msgs consumed per node per tick
-    inbox_impl: str = "scatter"    # inbox grouping: "scatter" (zero-sort
-                                   # scatter-min rounds, default) |
-                                   # "pallas" (fused kernel plane,
-                                   # oversim_tpu/kernels/ — also arms the
-                                   # fused outbox allocator) | "sort"
-                                   # (legacy full-pool sort, ORACLE-ONLY)
     tick_impl: str = "auto"        # node-step execution: "sparse" (the
                                    # awake-set plane: the awake nodes
                                    # are stepped in rounds of A
@@ -138,7 +132,7 @@ SPARSE_COUNTERS = ("awake_nodes", "active_dst", "lanes_stepped")
 # it was; a state without them runs the same selection, uncounted),
 # cumulative: candidates a round of the selection swept (D in a tick
 # whose due messages fit the D compacted lanes, P in a tick that took
-# the P-wide rounds; engine/pool.py build_inbox_scatter) and the pool
+# the P-wide rounds; engine/pool.py build_inbox) and the pool
 # slots the P-wide rounds would have swept (P every tick)
 INBOX_COUNTERS = ("inbox_lanes", "inbox_pool_slots")
 # what a state of the awake-set plane carries beside ENGINE_COUNTERS
@@ -218,15 +212,13 @@ class Simulation:
         self.spec = logic.key_spec
         # "dense" | "sparse": what ep.tick_impl comes to for this logic
         self.tick_impl = resolve_tick_impl(self.ep.tick_impl, logic)
-        # D — lanes the scatter selection compacts a tick's due messages
+        # D — lanes the inbox selection compacts a tick's due messages
         # into (engine/pool.py inbox_lanes: a rule of P alone); P = the
-        # P-wide rounds only, and what every other inbox_impl sweeps.
-        # Code's to pass (``for_vmap``, a test), never a user's: it
-        # moves the cost of a tick, not its result
+        # P-wide rounds only.  Code's to pass (``for_vmap``, a test),
+        # never a user's: it moves the cost of a tick, not its result
         p = self.ep.pool_factor * self.n
         if inbox_lanes is None:
-            inbox_lanes = (pool_mod.inbox_lanes(p)
-                           if self.ep.inbox_impl == "scatter" else p)
+            inbox_lanes = pool_mod.inbox_lanes(p)
         self.inbox_lanes = min(inbox_lanes, p)
 
     def for_vmap(self) -> "Simulation":
@@ -384,20 +376,21 @@ class Simulation:
 
     def _phase_inbox_select(self, s: SimState, t_end, alive):
         """Phase 3a: pick each destination's R earliest due messages
-        (scatter-min rounds over the due messages' compacted lanes by
-        default — zero full-pool sorts; see engine/pool.py and
-        ``EngineParams.inbox_impl``)."""
+        (scatter-min rounds over the due messages' compacted lanes —
+        zero full-pool sorts; see engine/pool.py).  The awake-set plane
+        stops here: each of its rounds gathers only its A compacted
+        rows' payload.  The tests' sort oracle overrides this one phase
+        (tests/oracles.py), so it keeps its name and its triple."""
         return pool_mod.build_inbox(
             s.pool, self.n, self.ep.inbox_slots, t_end, alive,
-            impl=self.ep.inbox_impl, hold=self._hold_mask(s),
-            lanes=self.inbox_lanes)
+            hold=self._hold_mask(s), lanes=self.inbox_lanes)
 
     def _msgs_from_block(self, s: SimState, t_next, inbox, blk,
                          t_deliver=None, stamp=None):
         """[N, R] index table + gathered [N, R, W] payload block → the
-        Msg view (shared by the lax gather and the fused kernel path;
-        the two i64 fields are gathered here off the index table — the
-        Pallas core has no 64-bit lanes — unless the caller already
+        Msg view (shared by the dense sweep's full gather and the
+        awake-set plane's per-round gather; the two i64 fields are
+        gathered here off the index table unless the caller already
         holds them: the sharded tick (parallel/shard_tick.py) passes
         its owner-gathered [N, R] ``t_deliver``/``stamp``, since the
         local pool tile cannot be indexed by global inbox entries)."""
@@ -428,25 +421,9 @@ class Simulation:
         blk = s.pool.blk[jnp.maximum(inbox, 0)]       # [N, R, W]
         return self._msgs_from_block(s, t_next, inbox, blk)
 
-    def _phase_inbox_fused(self, s: SimState, t_next, t_end, alive):
-        """Phase 3 (kernel plane): selection AND the [P, W] payload
-        gather in one Pallas kernel (oversim_tpu/kernels/inbox.py) —
-        bit-identical to select+gather, pinned in tests/test_kernels.py
-        under interpret mode."""
-        from oversim_tpu import kernels
-        inbox, delivered, to_dead, gblk = kernels.inbox.fused_inbox(
-            s.pool, self.n, self.ep.inbox_slots, t_end, alive,
-            hold=self._hold_mask(s))
-        return (self._msgs_from_block(s, t_next, inbox, gblk),
-                delivered, to_dead)
-
     def _phase_inbox(self, s: SimState, t_next, t_end, alive):
         """Phase 3: inbox select + gather composed (profiling.py times
-        the two halves separately; ``inbox_impl="pallas"`` fuses them
-        into one kernel and is timed as a single ``inbox_fused``
-        phase)."""
-        if self.ep.inbox_impl == "pallas":
-            return self._phase_inbox_fused(s, t_next, t_end, alive)
+        the two halves separately)."""
         inbox, delivered, to_dead = self._phase_inbox_select(s, t_end, alive)
         msgs = self._phase_inbox_gather(s, t_next, inbox)
         return msgs, delivered, to_dead
@@ -527,23 +504,10 @@ class Simulation:
 
     # -- awake-set plane (tick_impl="sparse") -------------------------------
 
-    def _phase_inbox_select_sparse(self, s: SimState, t_end, alive):
-        """Awake-set phase 3: selection WITHOUT the full [N, R, W]
-        payload gather — each round gathers only its A compacted rows.
-        Under ``inbox_impl="pallas"`` the fused kernel runs in
-        select-only mode (occupancy-bounded walk, no gather pass)."""
-        if self.ep.inbox_impl == "pallas":
-            from oversim_tpu import kernels
-            return kernels.inbox.fused_select(
-                s.pool, self.n, self.ep.inbox_slots, t_end, alive,
-                hold=self._hold_mask(s))
-        return self._phase_inbox_select(s, t_end, alive)
-
     def _phase_active_compact(self, s: SimState, t_end, alive, pre_killed,
                               logic_state, inbox):
         """Awake-set phase 4a: the awake nodes' indices in ascending
-        order (the ``pool.alloc`` cumsum-compaction idiom; the kernel
-        plane uses the serial-counting compaction in kernels/outbox.py).
+        order (the ``pool.alloc`` cumsum-compaction idiom).
 
         A node is awake when it has inbox traffic this window
         (``inbox[:, 0] >= 0`` — the selectors fill slot 0 first), a due
@@ -568,17 +532,11 @@ class Simulation:
         awake = has_msg | timer_due | churned
         node_idx = jnp.arange(n, dtype=I32)
         sentinels = n + jnp.arange(lanes, dtype=I32)
-        if self.ep.inbox_impl == "pallas":
-            from oversim_tpu import kernels
-            order, n_awake = kernels.outbox.compact_indices(
-                awake, node_idx, lanes, sentinel=n)
-            order = jnp.where(order < n, order, sentinels)
-        else:
-            aw_i = awake.astype(I32)
-            rank = jnp.cumsum(aw_i) - aw_i
-            n_awake = jnp.sum(aw_i)
-            order = sentinels.at[jnp.where(awake, rank, lanes)].set(
-                node_idx, mode="drop")
+        aw_i = awake.astype(I32)
+        rank = jnp.cumsum(aw_i) - aw_i
+        n_awake = jnp.sum(aw_i)
+        order = sentinels.at[jnp.where(awake, rank, lanes)].set(
+            node_idx, mode="drop")
         rounds = ((n_awake + (cap - 1)) // cap).astype(I32)
         active = (n_awake.astype(I64),
                   jnp.sum(has_msg.astype(I32)).astype(I64),
@@ -673,9 +631,7 @@ class Simulation:
         flat["src"] = jnp.broadcast_to(node_idx[:, None],
                                        out_valid.shape).reshape(-1)
         new_pool, pool_overflow = pool_mod.alloc(
-            new_pool, flat, (out_valid & ok).reshape(-1),
-            impl=("pallas" if self.ep.inbox_impl == "pallas"
-                  else "scatter"))
+            new_pool, flat, (out_valid & ok).reshape(-1))
 
         # stats
         new_stats = stats_mod.record(s.stats, events, measuring)
@@ -777,7 +733,7 @@ class Simulation:
         (churn_state, alive, pre_killed, node_keys, ul_state,
          logic_state) = self._phase_churn(s, t_next, t_end, r_churn, r_keys,
                                           r_reset, r_mig, ov=ov)
-        inbox, delivered, to_dead = self._phase_inbox_select_sparse(
+        inbox, delivered, to_dead = self._phase_inbox_select(
             s, t_end, alive)
         order, rounds, active = self._phase_active_compact(
             s, t_end, alive, pre_killed, logic_state, inbox)

@@ -13,14 +13,14 @@ runners already have at their host-sync points:
     events (retry/backoff, chaos kill, AOT hit/miss, contract verdict).
 
 ``statusz()`` assembles the ``/statusz`` snapshot — tick, window,
-replica shards, inbox_impl, checkpoint age — purely
-from those host-side updates, so a scrape never touches the device.
+replica shards, checkpoint age — purely from those host-side
+updates, so a scrape never touches the device.
 
 Typical runner wiring (scripts/service_run.py)::
 
     obs = RunObserver(role="service", port=args.metrics_port,
                       flight_path=args.flight)
-    obs.set_static(inbox_impl=sim.ep.inbox_impl, replicas=args.replicas)
+    obs.set_static(replicas=args.replicas)
     obs.start()                       # → bound port (0 = ephemeral)
     loop = ServiceLoop(..., on_window=..., events=obs.loop_event)
     ...
@@ -152,7 +152,7 @@ class RunObserver:
     # -------------------------------------------------------- updates --
     def set_static(self, **fields) -> None:
         """Scrape-visible run facts that don't change per window:
-        inbox_impl, replicas, shards, ..."""
+        replicas, shards, ..."""
         self._static.update(fields)
 
     def record(self, kind: str, **fields) -> None:

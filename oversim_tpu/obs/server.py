@@ -12,8 +12,8 @@ metrics registry (obs/metrics.py):
              and will return to ready when the backlog clears
   /statusz   JSON operational snapshot: server info merged with the
              runner-provided ``statusz`` callable (tick, window,
-             replica shards, inbox_impl, checkpoint
-             age — see obs/runtime.py RunObserver.statusz)
+             replica shards, checkpoint age — see obs/runtime.py
+             RunObserver.statusz)
 
 The ``statusz`` callable MUST be cheap and sync-free: it is invoked
 from the serving thread on every scrape, so it may only read host-side

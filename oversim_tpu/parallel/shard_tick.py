@@ -113,11 +113,6 @@ class ShardedSim:
                 f", which runs {sim.tick_impl!r} for "
                 f"{type(sim.logic).__name__}): the awake-set plane "
                 "compacts across the whole node axis")
-        if sim.ep.inbox_impl not in ("scatter", "pallas"):
-            raise ValueError(
-                f"sharded tick supports inbox_impl 'scatter' or 'pallas', "
-                f"got {sim.ep.inbox_impl!r} (the sort path is a full-pool "
-                "lexicographic sort — all-to-all under sharding)")
         self.sim = sim
         self.mesh = mesh
         self.axis = mesh_mod.NODE_AXIS
@@ -225,18 +220,11 @@ class ShardedSim:
             r_churn, r_keys, r_reset, r_mig)
 
         # ---- phase 3: inbox — local select over the pool tile + the
-        # cross-shard all-reduce:min merge (engine/pool.py scatter form
-        # or the shard-aware fused kernel, kernels/inbox.py).
+        # cross-shard all-reduce:min merge (engine/pool.py build_inbox).
         hold = sim._hold_mask(s)  # local: pool columns only
-        if sim.ep.inbox_impl == "pallas":
-            from oversim_tpu import kernels
-            inbox, delivered, to_dead = kernels.inbox.fused_select_sharded(
-                s.pool, n, sim.ep.inbox_slots, t_end, alive, hold=hold,
-                axis_name=self.axis, base=base_p, p_total=p)
-        else:
-            inbox, delivered, to_dead = pool_mod.build_inbox_scatter(
-                s.pool, n, sim.ep.inbox_slots, t_end, alive, hold,
-                axis_name=self.axis, base=base_p, p_total=p)
+        inbox, delivered, to_dead = pool_mod.build_inbox(
+            s.pool, n, sim.ep.inbox_slots, t_end, alive, hold,
+            axis_name=self.axis, base=base_p, p_total=p)
 
         # payload gather: owner-contributed rows of the packed block +
         # the two i64 fields (empty slots read global row 0 — owned by

@@ -8,17 +8,10 @@ diagnosis).  This module times the phases of ``Simulation.step``
 
   horizon       event-horizon scan + rng split
   churn         churn events, alive flips, key/coord migration, resets
-  inbox_select  due-message top-R selection (scatter-min rounds by
-                default; the legacy full-pool sort under
-                inbox_impl="sort")
+  inbox_select  due-message top-R selection (scatter-min rounds)
   inbox_gather  packed-block gather of the selected messages → Msg view
   node_step     tick context + the vmapped per-node logic sweep
   alloc_stats   underlay send, sort-free pool alloc, stat folding
-
-Under the kernel plane (``inbox_impl="pallas"``) selection and gather
-are ONE fused Pallas kernel — the report then carries a single
-``inbox_fused`` phase in their place (plus ``kernel_plane: true``)
-instead of silently attributing the kernel time to neither half.
 
 Under the sparse plane (``tick_impl="sparse"``) the layout is
 ``horizon / churn / inbox_select / active_compact / sparse_step /
@@ -43,8 +36,7 @@ Usage:
         report, s = profiling.profile_ticks(sim, s, n_ticks=4)
         print(json.dumps(report))
 
-``bench.py``, ``scripts/perf_probe.py`` and ``scripts/scale_smoke.py``
-emit the report as a JSON line when OVERSIM_PROFILE=1.
+``bench.py`` emits the report as a JSON line when OVERSIM_PROFILE=1.
 """
 
 from __future__ import annotations
@@ -56,9 +48,6 @@ import jax
 
 PHASES = ("horizon", "churn", "inbox_select", "inbox_gather", "node_step",
           "alloc_stats")
-# kernel-plane layout: the fused Pallas kernel owns both inbox halves
-PHASES_FUSED = ("horizon", "churn", "inbox_fused", "node_step",
-                "alloc_stats")
 # sparse-plane layout (tick_impl="sparse"): selection never gathers the
 # full [N, R, W] payload; the awake set compacts into A lanes
 # (active_compact) and only those lanes run the logic sweep (sparse_step)
@@ -66,11 +55,9 @@ PHASES_SPARSE = ("horizon", "churn", "inbox_select", "active_compact",
                  "sparse_step", "alloc_stats")
 
 
-def phases_for(inbox_impl: str, tick_impl: str = "dense") -> tuple:
+def phases_for(tick_impl: str = "dense") -> tuple:
     """The phase layout a Simulation's tick decomposes into."""
-    if tick_impl == "sparse":
-        return PHASES_SPARSE
-    return PHASES_FUSED if inbox_impl == "pallas" else PHASES
+    return PHASES_SPARSE if tick_impl == "sparse" else PHASES
 
 
 def enabled() -> bool:
@@ -91,9 +78,6 @@ def _jit_phases(sim):
             lambda s, te, alive: sim._phase_inbox_select(s, te, alive)),
         "inbox_gather": jax.jit(
             lambda s, tn, inbox: sim._phase_inbox_gather(s, tn, inbox)),
-        "inbox_fused": jax.jit(
-            lambda s, tn, te, alive: sim._phase_inbox_fused(
-                s, tn, te, alive)),
         "node_step": jax.jit(
             lambda s, tn, te, alive, pk, cs, nk, ul, lg, msgs, rn:
             sim._phase_node_step(s, tn, te, alive, pk, cs, nk, ul, lg,
@@ -104,9 +88,6 @@ def _jit_phases(sim):
                 s, te, rng, rs, alive, pk, nk, ul, cs, lg, dlv, dead,
                 of, ov, oo, ev, ms)),
         # sparse plane (tick_impl="sparse")
-        "inbox_select_sparse": jax.jit(
-            lambda s, te, alive: sim._phase_inbox_select_sparse(
-                s, te, alive)),
         "active_compact": jax.jit(
             lambda s, te, alive, pk, lg, inbox:
             sim._phase_active_compact(s, te, alive, pk, lg, inbox)),
@@ -149,8 +130,7 @@ def profile_ticks(sim, s, n_ticks: int = 4, fused_reference: bool = True,
     """
     fns = _jit_phases(sim)
     sparse = sim.tick_impl == "sparse"
-    fused_inbox = sim.ep.inbox_impl == "pallas" and not sparse
-    phases = phases_for(sim.ep.inbox_impl, sim.tick_impl)
+    phases = phases_for(sim.tick_impl)
     totals = {p: 0.0 for p in phases}
     compile_s = 0.0
     measured = 0
@@ -175,7 +155,7 @@ def profile_ticks(sim, s, n_ticks: int = 4, fused_reference: bool = True,
         if sparse:
             t0 = time.perf_counter()
             inbox, delivered, to_dead = jax.block_until_ready(
-                fns["inbox_select_sparse"](s, t_end, alive))
+                fns["inbox_select"](s, t_end, alive))
             dt_is = time.perf_counter() - t0
 
             t0 = time.perf_counter()
@@ -202,21 +182,15 @@ def profile_ticks(sim, s, n_ticks: int = 4, fused_reference: bool = True,
                     active))
             dt_a = time.perf_counter() - t0
         else:
-            if fused_inbox:
-                t0 = time.perf_counter()
-                msgs, delivered, to_dead = jax.block_until_ready(
-                    fns["inbox_fused"](s, t_next, t_end, alive))
-                inbox_dts = (time.perf_counter() - t0,)
-            else:
-                t0 = time.perf_counter()
-                inbox, delivered, to_dead = jax.block_until_ready(
-                    fns["inbox_select"](s, t_end, alive))
-                dt_is = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            inbox, delivered, to_dead = jax.block_until_ready(
+                fns["inbox_select"](s, t_end, alive))
+            dt_is = time.perf_counter() - t0
 
-                t0 = time.perf_counter()
-                msgs = jax.block_until_ready(
-                    fns["inbox_gather"](s, t_next, inbox))
-                inbox_dts = (dt_is, time.perf_counter() - t0)
+            t0 = time.perf_counter()
+            msgs = jax.block_until_ready(
+                fns["inbox_gather"](s, t_next, inbox))
+            inbox_dts = (dt_is, time.perf_counter() - t0)
 
             t0 = time.perf_counter()
             (logic_state, out_fields, out_valid, out_overflow, events,
@@ -251,9 +225,7 @@ def profile_ticks(sim, s, n_ticks: int = 4, fused_reference: bool = True,
     report = {
         "metric": "tick_phase_breakdown",
         "n_ticks": measured,
-        "inbox_impl": sim.ep.inbox_impl,
         "tick_impl": sim.tick_impl,
-        "kernel_plane": fused_inbox,
         "phase_ms_per_tick": phase_ms,
         "phase_frac": {p: round(totals[p] / max(sum(totals.values()), 1e-12),
                                 4) for p in phases},

@@ -45,7 +45,7 @@ Host-side exporters (all dependency-free):
     (``add_series``); load in ui.perfetto.dev or chrome://tracing;
   * ``run_manifest`` — the unified RunManifest (config hash, mesh/
     sharding layout, HLO op-budget results, git rev, artifact paths)
-    attached to every bench/campaign/scale_smoke artifact
+    attached to every bench/campaign artifact
     (bench.ArtifactWriter.set_manifest).
 """
 
@@ -485,8 +485,8 @@ def env_knobs(environ=None) -> dict:
 
 def run_manifest(*, config=None, mesh=None, hlo_budget=None,
                  artifacts=None, extra=None) -> dict:
-    """The unified RunManifest attached to every bench/campaign/
-    scale_smoke artifact: enough provenance to re-run or audit the
+    """The unified RunManifest attached to every bench/campaign
+    artifact: enough provenance to re-run or audit the
     measurement — config hash (and the config itself), mesh/sharding
     layout, HLO op-budget results, git rev, artifact paths, effective
     OVERSIM_* env knobs, runtime versions.  ``hlo_budget`` defaults to

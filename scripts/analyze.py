@@ -7,7 +7,7 @@ Usage:
                             [--n N] [--overlay chord|kademlia]
                             [--window W] [--inbox I] [--replicas S]
                             [--compile-budget S]
-                            [--seed-breach hlo|trace|ast|compile|kernel]
+                            [--seed-breach hlo|trace|ast|compile|sparse|shard]
 
   No pass flag = --all.  Prints ONE machine-readable JSON verdict
   document on stdout (kind "graph_contract_verdict"), human-readable
@@ -150,35 +150,6 @@ def _seed_compile(ctx):
                                   {"compile_seconds": timing}}}
 
 
-# synthetic HLO carrying one off-allowlist custom-call — checks the
-# fused_tick allowlist rule pure-text, no jax/backend needed (mirrors
-# the dtype/host-transfer style of the text census tests)
-_SEED_KERNEL_HLO = '''\
-HloModule seeded_kernel, entry_computation_layout={(s32[8]{0})->s32[8]{0}}
-
-ENTRY %main (p0: s32[8]) -> s32[8] {
-  %p0 = s32[8]{0} parameter(0)
-  ROOT %evil = s32[8]{0} custom-call(s32[8]{0} %p0), \
-custom_call_target="rogue_vendor_kernel"
-}
-'''
-
-
-def _seed_kernel(ctx):
-    """Check a planted off-allowlist custom-call against the kernel
-    plane's fused_tick contract (custom_calls_enforced allowlist)."""
-    from oversim_tpu.analysis import contracts as C
-    from oversim_tpu.analysis import hlo_pass
-
-    contract = C.GraphContract(
-        custom_calls_enforced=True,
-        allowed_custom_calls=C.KERNEL_CUSTOM_CALLS)
-    m = hlo_pass.measure_entry(_SEED_KERNEL_HLO, 8)
-    findings = hlo_pass.check_contract("seeded_kernel", contract, m)
-    return findings, {"entries": {"seeded_kernel": {
-        "custom_calls": m["custom_calls"]}}}
-
-
 # synthetic base/sparse module pair where the "sparse" module KEEPS
 # the full-width gathers and stacks a new one on top — the sparse_tick
 # delta contract's required wide-gather REDUCTION must flag it
@@ -253,8 +224,8 @@ def _seed_shard(ctx):
 
 
 _SEEDS = {"hlo": _seed_hlo, "trace": _seed_trace, "ast": _seed_ast,
-          "compile": _seed_compile, "kernel": _seed_kernel,
-          "sparse": _seed_sparse, "shard": _seed_shard}
+          "compile": _seed_compile, "sparse": _seed_sparse,
+          "shard": _seed_shard}
 
 
 # ---------------------------------------------------------------------------
@@ -321,8 +292,8 @@ def main(argv) -> int:
         return 0
 
     if args.seed_breach:
-        # ast + kernel + sparse + shard breaches are pure-text — no backend
-        if args.seed_breach not in ("ast", "kernel", "sparse", "shard"):
+        # ast + sparse + shard breaches are pure-text — no backend
+        if args.seed_breach not in ("ast", "sparse", "shard"):
             _setup_jax()
         findings, summary = _SEEDS[args.seed_breach](None)
         doc = findings_mod.document(

@@ -97,10 +97,8 @@ def _build_from_flags(args):
                                lifetime_mean=args.lifetime,
                                init_interval=10.0 / args.n)
     from oversim_tpu import telemetry as telemetry_mod
-    from oversim_tpu.config import scenario as scenario_mod
     ep = sim_mod.EngineParams(
         window=args.window, inbox_slots=8, pool_factor=8,
-        inbox_impl=scenario_mod.resolve_inbox_impl(args.inbox_impl),
         telemetry=telemetry_mod.TelemetryParams(
             sample_ticks=args.telemetry,
             window=args.telemetry_window))
@@ -131,10 +129,6 @@ def main():
     ap.add_argument("--t", type=float, default=120.0)
     ap.add_argument("--chunk", type=int, default=64)
     ap.add_argument("--confidence", type=float, default=0.95)
-    ap.add_argument("--inbox-impl", default="scatter",
-                    choices=["scatter", "pallas", "sort"],
-                    help="inbox implementation (pallas = fused kernel "
-                    "plane; an error when unavailable)")
     ap.add_argument("--platform", default=None)
     ap.add_argument("--out", default=None, help="incremental atomic "
                     "report artifact path")
@@ -201,8 +195,6 @@ def main():
                 "replicas": camp.p.replicas, "base_seed": camp.p.base_seed,
                 "grid": camp.grid, "n": getattr(args, "n", None),
                 "overlay": args.overlay, "t": args.t, "chunk": args.chunk,
-                "inbox_impl": camp.sim.ep.inbox_impl,
-                "kernel_plane": camp.sim.ep.inbox_impl == "pallas",
                 "telemetry": {"sampleTicks": args.telemetry,
                               "window": args.telemetry_window}},
         mesh=mesh,

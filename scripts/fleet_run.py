@@ -62,7 +62,6 @@ def _scenario(args) -> dict:
             "churn": args.churn, "lifetime": args.lifetime,
             "interval": args.interval,
             "engine_window": args.engine_window,
-            "inbox_impl": args.inbox_impl,
             "replicas": args.replicas, "ticks": args.ticks,
             "chunk": args.chunk}
 
@@ -74,8 +73,7 @@ def _build_campaign(scn: dict, replica_ids=None):
     ns = argparse.Namespace(
         overlay=scn["overlay"], n=scn["n"], churn=scn["churn"],
         lifetime=scn["lifetime"], interval=scn["interval"],
-        engine_window=scn["engine_window"],
-        inbox_impl=scn.get("inbox_impl", "scatter"), telemetry=0,
+        engine_window=scn["engine_window"], telemetry=0,
         telemetry_window=256)
     sim = service_run._build_sim(ns)
     p = CampaignParams(
@@ -701,10 +699,6 @@ def main() -> int:
     ap.add_argument("--lifetime", type=float, default=10_000.0)
     ap.add_argument("--interval", type=float, default=0.2)
     ap.add_argument("--engine-window", type=float, default=0.2)
-    ap.add_argument("--inbox-impl", default="scatter",
-                    choices=["scatter", "pallas", "sort"],
-                    help="inbox implementation shipped to every worker "
-                    "via the scenario (and hashed into checkpoints)")
     ap.add_argument("--platform", default=None)
     ap.add_argument("--out", default="/tmp/oversim_fleet")
     ap.add_argument("--chaos", action="store_true",

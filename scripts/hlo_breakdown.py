@@ -72,14 +72,12 @@ def _setup_jax():
     return jax
 
 
-def _build_sim(n, overlay, window, inbox, pool_factor=4, inbox_impl="scatter",
-               telemetry_ticks=0):
+def _build_sim(n, overlay, window, inbox, pool_factor=4, telemetry_ticks=0):
     """Back-compat wrapper over the registry's shared sim builder."""
     from oversim_tpu.analysis import contracts as contracts_mod
     ctx = contracts_mod.EntryContext(n=n, overlay=overlay, window=window,
                                      inbox=inbox, pool_factor=pool_factor)
-    return contracts_mod.build_sim(ctx, inbox_impl=inbox_impl,
-                                   telemetry_ticks=telemetry_ticks)
+    return contracts_mod.build_sim(ctx, telemetry_ticks=telemetry_ticks)
 
 
 def _ctx(n, overlay, window, inbox, **kw):

@@ -124,7 +124,7 @@ def smoke_service(workdir: Path) -> None:
             f"{second['oversim_windows_total']:.0f})")
 
         st = json.loads(_get(base + "/statusz")[1])
-        for key in ("role", "inbox_impl", "windows_done", "flight"):
+        for key in ("role", "overlay", "windows_done", "flight"):
             assert key in st, f"statusz missing {key}: {st}"
 
         # graceful drain: SIGTERM, then healthz must serve 503

@@ -147,8 +147,7 @@ def render(cur: dict, prev: dict | None) -> str:
     st = cur.get("statusz") or {}
     for key in ("role", "window", "tick", "t_sim", "alive",
                 "windows_done", "checkpoints_written",
-                "checkpoint_age_s", "inbox_impl", "replicas",
-                "ingest_rate"):
+                "checkpoint_age_s", "replicas", "ingest_rate"):
         if key in st and st[key] is not None:
             lines.append(f"{key:22s} {st[key]}")
     if isinstance(st.get("requests"), dict):

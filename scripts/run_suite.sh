@@ -76,24 +76,9 @@ else
   status=1
   echo "FAIL  fleet_smoke  $(tail -1 "$STATE/fleet_smoke.log")"
 fi
-# fused-tick kernel gate (scripts/fused_gate.py): 64 churned chord
-# ticks under pallas_call(interpret=True) must be bit-identical to the
-# lax-scatter oracle, and the compiled fused tick must drop >= 2R+1
-# scatter ops (zero sorts, zero custom-calls in interpret mode)
-fused_marker="$STATE/fused_gate.ok"
-if [ -f "$fused_marker" ]; then
-  echo "skip  fused_gate (done)"
-elif timeout "${SUITE_MODULE_TIMEOUT:-3000}" \
-    python scripts/fused_gate.py > "$STATE/fused_gate.log" 2>&1; then
-  touch "$fused_marker"
-  echo "PASS  fused_gate  $(tail -1 "$STATE/fused_gate.log")"
-else
-  status=1
-  echo "FAIL  fused_gate  $(tail -1 "$STATE/fused_gate.log")"
-fi
 # sparse-tick gate (scripts/sparse_gate.py): 64 churned chord ticks
-# under tick_impl="sparse" must be bit-identical to the dense oracle
-# (both inbox impls), and the compiled sparse tick must REPLACE the
+# under tick_impl="sparse" must be bit-identical to the dense oracle,
+# and the compiled sparse tick must REPLACE the
 # full-width payload gathers with [A]-lane ones (wide-gather drop >= 1,
 # no new sorts)
 sparse_marker="$STATE/sparse_gate.ok"
@@ -109,7 +94,7 @@ else
 fi
 # 2D-mesh sharded-tick gate (scripts/shard_gate.py): 64 churned chord
 # ticks through ShardedSim on the (1, 8) mesh must be bit-identical to
-# the unsharded oracle (both inbox impls), the compiled sharded step
+# the unsharded oracle, the compiled sharded step
 # may carry ONLY all-reduce:min collectives (no sorts), and on the
 # (2, 4) campaign mesh no replica_groups set may span replica rows
 shard_marker="$STATE/shard_gate.ok"

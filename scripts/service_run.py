@@ -93,10 +93,8 @@ def _build_sim(args):
     cp = churn_mod.ChurnParams(model=args.churn, target_num=args.n,
                                lifetime_mean=args.lifetime,
                                init_interval=10.0 / args.n)
-    from oversim_tpu.config import scenario as scenario_mod
     ep = sim_mod.EngineParams(
         window=args.engine_window, inbox_slots=8, pool_factor=8,
-        inbox_impl=scenario_mod.resolve_inbox_impl(args.inbox_impl),
         telemetry=telemetry_mod.TelemetryParams(
             sample_ticks=args.telemetry,
             window=args.telemetry_window))
@@ -159,7 +157,6 @@ def _run_daemon(args):
               "telemetry": {"sampleTicks": args.telemetry,
                             "window": args.telemetry_window}}
     sim = _build_echo_sim(args)
-    config["inbox_impl"] = sim.ep.inbox_impl
     camp = Campaign(sim, CampaignParams(replicas=T, base_seed=args.seed))
     artifact = ArtifactWriter(args.out)
 
@@ -196,8 +193,7 @@ def _run_daemon(args):
         from oversim_tpu.obs import RunObserver
         obs = RunObserver(role="daemon", port=args.metrics_port,
                           flight_path=args.flight, tracer=tracer)
-        obs.set_static(n=args.n, overlay="myoverlay",
-                       inbox_impl=sim.ep.inbox_impl, replicas=T,
+        obs.set_static(n=args.n, overlay="myoverlay", replicas=T,
                        tenants=T)
         obs_rec = {"phase": "obs", "metrics_port": obs.start(),
                    "flight": args.flight}
@@ -314,10 +310,6 @@ def main():
     ap.add_argument("--lifetime", type=float, default=10_000.0)
     ap.add_argument("--interval", type=float, default=0.2)
     ap.add_argument("--engine-window", type=float, default=0.2)
-    ap.add_argument("--inbox-impl", default="scatter",
-                    choices=["scatter", "pallas", "sort"],
-                    help="inbox implementation (pallas = fused kernel "
-                    "plane; an error when unavailable)")
     ap.add_argument("--platform", default=None)
     ap.add_argument("--out", default=None, help="incremental atomic "
                     "artifact path")
@@ -410,12 +402,6 @@ def main():
             checkpoint_path=args.checkpoint,
             double_buffer=not args.single_buffer)
 
-    # record the ACTIVE impl (ini key or --inbox-impl, after any
-    # pallas→scatter availability fallback) — resume recomputes the
-    # same value from the same flags/ini, so the config hash matches
-    config["inbox_impl"] = sim.ep.inbox_impl
-    config["kernel_plane"] = sim.ep.inbox_impl == "pallas"
-
     summarize = None
     if args.replicas:
         from oversim_tpu.campaign import Campaign, CampaignParams
@@ -447,7 +433,6 @@ def main():
         obs = RunObserver(role="service", port=args.metrics_port,
                           flight_path=args.flight, tracer=tracer)
         obs.set_static(n=args.n, overlay=args.overlay,
-                       inbox_impl=sim.ep.inbox_impl,
                        replicas=args.replicas,
                        ingest_rate=args.ingest_rate)
         obs_rec = {"phase": "obs", "metrics_port": obs.start(),

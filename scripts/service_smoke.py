@@ -37,8 +37,7 @@ WINDOWS = 6
 CKPT_EVERY = 2
 KILL_AT_WINDOW = 4          # on_window index: after the wd=4 checkpoint
 SEED = 3
-CONFIG = {"smoke": "service", "overlay": "chord", "n": 8, "seed": SEED,
-          "inbox_impl": "scatter"}
+CONFIG = {"smoke": "service", "overlay": "chord", "n": 8, "seed": SEED}
 
 
 def _setup_jax():

@@ -7,9 +7,7 @@ on the 8-virtual-device mesh:
   1. IDENTITY: 64 churned ticks through ``ShardedSim`` on the (1, 8)
      ``(replica, node)`` mesh produce a SimState whose every leaf is
      bit-identical to the unsharded oracle — same delivery order, same
-     rng consumption, same churn cascade — for BOTH inbox impls
-     (scatter, and the fused kernel plane in interpret mode when
-     available).
+     rng consumption, same churn cascade.
   2. COLLECTIVE CENSUS: the compiled sharded step may carry ONLY
      ``all-reduce:min`` collectives (the min-gather primitive — no
      all-gather, no all-to-all, no sort-based exchange), at least one
@@ -54,7 +52,7 @@ def _setup_jax():
     return jax
 
 
-def _build(inbox_impl, n=16):
+def _build(n=16):
     from oversim_tpu import churn as churn_mod
     from oversim_tpu.engine import sim as sim_mod
     from oversim_tpu.overlay.chord import ChordLogic
@@ -64,7 +62,7 @@ def _build(inbox_impl, n=16):
     # dense by name: ShardedSim refuses the awake-set plane, which the
     # engine's default gives Chord under KBRTestApp
     ep = sim_mod.EngineParams(window=0.1, inbox_slots=4, pool_factor=4,
-                              inbox_impl=inbox_impl, tick_impl="dense")
+                              tick_impl="dense")
     return sim_mod.Simulation(ChordLogic(), cp, engine_params=ep)
 
 
@@ -84,54 +82,47 @@ def main() -> int:
     jax = _setup_jax()
     import numpy as np
 
-    from oversim_tpu import kernels
     from oversim_tpu.analysis import hlo_text
     from oversim_tpu.parallel import mesh as mesh_mod
     from oversim_tpu.parallel.shard_tick import ShardedCampaign, ShardedSim
 
     verdict = {"gate": "shard_tick", "n_ticks": N_TICKS,
-               "node_shards": K,
-               "kernels_available": kernels.available()}
+               "node_shards": K}
     failures = []
 
-    # -- 1. identity: both inbox impls, every leaf bit-identical -------
+    # -- 1. identity: every leaf bit-identical --------------------------
     mesh = mesh_mod.make_mesh_2d(1, K)
-    impls = ["scatter"] + (["pallas"] if kernels.available() else [])
-    ssim = None
-    for inbox_impl in impls:
-        sim = _build(inbox_impl)
-        s = sim.init(seed=3)
-        step = jax.jit(sim.step)
-        for _ in range(N_TICKS):
-            s = step(s)
-        solo = jax.device_get(s)
+    sim = _build()
+    s = sim.init(seed=3)
+    step = jax.jit(sim.step)
+    for _ in range(N_TICKS):
+        s = step(s)
+    solo = jax.device_get(s)
 
-        ssim = ShardedSim(sim, mesh)
-        sh = ssim.place(sim.init(seed=3))
-        sstep = jax.jit(ssim.step, in_shardings=(ssim.shardings,),
-                        out_shardings=ssim.shardings)
-        for _ in range(N_TICKS):
-            sh = sstep(sh)
-        sharded = jax.device_get(sh)
+    ssim = ShardedSim(sim, mesh)
+    sh = ssim.place(sim.init(seed=3))
+    sstep = jax.jit(ssim.step, in_shardings=(ssim.shardings,),
+                    out_shardings=ssim.shardings)
+    for _ in range(N_TICKS):
+        sh = sstep(sh)
+    sharded = jax.device_get(sh)
 
-        la, ta = jax.tree_util.tree_flatten(solo)
-        lb, tb = jax.tree_util.tree_flatten(sharded)
-        if ta != tb:
-            failures.append(f"{inbox_impl}: state treedef mismatch")
-        bad = [i for i, (x, y) in enumerate(zip(la, lb))
-               if not np.array_equal(np.asarray(x), np.asarray(y))]
-        verdict[f"identity_ok_{inbox_impl}"] = ta == tb and not bad
-        if bad:
-            paths = jax.tree_util.tree_flatten_with_path(solo)[0]
-            failures.append(
-                f"{inbox_impl}: divergent leaves: "
-                + ", ".join(jax.tree_util.keystr(paths[i][0])
-                            for i in bad[:8]))
-        verdict["alive"] = int(np.sum(solo.alive))
+    la, ta = jax.tree_util.tree_flatten(solo)
+    lb, tb = jax.tree_util.tree_flatten(sharded)
+    if ta != tb:
+        failures.append("state treedef mismatch")
+    bad = [i for i, (x, y) in enumerate(zip(la, lb))
+           if not np.array_equal(np.asarray(x), np.asarray(y))]
+    verdict["identity_ok"] = ta == tb and not bad
+    if bad:
+        paths = jax.tree_util.tree_flatten_with_path(solo)[0]
+        failures.append(
+            "divergent leaves: "
+            + ", ".join(jax.tree_util.keystr(paths[i][0])
+                        for i in bad[:8]))
+    verdict["alive"] = int(np.sum(solo.alive))
 
     # -- 2. collective census: all-reduce:min ONLY, no sorts -----------
-    sim = _build("scatter")
-    ssim = ShardedSim(sim, mesh)
     txt = jax.jit(ssim.step, in_shardings=(ssim.shardings,),
                   out_shardings=ssim.shardings,
                   donate_argnums=(0,)).lower(
@@ -150,8 +141,7 @@ def main() -> int:
 
     # -- 3. (2, 4) campaign mesh: no cross-replica groups --------------
     from oversim_tpu.campaign import Campaign, CampaignParams
-    camp = Campaign(_build("scatter"), CampaignParams(replicas=2,
-                                                     base_seed=7))
+    camp = Campaign(_build(), CampaignParams(replicas=2, base_seed=7))
     mesh24 = mesh_mod.make_mesh_2d(2, 4)
     scamp = ShardedCampaign(camp, mesh24)
     ctxt = jax.jit(scamp.vstep, in_shardings=(scamp.shardings,),

@@ -5,10 +5,9 @@ Two checks on a small chord scenario under LifetimeChurn, CPU-only:
   1. IDENTITY: 64 churned ticks under ``tick_impl="sparse"`` (auto
      active_cap = full-N at this size) produce a SimState whose every
      leaf is bit-identical to the dense oracle — same delivery order,
-     same rng consumption, same churn cascade — for BOTH inbox impls
-     (scatter, and the fused kernel plane in interpret mode when
-     available).  The sparse-only counters are stripped before the
-     compare (the dense layout never carries them).  Since PR 27 the
+     same rng consumption, same churn cascade.  The sparse-only
+     counters are stripped before the compare (the dense layout never
+     carries them).  Since PR 27 the
      plane steps awake nodes past A in further rounds of the same tick,
      so identity holds at any active_cap; tests/test_zz_sparse.py pins
      the several-rounds cases.
@@ -50,7 +49,7 @@ def _setup_jax():
     return jax
 
 
-def _build(tick_impl, inbox_impl, n=12, active_cap=0):
+def _build(tick_impl, n=12, active_cap=0):
     from oversim_tpu import churn as churn_mod
     from oversim_tpu.engine import sim as sim_mod
     from oversim_tpu.overlay.chord import ChordLogic
@@ -58,8 +57,7 @@ def _build(tick_impl, inbox_impl, n=12, active_cap=0):
     cp = churn_mod.ChurnParams(model="lifetime", target_num=n,
                                init_interval=0.2, lifetime_mean=8.0)
     ep = sim_mod.EngineParams(window=0.1, inbox_slots=4, pool_factor=4,
-                              inbox_impl=inbox_impl, tick_impl=tick_impl,
-                              active_cap=active_cap)
+                              tick_impl=tick_impl, active_cap=active_cap)
     return sim_mod.Simulation(ChordLogic(), cp, engine_params=ep)
 
 
@@ -76,36 +74,31 @@ def main() -> int:
     jax = _setup_jax()
     import numpy as np
 
-    from oversim_tpu import kernels
     from oversim_tpu.analysis import hlo_text
 
-    verdict = {"gate": "sparse_tick", "n_ticks": N_TICKS,
-               "kernels_available": kernels.available()}
+    verdict = {"gate": "sparse_tick", "n_ticks": N_TICKS}
     failures = []
 
-    # -- 1. identity: both inbox impls, every leaf bit-identical -------
-    impls = ["scatter"] + (["pallas"] if kernels.available() else [])
-    for inbox_impl in impls:
-        finals = {}
-        for tick_impl in ("dense", "sparse"):
-            sim = _build(tick_impl, inbox_impl)
-            s = sim.init(seed=3)
-            finals[tick_impl] = jax.device_get(sim.run_chunk(s, N_TICKS))
-        sparse = _strip_sparse(finals["sparse"])
-        la, ta = jax.tree_util.tree_flatten(finals["dense"])
-        lb, tb = jax.tree_util.tree_flatten(sparse)
-        if ta != tb:
-            failures.append(f"{inbox_impl}: state treedef mismatch")
-        bad = [i for i, (x, y) in enumerate(zip(la, lb))
-               if not np.array_equal(np.asarray(x), np.asarray(y))]
-        verdict[f"identity_ok_{inbox_impl}"] = ta == tb and not bad
-        if bad:
-            paths = jax.tree_util.tree_flatten_with_path(
-                finals["dense"])[0]
-            failures.append(
-                f"{inbox_impl}: divergent leaves: "
-                + ", ".join(jax.tree_util.keystr(paths[i][0])
-                            for i in bad[:8]))
+    # -- 1. identity: every leaf bit-identical --------------------------
+    finals = {}
+    for tick_impl in ("dense", "sparse"):
+        sim = _build(tick_impl)
+        s = sim.init(seed=3)
+        finals[tick_impl] = jax.device_get(sim.run_chunk(s, N_TICKS))
+    sparse = _strip_sparse(finals["sparse"])
+    la, ta = jax.tree_util.tree_flatten(finals["dense"])
+    lb, tb = jax.tree_util.tree_flatten(sparse)
+    if ta != tb:
+        failures.append("state treedef mismatch")
+    bad = [i for i, (x, y) in enumerate(zip(la, lb))
+           if not np.array_equal(np.asarray(x), np.asarray(y))]
+    verdict["identity_ok"] = ta == tb and not bad
+    if bad:
+        paths = jax.tree_util.tree_flatten_with_path(finals["dense"])[0]
+        failures.append(
+            "divergent leaves: "
+            + ", ".join(jax.tree_util.keystr(paths[i][0])
+                        for i in bad[:8]))
     verdict["alive"] = int(np.sum(finals["dense"].alive))
     verdict["awake_nodes"] = int(finals["sparse"].counters["awake_nodes"])
 
@@ -115,7 +108,7 @@ def main() -> int:
     # where every [A]-lane gather would itself classify as N-wide.
     census = {}
     for tick_impl in ("dense", "sparse"):
-        sim = _build(tick_impl, "scatter", n=64, active_cap=16)
+        sim = _build(tick_impl, n=64, active_cap=16)
         s = sim.init(seed=3)
         txt = jax.jit(sim.step).lower(s).compile().as_text()
         census[tick_impl] = hlo_text.gather_counts(

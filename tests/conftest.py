@@ -40,9 +40,9 @@ if "xla_backend_optimization_level" not in flags:
 # round differently in two differently-structured graphs (observed: the
 # sparse tick diverging from the dense oracle by 1 ULP in Vivaldi
 # coords, PR 16).  Capping the CPU ISA below FMA makes every
-# cross-graph bit-identity pin (dense/sparse, scatter/pallas, telemetry
-# on/off, plain/sharded) exact by construction; at -O0 tiny-N shapes
-# the vector-width cost is noise.
+# cross-graph bit-identity pin (dense/sparse, inbox selection/sort
+# oracle, telemetry on/off, plain/sharded) exact by construction; at -O0
+# tiny-N shapes the vector-width cost is noise.
 if "xla_cpu_max_isa" not in flags:
     flags += " --xla_cpu_max_isa=AVX"
 os.environ["XLA_FLAGS"] = flags
@@ -80,22 +80,23 @@ jax.config.update("jax_enable_compilation_cache", False)
 # ---------------------------------------------------------------------------
 
 # The modules whose serial time is longest, in descending order (CPU
-# seconds of one whole run, PR 26; CHANGES.md has the table).  They are
-# collected first so the long fixtures start at second 0 and the tail of
-# the run is cheap tests; every other module follows as collected.
+# seconds of one whole run, PR 35's closing run; CHANGES.md has PR 26's
+# table).  They are collected first so the long fixtures start at
+# second 0 and the tail of the run is cheap tests; every other module
+# follows as collected.
 COST_ORDER = (
-    "test_route_modes.py", "test_route_modes_epichord.py",
-    "test_mesh_2d.py", "test_kernels.py", "test_route_modes_broose.py",
-    "test_mesh_dryrun.py", "test_mesh.py", "test_zz_sparse_rounds.py",
-    "test_vmap_campaign.py",
-    "test_pastry_multihop.py", "test_engine.py", "test_epichord.py",
-    "test_faults.py", "test_route_modes_koorde.py", "test_nice.py",
-    "test_kademlia_depth.py", "test_ncs.py", "test_parity.py",
-    "test_zz_sparse.py", "test_zz_service_resume.py",
-    "test_pastry_bamboo.py", "test_p2pns.py", "test_pastry.py",
-    "test_pastry_iterative.py", "test_koorde.py", "test_stack.py",
-    "test_gateway.py", "test_dht_variants.py", "test_churn.py",
-    "test_dht.py", "test_mesh_run_until.py",
+    "test_mesh.py", "test_mesh_dryrun.py", "test_route_modes.py",
+    "test_route_modes_broose.py", "test_route_modes_epichord.py",
+    "test_zz_sparse_rounds.py", "test_zz_sparse.py",
+    "test_vmap_campaign.py", "test_engine.py", "test_faults.py",
+    "test_pastry_multihop.py", "test_kademlia_depth.py",
+    "test_epichord.py", "test_route_modes_koorde.py", "test_mesh_2d.py",
+    "test_ncs.py", "test_zz_service_resume.py", "test_churn.py",
+    "test_koorde.py", "test_nice.py", "test_pastry.py",
+    "test_pastry_iterative.py", "test_stack.py", "test_dht_variants.py",
+    "test_mesh_run_until.py", "test_gateway.py",
+    "test_pastry_bamboo.py", "test_p2pns.py", "test_parity.py",
+    "test_dht.py",
 )
 
 

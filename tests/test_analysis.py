@@ -2,8 +2,8 @@
 
 Fast tier-1 pins: the AST rules + suppression syntax on crafted
 sources, bytecode guards on tmp trees, the new HLO text censuses on
-synthetic modules, the contract registry's shape, the scenario-level
-inbox_impl pins, and — per pass — one DELIBERATE seeded breach through
+synthetic modules, the contract registry's shape, and — per pass —
+one DELIBERATE seeded breach through
 the scripts/analyze.py CLI exiting non-zero with a machine-readable
 JSON finding.  The repo itself must lint clean (the allow markers are
 part of the tree)."""
@@ -17,8 +17,8 @@ import pytest
 from oversim_tpu.analysis import ast_pass, findings as findings_mod
 from oversim_tpu.analysis import contracts as contracts_mod
 from oversim_tpu.analysis.hlo_text import (
-    collective_census, custom_call_census, donated_leaf_count,
-    dtype_census, gather_counts, host_transfer_count)
+    collective_census, donated_leaf_count, dtype_census, gather_counts,
+    host_transfer_count)
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -215,23 +215,6 @@ def test_host_transfer_count():
     assert host_transfer_count(txt) == 3
 
 
-def test_custom_call_census_by_target():
-    txt = ("ENTRY %e {\n"
-           "  %m = f32[8]{0} custom-call(%x), "
-           "custom_call_target=\"tpu_custom_call\"\n"
-           "  %m2 = f32[8]{0} custom-call-start(%y), "
-           "custom_call_target=\"tpu_custom_call\"\n"
-           "  %r = f32[] custom-call(%x), "
-           "custom_call_target=\"rogue_vendor_kernel\"\n"
-           "  %u = f32[] custom-call(%x)\n"
-           "}\n")
-    assert custom_call_census(txt) == {"tpu_custom_call": 2,
-                                       "rogue_vendor_kernel": 1,
-                                       "<unknown>": 1}
-    assert custom_call_census("ENTRY %e { ROOT %r = f32[] add(%x,%y) }\n") \
-        == {}
-
-
 def test_dtype_census_and_allowlist():
     txt = ("  %a = f64[8]{0} add(%x, %y)\n"
            "  %b = bf16[4]{0} convert(%a)\n"
@@ -264,33 +247,24 @@ def test_hlo_breakdown_reexports_are_the_registry_helpers():
 
 
 # ---------------------------------------------------------------------------
-# contract registry + scenario pins
+# contract registry
 # ---------------------------------------------------------------------------
 
 def test_registry_shape():
     names = list(contracts_mod.REGISTRY)
     assert names == ["solo_tick", "solo_chunk", "run_until_device",
                      "campaign_tick", "telemetry_tick", "service_window",
-                     "daemon_window", "fused_tick", "fused_chunk",
-                     "sparse_tick", "sparse_chunk", "sharded_tick",
-                     "sharded_campaign_tick", "resharded_resume"]
+                     "daemon_window", "sparse_tick", "sparse_chunk",
+                     "sharded_tick", "sharded_campaign_tick",
+                     "resharded_resume"]
     tel = contracts_mod.REGISTRY["telemetry_tick"]
     assert tel.delta is not None and tel.delta.base == "solo_tick"
     for donated in ("solo_chunk", "run_until_device", "service_window",
-                    "daemon_window", "fused_chunk"):
+                    "daemon_window"):
         assert contracts_mod.REGISTRY[donated].contract.require_donation
     camp = contracts_mod.REGISTRY["campaign_tick"].contract
     assert camp.collectives_enforced
     assert camp.allowed_collectives == frozenset()
-    # kernel-plane entries: custom-call allowlist armed, and the fused
-    # tick must DROP scatters vs solo_tick (negative delta bound)
-    for kname in ("fused_tick", "fused_chunk"):
-        kc = contracts_mod.REGISTRY[kname].contract
-        assert kc.custom_calls_enforced
-        assert kc.allowed_custom_calls == frozenset({"tpu_custom_call"})
-    fused = contracts_mod.REGISTRY["fused_tick"]
-    assert fused.delta is not None and fused.delta.base == "solo_tick"
-    assert fused.delta.max_scatter_delta < 0
     # sparse active-set entries: donation required, no new sorts or
     # collectives vs the dense base, and the wide-gather bound is a
     # REQUIRED reduction (negative) — the whole point of the plane
@@ -316,20 +290,6 @@ def test_register_entry_validation():
         contracts_mod.register_entry(bad)          # dangling delta base
     with pytest.raises(KeyError):
         contracts_mod.entries(["bogus_entry"])
-
-
-def test_scenario_pins_default_inbox_scatter():
-    """Satellite: the default scenario must never resolve the oracle-
-    only sort inbox; an explicit **.inboxImpl key must stay honored."""
-    assert contracts_mod.scenario_pins() == []
-
-
-def test_scenario_inbox_flip_still_honored():
-    from oversim_tpu.config import scenario
-    from oversim_tpu.config.ini import IniFile
-    ini = IniFile.loads(contracts_mod._DEFAULT_INI
-                        + '\n**.inboxImpl = "sort"\n')
-    assert scenario.build_simulation(ini, "General").ep.inbox_impl == "sort"
 
 
 # ---------------------------------------------------------------------------
@@ -396,17 +356,6 @@ def test_seeded_trace_breach_exits_nonzero(tmp_path):
     assert rc == 1 and doc["ok"] is False
     [f] = [f for f in doc["findings"] if f["rule"] == "recompile"]
     assert f["pass"] == "trace" and f["measured"] == 1
-
-
-def test_seeded_kernel_breach_exits_nonzero(tmp_path):
-    """--seed-breach kernel: a planted off-allowlist custom-call vs the
-    fused_tick allowlist — pure-text, no backend, exits non-zero."""
-    rc, doc = _run_seed("kernel", tmp_path)
-    assert rc == 1 and doc["ok"] is False
-    [f] = [f for f in doc["findings"] if f["rule"] == "custom-calls"]
-    assert f["pass"] == "hlo"
-    assert f["measured"] == {"rogue_vendor_kernel": 1}
-    assert f["limit"] == ["tpu_custom_call"]
 
 
 def test_seeded_sparse_breach_exits_nonzero(tmp_path):

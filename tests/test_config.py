@@ -51,14 +51,6 @@ INI = textwrap.dedent("""
     **.overlayType = "oversim.overlay.kademlia.KademliaModules"
     **.overlay*.kademlia.k = 16
 
-    [Config KadSortInbox]
-    extends = Kad
-    **.inboxImpl = "sort"
-
-    [Config KadBadInbox]
-    extends = Kad
-    **.inboxImpl = "bogosort"
-
     [Config KadSparseTick]
     extends = Kad
     **.tickImpl = "sparse"
@@ -108,17 +100,6 @@ def test_scenario_kademlia(ini):
     assert isinstance(sim.logic, KademliaLogic)
     assert sim.logic.p.k == 16
     assert sim.logic.lcfg.merge is True
-    assert sim.ep.inbox_impl == "scatter"        # zero-sort default
-
-
-def test_scenario_inbox_impl_key(ini):
-    """``**.inboxImpl`` selects the inbox grouping implementation
-    (engine/pool.py); anything but scatter/pallas/sort is a config
-    error."""
-    sim = scenario.build_simulation(ini, "KadSortInbox")
-    assert sim.ep.inbox_impl == "sort"
-    with pytest.raises(scenario.ScenarioError):
-        scenario.build_simulation(ini, "KadBadInbox")
 
 
 def test_scenario_tick_impl_key(ini):
@@ -147,20 +128,6 @@ def test_resolve_tick_impl():
     assert scenario.resolve_tick_impl('"sparse"') == "sparse"
     with pytest.raises(scenario.ScenarioError):
         scenario.resolve_tick_impl("eager")
-
-
-def test_resolve_inbox_impl_kernel_plane():
-    """The pallas key resolves by kernel-plane availability: honored
-    when importable, a ScenarioError when not — never a quiet run on
-    the scatter path under the pallas name."""
-    assert scenario.resolve_inbox_impl(
-        "pallas", available=True, warn=False) == "pallas"
-    with pytest.raises(scenario.ScenarioError, match="unavailable"):
-        scenario.resolve_inbox_impl("pallas", available=False, warn=False)
-    assert scenario.resolve_inbox_impl('"scatter"') == "scatter"
-    assert scenario.resolve_inbox_impl("sort") == "sort"
-    with pytest.raises(scenario.ScenarioError):
-        scenario.resolve_inbox_impl("quantum")
 
 
 @pytest.mark.skipif(

@@ -21,6 +21,8 @@ from oversim_tpu.engine.logic import Msg, Outbox, T_INF
 from oversim_tpu.engine.sim import EngineParams, Simulation
 from oversim_tpu.underlay import simple as underlay_mod
 
+from oracles import SortSimulation, build_inbox_sort
+
 I32 = jnp.int32
 I64 = jnp.int64
 NS = 1_000_000_000
@@ -77,13 +79,13 @@ def test_pool_alloc_inbox_free_roundtrip():
     assert ts2[0] == 9
 
 
-def test_inbox_impl_identity_randomized_pool():
-    """sort vs scatter inbox grouping must be BIT-IDENTICAL on random
-    pools — including t_deliver ties (index tie-break), dead
+def test_inbox_identity_randomized_pool():
+    """The inbox grouping must be BIT-IDENTICAL to the sort oracle
+    (tests/oracles.py) on random pools — including t_deliver ties (index tie-break), dead
     destinations and R-overflow rows."""
     rng = np.random.default_rng(42)
-    sort_j = jax.jit(pool_mod.build_inbox_sort, static_argnames=("n", "r"))
-    scat_j = jax.jit(pool_mod.build_inbox_scatter, static_argnames=("n", "r"))
+    sort_j = jax.jit(build_inbox_sort, static_argnames=("n", "r"))
+    scat_j = jax.jit(pool_mod.build_inbox, static_argnames=("n", "r"))
     # fixed shapes -> ONE compile per impl; randomness lives in the data
     n, p, r = 7, 40, 3
     base = pool_mod.empty(p, key_lanes=5, rmax=4)
@@ -169,10 +171,10 @@ def _selectors(lanes):
     if lanes not in _SELECTORS:
         static = ("n", "r")
         _SELECTORS[lanes] = (
-            jax.jit(pool_mod.build_inbox_sort, static_argnames=static),
-            jax.jit(lambda pool, **kw: pool_mod.build_inbox_scatter(
+            jax.jit(build_inbox_sort, static_argnames=static),
+            jax.jit(lambda pool, **kw: pool_mod.build_inbox(
                 pool, lanes=pool.capacity, **kw), static_argnames=static),
-            jax.jit(lambda *a, **kw: pool_mod.build_inbox_scatter(
+            jax.jit(lambda *a, **kw: pool_mod.build_inbox(
                 *a, lanes=lanes, **kw), static_argnames=static),
             jax.jit(lambda pool, n, t_end, alive, hold:
                     pool_mod.lanes_swept(pool, n, t_end, alive, hold,
@@ -229,17 +231,17 @@ def test_inbox_lanes_rule_and_wide_forms():
     pool = _case_pool(1, N_A, P_A, 10)
     args = (pool, N_A, 3, jnp.int64(T_END), jnp.ones((N_A,), bool))
     txt = str(jax.make_jaxpr(
-        lambda: pool_mod.build_inbox_scatter(*args, lanes=P_A))())
+        lambda: pool_mod.build_inbox(*args, lanes=P_A))())
     assert "cond" not in txt and "cumsum" not in txt
     assert "cond" in str(jax.make_jaxpr(
-        lambda: pool_mod.build_inbox_scatter(*args))())
+        lambda: pool_mod.build_inbox(*args))())
 
 
 def test_inbox_overflow_keeps_earliest_r():
     """A node with more than R due messages must receive exactly the R
     EARLIEST (t_deliver, idx)-ordered ones this tick; the overflow stays
     valid in the pool and delivers next tick (backpressure, not loss) —
-    pinned on both implementations."""
+    pinned on the selection and on its sort oracle."""
     p = pool_mod.empty(16, key_lanes=5, rmax=4)
     q = 6
     out = {
@@ -259,29 +261,19 @@ def test_inbox_overflow_keeps_earliest_r():
     }
     p, _ = pool_mod.alloc(p, out, jnp.ones((q,), bool))
     alive = jnp.ones((2,), bool)
-    for impl in ("sort", "scatter"):
-        inbox, delivered, _ = pool_mod.build_inbox(
-            p, n=2, r=2, t_end=jnp.int64(10), alive=alive, impl=impl)
+    for impl, build in (("sort", build_inbox_sort),
+                        ("scatter", pool_mod.build_inbox)):
+        inbox, delivered, _ = build(
+            p, n=2, r=2, t_end=jnp.int64(10), alive=alive)
         # earliest two: t=3@idx1, t=3@idx2 (tie → lower pool index first)
         assert list(np.asarray(inbox[0])) == [1, 2], impl
         assert int(jnp.sum(delivered)) == 2, impl
         # the four overflow messages stay pooled for next tick
         p2 = pool_mod.free(p, delivered)
         assert int(jnp.sum(p2.valid)) == 4, impl
-        inbox2, delivered2, _ = pool_mod.build_inbox(
-            p2, n=2, r=2, t_end=jnp.int64(10), alive=alive, impl=impl)
+        inbox2, delivered2, _ = build(
+            p2, n=2, r=2, t_end=jnp.int64(10), alive=alive)
         assert list(np.asarray(inbox2[0])) == [4, 0], impl  # t=4 then t=5
-
-
-def test_build_inbox_rejects_unknown_impl():
-    p = pool_mod.empty(8, key_lanes=5, rmax=4)
-    try:
-        pool_mod.build_inbox(p, n=2, r=2, t_end=jnp.int64(1),
-                             alive=jnp.ones((2,), bool), impl="quantum")
-    except ValueError as e:
-        assert "inbox_impl" in str(e)
-    else:
-        raise AssertionError("unknown impl accepted")
 
 
 def test_pool_overflow_counted():
@@ -294,6 +286,117 @@ def test_pool_overflow_counted():
     p, overflow = pool_mod.alloc(p, out, jnp.ones((q,), bool))
     assert int(overflow) == 2
     assert int(jnp.sum(p.valid)) == 4
+
+
+# name -> (P, Q, occupied slots: a share taken at random or an exact
+# count, wanted messages: likewise)
+ALLOC_CASES = {
+    "empty_pool": (24, 10, 0, 0.6),
+    "full_pool": (24, 10, 24, 0.6),
+    "no_wants": (24, 10, 0.5, 0),
+    "one_free_slot": (24, 10, 23, 0.6),
+    "wants_exceed_free": (24, 24, 0.8, 24),
+    "q_larger_than_p": (8, 40, 0.25, 0.9),
+    "all_wanted_all_free": (16, 16, 0, 16),
+    # N=1000's own shapes (P = 8 N, Q = 16 N): no power of two
+    "p8000_q16000": (8000, 16000, 0.4, 0.02),
+    "random_a": (40, 30, 0.5, 0.5),
+    "random_b": (40, 70, 0.7, 0.4),
+}
+KL, RMAX = 5, 4
+
+
+def _pick(rng, size, how):
+    """[size] bool: exactly ``how`` at random places when an int, else
+    each with probability ``how``."""
+    if isinstance(how, int):
+        mask = np.zeros(size, bool)
+        mask[rng.choice(size, size=how, replace=False)] = True
+        return mask
+    return rng.random(size) < how
+
+
+def _random_pool(rng, p, occupied):
+    """A [P] pool whose every slot, valid or not, holds random content:
+    a write to a slot it should not touch shows."""
+    w = len(pool_mod.SCAL_COLS) + KL + RMAX
+    return pool_mod.MsgPool(
+        valid=jnp.asarray(_pick(rng, p, occupied)),
+        t_deliver=jnp.asarray(rng.integers(0, 1000, size=p), I64),
+        stamp=jnp.asarray(rng.integers(0, 1000, size=p), I64),
+        blk=jnp.asarray(rng.integers(-5, 1000, size=(p, w)), I32),
+        kl=KL, rmax=RMAX)
+
+
+@pytest.mark.parametrize("name", list(ALLOC_CASES))
+def test_pool_alloc_equals_plain_allocator(name):
+    """``pool.alloc`` against a plain allocator: the j-th wanted message
+    goes into the j-th free slot (both in index order), wanted messages
+    past the free supply are counted as overflow, ``blk``,
+    ``t_deliver``, ``stamp`` and ``valid`` are written there and no
+    other slot is touched."""
+    p, q, occupied, wanted = ALLOC_CASES[name]
+    rng = np.random.default_rng(sum(map(ord, name)))
+    pool = _random_pool(rng, p, occupied)
+    want = _pick(rng, q, wanted)
+    out = {k: rng.integers(0, 1000, size=q).astype(np.int32)
+           for k in pool_mod.SCAL_COLS}
+    out["key"] = rng.integers(0, 2**32, size=(q, KL), dtype=np.uint32)
+    out["nodes"] = rng.integers(-1, 50, size=(q, RMAX)).astype(np.int32)
+    out["t_deliver"] = rng.integers(1000, 2000, size=q)
+    out["stamp"] = rng.integers(1000, 2000, size=q)
+    new, overflow = jax.jit(pool_mod.alloc)(
+        pool, {k: jnp.asarray(v) for k, v in out.items()},
+        jnp.asarray(want))
+
+    exp = {k: np.array(getattr(pool, k))
+           for k in ("valid", "t_deliver", "stamp", "blk")}
+    rows = np.concatenate(
+        [np.stack([out[c] for c in pool_mod.SCAL_COLS], axis=1),
+         out["key"].view(np.int32), out["nodes"]], axis=1)
+    free = np.nonzero(~exp["valid"])[0]
+    wanted_idx = np.nonzero(want)[0]
+    for slot, j in zip(free, wanted_idx):      # the shorter one ends it
+        exp["valid"][slot] = True
+        exp["t_deliver"][slot] = out["t_deliver"][j]
+        exp["stamp"][slot] = out["stamp"][j]
+        exp["blk"][slot] = rows[j]
+    assert int(overflow) == max(len(wanted_idx) - len(free), 0)
+    for k, v in exp.items():
+        got = np.asarray(getattr(new, k))
+        assert got.dtype == v.dtype and (got == v).all(), k
+    assert (new.kl, new.rmax) == (KL, RMAX)
+
+
+# name -> the freed slots of 40 (a count or a share, as above)
+FREE_CASES = {"none_freed": 0, "all_freed": 40, "random": 0.5}
+
+
+@pytest.mark.parametrize("name", list(FREE_CASES))
+def test_pool_free_and_next_deliver_time(name):
+    """``pool.free`` clears ``valid`` and parks ``t_deliver`` at T_INF in
+    the freed slots and nowhere else, payload left as it was;
+    ``next_deliver_time`` is the earliest deliver time among the valid
+    slots, T_INF when there is none."""
+    p = 40
+    rng = np.random.default_rng(sum(map(ord, name)))
+    pool = _random_pool(rng, p, 0.6)
+    mask = _pick(rng, p, FREE_CASES[name])
+    new = jax.jit(pool_mod.free)(pool, jnp.asarray(mask))
+    valid, t = np.asarray(pool.valid), np.asarray(pool.t_deliver)
+    assert (np.asarray(new.valid) == (valid & ~mask)).all()
+    assert (np.asarray(new.t_deliver)
+            == np.where(mask, int(pool_mod.T_INF), t)).all()
+    assert (np.asarray(new.stamp) == np.asarray(pool.stamp)).all()
+    assert (np.asarray(new.blk) == np.asarray(pool.blk)).all()
+    for pl in (pool, new):
+        live = np.asarray(pl.t_deliver)[np.asarray(pl.valid)]
+        assert int(pool_mod.next_deliver_time(pl)) == (
+            live.min() if live.size else int(pool_mod.T_INF))
+    if name == "all_freed":
+        assert int(pool_mod.next_deliver_time(new)) == int(pool_mod.T_INF)
+    if name == "none_freed":
+        assert int(np.sum(new.valid)) == int(valid.sum()) > 0
 
 
 # ---------------------------------------------------------------------------
@@ -376,11 +479,11 @@ class PingLogic:
         return st, out, events
 
 
-def make_sim(n=16, window=0.010, inbox_impl="scatter"):
+def make_sim(n=16, window=0.010):
     logic = PingLogic()
     cp = churn_mod.ChurnParams(model="none", target_num=n, init_interval=0.1)
     ep = EngineParams(window=window, inbox_slots=4, outbox_slots=8,
-                      pool_factor=8, rmax=4, inbox_impl=inbox_impl)
+                      pool_factor=8, rmax=4)
     return Simulation(logic, cp, underlay_mod.UnderlayParams(), ep)
 
 
@@ -402,6 +505,19 @@ def test_ping_pong_end_to_end():
     assert out["_engine"]["pool_overflow"] == 0
     assert out["_engine"]["outbox_overflow"] == 0
     assert out["_engine"]["dest_unavailable_lost"] == 0
+
+
+def test_tick_equals_the_sort_oracles_tick():
+    """Whole ticks under the sort oracle (tests/oracles.py
+    SortSimulation: ``_phase_inbox_select`` overridden, every other
+    phase the engine's) end on the engine's own SimState, leaf for
+    leaf, over a fill and a window of ping/pong traffic."""
+    sim = make_sim(n=16)
+    a, b = (jax.device_get(s.run_chunk(s.init(seed=3), 400))
+            for s in (sim, SortSimulation.of(sim)))
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    assert all(jax.tree.leaves(jax.tree.map(np.array_equal, a, b)))
+    assert int(a.stats["c:pong.received"]) > 20
 
 
 # ---------------------------------------------------------------------------
@@ -428,18 +544,6 @@ def test_tick_hlo_zero_sorts_bounded_scatters():
         max_scatters=8 + 4 * sim.ep.inbox_slots + 1)
     assert ok, counts
     assert counts["full_pool_sort_count"] == 0, counts
-
-
-def test_tick_sort_impl_has_at_most_one_full_pool_sort():
-    """The legacy inbox_impl="sort" path keeps its old pin: the inbox
-    grouping is the tick's ONLY full-pool sort (the outbox allocator
-    stays sort-free)."""
-    sim = make_sim(n=24, inbox_impl="sort")
-    s = sim.init(seed=1)
-    txt = jax.jit(lambda st: sim.step(st)).lower(s).compile().as_text()
-    full_pool_sorts = [ln for ln in txt.splitlines()
-                       if " sort(" in ln and "[192" in ln]
-    assert len(full_pool_sorts) <= 1, full_pool_sorts
 
 
 def test_run_chunk_donates_state():
@@ -508,9 +612,10 @@ def test_run_until_device_matches_host_loop_chord64():
 
 def _inbox_identity_run(overlay: str, n_ticks: int = 64, seed: int = 3):
     """Run ``n_ticks`` overlay ticks under LifetimeChurn; at every tick
-    compare the sort and scatter inbox selections on the SAME pool/alive
-    snapshot (one fused scan, one dispatch).  Returns per-tick equality,
-    due-message counts and dead-destination drop counts."""
+    compare the sort oracle's and the engine's inbox selections on the
+    SAME pool/alive snapshot (one fused scan, one dispatch).  Returns
+    per-tick equality, due-message counts and dead-destination drop
+    counts."""
     if overlay == "chord":
         from oversim_tpu.overlay.chord import ChordLogic
         logic = ChordLogic()
@@ -527,9 +632,9 @@ def _inbox_identity_run(overlay: str, n_ticks: int = 64, seed: int = 3):
         t_next, t_end, rngs = sim._phase_horizon(st)
         _, alive, *_rest = sim._phase_churn(
             st, t_next, t_end, rngs[1], rngs[2], rngs[3], rngs[5])
-        a = pool_mod.build_inbox_sort(
+        a = build_inbox_sort(
             st.pool, sim.n, sim.ep.inbox_slots, t_end, alive)
-        b = pool_mod.build_inbox_scatter(
+        b = pool_mod.build_inbox(
             st.pool, sim.n, sim.ep.inbox_slots, t_end, alive)
         same = jnp.array(True)
         for x, y in zip(a, b):
@@ -546,7 +651,7 @@ def _inbox_identity_run(overlay: str, n_ticks: int = 64, seed: int = 3):
             int(jnp.sum(final.alive)))
 
 
-def test_inbox_impl_identity_chord_under_churn():
+def test_inbox_identity_chord_under_churn():
     """Satellite pin: sort vs scatter inbox selection is bit-identical
     (inbox, delivered, dropped_dead) across 64 chord ticks with lifetime
     churn killing/rebirthing nodes mid-run."""
@@ -555,7 +660,7 @@ def test_inbox_impl_identity_chord_under_churn():
     assert int(due.sum()) > 50, int(due.sum())   # the run carried traffic
 
 
-def test_inbox_impl_identity_kademlia_under_churn():
+def test_inbox_identity_kademlia_under_churn():
     same, due, to_dead, _alive = _inbox_identity_run("kademlia")
     assert same.all(), f"first divergent tick: {int(np.argmin(same))}"
     assert int(due.sum()) > 50, int(due.sum())

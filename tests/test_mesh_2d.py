@@ -17,7 +17,7 @@ K2D = 8
 TICKS_2D = 64
 
 
-def _make_churn_sim(overlay="chord", inbox_impl="scatter", n=N2D):
+def _make_churn_sim(overlay="chord", n=N2D):
     app = KbrTestApp(KbrTestParams(test_interval=1.0))
     if overlay == "kademlia":
         from oversim_tpu.overlay.kademlia import KademliaLogic
@@ -29,7 +29,7 @@ def _make_churn_sim(overlay="chord", inbox_impl="scatter", n=N2D):
     # dense by name: the hand-sharded tick refuses the awake-set plane,
     # which the engine's default gives these logics
     ep = sim_mod.EngineParams(window=0.1, inbox_slots=4, pool_factor=4,
-                              inbox_impl=inbox_impl, tick_impl="dense")
+                              tick_impl="dense")
     return sim_mod.Simulation(logic, cp, engine_params=ep)
 
 
@@ -121,17 +121,14 @@ def test_reshard_place_campaign_2d():
 
 
 @pytest.mark.parametrize("overlay", ["chord", "kademlia"])
-@pytest.mark.parametrize("inbox_impl", ["scatter", "pallas"])
-def test_sharded_tick_bit_identical(overlay, inbox_impl):
+def test_sharded_tick_bit_identical(overlay):
     """THE 2D contract: 64 churned ticks through parallel/shard_tick.py
     on the (1, 8) mesh reproduce the solo oracle BIT-IDENTICALLY on
     every SimState leaf — churn joins/leaves, KBR traffic, pool
-    alloc/free and stats all crossing shard boundaries.  pallas runs
-    the fused-inbox kernel in interpret mode on CPU (same lowering
-    decisions as the sub-core guide's interpret contract)."""
+    alloc/free and stats all crossing shard boundaries."""
     from oversim_tpu.parallel.shard_tick import ShardedSim
 
-    sim = _make_churn_sim(overlay=overlay, inbox_impl=inbox_impl)
+    sim = _make_churn_sim(overlay=overlay)
     s = sim.init(seed=3)
     step = jax.jit(sim.step)
     for _ in range(TICKS_2D):

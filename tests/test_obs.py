@@ -395,13 +395,13 @@ def test_synthetic_load_round_robin_and_cap():
 def test_run_observer_window_and_loop_events(tmp_path):
     obs = RunObserver(role="test", registry=Registry(),
                       flight_path=str(tmp_path / "f.jsonl"))
-    obs.set_static(inbox_impl="fused", replicas=2)
+    obs.set_static(overlay="chord", replicas=2)
     obs.on_window(0, {"_ticks": 64, "_t_sim": 1.0, "_alive": 8}, 0.5)
     obs.on_window(1, {"_ticks": 128, "_t_sim": 2.0, "_alive": 8}, 0.8)
     obs.loop_event("checkpoint_written", windows_done=2, path="ck")
     st = obs.statusz()
     assert st["role"] == "test"
-    assert st["inbox_impl"] == "fused" and st["replicas"] == 2
+    assert st["overlay"] == "chord" and st["replicas"] == 2
     assert st["window"] == 1 and st["tick"] == 128
     assert st["t_sim"] == 2.0 and st["alive"] == 8
     assert st["windows_done"] == 2

@@ -30,6 +30,8 @@ from oversim_tpu.engine import sim as sim_mod
 from oversim_tpu.service import (InProcessIngest, ServiceLoop,
                                  ServiceParams)
 
+from oracles import build_inbox_sort
+
 I32 = jnp.int32
 I64 = jnp.int64
 NS = 1_000_000_000
@@ -368,7 +370,8 @@ def test_ext_hold_parks_ext_out_for_the_drain():
     """The engine-side half of serving: a hold mask keeps EXT_OUT
     responses addressed to the gateway slot OUT of the inbox (they'd be
     consumed one tick after being sent otherwise); everything else
-    delivers normally.  Both inbox impls honor it identically."""
+    delivers normally.  The selection and its sort oracle honor it
+    identically."""
     st = _pool_state(p=8)
     frames = [
         gateway_mod.ExtFrame(a=1, b=7, c=70, kind=gateway_mod.EXT_OUT,
@@ -381,9 +384,9 @@ def test_ext_hold_parks_ext_out_for_the_drain():
     t_end = jnp.int64(10_000)
     hold = (pool.valid & (pool.kind == sim_mod.EXT_OUT_KIND)
             & (pool.dst == 0))
-    for impl in ("scatter", "sort"):
-        inbox, delivered, _ = pool_mod.build_inbox(
-            pool, 2, 2, t_end, alive, impl=impl, hold=hold)
+    for impl, build in (("scatter", pool_mod.build_inbox),
+                        ("sort", build_inbox_sort)):
+        inbox, delivered, _ = build(pool, 2, 2, t_end, alive, hold=hold)
         kinds = np.asarray(pool.kind)
         dlv = np.asarray(delivered)
         assert not dlv[np.asarray(pool.valid)
@@ -392,8 +395,7 @@ def test_ext_hold_parks_ext_out_for_the_drain():
                    & (kinds == gateway_mod.EXT_IN)].all(), impl
         # without the hold the response WOULD be consumed — the hazard
         # the ext_hold_slot engine knob exists for
-        _, dlv_nohold, _ = pool_mod.build_inbox(pool, 2, 2, t_end,
-                                                alive, impl=impl)
+        _, dlv_nohold, _ = build(pool, 2, 2, t_end, alive)
         assert np.asarray(dlv_nohold)[np.asarray(pool.valid)].all(), impl
 
 

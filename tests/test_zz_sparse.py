@@ -2,25 +2,26 @@
 
 The dense tick is the bit-identity ORACLE: every SimState leaf after a
 churned run must match the dense engine exactly — chord and kademlia,
-scatter and fused inbox, across awake-set occupancy extremes (an idle
-tick, 100% awake, R-overflow pressure) and at ANY active_cap: awake
+across awake-set occupancy extremes (an idle tick, 100% awake,
+R-overflow pressure) and at ANY active_cap: awake
 nodes past one round's A lanes are stepped in further rounds of the
 same tick, never deferred.
 
 (Late-alphabet filename on purpose: these are compile-heavy tests.
-Tier-1 keeps the scatter identity runs, the default resolution, the
-one-node-step-body pin and the compaction oracles here, and in
+Tier-1 keeps the identity runs, the default resolution, the
+one-node-step-body pin and the awake-order oracle here, and in
 test_zz_sparse_rounds.py (a module of its own: a module is one unit of
 work on one xdist worker) the cell's own deployment at N=128 under a
 cap that forces several rounds a tick and the all-awake and idle
-extremes; the remaining occupancy/pallas/window variants are marked
-slow — scripts/sparse_gate.py re-covers both inbox impls' identity in
-every run_suite pass.)
+extremes; the remaining occupancy/window variants are marked
+slow — scripts/sparse_gate.py re-covers the identity in every
+run_suite pass.)
 """
 
 import dataclasses
 import json
 import os
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -28,13 +29,13 @@ import numpy as np
 import pytest
 
 from oversim_tpu import churn as churn_mod
-from oversim_tpu import kernels
 from oversim_tpu.apps.kbrtest import KbrTestApp, KbrTestParams
+from oversim_tpu.core import keys as keys_mod
 from oversim_tpu.engine.sim import (
     ENGINE_COUNTERS, PLANE_COUNTERS, EngineParams, Simulation)
 
 
-def _sim(overlay, inbox_impl="scatter", tick_impl="dense", active_cap=0,
+def _sim(overlay, tick_impl="dense", active_cap=0,
          churn="lifetime", interval=None, slots=4, n=12):
     app = (KbrTestApp(KbrTestParams(test_interval=interval))
            if interval else None)
@@ -47,8 +48,7 @@ def _sim(overlay, inbox_impl="scatter", tick_impl="dense", active_cap=0,
     cp = churn_mod.ChurnParams(model=churn, target_num=n,
                                init_interval=0.2, lifetime_mean=8.0)
     ep = EngineParams(window=0.1, inbox_slots=slots, pool_factor=4,
-                      inbox_impl=inbox_impl, tick_impl=tick_impl,
-                      active_cap=active_cap)
+                      tick_impl=tick_impl, active_cap=active_cap)
     return Simulation(logic, cp, engine_params=ep)
 
 
@@ -95,14 +95,13 @@ def _assert_tree_equal(a, b):
             jax.tree_util.keystr(path)
 
 
-def _identity_run(overlay, inbox_impl, n_ticks=64, seed=3, **kw):
+def _identity_run(overlay, n_ticks=64, seed=3, **kw):
     """64 churned ticks full-step: the awake-set plane (auto cap =
     full-N here unless ``active_cap`` says less) must land on the EXACT
     dense SimState, bit for bit."""
     finals = {}
     for tick_impl in ("dense", "sparse"):
-        sim = _sim(overlay, inbox_impl=inbox_impl, tick_impl=tick_impl,
-                   **kw)
+        sim = _sim(overlay, tick_impl=tick_impl, **kw)
         s = sim.init(seed=seed)
         finals[tick_impl] = jax.device_get(sim.run_chunk(s, n_ticks))
     # counter layout: dense stays pre-sparse, sparse rides its three
@@ -138,35 +137,20 @@ def test_dense_step_counts_its_rows_on_an_awake_set_layout():
     _assert_tree_equal(jax.device_get(ref), _strip_sparse(jax.device_get(s)))
 
 
-# -- bit-identity under lifetime churn: overlays x inbox impls --------------
+# -- bit-identity under lifetime churn ----------------------------------------
 
 
 def test_sparse_identity_chord_scatter_under_churn():
-    finals = _identity_run("chord", "scatter")
+    finals = _identity_run("chord")
     assert int(np.sum(finals["dense"].alive)) > 0
     assert int(np.sum(finals["dense"].pool.valid)) > 0   # traffic ran
     assert int(finals["sparse"].counters["awake_nodes"]) > 0
 
 
 def test_sparse_identity_kademlia_scatter_under_churn():
-    finals = _identity_run("kademlia", "scatter")
+    finals = _identity_run("kademlia")
     assert int(np.sum(finals["dense"].alive)) > 0
     assert int(finals["sparse"].counters["active_dst"]) > 0
-
-
-@pytest.mark.slow
-@pytest.mark.skipif(not kernels.available(), reason="pallas unavailable")
-def test_sparse_identity_chord_pallas_under_churn():
-    """The sparse plane composes with the fused kernel inbox: the
-    select-only kernel (kernels.inbox.fused_select) feeds compaction
-    and the final state still matches the dense scatter-fed oracle."""
-    _identity_run("chord", "pallas")
-
-
-@pytest.mark.slow
-@pytest.mark.skipif(not kernels.available(), reason="pallas unavailable")
-def test_sparse_identity_kademlia_pallas_under_churn():
-    _identity_run("kademlia", "pallas")
 
 
 # -- occupancy extremes -----------------------------------------------------
@@ -223,7 +207,7 @@ def test_sparse_identity_r_overflow_pressure():
     """inbox_slots=2 under kbr traffic + churn: per-dest R-overflow
     defers deliveries to later ticks (inbox_deferred > 0) and the
     deferred pool slots re-enter compaction identically."""
-    finals = _identity_run("chord", "scatter", slots=2, interval=0.2)
+    finals = _identity_run("chord", slots=2, interval=0.2)
     assert int(finals["dense"].counters["inbox_deferred"]) > 0
 
 
@@ -336,43 +320,75 @@ def test_active_cap_at_capacity_is_exact():
     _assert_tree_equal(a, _strip_sparse(b))
 
 
-# -- compact_indices kernel vs numpy oracle ---------------------------------
+# -- the awake order vs numpy nonzero -----------------------------------------
 
 
-@pytest.mark.skipif(not kernels.available(), reason="pallas unavailable")
-def test_compact_indices_randomized_oracle():
-    """kernels.outbox.compact_indices == numpy nonzero-compaction:
-    lane k holds the k-th set index, sentinel beyond, and count is the
-    TRUE set-bit total even past cap."""
-    rng = np.random.default_rng(23)
-    for trial in range(25):
-        m = int(rng.integers(1, 48))
-        cap = int(rng.integers(1, m + 1))
-        mask = rng.random(m) < rng.random()
-        vals = rng.integers(0, 1000, size=m).astype(np.int32)
-        lanes, count = kernels.outbox.compact_indices(
-            jnp.asarray(mask), jnp.asarray(vals), cap, sentinel=m,
-            interpret=True)
-        want = vals[np.nonzero(mask)[0]]
-        exp = np.full((cap,), m, np.int32)
-        exp[:min(cap, len(want))] = want[:cap]
-        assert (np.asarray(lanes) == exp).all(), trial
-        assert int(count) == int(mask.sum()), trial
+class _TimersOnly:
+    """The least a Simulation asks of a logic to compact an awake set:
+    its state IS the [N] next-event times."""
+    key_spec = keys_mod.KeySpec(160)
+    awake_set_exact = True
+
+    def next_event(self, state):
+        return state
 
 
-@pytest.mark.skipif(not kernels.available(), reason="pallas unavailable")
-def test_compact_indices_extremes():
-    mask0 = jnp.zeros((16,), bool)
-    vals = jnp.arange(16, dtype=jnp.int32)
-    lanes, count = kernels.outbox.compact_indices(mask0, vals, 8,
-                                                  sentinel=16,
-                                                  interpret=True)
-    assert (np.asarray(lanes) == 16).all() and int(count) == 0
-    lanes, count = kernels.outbox.compact_indices(~mask0, vals, 8,
-                                                  sentinel=16,
-                                                  interpret=True)
-    assert list(np.asarray(lanes)) == list(range(8))
-    assert int(count) == 16                     # true count past cap
+# name -> (n, A, the awake nodes: a count taken at random, or "random")
+AWAKE_CASES = {
+    "none_awake": (16, 4, 0),
+    "all_awake": (16, 4, 16),
+    "awake_eq_a": (16, 4, 4),
+    "awake_a_plus_1": (16, 4, 5),
+    "random_a": (48, 8, "random"),
+    "random_b": (1000, 32, "random"),      # N=1000's own N and A
+    "n_not_multiple_of_a": (13, 4, 13),
+}
+
+
+@pytest.mark.parametrize("name", list(AWAKE_CASES))
+def test_awake_order_equals_nonzero(name):
+    """``_phase_active_compact`` on a made-up inbox table and made-up
+    timers: ``order`` is ``np.nonzero`` of the awake mask (a message in
+    slot 0, a due timer, a slot churn made this tick), ascending, then
+    the sentinels n + j out to ceil(N/A) * A lanes; ``rounds`` is
+    ceil(awake / A); and ``active`` tallies the awake nodes, the nodes
+    with a message and the lanes the rounds step."""
+    n, cap, k = AWAKE_CASES[name]
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if k == "random":
+        awake = rng.random(n) < rng.random()
+    else:
+        awake = np.zeros(n, bool)
+        awake[rng.choice(n, size=k, replace=False)] = True
+    # every awake node has a message, a due timer, both, or was created
+    # by this tick's churn
+    has_msg = awake & (rng.random(n) < 0.5)
+    timer = awake & (rng.random(n) < 0.4)
+    created = awake & ~has_msg & ~timer
+    t_end = 1000
+    sim = Simulation(
+        _TimersOnly(), churn_mod.ChurnParams(model="none", target_num=n),
+        engine_params=EngineParams(inbox_slots=3, active_cap=cap))
+    assert sim.n == n and sim.acap == cap and sim.tick_impl == "sparse"
+    inbox = np.full((n, 3), -1, np.int32)
+    inbox[has_msg, 0] = rng.integers(0, 8 * n, size=int(has_msg.sum()))
+    alive = jnp.ones((n,), bool)
+    order, rounds, active = jax.jit(
+        lambda was_alive, timers, ib: sim._phase_active_compact(
+            SimpleNamespace(alive=was_alive), jnp.int64(t_end), alive,
+            jnp.zeros((n,), bool), timers, ib))(
+        jnp.asarray(~created),
+        jnp.asarray(np.where(timer, t_end - 1, t_end + 5), jnp.int64),
+        jnp.asarray(inbox))
+    lanes = -(-n // cap) * cap
+    want = np.concatenate([np.nonzero(awake)[0],
+                           n + np.arange(awake.sum(), lanes)])
+    assert order.dtype == jnp.int32 and order.shape == (lanes,)
+    assert (np.asarray(order) == want).all()
+    assert (np.diff(np.asarray(order)) > 0).all()      # sorted, unique
+    assert int(rounds) == -(-int(awake.sum()) // cap)
+    assert [int(x) for x in active] == [
+        awake.sum(), has_msg.sum(), int(rounds) * cap]
 
 
 # -- the measurement loop stays one-dispatch-one-fetch ----------------------

@@ -8,10 +8,10 @@ settling and 100 ticks of window at A=4 (most ticks take several
 rounds), ``lanes_stepped`` against the recorded awake counts, every
 node awake (N/A rounds a tick), and an idle tick (no round).  The same
 recorded run pins the inbox selection over the due messages' D lanes
-(engine/pool.py build_inbox_scatter; ISSUE 34): its oracle selects by
-the full-pool sort, and ``inbox_lanes`` adds D in a tick whose due
-messages fit the lanes and P in a tick that fell back.  The
-helpers are test_zz_sparse.py's; a module of its own because a module
+(engine/pool.py build_inbox; ISSUE 34): its oracle selects by the
+full-pool sort (tests/oracles.py SortSimulation), and ``inbox_lanes``
+adds D in a tick whose due messages fit the lanes and P in a tick that
+fell back.  The helpers are test_zz_sparse.py's; a module of its own because a module
 is one unit of work on one xdist worker (tests/conftest.py).
 """
 
@@ -24,7 +24,10 @@ import pytest
 
 from oversim_tpu import churn as churn_mod
 from oversim_tpu.engine import pool as pool_mod
-from oversim_tpu.engine.sim import INBOX_COUNTERS, SPARSE_COUNTERS
+from oversim_tpu.engine.sim import (
+    INBOX_COUNTERS, SPARSE_COUNTERS, Simulation)
+
+from oracles import SortSimulation
 
 from test_zz_sparse import (
     CELL_N, _assert_tree_equal, _cell_sim, _sim, _strip_sparse)
@@ -38,15 +41,15 @@ def cell_run():
     """The cell's deployment at N=128 through its fill (20 s), its
     settling (20 s) and 100 ticks of window, tick by tick, under the
     oracle of both planes (``tick_impl="dense"``, every row swept, and
-    ``inbox_impl="sort"``, the full-pool sort) and under the engine's
+    ``SortSimulation``, the full-pool sort) and under the engine's
     defaults at A=4 (the awake-set plane; the inbox selected over the
     due messages' D lanes); the plane's counters after every tick, and
     the due messages each tick's selection met, are the recorded run."""
     sparse, config = _cell_sim(active_cap=CELL_CAP)
-    dense, _ = _cell_sim(tick_impl="dense", inbox_impl="sort")
+    dense = SortSimulation.of(_cell_sim(tick_impl="dense")[0])
     assert sparse.tick_impl == "sparse" and sparse.acap == CELL_CAP
-    assert sparse.ep.inbox_impl == "scatter"
-    assert dense.tick_impl == "dense" and dense.ep.inbox_impl == "sort"
+    assert type(sparse) is Simulation
+    assert dense.tick_impl == "dense"
     ticks = int(round((config["fill_s"] + config["settle_s"])
                       / config["engine"]["window"])) + 100
 
