@@ -37,7 +37,9 @@ i32 scalars, the key lanes (bitcast u32↔i32) and the RMAX node list —
 lives in ONE [P, W] i32 block, so the per-tick inbox build is one gather
 and the outbox allocation one scatter, instead of 12+ of each
 field-by-field.  Only the two i64 fields (t_deliver, stamp) and the
-valid mask stay separate; per-field access is provided by zero-copy
+valid mask stay separate leaves (the allocation's one row scatter
+carries them beside the block as 32-bit words, ``write_slots``);
+per-field access is provided by zero-copy
 column-slice properties, keeping the old field API for host-side readers
 (gateway drain, xmlrpcif) and the Msg view builder.
 """
@@ -325,6 +327,41 @@ def free(pool: MsgPool, mask) -> MsgPool:
         t_deliver=jnp.where(mask, T_INF, pool.t_deliver))
 
 
+def _words(x):
+    """[., 2] i32: the two 32-bit words of an i64 array, the same bits."""
+    return jax.lax.bitcast_convert_type(jnp.asarray(x, I64), I32)
+
+
+def write_slots(pool: MsgPool, dest, out: dict) -> MsgPool:
+    """Write the messages ``out`` ([Q]-leading field arrays) into the
+    slots ``dest`` ([Q] i32; an index of P or more writes nothing).
+
+    ONE row scatter, and no 64-bit one: on the chip a scatter into an
+    i64 operand costs 53 to 112 ns an UPDATE, dropped ones too (the two
+    fields' 16 N updates were 11.7 of a 28.9 ms tick at N=4096), and
+    every further scatter of Q updates 0.4 ms, where five more columns
+    of the packed block's row scatter cost nothing that shows (PERF.md,
+    PR 36).  So ``t_deliver`` and ``stamp`` ride it as two i32 words
+    each and ``valid`` as one, [Q, W+5] rows into [P, W+5], and are
+    split off again: plain copies over P and Q words, every leaf the
+    same bits as field-by-field writes."""
+    w = pool.blk.shape[1]
+    q = dest.shape[0]
+    rows = jnp.concatenate(
+        [pack_block(out, pool.kl, pool.rmax), _words(out["t_deliver"]),
+         _words(out["stamp"]), jnp.ones((q, 1), I32)], axis=1)
+    wide = jnp.concatenate(
+        [pool.blk, _words(pool.t_deliver), _words(pool.stamp),
+         pool.valid.astype(I32)[:, None]],
+        axis=1).at[dest].set(rows, mode="drop")
+    return dataclasses.replace(
+        pool,
+        blk=wide[:, :w],
+        t_deliver=jax.lax.bitcast_convert_type(wide[:, w:w + 2], I64),
+        stamp=jax.lax.bitcast_convert_type(wide[:, w + 2:w + 4], I64),
+        valid=wide[:, w + 4] != 0)
+
+
 def alloc(pool: MsgPool, out: dict, want):
     """Write the tick's outbox into free pool slots — SORT-FREE.
 
@@ -336,8 +373,9 @@ def alloc(pool: MsgPool, out: dict, want):
     mapping is built from two prefix sums plus ONE tiny [P] i32 scatter
     (the compacted free-slot list) — O(P) work instead of two
     O(P log P) full-pool sorts, the dominant per-tick cost at P = 8N.
-    The payload write stays one gather + one scatter of the packed
-    [·, W] block plus the two i64 fields and the valid mask.
+    The payload write is ``write_slots``: ONE row scatter of the packed
+    block with the two i64 fields and the valid mask beside it as
+    32-bit words.
     """
     p = pool.capacity
     n_want = jnp.sum(want.astype(I32))
@@ -363,13 +401,4 @@ def alloc(pool: MsgPool, out: dict, want):
                      fslot[jnp.minimum(want_rank, p - 1)], p)
     overflow = jnp.maximum(n_want - n_free, 0)
 
-    out_blk = pack_block(out, pool.kl, pool.rmax)
-    new_pool = dataclasses.replace(
-        pool,
-        blk=pool.blk.at[dest].set(out_blk, mode="drop"),
-        t_deliver=pool.t_deliver.at[dest].set(
-            jnp.asarray(out["t_deliver"], I64), mode="drop"),
-        stamp=pool.stamp.at[dest].set(
-            jnp.asarray(out["stamp"], I64), mode="drop"),
-        valid=pool.valid.at[dest].set(True, mode="drop"))
-    return new_pool, overflow
+    return write_slots(pool, dest, out), overflow

@@ -299,15 +299,7 @@ class ShardedSim:
         pool_overflow = jnp.maximum(n_want - n_free, 0)
         dl = dest - base_p
         dloc = jnp.where((dl >= 0) & (dl < pl), dl, pl)  # pl drops
-        out_blk = pool_mod.pack_block(flat, s.pool.kl, s.pool.rmax)
-        new_pool = dataclasses.replace(
-            new_pool,
-            blk=new_pool.blk.at[dloc].set(out_blk, mode="drop"),
-            t_deliver=new_pool.t_deliver.at[dloc].set(
-                jnp.asarray(flat["t_deliver"], I64), mode="drop"),
-            stamp=new_pool.stamp.at[dloc].set(
-                jnp.asarray(flat["stamp"], I64), mode="drop"),
-            valid=new_pool.valid.at[dloc].set(True, mode="drop"))
+        new_pool = pool_mod.write_slots(new_pool, dloc, flat)
 
         # stats + counters (global sums of pool-local masks ride [K]
         # count-vector min-gathers — integer-exact, census-clean)

@@ -302,7 +302,19 @@ ALLOC_CASES = {
     "p8000_q16000": (8000, 16000, 0.4, 0.02),
     "random_a": (40, 30, 0.5, 0.5),
     "random_b": (40, 70, 0.7, 0.4),
+    # t_deliver and stamp span the 64 bits (WORDS_CASES, below)
+    "words_random": (40, 30, 0.5, 0.5),
+    "words_wants_exceed_free": (24, 24, 0.8, 24),
+    "words_p8000_q16000": (8000, 16000, 0.4, 0.02),
+    # Q = 1: xmlrpcif's one injected packet
+    "words_q1_inject": (16, 1, 0.5, 1),
+    "words_q1_full_pool": (8, 1, 8, 1),
 }
+# the cases whose two i64 fields use both of their 32-bit words, in the
+# pool and in the outbox: negative, above 2**32, T_INF in the slots that
+# are free.  (The others draw them from 0 to 2,000: the high word is 0
+# there, and a swapped or dropped word would pass.)
+WORDS_CASES = {name for name in ALLOC_CASES if name.startswith("words_")}
 KL, RMAX = 5, 4
 
 
@@ -316,14 +328,33 @@ def _pick(rng, size, how):
     return rng.random(size) < how
 
 
-def _random_pool(rng, p, occupied):
+def _span64(rng, size):
+    """[size] i64 over all 64 bits, with the values a word mix-up would
+    turn into one another planted first."""
+    v = rng.integers(-2**63, 2**63 - 1, size=size, dtype=np.int64)
+    edge = np.array([-1, 2**32, -2**32, 2**32 + 1, 2**31, 1, 0, 2**62],
+                    np.int64)[:size]
+    v[:edge.size] = edge
+    return rng.permutation(v)
+
+
+def _random_pool(rng, p, occupied, words=False):
     """A [P] pool whose every slot, valid or not, holds random content:
-    a write to a slot it should not touch shows."""
+    a write to a slot it should not touch shows.  ``words``: the two i64
+    fields span the 64 bits, and a free slot's ``t_deliver`` is T_INF,
+    as ``pool.free`` leaves it."""
     w = len(pool_mod.SCAL_COLS) + KL + RMAX
+    valid = _pick(rng, p, occupied)
+    if words:
+        t_deliver = np.where(valid, _span64(rng, p), int(pool_mod.T_INF))
+        stamp = _span64(rng, p)
+    else:
+        t_deliver = rng.integers(0, 1000, size=p)
+        stamp = rng.integers(0, 1000, size=p)
     return pool_mod.MsgPool(
-        valid=jnp.asarray(_pick(rng, p, occupied)),
-        t_deliver=jnp.asarray(rng.integers(0, 1000, size=p), I64),
-        stamp=jnp.asarray(rng.integers(0, 1000, size=p), I64),
+        valid=jnp.asarray(valid),
+        t_deliver=jnp.asarray(t_deliver, I64),
+        stamp=jnp.asarray(stamp, I64),
         blk=jnp.asarray(rng.integers(-5, 1000, size=(p, w)), I32),
         kl=KL, rmax=RMAX)
 
@@ -336,15 +367,17 @@ def test_pool_alloc_equals_plain_allocator(name):
     ``t_deliver``, ``stamp`` and ``valid`` are written there and no
     other slot is touched."""
     p, q, occupied, wanted = ALLOC_CASES[name]
+    words = name in WORDS_CASES
     rng = np.random.default_rng(sum(map(ord, name)))
-    pool = _random_pool(rng, p, occupied)
+    pool = _random_pool(rng, p, occupied, words)
     want = _pick(rng, q, wanted)
     out = {k: rng.integers(0, 1000, size=q).astype(np.int32)
            for k in pool_mod.SCAL_COLS}
     out["key"] = rng.integers(0, 2**32, size=(q, KL), dtype=np.uint32)
     out["nodes"] = rng.integers(-1, 50, size=(q, RMAX)).astype(np.int32)
-    out["t_deliver"] = rng.integers(1000, 2000, size=q)
-    out["stamp"] = rng.integers(1000, 2000, size=q)
+    for k in ("t_deliver", "stamp"):
+        out[k] = (_span64(rng, q) if words
+                  else rng.integers(1000, 2000, size=q))
     new, overflow = jax.jit(pool_mod.alloc)(
         pool, {k: jnp.asarray(v) for k, v in out.items()},
         jnp.asarray(want))
@@ -527,11 +560,14 @@ def test_tick_equals_the_sort_oracles_tick():
 def test_tick_hlo_zero_sorts_bounded_scatters():
     """The default scatter-min inbox leaves the tick graph with ZERO
     full-pool sorts, and the scatter count stays within the engine
-    budget (8 baseline scatters — outbox alloc, stat hists, misc — plus
+    budget (4 baseline scatters, of which the outbox allocation takes
+    2: the free-slot list and the ONE row scatter that carries the
+    packed block with ``t_deliver``, ``stamp`` and ``valid`` as words;
+    a histogram is counted by comparison and takes none — plus
     2 per inbox round in EACH branch of the selection: over the due
     messages' D = 32 compacted lanes with one more to write
     ``delivered`` back, and P-wide for a tick whose due messages
-    outnumber the lanes), pinned via scripts/hlo_breakdown.py's
+    outnumber the lanes: 19 today), pinned via scripts/hlo_breakdown.py's
     counting helpers so the --budget CLI and this test share one
     definition.  n=24 makes the pool dimension P = 24*8 = 192
     distinctive in shape strings."""
@@ -541,9 +577,53 @@ def test_tick_hlo_zero_sorts_bounded_scatters():
     txt = jax.jit(lambda st: sim.step(st)).lower(s).compile().as_text()
     ok, counts = check_budget(
         txt, pool_dim=192, max_full_pool_sorts=0,
-        max_scatters=8 + 4 * sim.ep.inbox_slots + 1)
+        max_scatters=4 + 4 * sim.ep.inbox_slots + 1)
     assert ok, counts
     assert counts["full_pool_sort_count"] == 0, counts
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (cond
+    branches, loop bodies, calls) included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    yield from _eqns(x)
+
+
+@pytest.mark.parametrize("tick_impl", ["auto", "dense"])
+def test_tick_holds_no_wide_64_bit_scatter(tick_impl):
+    """On the chip a scatter into a 64-bit operand costs 53 to 112 ns an
+    UPDATE, the dropped and the zero ones too, a 32-bit one a twentieth: three such scatters of 13 N and 16 N updates were half of
+    the cells' tick (PERF.md, PR 36).  So in the traced tick of the
+    cells' own deployment no scatter into a 64-bit operand has N or
+    more updates, but for the R scatter-min rounds of the inbox
+    selection's P-wide branch, which only a tick with more due messages
+    than lanes runs.  Updates are counted a lane of a vmapped scatter:
+    the node step's own grow with its A = N/32 lanes, not with N."""
+    import math
+    from test_zz_sparse import _cell_sim    # kademlia4096.kbr60 at N=128
+    sim, _ = _cell_sim(tick_impl=tick_impl)
+    n, p, r = sim.n, sim.n * sim.ep.pool_factor, sim.ep.inbox_slots
+    shapes = jax.eval_shape(lambda: sim.init_from_rng(jax.random.PRNGKey(1)))
+    wide_rounds, seen = 0, 0
+    for e in _eqns(jax.make_jaxpr(sim.step)(shapes).jaxpr):
+        if not e.primitive.name.startswith("scatter"):
+            continue
+        operand, indices = e.invars[0].aval, e.invars[1].aval
+        batch = e.params["dimension_numbers"].scatter_indices_batching_dims
+        updates = math.prod(d for i, d in enumerate(indices.shape[:-1])
+                            if i not in batch)
+        seen += 1
+        if operand.dtype.itemsize < 8 or updates < n:
+            continue
+        assert (e.primitive.name, operand.shape, updates) == (
+            "scatter-min", (n,), p), (e.primitive.name, operand, updates)
+        wide_rounds += 1
+    assert wide_rounds == r and seen > 2 * r, (wide_rounds, seen)
 
 
 def test_run_chunk_donates_state():
