@@ -111,6 +111,12 @@ class ChurnState:
                            # later, SimpleUnderlayConfigurator.cc:375-376)
     graceful: jnp.ndarray  # [N] bool — NF_OVERLAY_NODE_GRACEFUL_LEAVE drawn
                            # (w.p. gracefulLeaveProbability, :370-373)
+    t_born: jnp.ndarray    # [N] i64 — the slot's INCARNATION: the start of
+                           # the tick that last created a node in it (-1:
+                           # never).  A slot is recycled under a fresh key,
+                           # so (slot, t_born) names a node where the slot
+                           # alone names an address; read by whoever
+                           # follows joins and deaths from outside
     l_mean: jnp.ndarray    # [N] f32 — per-slot mean lifetime (pareto)
     d_mean: jnp.ndarray    # [N] f32 — per-slot mean deadtime (pareto)
     t_tick: jnp.ndarray    # [] i64 — next periodic churn tick (random model)
@@ -119,6 +125,7 @@ class ChurnState:
 def _with_grace(state_kw, n):
     state_kw.setdefault("t_dead", jnp.full((n,), T_INF, I64))
     state_kw.setdefault("graceful", jnp.zeros((n,), bool))
+    state_kw.setdefault("t_born", jnp.full((n,), -1, I64))
     return state_kw
 
 
@@ -304,6 +311,7 @@ def step(state: ChurnState, p: ChurnParams, alive, t_start, t_end, rng,
     # LifetimeChurn::deleteNode; next_event() masks it while t_dead runs
 
     t_create = jnp.where(created, T_INF, state.t_create)
+    t_born = jnp.where(created, t_start, state.t_born)
     t_kill = state.t_kill
     t_tick = state.t_tick
     n = p.num_slots
@@ -365,5 +373,5 @@ def step(state: ChurnState, p: ChurnParams, alive, t_start, t_end, rng,
 
     return ChurnState(
         t_create=t_create, t_kill=t_kill, t_dead=t_dead, graceful=graceful,
-        l_mean=state.l_mean, d_mean=state.d_mean,
+        t_born=t_born, l_mean=state.l_mean, d_mean=state.d_mean,
         t_tick=t_tick), created, killed, leaving

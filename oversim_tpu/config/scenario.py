@@ -76,6 +76,14 @@ def build_churn(ini: IniFile, config: str) -> churn_mod.ChurnParams:
         kw["lifetime_dist"] = dist
         kw["lifetime_par1"] = float(_value(
             ini.get("**.lifetimeDistPar1", config), 1.0))
+        # the leave notice (default.ini:493-494); ChurnParams' defaults
+        # are upstream's, so an ini that is silent reads as before
+        for key, name in (("**.gracefulLeaveDelay", "graceful_leave_delay"),
+                          ("**.gracefulLeaveProbability",
+                           "graceful_leave_probability")):
+            raw = ini.get(key, config)
+            if raw is not None:
+                kw[name] = float(_value(raw))
     if model == "pareto":
         dm = ini.get("**.deadtimeMean", config)
         if dm is not None:

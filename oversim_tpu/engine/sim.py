@@ -135,8 +135,20 @@ SPARSE_COUNTERS = ("awake_nodes", "active_dst", "lanes_stepped")
 # the P-wide rounds; engine/pool.py build_inbox) and the pool
 # slots the P-wide rounds would have swept (P every tick)
 INBOX_COUNTERS = ("inbox_lanes", "inbox_pool_slots")
-# what a state of the awake-set plane carries beside ENGINE_COUNTERS
-PLANE_COUNTERS = SPARSE_COUNTERS + INBOX_COUNTERS
+# churn accounting, carried where SPARSE_COUNTERS are by a deployment
+# whose churn law can kill a node (every model but NoChurn: a population
+# that only fills has nothing to count, and every counter is a handful
+# of operations in every tick), cumulative: slots
+# a tick created, pre-killed (the leave notice) and finally killed,
+# ticks in which churn touched a slot at all, and the rows the churn
+# phase rewrote: N every tick, since ``logic.reset``, the fresh keys and
+# ``underlay.migrate`` are dense selects over all rows whatever churn
+# did (what a churn phase that follows the touched slots would lower)
+CHURN_COUNTERS = ("churn_created", "churn_prekilled", "churn_killed",
+                  "churn_ticks", "reset_rows")
+# what a state of the awake-set plane may carry beside ENGINE_COUNTERS
+# (the last group under a churn law only: Simulation.counter_names)
+PLANE_COUNTERS = SPARSE_COUNTERS + INBOX_COUNTERS + CHURN_COUNTERS
 
 
 def resolve_tick_impl(tick_impl: str, logic) -> str:
@@ -243,10 +255,13 @@ class Simulation:
     def counter_names(self) -> tuple:
         """Counter keys carried in SimState.counters for this engine
         config (the awake-set plane rides its accounting and the inbox
-        selection's along; the dense layout is untouched)."""
-        if self.tick_impl == "sparse":
-            return ENGINE_COUNTERS + PLANE_COUNTERS
-        return ENGINE_COUNTERS
+        selection's along, and the churn phase's where the churn law can
+        kill a node; the dense layout is untouched)."""
+        if self.tick_impl != "sparse":
+            return ENGINE_COUNTERS
+        if self.cp.model == "none":
+            return ENGINE_COUNTERS + SPARSE_COUNTERS + INBOX_COUNTERS
+        return ENGINE_COUNTERS + PLANE_COUNTERS
 
     @property
     def acap(self) -> int:
@@ -671,6 +686,19 @@ class Simulation:
                 s.pool, self.n, t_end, alive, self._hold_mask(s),
                 self.inbox_lanes).astype(I64)
             counters["inbox_pool_slots"] += s.pool.capacity
+        if "reset_rows" in counters:
+            # CHURN_COUNTERS, from what the churn phase left: a slot is
+            # created or finally killed where ``alive`` flipped (the two
+            # exclude each other within a tick, churn.step), pre-killed
+            # where its grace window opened (one reduction for the three)
+            touched = jnp.sum(jnp.stack([
+                alive & ~s.alive, pre_killed & ~(s.churn.t_dead < T_INF),
+                s.alive & ~alive]).astype(I32), axis=1).astype(I64)
+            counters["churn_created"] += touched[0]
+            counters["churn_prekilled"] += touched[1]
+            counters["churn_killed"] += touched[2]
+            counters["churn_ticks"] += (jnp.sum(touched) > 0).astype(I64)
+            counters["reset_rows"] += self.n
 
         # telemetry sample point (telemetry.py): END-of-tick snapshot of
         # the accumulators into the ring buffers, gated on the sampling

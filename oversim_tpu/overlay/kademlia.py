@@ -55,7 +55,17 @@ minSibling/BucketRefreshInterval=1000s).  State is structure-of-arrays:
     lookup at a time off a dirty mask (bounded concurrency);
   * handleFailedNode (Kademlia.cc:979): drop from siblings; stale+1 in
     buckets, evict when staleCount > maxStaleCount, promote a
-    replacement-cache candidate into the freed slot.
+    replacement-cache candidate into the freed slot;
+  * recycled slots: a table entry is a slot index, and a churn law that
+    recycles slots brings a dead slot back under a FRESH key, where
+    upstream's NodeHandle is address and key.  So a contact refreshes
+    only the entry in the bucket its current key earns, findNode never
+    offers an entry whose slot's current key earns another bucket, and
+    a step that heard a message evicts such entries the failed node's
+    way.  All table maintenance (this, the sibling merge) runs in steps
+    that heard a message, never in an idle node: it reads other slots'
+    current keys, and an idle node has to stay a fixed point of ``step``
+    (``awake_set_exact``).
 """
 
 from __future__ import annotations
@@ -327,9 +337,17 @@ class KademliaLogic:
         bi = jnp.where(en, self._bucket_index(me_key, ck), num_b)
 
         # --- presence refresh (alive contacts only) ---
-        acand = jnp.where(en & alive, cands, NO_NODE)
+        # ... in the bucket the contact's CURRENT key earns: an entry is
+        # a slot index, a recycled slot comes back under a fresh key, and
+        # the copy its old key left in another bucket is another node's
+        # (upstream's handle is address AND key), never refreshed
+        # (slot and bucket folded into one word, so that the [B, K, C]
+        # comparison stays ONE comparison: a disabled candidate reads -1,
+        # which only an empty slot of the last row equals, masked below)
+        ckey = jnp.where(en & alive, cands * num_b + bi, -1)
+        bkey = st.buckets * num_b + jnp.arange(num_b, dtype=I32)[:, None]
         hit = jnp.any(
-            st.buckets[:, :, None] == acand[None, None, :], axis=-1) & (
+            bkey[:, :, None] == ckey[None, None, :], axis=-1) & (
             st.buckets != NO_NODE)
         b_seen = jnp.where(hit, now, st.b_seen)
         b_stale = jnp.where(hit, 0, st.b_stale)
@@ -404,12 +422,13 @@ class KademliaLogic:
         return st, NO_NODE
 
     def _routing_add_batch(self, ctx, st, me_key, node_idx, cands, alive,
-                           now):
+                           now, heard):
         """Batched routingAdd (Kademlia.cc:432) for a tick's whole
         candidate set: one sibling-table merge sort + one batched bucket
         pass.  ``alive`` marks verified contacts (message sources); false
         = unverified learned nodes.  An alive occurrence of a node wins
-        over an unverified duplicate."""
+        over an unverified duplicate.  ``heard``: an inbox message came
+        this tick (no table is touched without one)."""
         en = (cands != NO_NODE) & (cands != node_idx)
         cands = jnp.where(en, cands, NO_NODE)
         eq = cands[None, :] == cands[:, None]
@@ -420,6 +439,12 @@ class KademliaLogic:
 
         new_sib, disp_vec = self._sib_merge(ctx, me_key, node_idx, st.sib,
                                             cands, en)
+        # a node that heard from nobody keeps its table as it stands: the
+        # merge sorts by the slots' CURRENT keys, and a recycled slot's
+        # fresh key would otherwise re-sort the tables of nodes that no
+        # message woke (an idle node is a fixed point of the step)
+        new_sib = jnp.where(heard, new_sib, st.sib)
+        disp_vec = jnp.where(heard, disp_vec, NO_NODE)
         st = dataclasses.replace(st, sib=new_sib)
         became_sib = jnp.any(cands[:, None] == new_sib[None, :], axis=1) & en
         # bucket candidates: displaced ex-siblings re-file as verified
@@ -474,28 +499,43 @@ class KademliaLogic:
         """Top-R closest known nodes by XOR distance (Kademlia.cc:1101).
 
         Returns ([rmax] i32 slots NO_NODE-padded, is_sibling bool)."""
-        out, is_sib = self._find_node_batch(ctx, st, me_key, node_idx,
-                                            key[None], rmax)
+        out, is_sib, _ = self._find_node_batch(ctx, st, me_key, node_idx,
+                                               key[None], rmax)
         return out[0], is_sib[0]
 
     def _find_node_batch(self, ctx, st, me_key, node_idx, keys, rmax):
         """Batched findNode for T target keys at once ([T, KL] → ([T, rmax]
-        slots, [T] is_sibling)) — ONE sort over the shared candidate set
-        per tick instead of one per unrolled call site.
+        slots, [T] is_sibling, [B, K] stale)) — ONE sort over the shared
+        candidate set per tick instead of one per unrolled call site.
 
         findNode: top-R by XOR distance over self ∪ siblings ∪ all buckets
         (Kademlia.cc:1101 walks best bucket → surrounding buckets →
-        siblings; same result set).  isSiblingFor: Kademlia.cc:888."""
+        siblings; same result set).  isSiblingFor: Kademlia.cc:888.
+
+        ``stale`` marks the bucket entries whose slot's CURRENT key
+        earns another bucket than the one they sit in: entries of a slot
+        that died and was recycled under a fresh key, another node now.
+        They are read off the key gather this call makes anyway and are
+        never among its candidates; ``step`` hands them to
+        ``_handle_failed``.  (The step's calls see the same tables, so
+        the compiler shares the gather, the masks and this between them:
+        nothing may touch the tables in between.)"""
         p = self.p
         t_dim = keys.shape[0]
         # mask bucket entries that were since promoted into the sibling
         # table (routingAdd can adopt a bucket resident without purging
         # its bucket slot) so the result set never repeats a node
-        flat = st.buckets.reshape(-1)
-        in_sib = jnp.any(flat[:, None] == st.sib[None, :], axis=1)
-        flat = jnp.where(in_sib, NO_NODE, flat)
-        cands = jnp.concatenate([node_idx[None], st.sib, flat])    # [C]
+        held = st.buckets.reshape(-1)
+        drop = jnp.any(held[:, None] == st.sib[None, :], axis=1)
+        cands = jnp.concatenate([node_idx[None], st.sib, held])    # [C]
         ck = ctx.keys[jnp.maximum(cands, 0)]                       # [C, KL]
+        row = jnp.repeat(jnp.arange(p.num_buckets, dtype=I32), p.k)
+        stale = (held != NO_NODE) & (
+            self._bucket_index(me_key, ck[1 + p.s:]) != row)
+        drop = drop | stale
+        # (a dropped entry's key is never read: its distance is UMAX below)
+        cands = jnp.concatenate(
+            [cands[:1 + p.s], jnp.where(drop, NO_NODE, held)])
         d = ck[None, :, :] ^ keys[:, None, :]                      # [T, C, KL]
         d = jnp.where((cands == NO_NODE)[None, :, None], UMAX, d)
         (c_s,) = K.sort_by_distance(
@@ -521,15 +561,17 @@ class KademliaLogic:
             K.lt(d_sib_key, jnp.broadcast_to(d_me[:, None, :],
                                              d_sib_key.shape)), axis=1)
         is_sib = ready & (n_sib < 1) | (ready & ~not_ours & ~closer_sib)
-        return out, is_sib
+        return out, is_sib, stale.reshape(p.num_buckets, p.k)
 
-    def _handle_failed(self, ctx, st, me_key, node_idx, failed):
+    def _handle_failed(self, ctx, st, me_key, node_idx, failed, also=None):
         """handleFailedNode (Kademlia.cc:979): drop sibling / stale+evict.
 
         ``failed`` may be a scalar or a [K] batch — the whole tick's
         failure list is folded in one sort + one bucket sweep (each
         occurrence of a node in the batch counts one stale strike, like
-        the reference's one call per RPC timeout)."""
+        the reference's one call per RPC timeout).  ``also`` [B, K] marks
+        bucket entries to evict whatever their count: a recycled slot's
+        entries in the bucket its old key earned."""
         failed = jnp.atleast_1d(jnp.asarray(failed, I32))
         en = jnp.any(failed != NO_NODE)
         # sibling drop + re-sort
@@ -547,6 +589,8 @@ class KademliaLogic:
         strikes = jnp.where(st.buckets != NO_NODE, strikes, 0)
         stale = st.b_stale + strikes
         evict = (strikes > 0) & (stale > self.p.max_stale)
+        if also is not None:
+            evict = evict | also
         st = dataclasses.replace(
             st,
             buckets=jnp.where(evict, NO_NODE, st.buckets),
@@ -629,14 +673,15 @@ class KademliaLogic:
             [jnp.ones((r_in,), bool),
              jnp.zeros((learned.size,), bool)])
         now_add = jnp.max(jnp.where(v_r, t_del_r, 0))
-        st, rc_ping = self._routing_add_batch(ctx, st, me_key, node_idx,
-                                              add_cands, add_alive, now_add)
+        heard = now_add > 0         # an inbox message came (none is due at 0)
+        st, rc_ping = self._routing_add_batch(
+            ctx, st, me_key, node_idx, add_cands, add_alive, now_add, heard)
 
         # batched findNode + sibling flags for every inbox key: consumed
         # by the FindNodeCall responder below AND (R/Kademlia) by the
         # recursive route pre-pass as its forwarding candidates
-        res_b, sib_b = self._find_node_batch(ctx, st, me_key, node_idx,
-                                             msgs.key, rmax)
+        res_b, sib_b, stale_b = self._find_node_batch(
+            ctx, st, me_key, node_idx, msgs.key, rmax)
 
         if self.rcfg is not None:
             # R/Kademlia recursive hook (Kademlia::recursiveRoutingHook,
@@ -874,7 +919,7 @@ class KademliaLogic:
 
         # ONE batched findNode for every timer consumer: sibling refresh
         # (own key), the app lookup seed, and the bucket-refresh seed
-        seeds3, sib3 = self._find_node_batch(
+        seeds3, sib3, _ = self._find_node_batch(
             ctx, st, me_key, node_idx,
             jnp.stack([me_key, req.key, target_ref]), rmax)
         res0, seed_a, seed_r = seeds3[0], seeds3[1], seeds3[2]
@@ -949,9 +994,14 @@ class KademliaLogic:
                     size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
         # one batched repair for the tick's failures: lookup RPC
         # timeouts + maintenance-ping timeouts
+        # ... and a recycled slot's entries in the bucket its old key
+        # earned, a dead node's: they go the failed node's way whatever
+        # their count, in a step that heard a message (never in an idle
+        # node, which is a fixed point of the step)
         st = self._handle_failed(
             ctx, st, me_key, node_idx,
-            jnp.concatenate([failed_nodes, ping_failed]))
+            jnp.concatenate([failed_nodes, ping_failed]),
+            also=stale_b & heard)
         # R/Kademlia: reroute parked route messages around failed hops
         # (the failed hop was just dropped from the tables; a node that
         # became responsible meanwhile self-delivers)
@@ -960,7 +1010,7 @@ class KademliaLogic:
                 st.rr, t_end, self.rcfg)
             st = dataclasses.replace(st, rr=new_rr)
             st = self._handle_failed(ctx, st, me_key, node_idx, rt_failed)
-            nxt_q, sib_q = self._find_node_batch(
+            nxt_q, sib_q, _ = self._find_node_batch(
                 ctx, st, me_key, node_idx, st.rr.key, rmax)
             nxt_q2, found_q = jax.vmap(
                 rt_mod.pick_next_hop, in_axes=(0, 0, 0, 0, None, 0))(

@@ -32,7 +32,8 @@ from oversim_tpu import churn as churn_mod
 from oversim_tpu.apps.kbrtest import KbrTestApp, KbrTestParams
 from oversim_tpu.core import keys as keys_mod
 from oversim_tpu.engine.sim import (
-    ENGINE_COUNTERS, PLANE_COUNTERS, EngineParams, Simulation)
+    CHURN_COUNTERS, ENGINE_COUNTERS, PLANE_COUNTERS, EngineParams,
+    Simulation)
 
 
 def _sim(overlay, tick_impl="dense", active_cap=0,
@@ -241,7 +242,9 @@ def test_default_tick_plane_resolution():
     assert not any("tickImpl" in ln for ln in config["ini"])
     assert cell.logic.awake_set_exact and cell.tick_impl == "sparse"
     assert cell.acap == min(cell.n, max(32, cell.n // 32)) == 32
-    assert set(cell.counter_names) == set(ENGINE_COUNTERS + PLANE_COUNTERS)
+    # (the churn phase's counters ride along under a churn law only)
+    assert set(cell.counter_names) == set(
+        ENGINE_COUNTERS + PLANE_COUNTERS) - set(CHURN_COUNTERS)
 
     pastry = build_simulation(IniFile.loads(PASTRY_INI))
     assert not getattr(pastry.logic, "awake_set_exact", False)
