@@ -51,6 +51,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from oversim_tpu.core import lanes as lanes_mod
+
 I32 = jnp.int32
 I64 = jnp.int64
 U32 = jnp.uint32
@@ -191,16 +193,23 @@ def inbox_lanes(p: int) -> int:
     hundred due messages among 8,000 to 131,072 slots, and a tick with
     more than D takes the P-wide rounds, so D moves the cost of a tick
     and never its result (PERF.md, PR 34)."""
-    return min(p, max(32, p // 32))
+    return lanes_mod.rule(p)
+
+
+def send_lanes(q: int) -> int:
+    """K — the static lane count of the compacted closing phase, from
+    Q = N x outbox_slots alone (as D is from P and A from N): Q/32, at
+    least 32.  On the chip a gather costs by its lanes (7.6 ns a lane
+    at Q = 16,000 and 131,072 alike), a steady tick of the KBR cells
+    wants under a hundred of its 16,000 to 262,144 outbox slots sent,
+    and a tick that wants more than K takes the Q-wide form, so K moves
+    the cost of a tick and never its result (PERF.md, PR 38;
+    ``engine/sim.py _phase_alloc_stats``)."""
+    return lanes_mod.rule(q)
 
 
 def _lanes(p: int, lanes) -> int:
     return inbox_lanes(p) if lanes is None else min(lanes, p)
-
-
-def _fits(due, d: int):
-    """The tick's due messages fit the D compacted lanes."""
-    return jnp.sum(due.astype(I32)) <= d
 
 
 def lanes_swept(pool: MsgPool, n: int, t_end, alive, hold=None,
@@ -214,7 +223,7 @@ def lanes_swept(pool: MsgPool, n: int, t_end, alive, hold=None,
     if d >= p:
         return jnp.int32(p)
     due, _ = _due_masks(pool, n, t_end, alive, hold)
-    return jnp.where(_fits(due, d), d, p).astype(I32)
+    return jnp.where(lanes_mod.fits(due, d), d, p).astype(I32)
 
 
 def _scatter_rounds(tkey, dstc, idx, n: int, r: int, pt: int,
@@ -304,11 +313,9 @@ def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
         return inbox, delivered, to_dead
 
     def compacted(_):
-        # lane j holds the pool index of the (j+1)-th due slot: the first
-        # slot whose inclusive due-count reaches j+1 (p past the last)
-        li = jnp.searchsorted(jnp.cumsum(due.astype(I32)),
-                              jnp.arange(1, d + 1, dtype=I32),
-                              side="left").astype(I32)
+        # lane j holds the pool index of the (j+1)-th due slot (p past
+        # the last)
+        li = lanes_mod.compact(due, d)
         lic = jnp.minimum(li, p - 1)
         inbox, taken = _scatter_rounds(
             jnp.where(li < p, pool.t_deliver[lic], T_INF), dstc[lic], li,
@@ -316,7 +323,8 @@ def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
         return inbox, jnp.zeros((p,), bool).at[
             jnp.where(taken, li, p)].set(True, mode="drop")
 
-    inbox, delivered = jax.lax.cond(_fits(due, d), compacted, wide, None)
+    inbox, delivered = jax.lax.cond(lanes_mod.fits(due, d), compacted, wide,
+                                    None)
     return inbox, delivered, to_dead
 
 
@@ -365,8 +373,15 @@ def write_slots(pool: MsgPool, dest, out: dict) -> MsgPool:
 def alloc(pool: MsgPool, out: dict, want):
     """Write the tick's outbox into free pool slots — SORT-FREE.
 
-    ``out`` maps field name -> [Q, ...] flattened outbox arrays;
-    ``want`` is [Q] bool.  Returns (pool', overflow_count).
+    ``out`` maps field name -> [L, ...] arrays of messages in outbox
+    order and ``want`` is [L] bool: all Q = N x M flattened outbox
+    slots, or the K lanes the closing phase compacted the tick's wanted
+    slots into, ascending (``engine/sim.py _phase_alloc_stats``: the
+    ranking below is then a K-wide running sum, ``fslot[want_rank]`` a
+    K-lane gather and the row scatter K rows, where each cost by Q; the
+    free slots' ranking stays P-wide, plain passes and one 32-bit
+    scatter).  Same slots either way: a lane that holds no message
+    wants nothing.  Returns (pool', overflow_count).
 
     The j-th wanted message goes to the j-th free slot (both in index
     order), exactly as the old two-`lax.sort` allocator did, but the

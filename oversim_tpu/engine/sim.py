@@ -42,6 +42,7 @@ from oversim_tpu import stats as stats_mod
 from oversim_tpu import telemetry as telemetry_mod
 from oversim_tpu.common.malicious import MaliciousParams
 from oversim_tpu.core import keys as keys_mod
+from oversim_tpu.core import lanes as lanes_mod
 from oversim_tpu.engine import pool as pool_mod
 from oversim_tpu.engine.logic import Ctx, Msg
 from oversim_tpu.underlay import simple as underlay_mod
@@ -135,6 +136,13 @@ SPARSE_COUNTERS = ("awake_nodes", "active_dst", "lanes_stepped")
 # the P-wide rounds; engine/pool.py build_inbox) and the pool
 # slots the P-wide rounds would have swept (P every tick)
 INBOX_COUNTERS = ("inbox_lanes", "inbox_pool_slots")
+# closing-phase accounting, carried where INBOX_COUNTERS are, cumulative:
+# outbox slots the receiver's stage of the underlay and the pool's
+# allocation ran over (K in a tick whose wanted messages fit the K
+# compacted lanes, Q = N x outbox_slots in a tick that took the Q-wide
+# form; _phase_alloc_stats) and the slots the Q-wide form runs over (Q
+# every tick)
+SEND_COUNTERS = ("send_lanes", "send_outbox_slots")
 # churn accounting, carried where SPARSE_COUNTERS are by a deployment
 # whose churn law can kill a node (every model but NoChurn: a population
 # that only fills has nothing to count, and every counter is a handful
@@ -148,7 +156,8 @@ CHURN_COUNTERS = ("churn_created", "churn_prekilled", "churn_killed",
                   "churn_ticks", "reset_rows")
 # what a state of the awake-set plane may carry beside ENGINE_COUNTERS
 # (the last group under a churn law only: Simulation.counter_names)
-PLANE_COUNTERS = SPARSE_COUNTERS + INBOX_COUNTERS + CHURN_COUNTERS
+PLANE_COUNTERS = (SPARSE_COUNTERS + INBOX_COUNTERS + SEND_COUNTERS
+                  + CHURN_COUNTERS)
 
 
 def resolve_tick_impl(tick_impl: str, logic) -> str:
@@ -210,7 +219,8 @@ class Simulation:
     def __init__(self, logic, churn_params: churn_mod.ChurnParams,
                  underlay_params=None,
                  engine_params: EngineParams | None = None,
-                 underlay_module=None, *, inbox_lanes: int | None = None):
+                 underlay_module=None, *, inbox_lanes: int | None = None,
+                 send_lanes: int | None = None):
         # the underlay is a strategy module (init/migrate/send_batch/
         # connection_matrix): underlay.simple (SimpleUnderlay, default)
         # or underlay.inet (InetUnderlay/ReaSEUnderlay router topology)
@@ -232,35 +242,51 @@ class Simulation:
         if inbox_lanes is None:
             inbox_lanes = pool_mod.inbox_lanes(p)
         self.inbox_lanes = min(inbox_lanes, p)
+        # K — lanes the closing phase compacts a tick's wanted outbox
+        # slots into (engine/pool.py send_lanes: a rule of Q alone);
+        # Q = the Q-wide form only, which is also all an underlay
+        # without the two stages (``send_tx`` / ``send_rx``:
+        # underlay/inet.py) can take.  Code's to pass, like D
+        q = self.n * self.ep.outbox_slots
+        if send_lanes is None:
+            send_lanes = pool_mod.send_lanes(q)
+        if not hasattr(self.ul, "send_rx"):
+            send_lanes = q
+        self.send_lanes = min(send_lanes, q)
 
     def for_vmap(self) -> "Simulation":
         """This deployment as a caller that vmaps the step wants it
         (campaign/runner.py): ``tick_impl="auto"`` settled as the dense
         sweep (under vmap the round loop runs every replica for the
-        busiest one's rounds) and the inbox selection P-wide (under vmap
-        a ``lax.cond`` runs both branches, so the compacted lanes would
-        come on top of the P-wide rounds).  Same results either way.
+        busiest one's rounds), the inbox selection P-wide and the
+        closing phase Q-wide (under vmap a ``lax.cond`` runs both
+        branches, so the D and the K compacted lanes would come on top
+        of the wide forms: with D = P and K = Q the program holds
+        neither ``cond``).  Same results either way.
         The GSPMD builders of parallel/mesh.py do not take it: on four
         chips the awake-set plane is the faster one (PERF.md, PR 28)."""
         p = self.ep.pool_factor * self.n
+        q = self.ep.outbox_slots * self.n
         dense = self.ep.tick_impl != "auto" or self.tick_impl == "dense"
-        if dense and self.inbox_lanes == p:
+        if dense and self.inbox_lanes == p and self.send_lanes == q:
             return self
         ep = self.ep if dense else dataclasses.replace(
             self.ep, tick_impl="dense")
         return Simulation(self.logic, self.cp, self.up, ep, self.ul,
-                          inbox_lanes=p)
+                          inbox_lanes=p, send_lanes=q)
 
     @property
     def counter_names(self) -> tuple:
         """Counter keys carried in SimState.counters for this engine
-        config (the awake-set plane rides its accounting and the inbox
-        selection's along, and the churn phase's where the churn law can
-        kill a node; the dense layout is untouched)."""
+        config (the awake-set plane rides its accounting, the inbox
+        selection's and the closing phase's along, and the churn phase's
+        where the churn law can kill a node; the dense layout is
+        untouched)."""
         if self.tick_impl != "sparse":
             return ENGINE_COUNTERS
         if self.cp.model == "none":
-            return ENGINE_COUNTERS + SPARSE_COUNTERS + INBOX_COUNTERS
+            return tuple(c for c in ENGINE_COUNTERS + PLANE_COUNTERS
+                         if c not in CHURN_COUNTERS)
         return ENGINE_COUNTERS + PLANE_COUNTERS
 
     @property
@@ -276,7 +302,7 @@ class Simulation:
         takes a dozen rounds a tick (PERF.md, PR 27)."""
         if self.ep.active_cap > 0:
             return min(self.ep.active_cap, self.n)
-        return min(self.n, max(32, self.n // 32))
+        return lanes_mod.rule(self.n)
 
     # -- init ---------------------------------------------------------------
 
@@ -631,22 +657,71 @@ class Simulation:
                            out_valid, out_overflow, events, measuring, *,
                            active=None):
         """Phase 5/5: free delivered slots, send the outbox through the
-        underlay into free pool slots (sort-free alloc), fold stats."""
+        underlay into free pool slots (sort-free alloc), fold stats.
+
+        What is indexed by a message's RECEIVER (the underlay's
+        ``send_rx``: liveness, channel, coordinates, node type) or by
+        its POOL SLOT (``pool.alloc``: ``fslot[want_rank]``, the row
+        scatter) runs over the tick's WANTED outbox slots, compacted
+        ascending into K static lanes (``pool.send_lanes``, a rule of
+        Q alone), through one row gather of everything a lane needs: a
+        gather costs by its lanes, and a steady tick wants a few of its
+        Q = N x M slots sent.  What is indexed by the sender's own row
+        stays [N, M] wide (``send_tx``: the queue model and the two
+        random draws, which so stay at their slots; the outbox fields'
+        packing for the gather): plain passes.  A tick that wants more
+        than K messages (a fill, a saturated mix, flooding) takes the
+        same two calls over all Q through a ``lax.cond``, for the same
+        answer: exact at any load, nothing dropped, deferred or
+        reordered, every leaf the same bits.  ``send_lanes >= Q`` is the
+        Q-wide form alone (``for_vmap``; an underlay without the two
+        stages)."""
         ep, up = self.ep, self.up
         node_idx = jnp.arange(self.n, dtype=I32)
         new_pool = pool_mod.free(s.pool, delivered | to_dead)
-        t_del, ok, ul_state, drops = self.ul.send_batch(
-            ul_state, up, r_send, jnp.broadcast_to(node_idx[:, None],
-                                                 out_fields["dst"].shape),
-            out_fields["dst"], out_fields["size_b"], out_fields["t_send"],
-            out_valid, alive, kind=out_fields["kind"])
-        flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in out_fields.items()
-                if k != "t_send"}
-        flat["t_deliver"] = t_del.reshape(-1)
-        flat["src"] = jnp.broadcast_to(node_idx[:, None],
-                                       out_valid.shape).reshape(-1)
-        new_pool, pool_overflow = pool_mod.alloc(
-            new_pool, flat, (out_valid & ok).reshape(-1))
+        src = jnp.broadcast_to(node_idx[:, None], out_valid.shape)
+        q, k = out_valid.size, self.send_lanes
+        want = out_valid.reshape(-1)
+        flat = {f: v.reshape((q,) + v.shape[2:])
+                for f, v in out_fields.items() if f != "t_send"}
+        flat["src"] = src.reshape(-1)
+        fit = None          # whether the tick fit the lanes; None: no lanes
+
+        if hasattr(self.ul, "send_rx"):
+            tx, ul_state = self.ul.send_tx(
+                ul_state, up, r_send, src, out_fields["dst"],
+                out_fields["size_b"], out_fields["t_send"], out_valid,
+                kind=out_fields["kind"])
+            tx = {f: v.reshape((q,) + v.shape[2:]) for f, v in tx.items()}
+
+            def close(lane):
+                """Receiver's stage and allocation over the slots
+                ``lane`` ([K] i32, Q where a lane holds none), or over
+                all Q (None)."""
+                tx_l, out_l = lanes_mod.take((tx, flat), lane)
+                if lane is not None:
+                    tx_l = dict(tx_l, want=tx_l["want"] & (lane < q))
+                t_del, ok, ul_l, drops = self.ul.send_rx(
+                    ul_state, up, tx_l, alive)
+                pool_l, overflow = pool_mod.alloc(
+                    new_pool, dict(out_l, t_deliver=t_del), ok)
+                return pool_l, overflow, ul_l, drops
+
+            if k >= q:
+                new_pool, pool_overflow, ul_state, drops = close(None)
+            else:
+                fit = lanes_mod.fits(want, k)
+                new_pool, pool_overflow, ul_state, drops = jax.lax.cond(
+                    fit, lambda: close(lanes_mod.compact(want, k)),
+                    lambda: close(None))
+        else:
+            t_del, ok, ul_state, drops = self.ul.send_batch(
+                ul_state, up, r_send, src, out_fields["dst"],
+                out_fields["size_b"], out_fields["t_send"], out_valid,
+                alive, kind=out_fields["kind"])
+            new_pool, pool_overflow = pool_mod.alloc(
+                new_pool, dict(flat, t_deliver=t_del.reshape(-1)),
+                ok.reshape(-1))
 
         # stats
         new_stats = stats_mod.record(s.stats, events, measuring)
@@ -686,6 +761,12 @@ class Simulation:
                 s.pool, self.n, t_end, alive, self._hold_mask(s),
                 self.inbox_lanes).astype(I64)
             counters["inbox_pool_slots"] += s.pool.capacity
+        if "send_lanes" in counters:
+            # SEND_COUNTERS: the slots this tick's closing phase ran its
+            # receiver's stage and its allocation over, over all Q
+            counters["send_lanes"] += (
+                q if fit is None else jnp.where(fit, k, q).astype(I64))
+            counters["send_outbox_slots"] += q
         if "reset_rows" in counters:
             # CHURN_COUNTERS, from what the churn phase left: a slot is
             # created or finally killed where ``alive`` flipped (the two

@@ -19,7 +19,12 @@ node-type partition (GlobalNodeList::areNodeTypesConnected).
 
 All of it is computed for a whole ``[N, MOUT]`` outbox batch at once; the
 per-sender transmit-queue serialization (``tx.finished`` carry) becomes a
-cumulative sum along the outbox axis.
+cumulative sum along the outbox axis.  In two stages: the sender's
+(``send_tx``: the queue model and the random draws, [N, MOUT] wide,
+indexed by the sender's own row) and the receiver's (``send_rx``:
+whatever is indexed by the destination, over a vector of messages: all
+N x MOUT slots for ``send_batch``, the engine's K lanes of wanted slots in
+a steady tick).
 """
 
 from __future__ import annotations
@@ -29,6 +34,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from oversim_tpu.core import lanes as lanes_mod
 
 I64 = jnp.int64
 F32 = jnp.float32
@@ -197,36 +204,26 @@ def migrate(state: UnderlayState, mask, rng, p: UnderlayParams) -> UnderlayState
                                tx_finished=tx_finished)
 
 
-@partial(jax.jit, static_argnames=("p",))
-def send_batch(state: UnderlayState, p: UnderlayParams, rng,
-               src, dst, size_bytes, t_send, want, alive, kind=None):
-    """Compute deliver times and drop decisions for an outbox batch.
+def send_tx(state: UnderlayState, p: UnderlayParams, rng,
+            src, dst, size_bytes, t_send, want, kind=None):
+    """The SENDER's stage of :func:`send_batch`, [N, M] wide: the
+    transmit-queue model and everything else that is indexed by the
+    sender's own row or by the outbox slot, which needs no gather and
+    costs a few elementwise passes.  The two random draws stay AT THEIR
+    SLOTS here, so a message meets the same draw whichever lanes the
+    receiver's stage runs over.
 
-    Args:
-      src, dst: [N, M] i32 sender/receiver slots (src row i is node i).
-      size_bytes: [N, M] i32 payload bytes (headers added here).
-      t_send: [N, M] i64 ns logical send times.
-      want: [N, M] bool — slot actually carries a message.
-      alive: [N] bool.
-
-    Returns (t_deliver [N,M] i64, ok [N,M] bool, new_state, drop_stats dict).
-    Messages with ok=False are dropped (queue overrun / bit error / dest
-    dead); t_deliver for self-sends is t_send (SimpleUDP.cc:322 skips the
-    delay model when srcAddr == destAddr).
-    """
+    Returns ``(tx, new_state)``: ``tx`` a dict of per-slot arrays
+    ([N, M, ...]; flattened to [Q, ...] or read at K lanes, it is what
+    :func:`send_rx` takes), ``new_state`` with the queues drained
+    (``tx_finished``)."""
     n, m = src.shape
     tbl = p.channel_table
     bits = (size_bytes + p.header_bytes) * 8
 
-    tx_bw = tbl[state.channel, 0][:, None]           # [N,1] sender bandwidth
-    tx_access = tbl[state.channel, 1][:, None]
-    tx_ber = tbl[state.channel, 2][:, None]
-    rx_bw = tbl[state.channel[dst], 0]               # [N,M] receiver side
-    rx_access = tbl[state.channel[dst], 1]
-    rx_ber = tbl[state.channel[dst], 2]
-
-    self_send = src == dst
-    queued = want & ~self_send
+    chan = tbl[state.channel]                        # [N,3] sender's channel
+    tx_bw = chan[:, 0][:, None]                      # [N,1] sender bandwidth
+    queued = want & (src != dst)
 
     # --- sender transmit queue (SimpleNodeEntry.cc:163-181) ---
     # Serialize this tick's messages through the sender's queue in outbox
@@ -246,18 +243,72 @@ def send_batch(state: UnderlayState, p: UnderlayParams, rng,
         jnp.max(jnp.where(queued & ~overrun, finish, 0), axis=1),
         state.tx_finished)
 
+    def slots(x):                       # a sender's value at its M slots
+        return jnp.broadcast_to(x[:, None], (n, m) + x.shape[1:])
+
+    tx = dict(
+        src=src, dst=dst, bits=bits, t_send=t_send, want=want,
+        overrun=overrun, queue_ns=finish - t_send,
+        tx_access=slots(chan[:, 1]), tx_ber=slots(chan[:, 2]),
+        tx_coords=slots(state.coords),
+        u=jax.random.uniform(jax.random.fold_in(rng, 1), (n, m), dtype=F32))
+    # --- jitter: positive half-normal (SimpleUDP.cc:360-373) ---
+    if p.jitter > 0:
+        tx["jit"] = jnp.abs(jax.random.normal(rng, (n, m), dtype=F32))
+    # --- SimpleTCP: the probe of the sender's own open-connection cache
+    if p.tcp_kinds and kind is not None:
+        is_tcp = jnp.zeros((n, m), bool)
+        for k in p.tcp_kinds:
+            is_tcp = is_tcp | (kind == k)
+        ct = p.tcp_connection_cache
+        tx["is_tcp"] = is_tcp & queued
+        tx["row"] = slots(jnp.arange(n, dtype=jnp.int32))
+        tx["open_hit"] = state.tcp_conn[
+            tx["row"], jnp.clip(dst % ct, 0, ct - 1)] == dst
+    # --- node-type partitions: the sender's row of the connection
+    # matrix, as it stands at the tick's first send
+    if p.partition_events:
+        conn = connection_matrix(p, jnp.min(jnp.where(want, t_send, T_MAX)))
+        tx["conn_row"] = conn[state.node_type[src]]              # [N, M, T]
+    return tx, dataclasses.replace(state, tx_finished=new_tx_finished)
+
+
+def send_rx(state: UnderlayState, p: UnderlayParams, tx: dict, alive):
+    """The RECEIVER's stage of :func:`send_batch`: everything that is
+    indexed by a message's destination (its liveness, its channel, its
+    coordinates, its node type), the delay that follows from them and
+    the drop decisions, over a VECTOR of messages: ``tx`` is
+    :func:`send_tx`'s dict with one leading axis [L, ...], all Q = N x M
+    outbox slots or the K lanes the engine compacted the tick's wanted
+    slots into (``engine/sim.py _phase_alloc_stats``; a lane that holds
+    no message has ``want`` false).  One body of logic for both widths:
+    the same elementwise arithmetic on the same operands at another
+    position, so every deliver time is the same to the bit.  The
+    receiver's values come through ONE row gather (``lanes.take``): a
+    gather costs by its lanes, not by its row's width.
+
+    Returns ``(t_deliver [L] i64, ok [L] bool, new_state, drops)``."""
+    dst, want, bits, t_send = tx["dst"], tx["want"], tx["bits"], tx["t_send"]
+    self_send = tx["src"] == dst
+    queued = want & ~self_send
+    tx_access, tx_ber = tx["tx_access"], tx["tx_ber"]
+    rx_alive, rx_chan, rx_coords, rx_type = lanes_mod.take(
+        (alive, p.channel_table[state.channel], state.coords,
+         state.node_type), dst)
+    rx_bw, rx_access, rx_ber = rx_chan[:, 0], rx_chan[:, 1], rx_chan[:, 2]
+
     # --- propagation: coordinate distance (SimpleNodeEntry.cc:144-152) ---
-    d = state.coords[:, None, :] - state.coords[dst]          # [N, M, D]
+    d = tx["tx_coords"] - rx_coords                           # [L, D]
     dist = jnp.sqrt(jnp.sum(d * d, axis=-1))
     coord_delay = p.coord_delay_per_unit * dist
 
     rx_delay = bits.astype(F32) / rx_bw
 
     if p.use_coordinate_based_delay:
-        total_ns = (finish - t_send) + (
+        total_ns = tx["queue_ns"] + (
             (tx_access + coord_delay + rx_delay + rx_access) * NS).astype(I64)
     else:
-        total_ns = jnp.full((n, m), jnp.int64(p.constant_delay * NS))
+        total_ns = jnp.full(dst.shape, jnp.int64(p.constant_delay * NS))
 
     # --- PlanetLab delay faults (getFaultyDelay, SimpleNodeEntry.cc:
     # 197-254): errorRatio = Kumaraswamy⁻¹(hash(delay)) + shift, sign
@@ -289,16 +340,10 @@ def send_batch(state: UnderlayState, p: UnderlayParams, rng,
     # SYN/SYN-ACK/ACK handshake (1.5 one-way delays); a collision
     # evicts the older connection (ExtTCPSocketMap reuse semantics,
     # bounded state)
-    if p.tcp_kinds and kind is not None:
-        is_tcp = jnp.zeros((n, m), bool)
-        for k in p.tcp_kinds:
-            is_tcp = is_tcp | (kind == k)
-        is_tcp = is_tcp & queued
-        ct = p.tcp_connection_cache
-        col_c = jnp.clip(dst % ct, 0, ct - 1)
-        rows_c = jnp.broadcast_to(jnp.arange(n)[:, None], (n, m))
-        open_hit = state.tcp_conn[rows_c, col_c] == dst
-        handshake = is_tcp & ~open_hit
+    tcp = "is_tcp" in tx
+    if tcp:
+        is_tcp = tx["is_tcp"] & queued
+        handshake = is_tcp & ~tx["open_hit"]
         one_way_ns = ((tx_access + coord_delay + rx_access) * NS).astype(I64)
         total_ns = total_ns + jnp.where(handshake,
                                         (one_way_ns * 3) // 2,
@@ -310,43 +355,78 @@ def send_batch(state: UnderlayState, p: UnderlayParams, rng,
     # --- jitter: positive half-normal, sigma = jitter * delay
     # (SimpleUDP.cc:360-373 truncnormal(0, delay*jitter)) ---
     if p.jitter > 0:
-        jit = jnp.abs(jax.random.normal(rng, (n, m), dtype=F32))
-        total_ns = total_ns + (jit * p.jitter * total_ns.astype(F32)).astype(I64)
+        total_ns = total_ns + (
+            tx["jit"] * p.jitter * total_ns.astype(F32)).astype(I64)
 
     # --- drops ---
     bit_err_p = 1.0 - (1.0 - tx_ber) ** bits * (1.0 - rx_ber) ** bits
-    u = jax.random.uniform(jax.random.fold_in(rng, 1), (n, m), dtype=F32)
-    bit_error = queued & (u < bit_err_p)
+    bit_error = queued & (tx["u"] < bit_err_p)
     # TCP retransmits instead of losing the segment: one RTO-scaled
     # extra delay (doubled transfer time), no drop
-    if p.tcp_kinds and kind is not None:
+    if tcp:
         retrans = bit_error & is_tcp
         total_ns = total_ns + jnp.where(retrans, total_ns, jnp.int64(0))
         bit_error = bit_error & ~is_tcp
-    dest_dead = want & ~alive[dst]
+    overrun = tx["overrun"] & want
+    dest_dead = want & ~rx_alive
 
     # node-type partition drop (SimpleUDP.cc:349-358:
     # !areNodeTypesConnected(src, dst) → numPartitionLost)
-    if p.partition_events:
-        conn = connection_matrix(p, jnp.min(jnp.where(want, t_send, T_MAX)))
-        part_cut = want & ~conn[state.node_type[src], state.node_type[dst]]
+    if "conn_row" in tx:
+        part_cut = want & ~jnp.take_along_axis(
+            tx["conn_row"], rx_type[:, None], axis=1)[:, 0]
     else:
         part_cut = jnp.zeros_like(want)
 
     ok = want & ~overrun & ~bit_error & ~dest_dead & ~part_cut
     t_deliver = jnp.where(self_send, t_send, t_send + total_ns)
 
-    if p.tcp_kinds and kind is not None:
+    if tcp:
+        n, ct = state.tcp_conn.shape
         new_conn = state.tcp_conn.at[
-            jnp.where(handshake & ok, rows_c, n), col_c].set(
-            dst, mode="drop")
+            jnp.where(handshake & ok, tx["row"], n),
+            jnp.clip(dst % ct, 0, ct - 1)].set(dst, mode="drop")
         state = dataclasses.replace(state, tcp_conn=new_conn)
 
-    new_state = dataclasses.replace(state, tx_finished=new_tx_finished)
     drops = {
-        "queue_lost": jnp.sum(overrun & want),
+        "queue_lost": jnp.sum(overrun),
         "bit_error_lost": jnp.sum(bit_error),
         "dest_unavailable_lost": jnp.sum(dest_dead),
         "partition_lost": jnp.sum(part_cut),
     }
-    return t_deliver, ok, new_state, drops
+    return t_deliver, ok, state, drops
+
+
+@partial(jax.jit, static_argnames=("p",))
+def send_batch(state: UnderlayState, p: UnderlayParams, rng,
+               src, dst, size_bytes, t_send, want, alive, kind=None):
+    """Compute deliver times and drop decisions for an outbox batch.
+
+    Args:
+      src, dst: [N, M] i32 sender/receiver slots (src row i is node i).
+      size_bytes: [N, M] i32 payload bytes (headers added here).
+      t_send: [N, M] i64 ns logical send times.
+      want: [N, M] bool — slot actually carries a message.
+      alive: [N] bool.
+
+    Returns (t_deliver [N,M] i64, ok [N,M] bool, new_state, drop_stats dict).
+    Messages with ok=False are dropped (queue overrun / bit error / dest
+    dead); t_deliver for self-sends is t_send (SimpleUDP.cc:322 skips the
+    delay model when srcAddr == destAddr).
+
+    Two stages, one body of logic each: :func:`send_tx` (the sender's
+    queue model and the random draws, [N, M] wide: it indexes by the
+    sender's own row and needs no gather) and :func:`send_rx` (whatever
+    is indexed by the RECEIVER, over a vector of messages).  This entry
+    runs the receiver's stage over all Q = N x M slots; the engine's
+    closing phase runs it over the K lanes that hold the tick's wanted
+    slots and comes here, in effect, only in a tick that wants more than
+    K (``engine/sim.py _phase_alloc_stats``).  Same answers either way.
+    """
+    n, m = src.shape
+    tx, state = send_tx(state, p, rng, src, dst, size_bytes, t_send, want,
+                        kind)
+    t_deliver, ok, state, drops = send_rx(
+        state, p, {k: v.reshape((n * m,) + v.shape[2:])
+                   for k, v in tx.items()}, alive)
+    return t_deliver.reshape(n, m), ok.reshape(n, m), state, drops

@@ -7,6 +7,7 @@ allocation, underlay delays, stats — without any overlay logic on top.
 """
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -582,16 +583,33 @@ def test_tick_hlo_zero_sorts_bounded_scatters():
     assert counts["full_pool_sort_count"] == 0, counts
 
 
-def _eqns(jaxpr):
+def _eqns(jaxpr, into_cond=True):
     """Every equation of a jaxpr, those of its sub-jaxprs (cond
-    branches, loop bodies, calls) included."""
+    branches unless ``into_cond`` is false, loop bodies, calls)
+    included."""
     for e in jaxpr.eqns:
         yield e
+        if e.primitive.name == "cond" and not into_cond:
+            continue
         for v in e.params.values():
             for x in (v if isinstance(v, (tuple, list)) else (v,)):
                 x = getattr(x, "jaxpr", x)
                 if hasattr(x, "eqns"):
-                    yield from _eqns(x)
+                    yield from _eqns(x, into_cond)
+
+
+def _lanes_of(e):
+    """Index rows of a gather, updates of a scatter (a lane of a vmapped
+    one counted once); None for any other equation."""
+    name = e.primitive.name
+    if name == "gather":
+        batch = e.params["dimension_numbers"].start_indices_batching_dims
+    elif name.startswith("scatter"):
+        batch = e.params["dimension_numbers"].scatter_indices_batching_dims
+    else:
+        return None
+    return math.prod(d for i, d in enumerate(e.invars[1].aval.shape[:-1])
+                     if i not in batch)
 
 
 @pytest.mark.parametrize("tick_impl", ["auto", "dense"])
@@ -604,7 +622,6 @@ def test_tick_holds_no_wide_64_bit_scatter(tick_impl):
     selection's P-wide branch, which only a tick with more due messages
     than lanes runs.  Updates are counted a lane of a vmapped scatter:
     the node step's own grow with its A = N/32 lanes, not with N."""
-    import math
     from test_zz_sparse import _cell_sim    # kademlia4096.kbr60 at N=128
     sim, _ = _cell_sim(tick_impl=tick_impl)
     n, p, r = sim.n, sim.n * sim.ep.pool_factor, sim.ep.inbox_slots
@@ -613,10 +630,7 @@ def test_tick_holds_no_wide_64_bit_scatter(tick_impl):
     for e in _eqns(jax.make_jaxpr(sim.step)(shapes).jaxpr):
         if not e.primitive.name.startswith("scatter"):
             continue
-        operand, indices = e.invars[0].aval, e.invars[1].aval
-        batch = e.params["dimension_numbers"].scatter_indices_batching_dims
-        updates = math.prod(d for i, d in enumerate(indices.shape[:-1])
-                            if i not in batch)
+        operand, updates = e.invars[0].aval, _lanes_of(e)
         seen += 1
         if operand.dtype.itemsize < 8 or updates < n:
             continue

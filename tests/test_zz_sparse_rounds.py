@@ -25,7 +25,7 @@ import pytest
 from oversim_tpu import churn as churn_mod
 from oversim_tpu.engine import pool as pool_mod
 from oversim_tpu.engine.sim import (
-    INBOX_COUNTERS, SPARSE_COUNTERS, Simulation)
+    INBOX_COUNTERS, SEND_COUNTERS, SPARSE_COUNTERS, Simulation)
 
 from oracles import SortSimulation
 
@@ -160,8 +160,9 @@ def test_every_node_awake_takes_n_over_a_rounds():
 
 def test_idle_tick_runs_no_round():
     """With nothing due anywhere the tick runs zero rounds and leaves
-    every leaf but the clock, the tick count, the rng and the inbox
-    selection's two counters as it was."""
+    every leaf but the clock, the tick count, the rng and the two
+    counters each of the inbox selection and of the closing phase as it
+    was."""
     sim = _sim("kademlia", tick_impl="sparse", churn="none")
     s0 = sim.init(seed=11)
     s0 = dataclasses.replace(s0, churn=dataclasses.replace(
@@ -183,9 +184,14 @@ def test_idle_tick_runs_no_round():
     p = sim.ep.pool_factor * n
     assert int(after.counters["inbox_lanes"]) == sim.inbox_lanes < p
     assert int(after.counters["inbox_pool_slots"]) == p
+    # ... and the closing phase's: no outbox slot was wanted, so it ran
+    # over its K empty lanes, of the Q outbox slots
+    q = sim.ep.outbox_slots * n
+    assert int(after.counters["send_lanes"]) == sim.send_lanes < q
+    assert int(after.counters["send_outbox_slots"]) == q
     same = dict(t_now=before.t_now, tick=before.tick, rng=before.rng,
                 counters=before.counters)
     _assert_tree_equal(dataclasses.replace(after, **same), before)
     assert all(int(after.counters[k]) == int(v)
                for k, v in before.counters.items()
-               if k not in INBOX_COUNTERS)
+               if k not in INBOX_COUNTERS + SEND_COUNTERS)
