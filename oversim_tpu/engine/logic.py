@@ -90,6 +90,10 @@ class Ctx:
     # Handlers opt in via ov_get(); absent keys keep the static-param
     # code path so a no-sweep trace stays bit-identical.
     ov: object = None
+    # the one due joiner that may start an overlay where no node is READY
+    # (i32 scalar, NO_NODE where none is due), built only for a logic
+    # that defines ``ring_starter`` (overlay/chord.py)
+    starter: object = None
 
     def ov_get(self, name, default=None):
         """Traced sweep-override lookup (trace-time dict access)."""

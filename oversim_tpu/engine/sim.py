@@ -504,6 +504,8 @@ class Simulation:
                            ready_cum_t=ready_cum_t)
         else:
             part_kw = {}
+        if hasattr(logic, "ring_starter"):
+            part_kw["starter"] = logic.ring_starter(logic_state, alive, t_end)
         ctx = Ctx(t_start=t_next, t_end=t_end, keys=node_keys, alive=alive,
                   ready=ready, ready_cumsum=ready_cumsum,
                   n_ready=ready_cumsum[-1], measuring=measuring, glob=glob,

@@ -70,6 +70,17 @@ DEAD, JOINING, READY = 0, 1, 2
 # lookup purposes (owner dispatch tags)
 P_JOIN, P_FINGER, P_APP, P_MERGE = 1, 2, 3, 4
 
+# what the overlay's upkeep did, cumulative (stats "c:" counters, gated
+# like every counter on the measurement phase): timer rounds started,
+# the RPC calls they sent, and the FindNode calls of the lookups by
+# purpose (the finger repair's against the application's)
+MAINTENANCE_COUNTERS = (
+    "chord_stab_rounds", "chord_notify_calls", "chord_notify_taken",
+    "chord_pred_pings", "chord_fix_rounds", "chord_fix_lookups",
+    "chord_fix_ended", "chord_fix_calls", "chord_app_calls",
+    "chord_join_passed", "chord_join_dropped")
+
+JOIN_HOP_MAX = 32  # hops a JoinCall is passed on towards its key
 BCAST_FANOUT = 8   # broadcast copies per hop (≥ distinct fingers at test N)
 
 
@@ -200,7 +211,7 @@ class ChordLogic:
             hists=tuple(app["hists"]),
             counters=tuple(app["counters"]) + (
                 "chord_joins", "lookup_success", "lookup_failed",
-                "route_dropped"),
+                "route_dropped") + MAINTENANCE_COUNTERS,
         )
 
     def split(self, st: ChordState):
@@ -280,6 +291,19 @@ class ChordLogic:
         if self.rcfg is not None:
             t = jnp.minimum(t, jax.vmap(rt_mod.next_event)(st.rr))
         return t
+
+    def ring_starter(self, st: ChordState, alive, t_end):
+        """The one joiner that may start a ring in a tick that finds no
+        node READY (``Ctx.starter``): of the nodes whose join timer is
+        due, the earliest, ties to the lowest slot; NO_NODE where none
+        is due.  Upstream's first node is READY inside its own creation
+        event, so its second node always finds it; two nodes created
+        inside one tick window must not both see an empty overlay and
+        start a ring each (the rings interleave and weak stabilization
+        never merges them)."""
+        due = alive & (st.state == JOINING) & (st.t_join < t_end)
+        first = jnp.argmin(jnp.where(due, st.t_join, T_INF)).astype(I32)
+        return jnp.where(jnp.any(due), first, NO_NODE)
 
     # -- internals (all per-node; vmapped by the engine) ---------------------
 
@@ -585,59 +609,87 @@ class ChordLogic:
             st.lk, dataclasses.replace(msgs, valid=en_res), metric_fn, lcfg))
 
         # JoinCall (rpcJoin, Chord.cc:917) — response compiled BEFORE
-        # the aggressive-join mutations (reference order).
+        # the aggressive-join mutations (reference order).  The call
+        # carries the joiner's slot (``a``) and key: it may have been
+        # passed on, so its sender need not be the joiner.
         #
-        # RESPONSIBILITY GUARD: the reference's JoinCall is ROUTED to
-        # the joiner's key, so the receiver is the responsible node
-        # by construction; our joiner sends directly to its lookup
-        # result, which can be stale during mass joins.  Accepting a
-        # joiner whose key is NOT in (pred, me] would drag pred
-        # backwards, widen this node's claimed range, attract more
-        # mis-routed joins, and cascade into a loopy succ
-        # permutation that weak stabilization provably cannot repair
-        # (observed: N=64 interleaved-ring fixed point).  A
-        # non-responsible receiver stays silent; the joiner's join
-        # timer retries with a fresh lookup.
-        en = v_r & (msgs.kind == wire.CHORD_JOIN_CALL) & (st.state == READY)
+        # RESPONSIBILITY: upstream's joiner sends the call to its
+        # lookup's result, which is stale when joins come faster than a
+        # lookup takes (ten a second against five hops of two ticks).
+        # Accepting a joiner whose key is NOT in (pred, me] would drag
+        # pred backwards, widen this node's claimed range, attract more
+        # mis-routed joins, and cascade into a loopy succ permutation
+        # that weak stabilization provably cannot repair.  A receiver
+        # that is not responsible passes the call on towards the key, as
+        # a routed call travels (findNode's next hop), so it reaches
+        # the node responsible NOW; only a call out of hops is dropped,
+        # and that joiner's join timer retries with a fresh lookup.
+        # So (pred, me] of the READY nodes always tile the key space:
+        # every accepted join splits one range in two.
+        en_jc = v_r & (msgs.kind == wire.CHORD_JOIN_CALL) & (
+            st.state == READY)
+        joiner = msgs.a                                      # [R]
+        jk = msgs.key                                        # [R, KL]
         alone = (st.pred == NO_NODE) & (st.succ[0] == NO_NODE)
-        jk = ctx.keys[jnp.maximum(msgs.src, 0)]              # [R, KL]
         pk_j = ctx.keys[jnp.maximum(st.pred, 0)]
-        responsible = alone | (st.pred == NO_NODE) | K.is_between(
+        responsible = alone | ((st.pred != NO_NODE) & K.is_between(
             jk, jnp.broadcast_to(pk_j, jk.shape),
-            jnp.broadcast_to(me_key, jk.shape), spec)
-        en = en & responsible
-        pred_hint = jnp.where(alone, node_idx, st.pred)
-        ob.send(en, now_r, msgs.src, wire.CHORD_JOIN_RES, a=pred_hint,
-                nodes=pad_nodes(st.succ),
+            jnp.broadcast_to(me_key, jk.shape), spec))
+        # a call of a joiner already taken (its timer fired twice)
+        stale_jc = (joiner == node_idx) | (joiner == st.pred)
+        en = en_jc & responsible & ~stale_jc & ~K.dup_mask(
+            jnp.where(en_jc, joiner, NO_NODE))
+        if type(self)._respond_find is ChordLogic._respond_find:
+            nxt_j = res_b[:, 0]          # findNode of the call's key
+        else:
+            nxt_j = jax.vmap(lambda kk: self._find_node(
+                ctx, st, me_key, node_idx, kk)[0])(jk)
+        astray = en_jc & ~responsible & ~stale_jc
+        pass_on = astray & (msgs.hops < JOIN_HOP_MAX) & (
+            nxt_j != NO_NODE) & (nxt_j != node_idx)
+        ob.send(pass_on, now_r, nxt_j, wire.CHORD_JOIN_CALL, key=jk,
+                a=joiner, hops=msgs.hops + 1,
+                size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
+        joinpass_cnt = jnp.sum(pass_on.astype(I32))
+        joindrop_cnt = jnp.sum((astray & ~pass_on).astype(I32))
+        # the joiners of one window are taken in KEY order, clockwise
+        # from the predecessor (from this node where it is alone), as
+        # if their calls had arrived one after the other in that order:
+        # each is told the one before it as its predecessor (the first:
+        # the old predecessor), the last becomes this node's
+        # predecessor, and every response carries them all beside the
+        # successor list, so each finds its successors among them.
+        # Taken in inbox order, three joiners of an alone node left one
+        # of them nobody's successor: a side branch that the nodes
+        # joining through it prolong, and the ring never closes.
+        base_j = jnp.where(st.pred != NO_NODE, pk_j, me_key)
+        d_j = K.sub(jk, jnp.broadcast_to(base_j, jk.shape), spec)
+        d_j = jnp.where(en[:, None], d_j, UMAX)
+        (perm_j,) = _sort_lanes(d_j, (jnp.arange(r_in, dtype=I32),))
+        ord_j, ord_en, now_o = joiner[perm_j], en[perm_j], now_r[perm_j]
+        n_acc = jnp.sum(en.astype(I32))
+        any_en = n_acc > 0
+        first_j = ord_j[0]
+        last_j = ord_j[jnp.clip(n_acc - 1, 0, r_in - 1)]
+        hint0 = jnp.where(alone, node_idx, st.pred)
+        pred_hint = jnp.concatenate([hint0[None], ord_j[:-1]])
+        ob.send(ord_en, now_o, ord_j, wire.CHORD_JOIN_RES, a=pred_hint,
+                nodes=pad_nodes(jnp.concatenate(
+                    [jnp.where(ord_en, ord_j, NO_NODE), st.succ])),
                 size_b=wire.BASE_CALL_B
-                + wire.NODEHANDLE_B * (p.succ_size + 1))
+                + wire.NODEHANDLE_B * (p.succ_size + n_acc))
         if p.aggressive_join:
-            # the sequential fold adopted each joiner in slot order and
-            # sent each SUCC_HINT to the predecessor adopted SO FAR —
-            # chaining pred -> j1 -> j2.  Reproduce the chain: joiner k's
-            # hint goes to the previous enabled joiner (k=0: the pre-tick
-            # predecessor), so each ex-predecessor learns its new
-            # successor and the ring stays linked through a mass join.
-            idxs = jnp.arange(r_in, dtype=I32)
-            cm = jax.lax.cummax(jnp.where(en, idxs, -1))
-            prev = jnp.concatenate([jnp.full((1,), -1, I32), cm[:-1]])
-            hint_dst = jnp.where(prev >= 0,
-                                 msgs.src[jnp.maximum(prev, 0)], st.pred)
-            ob.send(en & (hint_dst != NO_NODE), now_r, hint_dst,
-                    wire.CHORD_SUCC_HINT, a=msgs.src,
+            # the old predecessor learns its new successor: the first
+            # joiner in key order
+            ob.send(any_en & (st.pred != NO_NODE), now_o[0], st.pred,
+                    wire.CHORD_SUCC_HINT, a=first_j,
                     size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
-            # final adopted predecessor = the LAST enabled joiner
-            any_en = jnp.any(en)
-            last_j = r_in - 1 - jnp.argmax(en[::-1]).astype(I32)
-            pred2 = jnp.where(any_en,
-                              msgs.src[jnp.clip(last_j, 0, r_in - 1)],
-                              st.pred)
+            pred2 = jnp.where(any_en, last_j, st.pred)
         else:
             pred2 = st.pred
-        # empty successor list is seeded by the FIRST enabled joiner
-        first_j = jnp.clip(jnp.argmax(en).astype(I32), 0, r_in - 1)
-        succ2 = jnp.where(jnp.any(en) & (st.succ[0] == NO_NODE),
-                          st.succ.at[0].set(msgs.src[first_j]), st.succ)
+        # an empty successor list is seeded by the joiner next clockwise
+        succ2 = jnp.where(any_en & (st.succ[0] == NO_NODE),
+                          st.succ.at[0].set(first_j), st.succ)
         st = dataclasses.replace(st, pred=pred2, succ=succ2)
 
         # JoinResponse (handleRpcJoinResponse): merge every enabled
@@ -645,9 +697,8 @@ class ChordLogic:
         en = v_r & (msgs.kind == wire.CHORD_JOIN_RES) & (st.state == JOINING)
         cand_jr = jnp.where(
             en[:, None],
-            jnp.concatenate([msgs.nodes[:, :p.succ_size],
-                             msgs.src[:, None]], axis=1),
-            NO_NODE).reshape(-1)                             # [R*(S+1)]
+            jnp.concatenate([msgs.nodes, msgs.src[:, None]], axis=1),
+            NO_NODE).reshape(-1)                         # [R*(RMAX+1)]
         succ3 = self._succ_sorted(ctx, me_key, node_idx, cand_jr)
         got_succ = jnp.any(en) & (succ3[0] != NO_NODE)
         joins_cnt += got_succ.astype(I32)
@@ -691,8 +742,8 @@ class ChordLogic:
                                any_sr)
         succ4 = jnp.where(any_sr, succ4, st.succ)
         # notify the (possibly new) successor
-        ob.send(any_sr & (succ4[0] != NO_NODE), now_sr, succ4[0],
-                wire.CHORD_NOTIFY_CALL,
+        fire_nc = any_sr & (succ4[0] != NO_NODE)
+        ob.send(fire_nc, now_sr, succ4[0], wire.CHORD_NOTIFY_CALL,
                 size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
         st = dataclasses.replace(
             st, succ=succ4,
@@ -908,7 +959,10 @@ class ChordLogic:
         now_j = jnp.maximum(st.t_join, t0)
         boot = ctx.sample_ready(rngs[1], node_idx)
         no_join_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_JOIN))
-        alone_start = en_j & (boot == NO_NODE)
+        # no node READY: ONE due joiner starts the ring (ring_starter);
+        # the others keep their timer, so they stay due (and awake) and
+        # find the starter READY in the next tick
+        alone_start = en_j & (boot == NO_NODE) & (ctx.starter == node_idx)
         st = self._become_ready(ctx, st, alone_start, now_j, rngs[2])
         joins_cnt += alone_start.astype(I32)
         slot, have = lk_mod.free_slot(st.lk)
@@ -917,7 +971,7 @@ class ChordLogic:
         st = dataclasses.replace(st, lk=lk_mod.start(
             st.lk, start_join, slot, P_JOIN, 0, me_key, seed, now_j, lcfg))
         st = dataclasses.replace(st, t_join=jnp.where(
-            en_j & ~alone_start,
+            en_j & (boot != NO_NODE),
             now_j + jnp.int64(int(p.join_delay * NS)), st.t_join))
 
         # GNP/NPS probe timer: measure RTT to a reference point — GNP
@@ -1002,7 +1056,8 @@ class ChordLogic:
 
         # fixfingers (handleFixFingersTimerExpired): mark non-trivial
         # fingers dirty, remove trivial ones
-        en_f = (st.state == READY) & (st.t_fix < t_end) & has_succ
+        due_f = (st.state == READY) & (st.t_fix < t_end)
+        en_f = due_f & has_succ
         s0k = ctx.keys[jnp.maximum(st.succ[0], 0)]
         sdist = K.sub(s0k, me_key, spec)                    # me → succ
         nontrivial = K.gt(self._pow2, jnp.broadcast_to(sdist,
@@ -1011,7 +1066,7 @@ class ChordLogic:
             st,
             finger_dirty=jnp.where(en_f, nontrivial, st.finger_dirty),
             finger=jnp.where(en_f & ~nontrivial, NO_NODE, st.finger),
-            t_fix=jnp.where((st.state == READY) & (st.t_fix < t_end),
+            t_fix=jnp.where(due_f,
                             jnp.maximum(st.t_fix, t0)
                             + jnp.int64(int(p.fixfingers_delay * NS)),
                             st.t_fix))
@@ -1178,7 +1233,7 @@ class ChordLogic:
 
         # join: contact our successor directly (one vector send)
         ob.send(taken & suc_l & (pur_l == P_JOIN), t0, res_l,
-                wire.CHORD_JOIN_CALL,
+                wire.CHORD_JOIN_CALL, key=me_key, a=node_idx,
                 size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
 
         # partition-merge probe completions (handleLookupResponse,
@@ -1269,6 +1324,11 @@ class ChordLogic:
             timeout_fn=nc_mod.adaptive_timeout_fn(st.nc,
                                                   lcfg.rpc_timeout_ns),
             prox_fn=(nc_mod.prox_fn(st.nc) if lcfg.prox_aware else None))
+        # the FindNode calls the pump sent, a lookup slot: the RPC slots
+        # it filled (it fills free ones only)
+        calls_l = jnp.sum(((new_lk.pending_dst != NO_NODE)
+                           & (st.lk.pending_dst == NO_NODE)).astype(I32),
+                          axis=1)
         st = dataclasses.replace(st, lk=new_lk)
 
         # Common API update() (BaseOverlay::callUpdate → BaseApp::update,
@@ -1306,6 +1366,19 @@ class ChordLogic:
             "c:lookup_success": lksucc_cnt,
             "c:lookup_failed": anyfail_cnt,
             "c:route_dropped": routedrop_cnt,
+            "c:chord_stab_rounds": fire_s.astype(I32),
+            "c:chord_notify_calls": fire_nc.astype(I32),
+            "c:chord_notify_taken": take_nr.astype(I32),
+            "c:chord_pred_pings": fire_c.astype(I32),
+            "c:chord_fix_rounds": due_f.astype(I32),
+            "c:chord_fix_lookups": start_fix.astype(I32),
+            "c:chord_fix_ended": jnp.sum(enf.astype(I32)),
+            "c:chord_fix_calls": jnp.sum(
+                jnp.where(new_lk.purpose == P_FINGER, calls_l, 0)),
+            "c:chord_app_calls": jnp.sum(
+                jnp.where(new_lk.purpose == P_APP, calls_l, 0)),
+            "c:chord_join_passed": joinpass_cnt,
+            "c:chord_join_dropped": joindrop_cnt,
             "s:lookup_hops": comp_hops_ev,
         }
         ev.finish(events, self.app.hist_map)

@@ -89,6 +89,7 @@ COST_ORDER = (
     "test_route_modes_broose.py", "test_route_modes_epichord.py",
     "test_zz_sparse_rounds.py", "test_zz_sparse.py",
     "test_zz_sparse_churn.py", "test_zz_send_lanes.py",
+    "test_chord_ring.py",
     "test_vmap_campaign.py", "test_engine.py", "test_faults.py",
     "test_pastry_multihop.py", "test_kademlia_depth.py",
     "test_epichord.py", "test_route_modes_koorde.py", "test_mesh_2d.py",

@@ -37,7 +37,8 @@ from oversim_tpu.engine.sim import (
 
 
 def _sim(overlay, tick_impl="dense", active_cap=0,
-         churn="lifetime", interval=None, slots=4, n=12):
+         churn="lifetime", interval=None, slots=4, n=12,
+         init_interval=0.2):
     app = (KbrTestApp(KbrTestParams(test_interval=interval))
            if interval else None)
     if overlay == "chord":
@@ -47,7 +48,8 @@ def _sim(overlay, tick_impl="dense", active_cap=0,
         from oversim_tpu.overlay.kademlia import KademliaLogic
         logic = KademliaLogic(app=app)
     cp = churn_mod.ChurnParams(model=churn, target_num=n,
-                               init_interval=0.2, lifetime_mean=8.0)
+                               init_interval=init_interval,
+                               lifetime_mean=8.0)
     ep = EngineParams(window=0.1, inbox_slots=slots, pool_factor=4,
                       tick_impl=tick_impl, active_cap=active_cap)
     return Simulation(logic, cp, engine_params=ep)
@@ -146,6 +148,28 @@ def test_sparse_identity_chord_scatter_under_churn():
     assert int(np.sum(finals["dense"].alive)) > 0
     assert int(np.sum(finals["dense"].pool.valid)) > 0   # traffic ran
     assert int(finals["sparse"].counters["awake_nodes"]) > 0
+
+
+def test_sparse_identity_chord_two_creations_in_one_tick():
+    """A fill of one node every 20 ms under a 0.1 s window: several
+    nodes fall due in a tick that finds no READY node, ONE starts the
+    ring (``ChordLogic.ring_starter``) and the others keep their join
+    timer.  A joiner that waits stays due, so the awake-set plane must
+    step it in the next tick as the dense sweep does: every leaf equal,
+    and every node READY in one ring at the end."""
+    from test_chord_ring import ring_faults
+    finals = {}
+    for tick_impl in ("dense", "sparse"):
+        sim = _sim("chord", tick_impl=tick_impl, churn="none",
+                   init_interval=0.02)
+        finals[tick_impl] = jax.device_get(
+            sim.run_chunk(sim.init(seed=3), 64))
+    _assert_tree_equal(finals["dense"], _strip_sparse(finals["sparse"]))
+    st = finals["sparse"]
+    assert int(st.counters["awake_nodes"]) > 0
+    t_born = np.sort(np.asarray(st.churn.t_born))
+    assert (np.diff(t_born) == 0).any(), "no two creations in one tick"
+    assert ring_faults(st) == (12, 0, 0)
 
 
 def test_sparse_identity_kademlia_scatter_under_churn():
