@@ -40,15 +40,15 @@ DEFAULT_DTYPES = frozenset({
 
 # measured at -O0/inbox=8: kademlia 151 / chord 123 scatters per tick
 # before PR 34 (mostly small per-node logic scatters; engine share
-# 8 + 2*inbox).  Since PR 34 the default inbox selection holds its
-# rounds twice — 2*inbox D-update scatter-mins over the due messages'
-# compacted lanes plus ONE D-update write-back of ``delivered``, and
-# behind a lax.cond the 2*inbox P-wide scatter-mins for a tick whose due
-# messages outnumber the lanes — so the engine share is
-# 8 + 4*inbox + 1 and the ticks hold 2*inbox + 1 = 17 more (168 / 140);
-# no sort came with it (the lanes are compacted by a prefix sum and a
-# binary search, and selected by the same rounds).  200 still catches
-# gross regressions while zero-full-pool-sorts stays the sharp pin
+# 8 + 2*inbox).  PR 34's inbox selection held its rounds twice (over
+# the due messages' compacted lanes and, behind a lax.cond, P-wide) and
+# PR 36 took four scatters out of the closing phase; since PR 40 the
+# compacted branch is ONE sort of the D lanes and two scatters (the
+# [N, R] table and ``delivered``), so the engine share is
+# 4 + 2 + 2*inbox (tests/test_engine.py pins it on the compiled tick
+# through the same ``check_budget``) and Kademlia's bucket update holds
+# one scatter where it had three.  200 still catches gross regressions
+# while zero-full-pool-sorts stays the sharp pin
 DEFAULT_MAX_SCATTERS = 200
 
 

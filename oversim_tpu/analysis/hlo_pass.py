@@ -43,8 +43,8 @@ def check_contract(name: str, contract, m: dict) -> list:
     if m["full_pool_sort_count"] > contract.max_full_pool_sorts:
         breach("full-pool-sorts",
                "full-pool sorts appeared in the compiled graph — the "
-               "zero-sort tick regressed (engine/pool.py scatter-min "
-               "selection)",
+               "zero-full-pool-sort tick regressed (engine/pool.py "
+               "inbox selection)",
                m["full_pool_sort_count"], contract.max_full_pool_sorts)
     if contract.max_sorts is not None and \
             m["sort_count"] > contract.max_sorts:

@@ -1,7 +1,8 @@
 """Static lanes that follow the few set entries of a wide mask.
 
-On the chip a gather and a scatter cost by their LANES (7.6 ns a lane of
-a gather, 134 ns an update of a scatter-min round; PERF.md), whether a
+On the chip a gather, a scatter and a sort cost by their LANES (7.6 ns a
+lane of a gather; 53 to 134 ns an update of a scatter into a 64-bit
+operand and a twentieth of that into a 32-bit one; PERF.md), whether a
 lane holds anything or not, and a steady tick's due messages, awake
 nodes and wanted outbox slots are a few of thousands.  So the wide mask
 is compacted into K static lanes, the costly indexing runs over those,

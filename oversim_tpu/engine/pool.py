@@ -6,19 +6,24 @@ in-flight packets live in one structure-of-arrays pool of P slots; each
 simulation tick:
 
   * the due messages (deliver time inside the tick window) are grouped by
-    destination into a fixed-width inbox index table by R rounds of
-    deterministic scatter-min selection: each round one scatter-min on
-    t_deliver over the destination axis picks every destination's
-    earliest remaining due message (a second scatter-min on the pool
-    index breaks t_deliver ties exactly like a stable sort), the
-    winners are masked out, and R rounds fill the [N, R] table.  The
-    rounds run over the tick's due messages, compacted into D = P/32
-    lanes, and over all P slots only in a tick with more due messages
-    than lanes: exact at any load, ZERO full-pool sorts in the tick
-    graph (tests/test_engine.py pins sort and scatter counts on the
-    HLO).  Its oracle, one lexicographic (dst, t_deliver) full-pool
+    destination into a fixed-width inbox index table: each row holds its
+    destination's R earliest due messages by (t_deliver, pool index).
+    A steady tick's due messages are a few of the pool's P slots, so
+    they are compacted into D = P/32 lanes and ranked by ONE stable
+    sort of those D lanes by (dst, t_deliver) and a rank within each
+    destination's run; one 32-bit scatter of D updates writes the
+    [N, R] table.  A tick with more due messages than lanes takes R
+    rounds of deterministic scatter-min selection over all P slots
+    (each round one scatter-min on t_deliver over the destination axis
+    picks every destination's earliest remaining due message, a second
+    on the pool index breaks t_deliver ties exactly like a stable sort,
+    and the winners are masked out): the same table, bit for bit, at
+    any load, with ZERO full-pool sorts in the tick graph
+    (tests/test_engine.py pins sort and scatter counts on the HLO, and
+    that the steady branch holds no 64-bit scatter).  The oracle of
+    both forms, one lexicographic (dst, t_deliver) full-pool
     ``lax.sort``, lives with the tests (tests/oracles.py), which hold
-    the two bit-identical;
+    all three bit-identical;
   * delivered slots are freed, and the tick's outbox is written into free
     slots with a sort-free cumsum allocation (prefix sum over the free
     mask + one scatter).
@@ -188,11 +193,11 @@ def _due_masks(pool: MsgPool, n: int, t_end, alive, hold=None):
 def inbox_lanes(p: int) -> int:
     """D — the static lane count of the compacted inbox selection, from
     P alone (as ``Simulation.acap`` is from N): P/32, at least 32.  On
-    the chip a scatter costs by its UPDATES (134 ns a slot a round at
-    N=1000 and N=4096 alike), a steady tick of the KBR cells has under a
-    hundred due messages among 8,000 to 131,072 slots, and a tick with
-    more than D takes the P-wide rounds, so D moves the cost of a tick
-    and never its result (PERF.md, PR 34)."""
+    the chip a scatter costs by its UPDATES and a sort by its lanes, a
+    steady tick of the KBR cells has under a hundred due messages among
+    8,000 to 131,072 slots, and a tick with more than D takes the
+    P-wide rounds, so D moves the cost of a tick and never its result
+    (PERF.md, PR 34 and PR 40)."""
     return lanes_mod.rule(p)
 
 
@@ -228,11 +233,11 @@ def lanes_swept(pool: MsgPool, n: int, t_end, alive, hold=None,
 
 def _scatter_rounds(tkey, dstc, idx, n: int, r: int, pt: int,
                     axis_name=None):
-    """R rounds of deterministic scatter-min over L candidates (all P
-    pool slots, or the D compacted lanes): ``tkey`` [L] i64 deliver time
-    (T_INF = no candidate), ``dstc`` [L] clipped destination, ``idx``
-    [L] GLOBAL pool index.  Returns the [N, R] table and the [L] mask of
-    candidates placed in it."""
+    """R rounds of deterministic scatter-min over the P pool slots (a
+    shard's tile of them under ``axis_name``): ``tkey`` [P] i64 deliver
+    time (T_INF = no candidate), ``dstc`` [P] clipped destination,
+    ``idx`` [P] GLOBAL pool index.  Returns the [N, R] table and the
+    [P] mask of candidates placed in it."""
     cols, taken = [], jnp.zeros(tkey.shape, bool)
     for _ in range(r):
         min_t = jnp.full((n,), T_INF, I64).at[dstc].min(tkey)
@@ -249,41 +254,80 @@ def _scatter_rounds(tkey, dstc, idx, n: int, r: int, pt: int,
     return jnp.stack(cols, axis=1), taken
 
 
+def _sort_ranks(tkey, dstc, li, n: int, r: int, p: int):
+    """The D compacted lanes ranked by ONE sort: ``tkey`` [D] i64
+    deliver time, ``dstc`` [D] clipped destination, ``li`` [D] pool
+    index, ASCENDING, ``p`` and more in a lane that holds no message.
+    Returns the [N, R] table and the [P] mask of the slots placed in
+    it, the bits :func:`_scatter_rounds` returns over the same lanes.
+
+    The lanes ascend by pool index, so a STABLE sort by (dst,
+    t_deliver) leaves each destination's run in the rounds' own
+    (t_deliver, pool index) order; a lane without a message is keyed
+    past every row.  A lane's rank within its run, capped at R, is the
+    count of its R predecessors that share its destination (a run is
+    contiguous): R shifted comparisons, no ``searchsorted``.  No
+    scatter here is 64-bit: on the chip one costs 53 to 134 ns an
+    update, and the rounds' 2R of D updates each were a tenth of the
+    cells' tick (PERF.md, PR 40)."""
+    d = li.shape[0]
+    dkey = jnp.where(li < p, dstc, n)
+    # t_deliver as its two 32-bit words (signed high, unsigned low):
+    # the same order as the i64's
+    d_s, _, _, li_s = jax.lax.sort(  # analysis: allow(sort-call)
+        (dkey, (tkey >> 32).astype(I32), tkey.astype(U32), li),
+        num_keys=3, is_stable=True)
+    rank = jnp.zeros((d,), I32)
+    for k in range(1, min(r, d - 1) + 1):
+        rank += jnp.concatenate(
+            [jnp.zeros((k,), bool), d_s[k:] == d_s[:-k]]).astype(I32)
+    taken = (d_s < n) & (rank < r)
+    inbox = jnp.full((n, r), NO_NODE, I32).at[
+        jnp.where(taken, d_s, n), jnp.minimum(rank, r - 1)].set(
+        li_s, mode="drop")
+    delivered = jnp.zeros((p,), bool).at[
+        jnp.where(taken, li_s, p)].set(True, mode="drop")
+    return inbox, delivered
+
+
 def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
                         hold=None, *, lanes=None, axis_name=None, base=0,
                         p_total=None):
-    """Group due messages by destination into an index table: R rounds
-    of deterministic scatter-min (no sort), over the tick's DUE messages
-    compacted into D lanes.
+    """Group due messages by destination into an index table: each
+    row its destination's R earliest due messages by (t_deliver, pool
+    index), found over the tick's DUE messages compacted into D lanes
+    by one sort of those lanes, and by R rounds of deterministic
+    scatter-min over all P slots in a tick that overruns them.
 
-    Round k scatter-mins t_deliver over the destination axis to find each
-    row's earliest remaining due message, then scatter-mins the POOL INDEX
-    over the messages matching that minimum — reproducing a stable
-    sort's exact (t_deliver, idx) tie-break — and masks the winners out.
-    Bit-identical to the full-pool sort oracle (tests/oracles.py
+    A scatter costs by its updates and a sort by its lanes, and a tick's
+    due messages are a few of the pool's P slots: so the due slots' pool
+    indices are compacted, ascending, into ``lanes`` static lanes (None:
+    :func:`inbox_lanes`, a rule of P alone), :func:`_sort_ranks` ranks
+    them (one stable D-lane sort by (dst, t_deliver), one 32-bit scatter
+    of D updates into the [N, R] table), and ``delivered`` is written
+    back at full width by one more: O(P) elementwise work, no full-pool
+    sort and no 64-bit scatter.  A tick with more due messages than
+    lanes (a fill, a saturated mix, flooding) takes the P-wide rounds
+    (:func:`_scatter_rounds`) through a ``lax.cond``: round k
+    scatter-mins t_deliver over the destination axis to find each row's
+    earliest remaining due message, then scatter-mins the POOL INDEX
+    over the messages matching that minimum — a stable sort's exact
+    (t_deliver, idx) tie-break — and masks the winners out; 2R [P]→[N]
+    scatters and O(R·P) work, for the same answer: exact at any load,
+    nothing deferred that the P-wide rounds would deliver.  Both forms
+    are bit-identical to the full-pool sort oracle (tests/oracles.py
     ``build_inbox_sort``; pinned by the identity tests in
     tests/test_engine.py).  ``hold`` ([P] bool) excludes messages from
-    delivery entirely — see :func:`_due_masks`.
-
-    A scatter costs by its updates, and a tick's due messages are a few
-    of the pool's P slots: so the due slots' pool indices are compacted,
-    ascending, into ``lanes`` static lanes (None: :func:`inbox_lanes`,
-    a rule of P alone), the rounds run over those, and ``delivered`` is
-    written back at full width — O(P) elementwise work and 2R+1
-    D-update scatters.  A tick with more due messages than lanes (a
-    fill, a saturated mix, flooding) takes the P-wide rounds through a
-    ``lax.cond``, 2R [P]→[N] scatters and O(R·P) work, for the same
-    answer: exact at any load, nothing deferred that the P-wide rounds
-    would deliver.  ``lanes >= P`` is the P-wide rounds alone (what a
-    caller that vmaps the step wants: under vmap a cond runs both
-    branches).
+    delivery entirely — see :func:`_due_masks`.  ``lanes >= P`` is the
+    P-wide rounds alone (what a caller that vmaps the step wants: under
+    vmap a cond runs both branches).
 
     Under explicit node sharding (parallel/shard_tick.py) ``pool`` is
     one shard's contiguous tile: pass the shard_map ``axis_name``, the
     tile's ``base`` pool offset and the global ``p_total``.  Each round's
     two scatter-mins then run on the LOCAL tile, P-wide, and merge across
     shards with ``lax.pmin`` — the local-select + all-reduce:min form
-    this selection was designed for.  The per-round global minimum over
+    the rounds were designed for.  The per-round global minimum over
     (t_deliver, pool index) is the min of the per-shard minima, so the
     sharded table is bit-identical to the solo one; ``delivered`` /
     ``to_dead`` come back tile-local.
@@ -299,13 +343,13 @@ def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
     pt = p if p_total is None else p_total
     due, to_dead = _due_masks(pool, n, t_end, alive, hold)
 
-    idx = base + jnp.arange(p, dtype=I32)  # GLOBAL pool indices
     dstc = jnp.clip(pool.dst, 0, n - 1)
 
     def wide(_):
         # remaining-candidate key; winners flip to T_INF between rounds
         return _scatter_rounds(jnp.where(due, pool.t_deliver, T_INF), dstc,
-                               idx, n, r, pt, axis_name)
+                               base + jnp.arange(p, dtype=I32), n, r, pt,
+                               axis_name)
 
     d = _lanes(p, lanes)
     if axis_name is not None or d >= p:
@@ -317,11 +361,7 @@ def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
         # the last)
         li = lanes_mod.compact(due, d)
         lic = jnp.minimum(li, p - 1)
-        inbox, taken = _scatter_rounds(
-            jnp.where(li < p, pool.t_deliver[lic], T_INF), dstc[lic], li,
-            n, r, pt)
-        return inbox, jnp.zeros((p,), bool).at[
-            jnp.where(taken, li, p)].set(True, mode="drop")
+        return _sort_ranks(pool.t_deliver[lic], dstc[lic], li, n, r, p)
 
     inbox, delivered = jax.lax.cond(lanes_mod.fits(due, d), compacted, wide,
                                     None)

@@ -7,8 +7,10 @@ This replaces the reference's single-threaded OMNeT++ discrete-event kernel
     1. advance simulated time to the earliest pending event (message
        deliveries, per-node timers, churn) and open a window of
        ``window_ns`` nanoseconds;
-    2. group all messages due in the window by destination (R rounds of
-       scatter-min selection — zero full-pool sorts; engine/pool.py) and
+    2. group all messages due in the window by destination (one sort of
+       the due messages' compacted lanes, R rounds of scatter-min over
+       the pool in a tick that overruns them — zero full-pool sorts;
+       engine/pool.py) and
        run the vmapped per-node logic step — each node consumes up to R
        messages plus its due timers and appends to a bounded outbox;
     3. push the outbox through the analytic underlay delay model and write
@@ -417,8 +419,10 @@ class Simulation:
 
     def _phase_inbox_select(self, s: SimState, t_end, alive):
         """Phase 3a: pick each destination's R earliest due messages
-        (scatter-min rounds over the due messages' compacted lanes —
-        zero full-pool sorts; see engine/pool.py).  The awake-set plane
+        (the due messages compacted into D lanes and ranked by one sort
+        of those; P-wide scatter-min rounds in a tick with more due
+        messages than lanes — zero full-pool sorts and, in the steady
+        branch, no 64-bit scatter; see engine/pool.py).  The awake-set plane
         stops here: each of its rounds gathers only its A compacted
         rows' payload.  The tests' sort oracle overrides this one phase
         (tests/oracles.py), so it keeps its name and its triple."""

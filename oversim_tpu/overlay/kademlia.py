@@ -323,9 +323,10 @@ class KademliaLogic:
         first); unverified candidates (alive=False, nodes learned from
         FindNodeResponse payloads, Kademlia.cc:1412) fill free slots only
         and never displace.  Alive candidates get slot priority.  The
-        whole policy is one candidate sort + one column sort + three
-        scatters instead of C unrolled scatter chains (the round-2 tick
-        graph was dominated by exactly those chains).
+        whole policy is one candidate sort + one column sort + ONE
+        32-bit scatter (the placed slot with its flag; ``b_seen`` and
+        ``b_stale`` follow by mask) instead of C unrolled scatter chains
+        (the round-2 tick graph was dominated by exactly those chains).
 
         ``cands`` must be deduplicated by the caller; NO_NODE = disabled.
         """
@@ -381,12 +382,25 @@ class KademliaLogic:
         rows = jnp.where(okc, bi_c, num_b)
         vals = cands[idx_s]
         al_v = a_s == 0
+        # ONE 32-bit scatter places the accepted candidates: the slot
+        # (never NO_NODE here) with the unverified flag as its low bit,
+        # -1 where nothing is placed.  ``b_seen`` and ``b_stale`` take
+        # one of two constants a placed entry, so they are written by
+        # mask, elementwise over [B, K]: on the chip a scatter into the
+        # i64 ``b_seen`` cost 68 ns an update, the dropped ones too, and
+        # was the tick's longest operation (PERF.md, PR 40).  (rows,
+        # col) never repeats among accepted candidates (ranks within a
+        # bucket are distinct, ``order`` permutes the columns).
+        put = jnp.full((num_b, kk), -1, I32).at[rows, col].set(
+            vals * 2 + a_s, mode="drop")
+        placed = put >= 0
         st = dataclasses.replace(
             st,
-            buckets=buckets.at[rows, col].set(vals, mode="drop"),
-            b_seen=b_seen.at[rows, col].set(
-                jnp.where(al_v, now, jnp.int64(0)), mode="drop"),
-            b_stale=b_stale.at[rows, col].set(0, mode="drop"))
+            buckets=jnp.where(placed, put >> 1, buckets),
+            b_seen=jnp.where(placed,
+                             jnp.where((put & 1) == 0, now, jnp.int64(0)),
+                             b_seen),
+            b_stale=jnp.where(placed, 0, b_stale))
 
         # --- replacement cache (enableReplacementCache, Kademlia.cc:
         # routingAdd full-bucket branch): alive candidates that found no
@@ -888,12 +902,17 @@ class KademliaLogic:
         sent_p = lane_slot < pp
         ob.send(sent_p, t0, ping_cands, wire.KAD_PING_CALL,
                 size_b=wire.BASE_CALL_B)
+        # a ping takes a FREE slot and every ping of the tick the same
+        # timeout, so the i64 ``ping_to`` is written by mask, not by a
+        # second (64-bit) scatter: the slots that were free and hold a
+        # node now
+        ping_dst = st.ping_dst.at[lane_slot].set(ping_cands, mode="drop")
         st = dataclasses.replace(
             st,
-            ping_dst=st.ping_dst.at[lane_slot].set(ping_cands,
-                                                   mode="drop"),
-            ping_to=st.ping_to.at[lane_slot].set(
-                t0 + jnp.int64(int(p.rpc_timeout * NS)), mode="drop"))
+            ping_dst=ping_dst,
+            ping_to=jnp.where(
+                free_p & (ping_dst != NO_NODE),
+                t0 + jnp.int64(int(p.rpc_timeout * NS)), st.ping_to))
 
         # app timer
         # graceful-leave: hand app data to the closest sibling and stop

@@ -9,12 +9,13 @@ under `jit` with GSPMD partitioning — XLA inserts the collectives:
 
   * the global key-table gathers (`ctx.keys[slot]`) become all-gathers of
     the [N, KL] key table (small: 20 B/node) over ICI;
-  * the pool's scatter-min inbox selection (engine/pool.py
-    ``build_inbox``) partitions into a LOCAL per-shard select + an
-    all-reduce-min of the [N] per-destination minima — O(N) reduction
-    traffic per round, where a full-pool sort would be an all-to-all
-    merge exchange (XLA's partitioned `lax.sort` moves the whole [P]
-    pool's keys across chips);
+  * the pool's inbox selection (engine/pool.py ``build_inbox``): its
+    P-wide scatter-min rounds partition into a LOCAL per-shard select +
+    an all-reduce-min of the [N] per-destination minima — O(N)
+    reduction traffic per round, where a full-pool sort would be an
+    all-to-all merge exchange (XLA's partitioned `lax.sort` moves the
+    whole [P] pool's keys across chips); the steady tick's one sort is
+    over the D = P/32 compacted lanes, which every device holds whole;
   * per-node vmapped logic stays fully local to each shard (the dominant
     FLOPs — finger scans, key arithmetic — never cross chips);
   * scalar stats/counters are replicated and all-reduced.

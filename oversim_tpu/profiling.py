@@ -8,7 +8,8 @@ diagnosis).  This module times the phases of ``Simulation.step``
 
   horizon       event-horizon scan + rng split
   churn         churn events, alive flips, key/coord migration, resets
-  inbox_select  due-message top-R selection (scatter-min rounds)
+  inbox_select  due-message top-R selection (one D-lane sort; P-wide
+                scatter-min rounds in a tick that overruns the lanes)
   inbox_gather  packed-block gather of the selected messages → Msg view
   node_step     tick context + the vmapped per-node logic sweep
   alloc_stats   underlay send, sort-free pool alloc, stat folding

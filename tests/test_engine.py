@@ -112,16 +112,17 @@ T_END = 1000       # the selection cases' window end (ns)
 
 
 def _case_pool(seed, n, p, n_due, *, t_due=(0, T_END), later=0.3,
-               dst=None):
+               dst=None, t_end=T_END, t_mul=1):
     """A [P] pool with exactly ``n_due`` slots valid and due before
-    T_END at random places, and a share ``later`` of the others valid
-    but due after it; destinations random in [0, n) unless given."""
+    ``t_end`` at random places (deliver times drawn from ``t_due``,
+    times ``t_mul``), and a share ``later`` of the others valid but due
+    after it; destinations random in [0, n) unless given."""
     rng = np.random.default_rng(seed)
     due = np.zeros(p, bool)
     due[rng.choice(p, size=n_due, replace=False)] = True
     valid = due | (rng.random(p) < later)
-    t = np.where(due, rng.integers(*t_due, size=p),
-                 rng.integers(T_END, 2 * T_END, size=p)).astype(np.int64)
+    t = np.where(due, rng.integers(*t_due, size=p) * t_mul,
+                 rng.integers(t_end, 2 * t_end, size=p)).astype(np.int64)
     if dst is None:
         dst = rng.integers(0, n, size=p)
     base = pool_mod.empty(p, key_lanes=5, rmax=4)
@@ -149,6 +150,35 @@ SELECT_CASES = {
     "over_r": (N_A, P_A, 3, 20, None, {"dst": np.full(P_A, 5)}),
     "dead_dst": (N_A, P_A, 3, 40, 64, {"dead": (2, 5, 11)}),
     "hold": (N_A, P_A, 3, 30, None, {"hold_every": 3}),
+    # what tells the one sort of the D lanes from the rounds: ONE
+    # deliver time for every message of one row (the pool index alone
+    # orders them, and only R go) ...
+    "ties_in_one_row": (N_A, P_A, 3, 20, None,
+                        {"t_due": (5, 6), "dst": np.full(P_A, 5)}),
+    # ... three rows that each hold more than R, nearly all ties ...
+    "ties_rows_over_r": (N_A, P_A, 3, 30, None,
+                         {"t_due": (5, 7), "dst": np.arange(P_A) % 3}),
+    # ... exactly D due for one row, D + 1 (the rounds) likewise ...
+    "due_d_one_row": (N_A, P_A, 3, D_A, None, {"dst": np.full(P_A, 0)}),
+    "due_d_plus_1_one_row": (N_A, P_A, 3, D_A + 1, None,
+                             {"dst": np.full(P_A, N_A - 1)}),
+    # ... ties among held and unheld messages, and a tick whose every
+    # message is held (an empty tick that is not an empty pool) ...
+    "hold_ties": (N_A, P_A, 3, 30, None,
+                  {"hold_every": 2, "t_due": (5, 7),
+                   "dst": np.arange(P_A) % 5}),
+    "hold_all": (N_A, P_A, 3, 30, None, {"hold_every": 1}),
+    # ... and deliver times that differ in the high 32-bit word alone,
+    # in the low one alone where it reads negative as an i32, and in
+    # both (the sort compares the words)
+    "times_high_word": (N_A, P_A, 3, 30, None,
+                        {"t_due": (0, 4), "t_mul": 2**32, "t_end": 2**40,
+                         "dst": np.arange(P_A) % 4}),
+    "times_low_word_sign": (N_A, P_A, 3, 30, None,
+                            {"t_due": (2**31 - 3, 2**31 + 3),
+                             "t_end": 2**33, "dst": np.arange(P_A) % 4}),
+    "times_both_words": (N_A, P_A, 3, 30, None,
+                         {"t_due": (0, 2**45), "t_end": 2**45}),
     # dst outside [0, n) is clipped into the end rows, as the P-wide
     # rounds do (the sort oracle wraps or drops such rows: no oracle here)
     "dst_out_of_range": (N_A, P_A, 3, 30, None,
@@ -185,11 +215,11 @@ def _selectors(lanes):
 
 @pytest.mark.parametrize("name", list(SELECT_CASES))
 def test_inbox_select_over_due_lanes_equals_sort(name):
-    """The default selection (the due messages compacted into D lanes,
-    the P-wide rounds when they do not fit) equals the sort oracle on
-    ``inbox``, ``delivered`` and ``to_dead`` at every load around D, as
-    the P-wide rounds alone do, and sweeps D lanes exactly when the due
-    messages fit them."""
+    """The default selection (the due messages compacted into D lanes
+    and ranked by one sort of those, the P-wide rounds when they do not
+    fit) equals the sort oracle AND the P-wide rounds on ``inbox``,
+    ``delivered`` and ``to_dead`` at every load around D, and sweeps D
+    lanes exactly when the due messages fit them."""
     n, p, r, n_due, lanes, extra = SELECT_CASES[name]
     extra = dict(extra)
     dead = extra.pop("dead", ())
@@ -198,7 +228,7 @@ def test_inbox_select_over_due_lanes_equals_sort(name):
     pool = _case_pool(sum(map(ord, name)), n, p, n_due, **extra)
     alive = jnp.ones((n,), bool).at[jnp.asarray(dead, I32)].set(False)
     hold = (jnp.arange(p) % hold_every == 0) if hold_every else None
-    t_end = jnp.int64(T_END)
+    t_end = jnp.int64(extra.get("t_end", T_END))
     sort_j, wide_j, sel_j, swept_j = _selectors(lanes)
     kw = dict(n=n, r=r, t_end=t_end, alive=alive, hold=hold)
     want, got = wide_j(pool, **kw), sel_j(pool, **kw)
@@ -559,28 +589,30 @@ def test_tick_equals_the_sort_oracles_tick():
 # ---------------------------------------------------------------------------
 
 def test_tick_hlo_zero_sorts_bounded_scatters():
-    """The default scatter-min inbox leaves the tick graph with ZERO
-    full-pool sorts, and the scatter count stays within the engine
-    budget (4 baseline scatters, of which the outbox allocation takes
-    2: the free-slot list and the ONE row scatter that carries the
-    packed block with ``t_deliver``, ``stamp`` and ``valid`` as words;
-    a histogram is counted by comparison and takes none — plus
-    2 per inbox round in EACH branch of the selection: over the due
-    messages' D = 32 compacted lanes with one more to write
-    ``delivered`` back, and P-wide for a tick whose due messages
-    outnumber the lanes: 19 today), pinned via scripts/hlo_breakdown.py's
-    counting helpers so the --budget CLI and this test share one
-    definition.  n=24 makes the pool dimension P = 24*8 = 192
-    distinctive in shape strings."""
+    """The inbox selection leaves the tick graph with ZERO full-pool
+    sorts (its one sort is over the due messages' D = 32 compacted
+    lanes), and the scatter count stays within the engine budget: 4
+    baseline scatters (of which the outbox allocation takes 2: the
+    free-slot list and the ONE row scatter that carries the packed
+    block with ``t_deliver``, ``stamp`` and ``valid`` as words; a
+    histogram is counted by comparison and takes none), 2 in the
+    selection's D-lane branch (the [N, R] table and ``delivered``) and
+    2 per inbox round in its P-wide branch, which a tick whose due
+    messages outnumber the lanes takes: 12 today, pinned via
+    scripts/hlo_breakdown.py's counting helpers so the --budget CLI and
+    this test share one definition.  n=24 makes the pool dimension
+    P = 24*8 = 192 distinctive in shape strings."""
     from scripts.hlo_breakdown import check_budget
     sim = make_sim(n=24)
     s = sim.init(seed=1)
     txt = jax.jit(lambda st: sim.step(st)).lower(s).compile().as_text()
     ok, counts = check_budget(
         txt, pool_dim=192, max_full_pool_sorts=0,
-        max_scatters=4 + 4 * sim.ep.inbox_slots + 1)
+        max_scatters=4 + 2 + 2 * sim.ep.inbox_slots)
     assert ok, counts
     assert counts["full_pool_sort_count"] == 0, counts
+    # the selection's one D-lane sort, and the ping logic's own argsort
+    assert counts["sort_count"] == 2, counts
 
 
 def _eqns(jaxpr, into_cond=True):
@@ -614,30 +646,45 @@ def _lanes_of(e):
 
 @pytest.mark.parametrize("tick_impl", ["auto", "dense"])
 def test_tick_holds_no_wide_64_bit_scatter(tick_impl):
-    """On the chip a scatter into a 64-bit operand costs 53 to 112 ns an
-    UPDATE, the dropped and the zero ones too, a 32-bit one a twentieth: three such scatters of 13 N and 16 N updates were half of
-    the cells' tick (PERF.md, PR 36).  So in the traced tick of the
-    cells' own deployment no scatter into a 64-bit operand has N or
-    more updates, but for the R scatter-min rounds of the inbox
-    selection's P-wide branch, which only a tick with more due messages
-    than lanes runs.  Updates are counted a lane of a vmapped scatter:
-    the node step's own grow with its A = N/32 lanes, not with N."""
-    from test_zz_sparse import _cell_sim    # kademlia4096.kbr60 at N=128
-    sim, _ = _cell_sim(tick_impl=tick_impl)
+    """On the chip a scatter into a 64-bit operand costs 53 to 134 ns an
+    UPDATE, the dropped and the zero ones too, a 32-bit one a twentieth:
+    three such scatters of 13 N and 16 N updates were half of the cells'
+    tick (PERF.md, PR 36), the selection's 2R rounds over D lanes and
+    the bucket update's A x C updates into ``b_seen`` a sixth of what
+    was left (PR 40).  So in the traced tick of the cells' own
+    deployment no scatter into a 64-bit operand has D or more updates,
+    but for the R scatter-min rounds of the inbox selection's P-wide
+    branch, which only a tick with more due messages than lanes runs;
+    and none of the node step's (vmapped over its lanes, counted a
+    lane) has C or more, the candidates of one bucket update.  N=256,
+    where A = 32 < D = 64 < C = 80 tells the three apart: the awake-set
+    write-back's row scatters of A rows a leaf stay."""
+    from test_zz_sparse import _cell_sim    # kademlia4096.kbr60 at N=256
+    sim, _ = _cell_sim(tick_impl=tick_impl, n=256)
     n, p, r = sim.n, sim.n * sim.ep.pool_factor, sim.ep.inbox_slots
+    d = sim.inbox_lanes
+    c = sim.logic.p.s + r * (1 + sim.logic.lcfg.frontier)
+    assert (sim.acap, d, c) == (32, 64, 80)
     shapes = jax.eval_shape(lambda: sim.init_from_rng(jax.random.PRNGKey(1)))
-    wide_rounds, seen = 0, 0
+    wide_rounds, seen, widest = 0, 0, {False: 0, True: 0}
     for e in _eqns(jax.make_jaxpr(sim.step)(shapes).jaxpr):
         if not e.primitive.name.startswith("scatter"):
             continue
         operand, updates = e.invars[0].aval, _lanes_of(e)
+        lane = bool(
+            e.params["dimension_numbers"].scatter_indices_batching_dims)
         seen += 1
-        if operand.dtype.itemsize < 8 or updates < n:
+        if operand.dtype.itemsize < 8:
+            continue
+        if updates < (c if lane else d):
+            widest[lane] = max(widest[lane], updates)
             continue
         assert (e.primitive.name, operand.shape, updates) == (
             "scatter-min", (n,), p), (e.primitive.name, operand, updates)
         wide_rounds += 1
     assert wide_rounds == r and seen > 2 * r, (wide_rounds, seen)
+    # the pin is sharp: 64-bit scatters under the thresholds are there
+    assert widest[True] > 0, widest
 
 
 def test_run_chunk_donates_state():

@@ -65,6 +65,17 @@ def test_depth_ping_table_cycles(depth_run):
     assert (dst == -1).any(axis=1).all(), "ping table wedged full"
 
 
+def test_depth_ping_timeouts_sit_where_pings_fly(depth_run):
+    """A ping slot holds a node exactly while it holds a timeout:
+    ``ping_to`` is written by mask beside ``ping_dst``'s scatter (no
+    64-bit scatter, PR 40) and cleared with it, so the two never
+    part."""
+    _, st = depth_run
+    dst = np.asarray(st.logic.ping_dst)
+    to = np.asarray(st.logic.ping_to)
+    assert ((dst >= 0) == (to < int(sim_mod.T_INF))).all()
+
+
 def test_replacement_cache_populates():
     """With tiny buckets (k=1) on a 16-node static net, full buckets must
     push live candidates into the replacement cache."""
