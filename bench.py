@@ -59,17 +59,18 @@ rewritten atomically (tmp + os.replace) after EVERY window, so a SIGKILL
 at any point leaves a valid, parseable JSON artifact of everything
 measured so far ({"records": [...], "final": last, "complete": bool}).
 
-OVERSIM_PROFILE=1 additionally emits a per-phase tick-time breakdown
-(oversim_tpu/profiling.py) as a ``tick_phase_breakdown`` JSON line
-before the measurement windows — see PERFORMANCE.md for the format.
+Where a tick's device time goes, phase by phase: the tick program
+names its phases (oversim_tpu/core/scopes.py), and
+``benchmark/phases.py`` (a cell's own run) or ``benchmark/
+phase_reduce.py <dir>`` (any profiler dump, OVERSIM_XPROF's included)
+reduces a device trace by them.
 
 Telemetry plane (oversim_tpu/telemetry.py): OVERSIM_BENCH_TELEMETRY=K
 samples the KPI ring buffers every K ticks INSIDE the device loop
 (window capacity OVERSIM_BENCH_TELEMETRY_WINDOW, default 256) and emits
 the time series as a ``telemetry_series`` side-channel line after the
 run; OVERSIM_BENCH_TRACE=path writes a Perfetto/Chrome-trace JSON of
-the per-window dispatch/fetch spans (+ profiling phase spans under
-OVERSIM_PROFILE=1).  Every run emits a ``run_manifest`` line (config
+the per-window dispatch/fetch spans.  Every run emits a ``run_manifest`` line (config
 hash, mesh layout, git rev) that the orchestrator attaches to the
 artifact's top-level ``manifest`` key.
 """
@@ -217,8 +218,8 @@ def orchestrate() -> int:
             sys.stderr.write("bench child: %s\n" % line)
             continue
         if parsed.get("metric") not in (None, "kbr_lookups_per_sec"):
-            # diagnostic side-channel lines (e.g. the OVERSIM_PROFILE=1
-            # tick_phase_breakdown, the telemetry_series record) are
+            # diagnostic side-channel lines (e.g. the telemetry_series
+            # record) are
             # relayed verbatim but never enter the measurement-record
             # logic below; the child's run_manifest line attaches as
             # the artifact's top-level manifest instead of a record
@@ -576,8 +577,8 @@ def child_main():
     sim = sim_mod.Simulation(logic, cp, engine_params=ep)
 
     # OVERSIM_BENCH_TRACE=path: Perfetto/Chrome-trace JSON of the
-    # window dispatch/fetch spans (+ profiling phase spans when
-    # OVERSIM_PROFILE=1), rewritten atomically after every window
+    # window dispatch/fetch spans, rewritten atomically after every
+    # window
     trace_path = os.environ.get("OVERSIM_BENCH_TRACE")
     trace = telemetry_mod.PerfettoTrace("bench") if trace_path else None
 
@@ -739,21 +740,6 @@ def child_main():
                      % (warm_until, warm_wall))
     sys.stderr.write("bench: post-warm counters %r alive=%d\n"
                      % (base["_engine"], base["_alive"]))
-
-    from oversim_tpu import profiling
-    if camp is None and profiling.enabled():
-        # OVERSIM_PROFILE=1: per-phase tick-time breakdown as a JSON
-        # side-channel line (the orchestrator relays it; the driver's
-        # record stays the last kbr_lookups_per_sec line).  Profiled
-        # ticks are real simulation progress — keep the state.
-        report, s = profiling.profile_ticks(
-            sim, s, n_ticks=int(os.environ.get("OVERSIM_PROFILE_TICKS", 3)))
-        print(json.dumps(report), flush=True)
-        if trace is not None:
-            trace.add_profile(report)
-        sys.stderr.write("bench: phase ms/tick %r (fused %.3f)\n"
-                         % (report["phase_ms_per_tick"],
-                            report.get("fused_ms_per_tick", -1.0)))
 
     # measure in wall-clock windows (each ONE device dispatch + ONE host
     # sync, run_measurement_windows), emitting an updated JSON line after

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from oversim_tpu.apps import base
 from oversim_tpu.common import wire
+from oversim_tpu.core.scopes import scoped
 
 I32 = jnp.int32
 I64 = jnp.int64
@@ -228,6 +229,7 @@ class KbrTestApp:
     def next_event(self, app):
         return jnp.minimum(app.t_test, app.rpc_to)
 
+    @scoped("app.kbrtest")
     def on_timer(self, app, en, ctx, now, rng, ev, node_idx):
         """Fire the periodic test; round-robin the enabled modes."""
         modes = self.p.modes
@@ -276,6 +278,7 @@ class KbrTestApp:
             want=want, key=dest_key,
             tag=(app.seq * 4 + mode) * 2 + ctx.measuring.astype(I32))
 
+    @scoped("app.kbrtest")
     def on_lookup_done(self, app, done: base.LookupDone, ctx, ob, ev, now,
                        node_idx):
         en = done.en
@@ -335,6 +338,7 @@ class KbrTestApp:
                  en_l & right & meas)
         return app
 
+    @scoped("app.kbrtest")
     def on_lookup_done_batch(self, app, done: base.LookupDone, ctx, ob, ev,
                              now, node_idx):
         """Batched completion hook: ``done`` fields are [L]-shaped (one
@@ -396,6 +400,7 @@ class KbrTestApp:
                  en_l & right & meas)
         return app
 
+    @scoped("app.kbrtest")
     def on_msgs(self, app, msgs, ctx, ob, ev, is_sib, node_idx=None):
         """Batched deliver hook: ``msgs`` is the [R]-batch Msg view and
         ``is_sib[r]`` the receiver's responsibility flag for msgs.key[r].
@@ -459,6 +464,7 @@ class KbrTestApp:
         engine stops firing app timers during the grace window)."""
         return app
 
+    @scoped("app.kbrtest")
     def on_msg(self, app, m, ctx, ob, ev, is_sib):
         """KBRTestApp::deliver — (src, seq) dedup under recursive routing
         (checkSeen ring); wrong-node check mirrors KBRTestApp.cc:252-286."""

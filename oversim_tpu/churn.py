@@ -38,6 +38,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from oversim_tpu.core.scopes import scoped
 
 I64 = jnp.int64
 NS = 1_000_000_000
@@ -279,6 +280,7 @@ def next_event(state: ChurnState):
     return jnp.minimum(t, jnp.min(state.t_dead))
 
 
+@scoped("churn.step")
 def step(state: ChurnState, p: ChurnParams, alive, t_start, t_end, rng,
          life_mean=None):
     """Fire create/pre-kill/kill events inside [t_start, t_end).

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 
 from oversim_tpu.common import wire
 from oversim_tpu.core import keys as keys_mod
+from oversim_tpu.core.scopes import scoped
 
 I32 = jnp.int32
 I64 = jnp.int64
@@ -205,6 +206,7 @@ def num_free(lk: LookupState):
     return jnp.sum((~lk.active).astype(I32))
 
 
+@scoped("lookup.start")
 def start(lk: LookupState, en, slot, purpose, aux, target, seed_nodes,
           now, cfg: LookupConfig, ext=None) -> LookupState:
     """Occupy ``slot`` with a new lookup (no RPC fired yet — ``pump`` does).
@@ -394,6 +396,7 @@ def on_response(lk: LookupState, msg, metric_fn, cfg: LookupConfig):
     return lk
 
 
+@scoped("lookup.responses")
 def on_responses(lk: LookupState, msgs, metric_fn, cfg: LookupConfig):
     """Batched ``on_response``: consume ALL of a node's FINDNODE_RES inbox
     messages ([R]-batch Msg view, ``msgs.valid`` pre-masked to response
@@ -549,6 +552,7 @@ def response_rtts(lk: LookupState, msgs):
     return jnp.where(ok, msgs.src, NO_NODE), rtt_s, ok
 
 
+@scoped("lookup.timeouts")
 def on_timeouts(lk: LookupState, t_end, now, cfg: LookupConfig):
     """Expire pending RPCs / deadlines due strictly before ``t_end``.
 
@@ -637,6 +641,7 @@ def on_pongs(lk: LookupState, msgs, cfg: LookupConfig):
         ver_to=jnp.where(fin, T_INF, lk.ver_to))
 
 
+@scoped("lookup.pump")
 def pump(lk: LookupState, outbox, ctx, node_idx, now, rng,
          cfg: LookupConfig, *, num_siblings: int = 1,
          num_redundant: int = 1, timeout_fn=None, prox_fn=None):
@@ -785,6 +790,7 @@ def pump(lk: LookupState, outbox, ctx, node_idx, now, rng,
     return lk, fired_any
 
 
+@scoped("lookup.completions")
 def take_completions(lk: LookupState, t_end):
     """Harvest slots whose completion is due (done & t_done < t_end).
 

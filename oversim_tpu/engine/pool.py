@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from oversim_tpu.core import lanes as lanes_mod
+from oversim_tpu.core.scopes import scope, scoped
 
 I32 = jnp.int32
 I64 = jnp.int64
@@ -175,6 +176,7 @@ def next_deliver_time(pool: MsgPool):
     return jnp.min(jnp.where(pool.valid, pool.t_deliver, T_INF))
 
 
+@scoped("pool.due_masks")
 def _due_masks(pool: MsgPool, n: int, t_end, alive, hold=None):
     """(due, to_dead) masks of the inbox selection (and of its oracle
     under tests/).
@@ -231,6 +233,7 @@ def lanes_swept(pool: MsgPool, n: int, t_end, alive, hold=None,
     return jnp.where(lanes_mod.fits(due, d), d, p).astype(I32)
 
 
+@scoped("inbox.rounds")
 def _scatter_rounds(tkey, dstc, idx, n: int, r: int, pt: int,
                     axis_name=None):
     """R rounds of deterministic scatter-min over the P pool slots (a
@@ -254,6 +257,7 @@ def _scatter_rounds(tkey, dstc, idx, n: int, r: int, pt: int,
     return jnp.stack(cols, axis=1), taken
 
 
+@scoped("inbox.rank")
 def _sort_ranks(tkey, dstc, li, n: int, r: int, p: int):
     """The D compacted lanes ranked by ONE sort: ``tkey`` [D] i64
     deliver time, ``dstc`` [D] clipped destination, ``li`` [D] pool
@@ -359,15 +363,19 @@ def build_inbox(pool: MsgPool, n: int, r: int, t_end, alive,
     def compacted(_):
         # lane j holds the pool index of the (j+1)-th due slot (p past
         # the last)
-        li = lanes_mod.compact(due, d)
-        lic = jnp.minimum(li, p - 1)
-        return _sort_ranks(pool.t_deliver[lic], dstc[lic], li, n, r, p)
+        with scope("inbox.compact"):
+            li = lanes_mod.compact(due, d)
+            lic = jnp.minimum(li, p - 1)
+            t_l, dst_l = pool.t_deliver[lic], dstc[lic]
+        return _sort_ranks(t_l, dst_l, li, n, r, p)
 
-    inbox, delivered = jax.lax.cond(lanes_mod.fits(due, d), compacted, wide,
-                                    None)
+    with scope("inbox.compact"):
+        fit = lanes_mod.fits(due, d)
+    inbox, delivered = jax.lax.cond(fit, compacted, wide, None)
     return inbox, delivered, to_dead
 
 
+@scoped("pool.free")
 def free(pool: MsgPool, mask) -> MsgPool:
     return dataclasses.replace(
         pool,
@@ -410,6 +418,7 @@ def write_slots(pool: MsgPool, dest, out: dict) -> MsgPool:
         valid=wide[:, w + 4] != 0)
 
 
+@scoped("pool.alloc")
 def alloc(pool: MsgPool, out: dict, want):
     """Write the tick's outbox into free pool slots — SORT-FREE.
 

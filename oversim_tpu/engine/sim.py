@@ -45,6 +45,7 @@ from oversim_tpu import telemetry as telemetry_mod
 from oversim_tpu.common.malicious import MaliciousParams
 from oversim_tpu.core import keys as keys_mod
 from oversim_tpu.core import lanes as lanes_mod
+from oversim_tpu.core.scopes import scope, scoped
 from oversim_tpu.engine import pool as pool_mod
 from oversim_tpu.engine.logic import Ctx, Msg
 from oversim_tpu.underlay import simple as underlay_mod
@@ -350,11 +351,11 @@ class Simulation:
     # -- one tick -----------------------------------------------------------
     #
     # The tick is split into PHASE methods (horizon / churn /
-    # inbox_select / inbox_gather / node_step / alloc_stats) so
-    # oversim_tpu/profiling.py can jit and time each phase separately
-    # under OVERSIM_PROFILE=1.  ``step`` composes them; under one jit the
-    # split is invisible to XLA (same fused graph as the old monolithic
-    # step).
+    # inbox_select / inbox_gather / node_step / alloc_stats).  ``step``
+    # composes them, each under its first-level scope (core/scopes.py:
+    # the names a device trace is reduced by, benchmark/phase_reduce.py);
+    # under one jit the split is invisible to XLA (same fused graph as
+    # the old monolithic step).
 
     def _phase_horizon(self, s: SimState, *, ov=None):
         """Phase 1/5: advance to the event horizon + per-tick rng split."""
@@ -403,10 +404,12 @@ class Simulation:
                 created[:, None],
                 keys_mod.random_keys(r_keys, (n,), self.spec),
                 s.node_keys)
-        ul_state = self.ul.migrate(s.underlay, created, r_mig, up)
+        with scope("underlay.migrate"):
+            ul_state = self.ul.migrate(s.underlay, created, r_mig, up)
         # clear both created and killed slots; created ones schedule a join
-        logic_state = logic.reset(s.logic, created | killed, created, t_next,
-                                  r_reset)
+        with scope("logic.reset"):
+            logic_state = logic.reset(s.logic, created | killed, created,
+                                      t_next, r_reset)
         return churn_state, alive, pre_killed, node_keys, ul_state, logic_state
 
     def _hold_mask(self, s: SimState):
@@ -467,12 +470,16 @@ class Simulation:
         return self._msgs_from_block(s, t_next, inbox, blk)
 
     def _phase_inbox(self, s: SimState, t_next, t_end, alive):
-        """Phase 3: inbox select + gather composed (profiling.py times
-        the two halves separately)."""
-        inbox, delivered, to_dead = self._phase_inbox_select(s, t_end, alive)
-        msgs = self._phase_inbox_gather(s, t_next, inbox)
+        """Phase 3: inbox select + gather composed (two first-level
+        scopes: the awake-set plane runs the first alone)."""
+        with scope("phase.inbox_select"):
+            inbox, delivered, to_dead = self._phase_inbox_select(
+                s, t_end, alive)
+        with scope("phase.inbox_gather"):
+            msgs = self._phase_inbox_gather(s, t_next, inbox)
         return msgs, delivered, to_dead
 
+    @scoped("step.ctx")
     def _make_ctx(self, s: SimState, t_next, t_end, alive, pre_killed,
                   churn_state, node_keys, ul_state, logic_state, *, ov=None):
         """Tick context shared by the dense and awake-set node-step
@@ -614,13 +621,14 @@ class Simulation:
             ul_state, logic_state, ov=ov)
 
         def step_lanes(part, act):
-            act_c = jnp.minimum(act, n - 1)
-            inbox_act = jnp.where((act < n)[:, None], inbox[act_c], -1)
-            gblk = s.pool.blk[jnp.maximum(inbox_act, 0)]       # [A, R, W]
-            msgs = self._msgs_from_block(s, t_next, inbox_act, gblk)
-            part_act = jax.tree_util.tree_map(lambda x: x[act_c], part)
-            node_rngs = self._node_rngs(r_nodes, s.tick,
-                                        act_c.astype(jnp.int_))
+            with scope("step.gather"):
+                act_c = jnp.minimum(act, n - 1)
+                inbox_act = jnp.where((act < n)[:, None], inbox[act_c], -1)
+                gblk = s.pool.blk[jnp.maximum(inbox_act, 0)]   # [A, R, W]
+                msgs = self._msgs_from_block(s, t_next, inbox_act, gblk)
+                part_act = jax.tree_util.tree_map(lambda x: x[act_c], part)
+                node_rngs = self._node_rngs(r_nodes, s.tick,
+                                            act_c.astype(jnp.int_))
             return jax.vmap(self._node_step, in_axes=(None, 0, 0, 0, 0))(
                 ctx, part_act, msgs, node_rngs, act_c)
 
@@ -641,9 +649,11 @@ class Simulation:
             write = lambda base, upd: base.at[act].set(  # noqa: E731
                 upd, mode="drop", indices_are_sorted=True,
                 unique_indices=True)
-            return (r + 1,
-                    jax.tree_util.tree_map(write, part, part_act),
-                    jax.tree_util.tree_map(write, outs, tuple(outs_act)))
+            with scope("step.write_back"):
+                return (r + 1,
+                        jax.tree_util.tree_map(write, part, part_act),
+                        jax.tree_util.tree_map(write, outs,
+                                               tuple(outs_act)))
 
         _, node_part, outs = jax.lax.while_loop(
             lambda carry: carry[0] < rounds, one_round,
@@ -694,21 +704,24 @@ class Simulation:
         fit = None          # whether the tick fit the lanes; None: no lanes
 
         if hasattr(self.ul, "send_rx"):
-            tx, ul_state = self.ul.send_tx(
-                ul_state, up, r_send, src, out_fields["dst"],
-                out_fields["size_b"], out_fields["t_send"], out_valid,
-                kind=out_fields["kind"])
+            with scope("underlay.send_tx"):
+                tx, ul_state = self.ul.send_tx(
+                    ul_state, up, r_send, src, out_fields["dst"],
+                    out_fields["size_b"], out_fields["t_send"], out_valid,
+                    kind=out_fields["kind"])
             tx = {f: v.reshape((q,) + v.shape[2:]) for f, v in tx.items()}
 
             def close(lane):
                 """Receiver's stage and allocation over the slots
                 ``lane`` ([K] i32, Q where a lane holds none), or over
                 all Q (None)."""
-                tx_l, out_l = lanes_mod.take((tx, flat), lane)
-                if lane is not None:
-                    tx_l = dict(tx_l, want=tx_l["want"] & (lane < q))
-                t_del, ok, ul_l, drops = self.ul.send_rx(
-                    ul_state, up, tx_l, alive)
+                with scope("closing.compact"):
+                    tx_l, out_l = lanes_mod.take((tx, flat), lane)
+                    if lane is not None:
+                        tx_l = dict(tx_l, want=tx_l["want"] & (lane < q))
+                with scope("underlay.send_rx"):
+                    t_del, ok, ul_l, drops = self.ul.send_rx(
+                        ul_state, up, tx_l, alive)
                 pool_l, overflow = pool_mod.alloc(
                     new_pool, dict(out_l, t_deliver=t_del), ok)
                 return pool_l, overflow, ul_l, drops
@@ -716,10 +729,15 @@ class Simulation:
             if k >= q:
                 new_pool, pool_overflow, ul_state, drops = close(None)
             else:
-                fit = lanes_mod.fits(want, k)
+                def close_lanes():
+                    with scope("closing.compact"):
+                        lane = lanes_mod.compact(want, k)
+                    return close(lane)
+
+                with scope("closing.compact"):
+                    fit = lanes_mod.fits(want, k)
                 new_pool, pool_overflow, ul_state, drops = jax.lax.cond(
-                    fit, lambda: close(lanes_mod.compact(want, k)),
-                    lambda: close(None))
+                    fit, close_lanes, lambda: close(None))
         else:
             t_del, ok, ul_state, drops = self.ul.send_batch(
                 ul_state, up, r_send, src, out_fields["dst"],
@@ -731,70 +749,73 @@ class Simulation:
 
         # stats
         new_stats = stats_mod.record(s.stats, events, measuring)
-        counters = dict(s.counters)
-        counters["queue_lost"] += drops["queue_lost"]
-        counters["bit_error_lost"] += drops["bit_error_lost"]
-        counters["partition_lost"] += drops["partition_lost"]
-        counters["dest_unavailable_lost"] += (
-            drops["dest_unavailable_lost"] + jnp.sum(to_dead))
-        counters["pool_overflow"] += pool_overflow
-        counters["outbox_overflow"] += jnp.sum(out_overflow)
-        # high-water mark, not a sum: peak count of messages backpressured
-        # behind full inboxes in any one tick (a per-tick sum would count
-        # each waiting message once per tick it waits; a point-in-time
-        # gauge is noise at readout — the peak is stable and still proves
-        # whether the deferral path ever engaged)
-        counters["inbox_deferred"] = jnp.maximum(
-            counters["inbox_deferred"],
-            (jnp.sum(s.pool.valid & (s.pool.t_deliver < t_end)) -
-             jnp.sum(delivered | to_dead)).astype(jnp.int64))
-        if active is not None:
-            # awake-set accounting (SPARSE_COUNTERS): the tallies of
-            # _phase_active_compact — cumulative like the loss counters,
-            # so the telemetry rings carry the series
-            for name, tally in zip(SPARSE_COUNTERS, active):
-                counters[name] += tally
-        elif "lanes_stepped" in counters:
-            # the dense sweep on a state laid out by the awake-set plane
-            # (tick_impl="dense" by name on another Simulation's init):
-            # every alive row was stepped, so a reader of lanes over
-            # rows gets 100% and never 0; the other two tallies stay
-            counters["lanes_stepped"] += jnp.sum(alive).astype(I64)
-        if "inbox_lanes" in counters:
-            # INBOX_COUNTERS: what this tick's selection swept a round
-            # over what the P-wide rounds sweep
-            counters["inbox_lanes"] += pool_mod.lanes_swept(
-                s.pool, self.n, t_end, alive, self._hold_mask(s),
-                self.inbox_lanes).astype(I64)
-            counters["inbox_pool_slots"] += s.pool.capacity
-        if "send_lanes" in counters:
-            # SEND_COUNTERS: the slots this tick's closing phase ran its
-            # receiver's stage and its allocation over, over all Q
-            counters["send_lanes"] += (
-                q if fit is None else jnp.where(fit, k, q).astype(I64))
-            counters["send_outbox_slots"] += q
-        if "reset_rows" in counters:
-            # CHURN_COUNTERS, from what the churn phase left: a slot is
-            # created or finally killed where ``alive`` flipped (the two
-            # exclude each other within a tick, churn.step), pre-killed
-            # where its grace window opened (one reduction for the three)
-            touched = jnp.sum(jnp.stack([
-                alive & ~s.alive, pre_killed & ~(s.churn.t_dead < T_INF),
-                s.alive & ~alive]).astype(I32), axis=1).astype(I64)
-            counters["churn_created"] += touched[0]
-            counters["churn_prekilled"] += touched[1]
-            counters["churn_killed"] += touched[2]
-            counters["churn_ticks"] += (jnp.sum(touched) > 0).astype(I64)
-            counters["reset_rows"] += self.n
+        with scope("closing.counters"):
+            counters = dict(s.counters)
+            counters["queue_lost"] += drops["queue_lost"]
+            counters["bit_error_lost"] += drops["bit_error_lost"]
+            counters["partition_lost"] += drops["partition_lost"]
+            counters["dest_unavailable_lost"] += (
+                drops["dest_unavailable_lost"] + jnp.sum(to_dead))
+            counters["pool_overflow"] += pool_overflow
+            counters["outbox_overflow"] += jnp.sum(out_overflow)
+            # high-water mark, not a sum: peak count of messages backpressured
+            # behind full inboxes in any one tick (a per-tick sum would count
+            # each waiting message once per tick it waits; a point-in-time
+            # gauge is noise at readout — the peak is stable and still proves
+            # whether the deferral path ever engaged)
+            counters["inbox_deferred"] = jnp.maximum(
+                counters["inbox_deferred"],
+                (jnp.sum(s.pool.valid & (s.pool.t_deliver < t_end)) -
+                 jnp.sum(delivered | to_dead)).astype(jnp.int64))
+            if active is not None:
+                # awake-set accounting (SPARSE_COUNTERS): the tallies of
+                # _phase_active_compact — cumulative like the loss counters,
+                # so the telemetry rings carry the series
+                for name, tally in zip(SPARSE_COUNTERS, active):
+                    counters[name] += tally
+            elif "lanes_stepped" in counters:
+                # the dense sweep on a state laid out by the awake-set plane
+                # (tick_impl="dense" by name on another Simulation's init):
+                # every alive row was stepped, so a reader of lanes over
+                # rows gets 100% and never 0; the other two tallies stay
+                counters["lanes_stepped"] += jnp.sum(alive).astype(I64)
+            if "inbox_lanes" in counters:
+                # INBOX_COUNTERS: what this tick's selection swept a round
+                # over what the P-wide rounds sweep
+                counters["inbox_lanes"] += pool_mod.lanes_swept(
+                    s.pool, self.n, t_end, alive, self._hold_mask(s),
+                    self.inbox_lanes).astype(I64)
+                counters["inbox_pool_slots"] += s.pool.capacity
+            if "send_lanes" in counters:
+                # SEND_COUNTERS: the slots this tick's closing phase ran its
+                # receiver's stage and its allocation over, over all Q
+                counters["send_lanes"] += (
+                    q if fit is None else jnp.where(fit, k, q).astype(I64))
+                counters["send_outbox_slots"] += q
+            if "reset_rows" in counters:
+                # CHURN_COUNTERS, from what the churn phase left: a slot is
+                # created or finally killed where ``alive`` flipped (the two
+                # exclude each other within a tick, churn.step), pre-killed
+                # where its grace window opened (one reduction for the three)
+                touched = jnp.sum(jnp.stack([
+                    alive & ~s.alive, pre_killed & ~(s.churn.t_dead < T_INF),
+                    s.alive & ~alive]).astype(I32), axis=1).astype(I64)
+                counters["churn_created"] += touched[0]
+                counters["churn_prekilled"] += touched[1]
+                counters["churn_killed"] += touched[2]
+                counters["churn_ticks"] += (jnp.sum(touched) > 0).astype(I64)
+                counters["reset_rows"] += self.n
 
         # telemetry sample point (telemetry.py): END-of-tick snapshot of
         # the accumulators into the ring buffers, gated on the sampling
         # cadence via an out-of-bounds-dropped scatter index — no rng,
         # no sorts, and every non-telemetry leaf above is untouched
         # (the tests/test_zz_telemetry_identity.py bit-identity pin)
-        tel = telemetry_mod.fold(
-            s.telemetry, self.ep.telemetry, t_end=t_end, tick=s.tick + 1,
-            alive=alive, stats=new_stats, counters=counters)
+        with scope("telemetry.fold"):
+            tel = telemetry_mod.fold(
+                s.telemetry, self.ep.telemetry, t_end=t_end,
+                tick=s.tick + 1, alive=alive, stats=new_stats,
+                counters=counters)
 
         # advance to the window END: anything generated during this tick
         # with a due time inside the window is delivered next tick with
@@ -821,20 +842,24 @@ class Simulation:
         pre-campaign engine."""
         if self.tick_impl == "sparse":
             return self._step_sparse(s, ov=ov)
-        t_next, t_end, rngs = self._phase_horizon(s, ov=ov)
-        (rng, r_churn, r_keys, r_reset, r_nodes, r_mig, r_send) = rngs
-        (churn_state, alive, pre_killed, node_keys, ul_state,
-         logic_state) = self._phase_churn(s, t_next, t_end, r_churn, r_keys,
-                                          r_reset, r_mig, ov=ov)
+        with scope("phase.horizon"):
+            t_next, t_end, rngs = self._phase_horizon(s, ov=ov)
+            (rng, r_churn, r_keys, r_reset, r_nodes, r_mig, r_send) = rngs
+        with scope("phase.churn"):
+            (churn_state, alive, pre_killed, node_keys, ul_state,
+             logic_state) = self._phase_churn(
+                s, t_next, t_end, r_churn, r_keys, r_reset, r_mig, ov=ov)
         msgs, delivered, to_dead = self._phase_inbox(s, t_next, t_end, alive)
-        (logic_state, out_fields, out_valid, out_overflow, events,
-         measuring) = self._phase_node_step(
-            s, t_next, t_end, alive, pre_killed, churn_state, node_keys,
-            ul_state, logic_state, msgs, r_nodes, ov=ov)
-        return self._phase_alloc_stats(
-            s, t_end, rng, r_send, alive, pre_killed, node_keys, ul_state,
-            churn_state, logic_state, delivered, to_dead, out_fields,
-            out_valid, out_overflow, events, measuring)
+        with scope("phase.node_step"):
+            (logic_state, out_fields, out_valid, out_overflow, events,
+             measuring) = self._phase_node_step(
+                s, t_next, t_end, alive, pre_killed, churn_state, node_keys,
+                ul_state, logic_state, msgs, r_nodes, ov=ov)
+        with scope("phase.closing"):
+            return self._phase_alloc_stats(
+                s, t_end, rng, r_send, alive, pre_killed, node_keys,
+                ul_state, churn_state, logic_state, delivered, to_dead,
+                out_fields, out_valid, out_overflow, events, measuring)
 
     def _step_sparse(self, s: SimState, *, ov=None) -> SimState:
         """One tick of the awake-set plane: horizon/churn/alloc phases
@@ -843,30 +868,38 @@ class Simulation:
         rounds of A compacted lanes.  Bit-identical to the dense
         ``step`` at any load and any ``active_cap`` (but for the
         PLANE_COUNTERS it carries): nothing is ever deferred."""
-        t_next, t_end, rngs = self._phase_horizon(s, ov=ov)
-        (rng, r_churn, r_keys, r_reset, r_nodes, r_mig, r_send) = rngs
-        (churn_state, alive, pre_killed, node_keys, ul_state,
-         logic_state) = self._phase_churn(s, t_next, t_end, r_churn, r_keys,
-                                          r_reset, r_mig, ov=ov)
-        inbox, delivered, to_dead = self._phase_inbox_select(
-            s, t_end, alive)
-        order, rounds, active = self._phase_active_compact(
-            s, t_end, alive, pre_killed, logic_state, inbox)
-        (logic_state, out_fields, out_valid, out_overflow, events,
-         measuring) = self._phase_sparse_step(
-            s, t_next, t_end, alive, pre_killed, churn_state, node_keys,
-            ul_state, logic_state, inbox, order, rounds, r_nodes, ov=ov)
-        return self._phase_alloc_stats(
-            s, t_end, rng, r_send, alive, pre_killed, node_keys, ul_state,
-            churn_state, logic_state, delivered, to_dead, out_fields,
-            out_valid, out_overflow, events, measuring, active=active)
+        with scope("phase.horizon"):
+            t_next, t_end, rngs = self._phase_horizon(s, ov=ov)
+            (rng, r_churn, r_keys, r_reset, r_nodes, r_mig, r_send) = rngs
+        with scope("phase.churn"):
+            (churn_state, alive, pre_killed, node_keys, ul_state,
+             logic_state) = self._phase_churn(
+                s, t_next, t_end, r_churn, r_keys, r_reset, r_mig, ov=ov)
+        with scope("phase.inbox_select"):
+            inbox, delivered, to_dead = self._phase_inbox_select(
+                s, t_end, alive)
+        with scope("phase.active_compact"):
+            order, rounds, active = self._phase_active_compact(
+                s, t_end, alive, pre_killed, logic_state, inbox)
+        with scope("phase.node_step"):
+            (logic_state, out_fields, out_valid, out_overflow, events,
+             measuring) = self._phase_sparse_step(
+                s, t_next, t_end, alive, pre_killed, churn_state, node_keys,
+                ul_state, logic_state, inbox, order, rounds, r_nodes, ov=ov)
+        with scope("phase.closing"):
+            return self._phase_alloc_stats(
+                s, t_end, rng, r_send, alive, pre_killed, node_keys,
+                ul_state, churn_state, logic_state, delivered, to_dead,
+                out_fields, out_valid, out_overflow, events, measuring,
+                active=active)
 
     def _node_step(self, ctx, state_n, msgs_n, rng_n, node_idx):
         """Single-node step (vmapped): logic consumes inbox + timers."""
         state_n, outbox, events = self.logic.step(
             ctx, state_n, msgs_n, rng_n, node_idx,
             outbox_slots=self.ep.outbox_slots, rmax=self.ep.rmax)
-        fields, valid, overflow = outbox.finish()
+        with scope("outbox.finish"):
+            fields, valid, overflow = outbox.finish()
         return state_n, fields, valid, overflow, events
 
     # -- run ----------------------------------------------------------------

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import os
 import platform
+import re
 import sys
 
 _CPUINFO = "/proc/cpuinfo"
@@ -81,6 +82,19 @@ def enable(*, persistent: bool = True, min_compile_secs: float = 1.0,
     path) or disables persistence entirely (``persistent=False``; the
     CPU-tier opt-out — returns None).  Call AFTER platform env vars are
     final; safe whether or not jax is already imported.
+
+    The cache keys by the program's METADATA too
+    (``jax_compilation_cache_include_metadata_in_key``): by default the
+    key leaves ``op_name`` and source locations out, so an executable
+    out of the cache carries the scopes (core/scopes.py) and the line
+    numbers of the tree that FIRST compiled it, and a trace of a tree
+    that renamed or added a scope is reduced by another tree's names
+    (PERF.md, PR 35 and PR 41).  The price is one compile for each tree
+    that moves a line of the tick's code, and for each entry script (the
+    call stack is metadata too).  Source paths go into the key relative
+    to the checkout (``jax_hlo_source_file_canonicalization_regex``
+    strips its root), so a checkout that lives elsewhere finds the same
+    entries.
     """
     sys.modules["zstandard"] = None
     import jax
@@ -97,4 +111,7 @@ def enable(*, persistent: bool = True, min_compile_secs: float = 1.0,
     jax.config.update("jax_compilation_cache_dir", d)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_secs)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(_REPO_ROOT + os.sep))
     return d

@@ -54,6 +54,7 @@ from oversim_tpu.common import neighborcache as nc_mod
 from oversim_tpu.common import route as rt_mod
 from oversim_tpu.common import wire
 from oversim_tpu.core import keys as K
+from oversim_tpu.core.scopes import scope, scoped
 from oversim_tpu.engine.logic import Outbox, select_tree
 
 I32 = jnp.int32
@@ -307,6 +308,7 @@ class ChordLogic:
 
     # -- internals (all per-node; vmapped by the engine) ---------------------
 
+    @scoped("chord.find_node")
     def _find_node(self, ctx, st, me_key, node_idx, key):
         """Chord::findNode (Chord.cc:548) with numRedundantNodes=1.
 
@@ -383,6 +385,7 @@ class ChordLogic:
         return self._succ_sorted(ctx, me_key, node_idx,
                                  jnp.concatenate([succ, node[None]]))
 
+    @scoped("chord.failed")
     def _handle_failed(self, ctx, st, me_key, node_idx, failed, now):
         """Chord::handleFailedNode (Chord.cc:502) for a [F] vector of
         failed slots (NO_NODE entries ignored) — one sort for the whole
@@ -608,269 +611,272 @@ class ChordLogic:
         st = dataclasses.replace(st, lk=lk_mod.on_responses(
             st.lk, dataclasses.replace(msgs, valid=en_res), metric_fn, lcfg))
 
-        # JoinCall (rpcJoin, Chord.cc:917) — response compiled BEFORE
-        # the aggressive-join mutations (reference order).  The call
-        # carries the joiner's slot (``a``) and key: it may have been
-        # passed on, so its sender need not be the joiner.
-        #
-        # RESPONSIBILITY: upstream's joiner sends the call to its
-        # lookup's result, which is stale when joins come faster than a
-        # lookup takes (ten a second against five hops of two ticks).
-        # Accepting a joiner whose key is NOT in (pred, me] would drag
-        # pred backwards, widen this node's claimed range, attract more
-        # mis-routed joins, and cascade into a loopy succ permutation
-        # that weak stabilization provably cannot repair.  A receiver
-        # that is not responsible passes the call on towards the key, as
-        # a routed call travels (findNode's next hop), so it reaches
-        # the node responsible NOW; only a call out of hops is dropped,
-        # and that joiner's join timer retries with a fresh lookup.
-        # So (pred, me] of the READY nodes always tile the key space:
-        # every accepted join splits one range in two.
-        en_jc = v_r & (msgs.kind == wire.CHORD_JOIN_CALL) & (
-            st.state == READY)
-        joiner = msgs.a                                      # [R]
-        jk = msgs.key                                        # [R, KL]
-        alone = (st.pred == NO_NODE) & (st.succ[0] == NO_NODE)
-        pk_j = ctx.keys[jnp.maximum(st.pred, 0)]
-        responsible = alone | ((st.pred != NO_NODE) & K.is_between(
-            jk, jnp.broadcast_to(pk_j, jk.shape),
-            jnp.broadcast_to(me_key, jk.shape), spec))
-        # a call of a joiner already taken (its timer fired twice)
-        stale_jc = (joiner == node_idx) | (joiner == st.pred)
-        en = en_jc & responsible & ~stale_jc & ~K.dup_mask(
-            jnp.where(en_jc, joiner, NO_NODE))
-        if type(self)._respond_find is ChordLogic._respond_find:
-            nxt_j = res_b[:, 0]          # findNode of the call's key
-        else:
-            nxt_j = jax.vmap(lambda kk: self._find_node(
-                ctx, st, me_key, node_idx, kk)[0])(jk)
-        astray = en_jc & ~responsible & ~stale_jc
-        pass_on = astray & (msgs.hops < JOIN_HOP_MAX) & (
-            nxt_j != NO_NODE) & (nxt_j != node_idx)
-        ob.send(pass_on, now_r, nxt_j, wire.CHORD_JOIN_CALL, key=jk,
-                a=joiner, hops=msgs.hops + 1,
-                size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
-        joinpass_cnt = jnp.sum(pass_on.astype(I32))
-        joindrop_cnt = jnp.sum((astray & ~pass_on).astype(I32))
-        # the joiners of one window are taken in KEY order, clockwise
-        # from the predecessor (from this node where it is alone), as
-        # if their calls had arrived one after the other in that order:
-        # each is told the one before it as its predecessor (the first:
-        # the old predecessor), the last becomes this node's
-        # predecessor, and every response carries them all beside the
-        # successor list, so each finds its successors among them.
-        # Taken in inbox order, three joiners of an alone node left one
-        # of them nobody's successor: a side branch that the nodes
-        # joining through it prolong, and the ring never closes.
-        base_j = jnp.where(st.pred != NO_NODE, pk_j, me_key)
-        d_j = K.sub(jk, jnp.broadcast_to(base_j, jk.shape), spec)
-        d_j = jnp.where(en[:, None], d_j, UMAX)
-        (perm_j,) = _sort_lanes(d_j, (jnp.arange(r_in, dtype=I32),))
-        ord_j, ord_en, now_o = joiner[perm_j], en[perm_j], now_r[perm_j]
-        n_acc = jnp.sum(en.astype(I32))
-        any_en = n_acc > 0
-        first_j = ord_j[0]
-        last_j = ord_j[jnp.clip(n_acc - 1, 0, r_in - 1)]
-        hint0 = jnp.where(alone, node_idx, st.pred)
-        pred_hint = jnp.concatenate([hint0[None], ord_j[:-1]])
-        ob.send(ord_en, now_o, ord_j, wire.CHORD_JOIN_RES, a=pred_hint,
-                nodes=pad_nodes(jnp.concatenate(
-                    [jnp.where(ord_en, ord_j, NO_NODE), st.succ])),
-                size_b=wire.BASE_CALL_B
-                + wire.NODEHANDLE_B * (p.succ_size + n_acc))
-        if p.aggressive_join:
-            # the old predecessor learns its new successor: the first
-            # joiner in key order
-            ob.send(any_en & (st.pred != NO_NODE), now_o[0], st.pred,
-                    wire.CHORD_SUCC_HINT, a=first_j,
+        with scope("chord.join"):
+            # JoinCall (rpcJoin, Chord.cc:917) — response compiled BEFORE
+            # the aggressive-join mutations (reference order).  The call
+            # carries the joiner's slot (``a``) and key: it may have been
+            # passed on, so its sender need not be the joiner.
+            #
+            # RESPONSIBILITY: upstream's joiner sends the call to its
+            # lookup's result, which is stale when joins come faster than a
+            # lookup takes (ten a second against five hops of two ticks).
+            # Accepting a joiner whose key is NOT in (pred, me] would drag
+            # pred backwards, widen this node's claimed range, attract more
+            # mis-routed joins, and cascade into a loopy succ permutation
+            # that weak stabilization provably cannot repair.  A receiver
+            # that is not responsible passes the call on towards the key, as
+            # a routed call travels (findNode's next hop), so it reaches
+            # the node responsible NOW; only a call out of hops is dropped,
+            # and that joiner's join timer retries with a fresh lookup.
+            # So (pred, me] of the READY nodes always tile the key space:
+            # every accepted join splits one range in two.
+            en_jc = v_r & (msgs.kind == wire.CHORD_JOIN_CALL) & (
+                st.state == READY)
+            joiner = msgs.a                                      # [R]
+            jk = msgs.key                                        # [R, KL]
+            alone = (st.pred == NO_NODE) & (st.succ[0] == NO_NODE)
+            pk_j = ctx.keys[jnp.maximum(st.pred, 0)]
+            responsible = alone | ((st.pred != NO_NODE) & K.is_between(
+                jk, jnp.broadcast_to(pk_j, jk.shape),
+                jnp.broadcast_to(me_key, jk.shape), spec))
+            # a call of a joiner already taken (its timer fired twice)
+            stale_jc = (joiner == node_idx) | (joiner == st.pred)
+            en = en_jc & responsible & ~stale_jc & ~K.dup_mask(
+                jnp.where(en_jc, joiner, NO_NODE))
+            if type(self)._respond_find is ChordLogic._respond_find:
+                nxt_j = res_b[:, 0]          # findNode of the call's key
+            else:
+                nxt_j = jax.vmap(lambda kk: self._find_node(
+                    ctx, st, me_key, node_idx, kk)[0])(jk)
+            astray = en_jc & ~responsible & ~stale_jc
+            pass_on = astray & (msgs.hops < JOIN_HOP_MAX) & (
+                nxt_j != NO_NODE) & (nxt_j != node_idx)
+            ob.send(pass_on, now_r, nxt_j, wire.CHORD_JOIN_CALL, key=jk,
+                    a=joiner, hops=msgs.hops + 1,
                     size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
-            pred2 = jnp.where(any_en, last_j, st.pred)
-        else:
-            pred2 = st.pred
-        # an empty successor list is seeded by the joiner next clockwise
-        succ2 = jnp.where(any_en & (st.succ[0] == NO_NODE),
-                          st.succ.at[0].set(first_j), st.succ)
-        st = dataclasses.replace(st, pred=pred2, succ=succ2)
+            joinpass_cnt = jnp.sum(pass_on.astype(I32))
+            joindrop_cnt = jnp.sum((astray & ~pass_on).astype(I32))
+            # the joiners of one window are taken in KEY order, clockwise
+            # from the predecessor (from this node where it is alone), as
+            # if their calls had arrived one after the other in that order:
+            # each is told the one before it as its predecessor (the first:
+            # the old predecessor), the last becomes this node's
+            # predecessor, and every response carries them all beside the
+            # successor list, so each finds its successors among them.
+            # Taken in inbox order, three joiners of an alone node left one
+            # of them nobody's successor: a side branch that the nodes
+            # joining through it prolong, and the ring never closes.
+            base_j = jnp.where(st.pred != NO_NODE, pk_j, me_key)
+            d_j = K.sub(jk, jnp.broadcast_to(base_j, jk.shape), spec)
+            d_j = jnp.where(en[:, None], d_j, UMAX)
+            (perm_j,) = _sort_lanes(d_j, (jnp.arange(r_in, dtype=I32),))
+            ord_j, ord_en, now_o = joiner[perm_j], en[perm_j], now_r[perm_j]
+            n_acc = jnp.sum(en.astype(I32))
+            any_en = n_acc > 0
+            first_j = ord_j[0]
+            last_j = ord_j[jnp.clip(n_acc - 1, 0, r_in - 1)]
+            hint0 = jnp.where(alone, node_idx, st.pred)
+            pred_hint = jnp.concatenate([hint0[None], ord_j[:-1]])
+            ob.send(ord_en, now_o, ord_j, wire.CHORD_JOIN_RES, a=pred_hint,
+                    nodes=pad_nodes(jnp.concatenate(
+                        [jnp.where(ord_en, ord_j, NO_NODE), st.succ])),
+                    size_b=wire.BASE_CALL_B
+                    + wire.NODEHANDLE_B * (p.succ_size + n_acc))
+            if p.aggressive_join:
+                # the old predecessor learns its new successor: the first
+                # joiner in key order
+                ob.send(any_en & (st.pred != NO_NODE), now_o[0], st.pred,
+                        wire.CHORD_SUCC_HINT, a=first_j,
+                        size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
+                pred2 = jnp.where(any_en, last_j, st.pred)
+            else:
+                pred2 = st.pred
+            # an empty successor list is seeded by the joiner next clockwise
+            succ2 = jnp.where(any_en & (st.succ[0] == NO_NODE),
+                              st.succ.at[0].set(first_j), st.succ)
+            st = dataclasses.replace(st, pred=pred2, succ=succ2)
 
-        # JoinResponse (handleRpcJoinResponse): merge every enabled
-        # response's successor candidates in one sorted pass
-        en = v_r & (msgs.kind == wire.CHORD_JOIN_RES) & (st.state == JOINING)
-        cand_jr = jnp.where(
-            en[:, None],
-            jnp.concatenate([msgs.nodes, msgs.src[:, None]], axis=1),
-            NO_NODE).reshape(-1)                         # [R*(RMAX+1)]
-        succ3 = self._succ_sorted(ctx, me_key, node_idx, cand_jr)
-        got_succ = jnp.any(en) & (succ3[0] != NO_NODE)
-        joins_cnt += got_succ.astype(I32)
-        hint_ok = en & (msgs.a != NO_NODE)
-        last_h = jnp.clip(r_in - 1 - jnp.argmax(hint_ok[::-1]).astype(I32),
-                          0, r_in - 1)
-        st = dataclasses.replace(
-            st,
-            succ=jnp.where(got_succ, succ3, st.succ),
-            pred=jnp.where(got_succ & jnp.any(hint_ok)
-                           & jnp.bool_(p.aggressive_join),
-                           msgs.a[last_h], st.pred))
-        st = self._become_ready(ctx, st, got_succ,
-                                jnp.max(jnp.where(en, now_r, 0)), rngs[0])
+            # JoinResponse (handleRpcJoinResponse): merge every enabled
+            # response's successor candidates in one sorted pass
+            en = v_r & (msgs.kind == wire.CHORD_JOIN_RES) & (st.state == JOINING)
+            cand_jr = jnp.where(
+                en[:, None],
+                jnp.concatenate([msgs.nodes, msgs.src[:, None]], axis=1),
+                NO_NODE).reshape(-1)                         # [R*(RMAX+1)]
+            succ3 = self._succ_sorted(ctx, me_key, node_idx, cand_jr)
+            got_succ = jnp.any(en) & (succ3[0] != NO_NODE)
+            joins_cnt += got_succ.astype(I32)
+            hint_ok = en & (msgs.a != NO_NODE)
+            last_h = jnp.clip(r_in - 1 - jnp.argmax(hint_ok[::-1]).astype(I32),
+                              0, r_in - 1)
+            st = dataclasses.replace(
+                st,
+                succ=jnp.where(got_succ, succ3, st.succ),
+                pred=jnp.where(got_succ & jnp.any(hint_ok)
+                               & jnp.bool_(p.aggressive_join),
+                               msgs.a[last_h], st.pred))
+            st = self._become_ready(ctx, st, got_succ,
+                                    jnp.max(jnp.where(en, now_r, 0)), rngs[0])
 
-        # StabilizeCall -> reply with predecessor (rpcStabilize)
-        en = v_r & (msgs.kind == wire.CHORD_STABILIZE_CALL) & (
-            st.state == READY)
-        ob.send(en, now_r, msgs.src, wire.CHORD_STABILIZE_RES, a=st.pred,
-                size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
+        with scope("chord.stabilize"):
+            # StabilizeCall -> reply with predecessor (rpcStabilize)
+            en = v_r & (msgs.kind == wire.CHORD_STABILIZE_CALL) & (
+                st.state == READY)
+            ob.send(en, now_r, msgs.src, wire.CHORD_STABILIZE_RES, a=st.pred,
+                    size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
 
-        # StabilizeResponse (handleRpcStabilizeResponse): at most one
-        # inbox slot matches the single in-flight stabilize RPC
-        en_sr = v_r & (msgs.kind == wire.CHORD_STABILIZE_RES) & (
-            st.state == READY) & (st.stab_op == 1) & (
-            msgs.src == st.stab_dst)
-        any_sr = jnp.any(en_sr)
-        r_sr = jnp.clip(jnp.argmax(en_sr).astype(I32), 0, r_in - 1)
-        src_sr = msgs.src[r_sr]
-        now_sr = msgs.t_deliver[r_sr]
-        cand = msgs.a[r_sr]
-        ck = ctx.keys[jnp.maximum(cand, 0)]
-        s0 = st.succ[0]
-        s0k = ctx.keys[jnp.maximum(s0, 0)]
-        succ_empty = s0 == NO_NODE
-        adopt = (cand != NO_NODE) & (succ_empty | K.is_between(
-            ck, me_key, s0k, spec))
-        new_node = jnp.where(adopt, cand,
-                             jnp.where(succ_empty, src_sr, NO_NODE))
-        succ4 = self._succ_add(ctx, me_key, node_idx, st.succ, new_node,
-                               any_sr)
-        succ4 = jnp.where(any_sr, succ4, st.succ)
-        # notify the (possibly new) successor
-        fire_nc = any_sr & (succ4[0] != NO_NODE)
-        ob.send(fire_nc, now_sr, succ4[0], wire.CHORD_NOTIFY_CALL,
-                size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
-        st = dataclasses.replace(
-            st, succ=succ4,
-            stab_op=jnp.where(any_sr, 2, st.stab_op),
-            stab_dst=jnp.where(any_sr, succ4[0], st.stab_dst),
-            stab_to=jnp.where(any_sr, now_sr + rpc_to_ns, st.stab_to))
+            # StabilizeResponse (handleRpcStabilizeResponse): at most one
+            # inbox slot matches the single in-flight stabilize RPC
+            en_sr = v_r & (msgs.kind == wire.CHORD_STABILIZE_RES) & (
+                st.state == READY) & (st.stab_op == 1) & (
+                msgs.src == st.stab_dst)
+            any_sr = jnp.any(en_sr)
+            r_sr = jnp.clip(jnp.argmax(en_sr).astype(I32), 0, r_in - 1)
+            src_sr = msgs.src[r_sr]
+            now_sr = msgs.t_deliver[r_sr]
+            cand = msgs.a[r_sr]
+            ck = ctx.keys[jnp.maximum(cand, 0)]
+            s0 = st.succ[0]
+            s0k = ctx.keys[jnp.maximum(s0, 0)]
+            succ_empty = s0 == NO_NODE
+            adopt = (cand != NO_NODE) & (succ_empty | K.is_between(
+                ck, me_key, s0k, spec))
+            new_node = jnp.where(adopt, cand,
+                                 jnp.where(succ_empty, src_sr, NO_NODE))
+            succ4 = self._succ_add(ctx, me_key, node_idx, st.succ, new_node,
+                                   any_sr)
+            succ4 = jnp.where(any_sr, succ4, st.succ)
+            # notify the (possibly new) successor
+            fire_nc = any_sr & (succ4[0] != NO_NODE)
+            ob.send(fire_nc, now_sr, succ4[0], wire.CHORD_NOTIFY_CALL,
+                    size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
+            st = dataclasses.replace(
+                st, succ=succ4,
+                stab_op=jnp.where(any_sr, 2, st.stab_op),
+                stab_dst=jnp.where(any_sr, succ4[0], st.stab_dst),
+                stab_to=jnp.where(any_sr, now_sr + rpc_to_ns, st.stab_to))
 
-        # NotifyCall (rpcNotify): adopt closer predecessor, reply with
-        # successor list.  The sequential fold adopts every strictly
-        # closer notifier in turn; its fixed point is the clockwise-
-        # closest enabled source — pick it with one distance argmin.
-        en = v_r & (msgs.kind == wire.CHORD_NOTIFY_CALL) & (
-            st.state == READY)
-        sk = ctx.keys[jnp.maximum(msgs.src, 0)]              # [R, KL]
-        pk = ctx.keys[jnp.maximum(st.pred, 0)]
-        closer = en & ((st.pred == NO_NODE) | K.is_between(
-            sk, jnp.broadcast_to(pk, sk.shape),
-            jnp.broadcast_to(me_key, sk.shape), spec))
-        d_nc = K.sub(jnp.broadcast_to(me_key, sk.shape), sk, spec)
-        d_nc = jnp.where(closer[:, None], d_nc, UMAX)
-        best_r = _lex_argmin(d_nc)
-        any_nc = jnp.any(closer)
-        newpred_src = msgs.src[best_r]
-        succ5 = jnp.where(any_nc & (st.succ[0] == NO_NODE),
-                          st.succ.at[0].set(newpred_src), st.succ)
-        st = dataclasses.replace(
-            st, pred=jnp.where(any_nc, newpred_src, st.pred), succ=succ5)
-        ob.send(en, now_r, msgs.src, wire.CHORD_NOTIFY_RES,
-                nodes=pad_nodes(st.succ),
-                size_b=wire.BASE_CALL_B
-                + wire.NODEHANDLE_B * (p.succ_size + 1))
+            # NotifyCall (rpcNotify): adopt closer predecessor, reply with
+            # successor list.  The sequential fold adopts every strictly
+            # closer notifier in turn; its fixed point is the clockwise-
+            # closest enabled source — pick it with one distance argmin.
+            en = v_r & (msgs.kind == wire.CHORD_NOTIFY_CALL) & (
+                st.state == READY)
+            sk = ctx.keys[jnp.maximum(msgs.src, 0)]              # [R, KL]
+            pk = ctx.keys[jnp.maximum(st.pred, 0)]
+            closer = en & ((st.pred == NO_NODE) | K.is_between(
+                sk, jnp.broadcast_to(pk, sk.shape),
+                jnp.broadcast_to(me_key, sk.shape), spec))
+            d_nc = K.sub(jnp.broadcast_to(me_key, sk.shape), sk, spec)
+            d_nc = jnp.where(closer[:, None], d_nc, UMAX)
+            best_r = _lex_argmin(d_nc)
+            any_nc = jnp.any(closer)
+            newpred_src = msgs.src[best_r]
+            succ5 = jnp.where(any_nc & (st.succ[0] == NO_NODE),
+                              st.succ.at[0].set(newpred_src), st.succ)
+            st = dataclasses.replace(
+                st, pred=jnp.where(any_nc, newpred_src, st.pred), succ=succ5)
+            ob.send(en, now_r, msgs.src, wire.CHORD_NOTIFY_RES,
+                    nodes=pad_nodes(st.succ),
+                    size_b=wire.BASE_CALL_B
+                    + wire.NODEHANDLE_B * (p.succ_size + 1))
 
-        # NotifyResponse (handleRpcNotifyResponse): replace successor
-        # list with successor's list; at most one slot matches the
-        # in-flight notify
-        fin_m = v_r & (msgs.kind == wire.CHORD_NOTIFY_RES) & (
-            st.stab_op == 2) & (msgs.src == st.stab_dst)
-        any_fin = jnp.any(fin_m)
-        r_nr = jnp.clip(jnp.argmax(fin_m).astype(I32), 0, r_in - 1)
-        take_nr = any_fin & (st.state == READY) & (
-            msgs.src[r_nr] == st.succ[0])
-        succ6 = self._succ_sorted(
-            ctx, me_key, node_idx,
-            jnp.concatenate([msgs.nodes[r_nr][:p.succ_size],
-                             msgs.src[r_nr][None]]))
-        st = dataclasses.replace(
-            st, succ=jnp.where(take_nr, succ6, st.succ),
-            stab_op=jnp.where(any_fin, 0, st.stab_op),
-            stab_to=jnp.where(any_fin, T_INF, st.stab_to))
+            # NotifyResponse (handleRpcNotifyResponse): replace successor
+            # list with successor's list; at most one slot matches the
+            # in-flight notify
+            fin_m = v_r & (msgs.kind == wire.CHORD_NOTIFY_RES) & (
+                st.stab_op == 2) & (msgs.src == st.stab_dst)
+            any_fin = jnp.any(fin_m)
+            r_nr = jnp.clip(jnp.argmax(fin_m).astype(I32), 0, r_in - 1)
+            take_nr = any_fin & (st.state == READY) & (
+                msgs.src[r_nr] == st.succ[0])
+            succ6 = self._succ_sorted(
+                ctx, me_key, node_idx,
+                jnp.concatenate([msgs.nodes[r_nr][:p.succ_size],
+                                 msgs.src[r_nr][None]]))
+            st = dataclasses.replace(
+                st, succ=jnp.where(take_nr, succ6, st.succ),
+                stab_op=jnp.where(any_fin, 0, st.stab_op),
+                stab_to=jnp.where(any_fin, T_INF, st.stab_to))
 
-        # NewSuccessorHint (handleNewSuccessorHint): adopt hinted nodes
-        # inside (me, succ0) — batch = one sorted merge of all taken
-        # hints.  Documented deviation from the sequential fold: the fold
-        # re-checks each hint against the SHRINKING (me, succ0) interval,
-        # so with two same-tick hints h1 < h2 < succ0 it would adopt only
-        # h1; the batch checks both against the pre-tick succ0 and keeps
-        # both (h2 is still a valid, closer-than-old-succ0 successor that
-        # the next stabilize round would have learned anyway).
-        en = v_r & (msgs.kind == wire.CHORD_SUCC_HINT) & (st.state == READY)
-        hk = ctx.keys[jnp.maximum(msgs.a, 0)]
-        s0k2 = ctx.keys[jnp.maximum(st.succ[0], 0)]
-        take = en & (msgs.a != NO_NODE) & (
-            (st.succ[0] == NO_NODE)
-            | K.is_between(hk, jnp.broadcast_to(me_key, hk.shape),
-                           jnp.broadcast_to(s0k2, hk.shape), spec))
-        succ7 = self._succ_sorted(
-            ctx, me_key, node_idx,
-            jnp.concatenate([st.succ, jnp.where(take, msgs.a, NO_NODE)]))
-        st = dataclasses.replace(
-            st, succ=jnp.where(jnp.any(take), succ7, st.succ))
+            # NewSuccessorHint (handleNewSuccessorHint): adopt hinted nodes
+            # inside (me, succ0) — batch = one sorted merge of all taken
+            # hints.  Documented deviation from the sequential fold: the fold
+            # re-checks each hint against the SHRINKING (me, succ0) interval,
+            # so with two same-tick hints h1 < h2 < succ0 it would adopt only
+            # h1; the batch checks both against the pre-tick succ0 and keeps
+            # both (h2 is still a valid, closer-than-old-succ0 successor that
+            # the next stabilize round would have learned anyway).
+            en = v_r & (msgs.kind == wire.CHORD_SUCC_HINT) & (st.state == READY)
+            hk = ctx.keys[jnp.maximum(msgs.a, 0)]
+            s0k2 = ctx.keys[jnp.maximum(st.succ[0], 0)]
+            take = en & (msgs.a != NO_NODE) & (
+                (st.succ[0] == NO_NODE)
+                | K.is_between(hk, jnp.broadcast_to(me_key, hk.shape),
+                               jnp.broadcast_to(s0k2, hk.shape), spec))
+            succ7 = self._succ_sorted(
+                ctx, me_key, node_idx,
+                jnp.concatenate([st.succ, jnp.where(take, msgs.a, NO_NODE)]))
+            st = dataclasses.replace(
+                st, succ=jnp.where(jnp.any(take), succ7, st.succ))
 
-        # KBR broadcast (Chord::forwardBroadcast, Chord.cc:1410-1446):
-        # walk fingers+successors by DESCENDING clockwise distance;
-        # every candidate inside (me, limit) gets a copy whose limit
-        # is the previous candidate, shrinking the covered range.
-        # Fan-out is capped at BCAST_FANOUT copies with the closest
-        # successor always last so the near range stays covered
-        # (distinct fingers ~ log N; the cap only binds at huge N).
-        # The per-slot fanout walk is vmapped; all copies leave in one
-        # vector send.
-        en_b = v_r & (msgs.kind == wire.BROADCAST) & (st.state == READY)
-        bc = jnp.concatenate([st.finger, st.succ])
-        bck = ctx.keys[jnp.maximum(bc, 0)]
-        me_bb = jnp.broadcast_to(me_key, bck.shape)
-        dup_bc = K.dup_mask(bc)
-        d_bc = K.sub(bck, me_bb, spec)          # cw distance me -> cand
+        with scope("chord.broadcast"):
+            # KBR broadcast (Chord::forwardBroadcast, Chord.cc:1410-1446):
+            # walk fingers+successors by DESCENDING clockwise distance;
+            # every candidate inside (me, limit) gets a copy whose limit
+            # is the previous candidate, shrinking the covered range.
+            # Fan-out is capped at BCAST_FANOUT copies with the closest
+            # successor always last so the near range stays covered
+            # (distinct fingers ~ log N; the cap only binds at huge N).
+            # The per-slot fanout walk is vmapped; all copies leave in one
+            # vector send.
+            en_b = v_r & (msgs.kind == wire.BROADCAST) & (st.state == READY)
+            bc = jnp.concatenate([st.finger, st.succ])
+            bck = ctx.keys[jnp.maximum(bc, 0)]
+            me_bb = jnp.broadcast_to(me_key, bck.shape)
+            dup_bc = K.dup_mask(bc)
+            d_bc = K.sub(bck, me_bb, spec)          # cw distance me -> cand
 
-        def _bcast_slot(mkey, enb):
-            lim_b = jnp.broadcast_to(mkey, bck.shape)
-            ok_b = (bc != NO_NODE) & (bc != node_idx) & ~dup_bc \
-                & K.is_between(bck, me_bb, lim_b, spec)
-            db = jnp.where(ok_b[:, None], d_bc, jnp.zeros_like(d_bc))
-            (bc_s,) = _sort_lanes(db, (jnp.where(ok_b, bc, NO_NODE),))
-            n_ok = jnp.sum(ok_b.astype(I32))
-            cdim = bc_s.shape[0]
-            j = jnp.arange(BCAST_FANOUT, dtype=I32)
-            idx_j = jnp.clip(cdim - 1 - j, 0, cdim - 1)
-            tgt = jnp.where(j < n_ok, bc_s[idx_j], NO_NODE)  # far -> near
-            # copy j's limit = the previous copy's target key (j=0: mkey)
-            tk = ctx.keys[jnp.maximum(tgt, 0)]               # [F, KL]
-            lim = jnp.concatenate([mkey[None], tk[:-1]], axis=0)
-            fire = enb & (tgt != NO_NODE)
-            # cap bound (> FANOUT candidates): one extra copy to the
-            # NEAREST candidate carries the remaining (me, limit) range,
-            # which it re-splits recursively — without it the near range
-            # would never see the broadcast.  fire_n requires n_ok >
-            # FANOUT, so the last fired copy is always index FANOUT-1.
-            near = bc_s[jnp.clip(cdim - n_ok, 0, cdim - 1)]
-            fire_n = enb & (n_ok > BCAST_FANOUT) & (near != NO_NODE)
-            lim_n = tk[BCAST_FANOUT - 1]
-            return tgt, lim, fire, near, fire_n, lim_n
+            def _bcast_slot(mkey, enb):
+                lim_b = jnp.broadcast_to(mkey, bck.shape)
+                ok_b = (bc != NO_NODE) & (bc != node_idx) & ~dup_bc \
+                    & K.is_between(bck, me_bb, lim_b, spec)
+                db = jnp.where(ok_b[:, None], d_bc, jnp.zeros_like(d_bc))
+                (bc_s,) = _sort_lanes(db, (jnp.where(ok_b, bc, NO_NODE),))
+                n_ok = jnp.sum(ok_b.astype(I32))
+                cdim = bc_s.shape[0]
+                j = jnp.arange(BCAST_FANOUT, dtype=I32)
+                idx_j = jnp.clip(cdim - 1 - j, 0, cdim - 1)
+                tgt = jnp.where(j < n_ok, bc_s[idx_j], NO_NODE)  # far -> near
+                # copy j's limit = the previous copy's target key (j=0: mkey)
+                tk = ctx.keys[jnp.maximum(tgt, 0)]               # [F, KL]
+                lim = jnp.concatenate([mkey[None], tk[:-1]], axis=0)
+                fire = enb & (tgt != NO_NODE)
+                # cap bound (> FANOUT candidates): one extra copy to the
+                # NEAREST candidate carries the remaining (me, limit) range,
+                # which it re-splits recursively — without it the near range
+                # would never see the broadcast.  fire_n requires n_ok >
+                # FANOUT, so the last fired copy is always index FANOUT-1.
+                near = bc_s[jnp.clip(cdim - n_ok, 0, cdim - 1)]
+                fire_n = enb & (n_ok > BCAST_FANOUT) & (near != NO_NODE)
+                lim_n = tk[BCAST_FANOUT - 1]
+                return tgt, lim, fire, near, fire_n, lim_n
 
-        tgt_v, lim_v, fire_v, near_v, firen_v, limn_v = jax.vmap(
-            _bcast_slot)(msgs.key, en_b)
-        bshape = (r_in, BCAST_FANOUT)
-        ob.send(fire_v.reshape(-1),
-                jnp.broadcast_to(now_r[:, None], bshape).reshape(-1),
-                tgt_v.reshape(-1), wire.BROADCAST,
-                key=lim_v.reshape(r_in * BCAST_FANOUT, -1),
-                a=jnp.broadcast_to(msgs.a[:, None], bshape).reshape(-1),
-                b=jnp.broadcast_to(msgs.b[:, None], bshape).reshape(-1),
-                hops=jnp.broadcast_to((msgs.hops + 1)[:, None],
-                                      bshape).reshape(-1),
-                size_b=wire.BASE_CALL_B + 20)
-        ob.send(firen_v, now_r, jnp.maximum(near_v, 0), wire.BROADCAST,
-                key=limn_v, a=msgs.a, b=msgs.b, hops=msgs.hops + 1,
-                size_b=wire.BASE_CALL_B + 20)
+            tgt_v, lim_v, fire_v, near_v, firen_v, limn_v = jax.vmap(
+                _bcast_slot)(msgs.key, en_b)
+            bshape = (r_in, BCAST_FANOUT)
+            ob.send(fire_v.reshape(-1),
+                    jnp.broadcast_to(now_r[:, None], bshape).reshape(-1),
+                    tgt_v.reshape(-1), wire.BROADCAST,
+                    key=lim_v.reshape(r_in * BCAST_FANOUT, -1),
+                    a=jnp.broadcast_to(msgs.a[:, None], bshape).reshape(-1),
+                    b=jnp.broadcast_to(msgs.b[:, None], bshape).reshape(-1),
+                    hops=jnp.broadcast_to((msgs.hops + 1)[:, None],
+                                          bshape).reshape(-1),
+                    size_b=wire.BASE_CALL_B + 20)
+            ob.send(firen_v, now_r, jnp.maximum(near_v, 0), wire.BROADCAST,
+                    key=limn_v, a=msgs.a, b=msgs.b, hops=msgs.hops + 1,
+                    size_b=wire.BASE_CALL_B + 20)
 
         # app-owned message kinds (Common API deliver path,
         # BaseApp::handleCommonAPIMessage), with the per-slot findNode
@@ -883,47 +889,48 @@ class ChordLogic:
                 st = dataclasses.replace(st, app=self.app.on_msg(
                     st.app, msgs.slot(r), ctx, ob, ev, sib_b[r]))
 
-        # ping (predecessor liveness + generic); the response piggybacks
-        # this node's Vivaldi coordinates (the reference attaches
-        # ncsInfo[] to every RPC response, CommonMessages.msg:233 /
-        # NeighborCache piggybacking)
-        if self.ncs.is_landmark_type:
-            ping_key = ncs_mod.pack_wire_nps(
-                st.ncs.coords, st.ncs.error, st.ncs.layer, spec.lanes)
-        else:
-            ping_key = ncs_mod.pack_wire(st.ncs.coords, st.ncs.error,
-                                         spec.lanes)
-        ob.send(v_r & (msgs.kind == wire.PING_CALL), now_r, msgs.src,
-                wire.PING_RES, a=msgs.a, key=ping_key,
-                size_b=wire.BASE_CALL_B + 4 * (
-                    self.ncs.dims
-                    + (2 if self.ncs.is_landmark_type else 1)))
-        # ping response: at most one slot matches the in-flight
-        # predecessor ping (a == -3 marks NPS probe pongs — the probe
-        # target can BE the predecessor, so src alone is ambiguous)
-        en_p = v_r & (msgs.kind == wire.PING_RES) & (
-            msgs.src == st.cp_dst) & (msgs.a != -3)
-        any_p = jnp.any(en_p)
-        r_p = jnp.clip(jnp.argmax(en_p).astype(I32), 0, r_in - 1)
-        now_p = msgs.t_deliver[r_p]
-        rtt_s = (now_p - st.cp_sent).astype(jnp.float32) / NS
-        nc_row = dict(peer=st.nc.peer, rtt_mean=st.nc.rtt_mean,
-                      rtt_var=st.nc.rtt_var, last=st.nc.last,
-                      live=st.nc.live)
-        nc_row = nc_mod.insert_rtt(nc_row, msgs.src[r_p], rtt_s, now_p,
-                                   any_p)
-        st = dataclasses.replace(st, nc=nc_mod.NcState(**nc_row))
-        if self.ncs.ncs_type in ("vivaldi", "svivaldi"):
-            xj, ej = ncs_mod.unpack_wire(msgs.key[r_p], self.ncs.dims)
-            me_ncs = dict(coords=st.ncs.coords, height=st.ncs.height,
-                          error=st.ncs.error, loss=st.ncs.loss)
-            upd = ncs_mod.update(me_ncs, jnp.where(any_p, rtt_s, -1.0),
-                                 xj, ej, jnp.float32(0.0), self.ncs)
+        with scope("chord.ping"):
+            # ping (predecessor liveness + generic); the response piggybacks
+            # this node's Vivaldi coordinates (the reference attaches
+            # ncsInfo[] to every RPC response, CommonMessages.msg:233 /
+            # NeighborCache piggybacking)
+            if self.ncs.is_landmark_type:
+                ping_key = ncs_mod.pack_wire_nps(
+                    st.ncs.coords, st.ncs.error, st.ncs.layer, spec.lanes)
+            else:
+                ping_key = ncs_mod.pack_wire(st.ncs.coords, st.ncs.error,
+                                             spec.lanes)
+            ob.send(v_r & (msgs.kind == wire.PING_CALL), now_r, msgs.src,
+                    wire.PING_RES, a=msgs.a, key=ping_key,
+                    size_b=wire.BASE_CALL_B + 4 * (
+                        self.ncs.dims
+                        + (2 if self.ncs.is_landmark_type else 1)))
+            # ping response: at most one slot matches the in-flight
+            # predecessor ping (a == -3 marks NPS probe pongs — the probe
+            # target can BE the predecessor, so src alone is ambiguous)
+            en_p = v_r & (msgs.kind == wire.PING_RES) & (
+                msgs.src == st.cp_dst) & (msgs.a != -3)
+            any_p = jnp.any(en_p)
+            r_p = jnp.clip(jnp.argmax(en_p).astype(I32), 0, r_in - 1)
+            now_p = msgs.t_deliver[r_p]
+            rtt_s = (now_p - st.cp_sent).astype(jnp.float32) / NS
+            nc_row = dict(peer=st.nc.peer, rtt_mean=st.nc.rtt_mean,
+                          rtt_var=st.nc.rtt_var, last=st.nc.last,
+                          live=st.nc.live)
+            nc_row = nc_mod.insert_rtt(nc_row, msgs.src[r_p], rtt_s, now_p,
+                                       any_p)
+            st = dataclasses.replace(st, nc=nc_mod.NcState(**nc_row))
+            if self.ncs.ncs_type in ("vivaldi", "svivaldi"):
+                xj, ej = ncs_mod.unpack_wire(msgs.key[r_p], self.ncs.dims)
+                me_ncs = dict(coords=st.ncs.coords, height=st.ncs.height,
+                              error=st.ncs.error, loss=st.ncs.loss)
+                upd = ncs_mod.update(me_ncs, jnp.where(any_p, rtt_s, -1.0),
+                                     xj, ej, jnp.float32(0.0), self.ncs)
+                st = dataclasses.replace(
+                    st, ncs=dataclasses.replace(st.ncs, **upd))
             st = dataclasses.replace(
-                st, ncs=dataclasses.replace(st.ncs, **upd))
-        st = dataclasses.replace(
-            st, cp_to=jnp.where(any_p, T_INF, st.cp_to),
-            cp_dst=jnp.where(any_p, NO_NODE, st.cp_dst))
+                st, cp_to=jnp.where(any_p, T_INF, st.cp_to),
+                cp_dst=jnp.where(any_p, NO_NODE, st.cp_dst))
 
         # GNP/NPS landmark-probe pong: RTT sample to the reference point
         # → triangulate own coords (Nps::doTriangulation equivalent) and
@@ -954,25 +961,26 @@ class ChordLogic:
         # ------------------------------------------------------- timers ----
         t_end = ctx.t_end
 
-        # join (joinOverlay / handleJoinTimerExpired Chord.cc:758)
-        en_j = (st.state == JOINING) & (st.t_join < t_end)
-        now_j = jnp.maximum(st.t_join, t0)
-        boot = ctx.sample_ready(rngs[1], node_idx)
-        no_join_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_JOIN))
-        # no node READY: ONE due joiner starts the ring (ring_starter);
-        # the others keep their timer, so they stay due (and awake) and
-        # find the starter READY in the next tick
-        alone_start = en_j & (boot == NO_NODE) & (ctx.starter == node_idx)
-        st = self._become_ready(ctx, st, alone_start, now_j, rngs[2])
-        joins_cnt += alone_start.astype(I32)
-        slot, have = lk_mod.free_slot(st.lk)
-        start_join = en_j & (boot != NO_NODE) & no_join_lk & have
-        seed = jnp.full((lcfg.frontier,), NO_NODE, I32).at[0].set(boot)
-        st = dataclasses.replace(st, lk=lk_mod.start(
-            st.lk, start_join, slot, P_JOIN, 0, me_key, seed, now_j, lcfg))
-        st = dataclasses.replace(st, t_join=jnp.where(
-            en_j & (boot != NO_NODE),
-            now_j + jnp.int64(int(p.join_delay * NS)), st.t_join))
+        with scope("chord.join"):
+            # join (joinOverlay / handleJoinTimerExpired Chord.cc:758)
+            en_j = (st.state == JOINING) & (st.t_join < t_end)
+            now_j = jnp.maximum(st.t_join, t0)
+            boot = ctx.sample_ready(rngs[1], node_idx)
+            no_join_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_JOIN))
+            # no node READY: ONE due joiner starts the ring (ring_starter);
+            # the others keep their timer, so they stay due (and awake) and
+            # find the starter READY in the next tick
+            alone_start = en_j & (boot == NO_NODE) & (ctx.starter == node_idx)
+            st = self._become_ready(ctx, st, alone_start, now_j, rngs[2])
+            joins_cnt += alone_start.astype(I32)
+            slot, have = lk_mod.free_slot(st.lk)
+            start_join = en_j & (boot != NO_NODE) & no_join_lk & have
+            seed = jnp.full((lcfg.frontier,), NO_NODE, I32).at[0].set(boot)
+            st = dataclasses.replace(st, lk=lk_mod.start(
+                st.lk, start_join, slot, P_JOIN, 0, me_key, seed, now_j, lcfg))
+            st = dataclasses.replace(st, t_join=jnp.where(
+                en_j & (boot != NO_NODE),
+                now_j + jnp.int64(int(p.join_delay * NS)), st.t_join))
 
         # GNP/NPS probe timer: measure RTT to a reference point — GNP
         # pings landmarks only; NPS alternates landmarks and random
@@ -1039,55 +1047,58 @@ class ChordLogic:
                 en_m, now_m + jnp.int64(int(p.merge_interval * NS)),
                 st.t_merge))
 
-        # stabilize (handleStabilizeTimerExpired)
-        en_s = (st.state == READY) & (st.t_stab < t_end)
-        now_s = jnp.maximum(st.t_stab, t0)
-        has_succ = st.succ[0] != NO_NODE
-        fire_s = en_s & has_succ
-        ob.send(fire_s, now_s, st.succ[0], wire.CHORD_STABILIZE_CALL,
-                size_b=wire.BASE_CALL_B)
-        st = dataclasses.replace(
-            st,
-            stab_op=jnp.where(fire_s, 1, st.stab_op),
-            stab_dst=jnp.where(fire_s, st.succ[0], st.stab_dst),
-            stab_to=jnp.where(fire_s, now_s + rpc_to_ns, st.stab_to),
-            t_stab=jnp.where(en_s, now_s + jnp.int64(
-                int(p.stabilize_delay * NS)), st.t_stab))
+        with scope("chord.stabilize"):
+            # stabilize (handleStabilizeTimerExpired)
+            en_s = (st.state == READY) & (st.t_stab < t_end)
+            now_s = jnp.maximum(st.t_stab, t0)
+            has_succ = st.succ[0] != NO_NODE
+            fire_s = en_s & has_succ
+            ob.send(fire_s, now_s, st.succ[0], wire.CHORD_STABILIZE_CALL,
+                    size_b=wire.BASE_CALL_B)
+            st = dataclasses.replace(
+                st,
+                stab_op=jnp.where(fire_s, 1, st.stab_op),
+                stab_dst=jnp.where(fire_s, st.succ[0], st.stab_dst),
+                stab_to=jnp.where(fire_s, now_s + rpc_to_ns, st.stab_to),
+                t_stab=jnp.where(en_s, now_s + jnp.int64(
+                    int(p.stabilize_delay * NS)), st.t_stab))
 
-        # fixfingers (handleFixFingersTimerExpired): mark non-trivial
-        # fingers dirty, remove trivial ones
-        due_f = (st.state == READY) & (st.t_fix < t_end)
-        en_f = due_f & has_succ
-        s0k = ctx.keys[jnp.maximum(st.succ[0], 0)]
-        sdist = K.sub(s0k, me_key, spec)                    # me → succ
-        nontrivial = K.gt(self._pow2, jnp.broadcast_to(sdist,
-                                                       self._pow2.shape))
-        st = dataclasses.replace(
-            st,
-            finger_dirty=jnp.where(en_f, nontrivial, st.finger_dirty),
-            finger=jnp.where(en_f & ~nontrivial, NO_NODE, st.finger),
-            t_fix=jnp.where(due_f,
-                            jnp.maximum(st.t_fix, t0)
-                            + jnp.int64(int(p.fixfingers_delay * NS)),
-                            st.t_fix))
+        with scope("chord.fix_fingers"):
+            # fixfingers (handleFixFingersTimerExpired): mark non-trivial
+            # fingers dirty, remove trivial ones
+            due_f = (st.state == READY) & (st.t_fix < t_end)
+            en_f = due_f & has_succ
+            s0k = ctx.keys[jnp.maximum(st.succ[0], 0)]
+            sdist = K.sub(s0k, me_key, spec)                    # me → succ
+            nontrivial = K.gt(self._pow2, jnp.broadcast_to(sdist,
+                                                           self._pow2.shape))
+            st = dataclasses.replace(
+                st,
+                finger_dirty=jnp.where(en_f, nontrivial, st.finger_dirty),
+                finger=jnp.where(en_f & ~nontrivial, NO_NODE, st.finger),
+                t_fix=jnp.where(due_f,
+                                jnp.maximum(st.t_fix, t0)
+                                + jnp.int64(int(p.fixfingers_delay * NS)),
+                                st.t_fix))
 
         # subclass periodic protocols (Koorde de Bruijn timer)
         st = self._extra_timers(ctx, st, ob, me_key, node_idx, t0, t_end,
                                 rngs[5])
 
-        # predecessor check (handleCheckPredecessorTimerExpired)
-        en_c = (st.state == READY) & (st.t_cp < t_end)
-        now_c = jnp.maximum(st.t_cp, t0)
-        fire_c = en_c & (st.pred != NO_NODE) & (st.cp_to == T_INF)
-        ob.send(fire_c, now_c, st.pred, wire.PING_CALL,
-                size_b=wire.BASE_CALL_B)
-        st = dataclasses.replace(
-            st,
-            cp_to=jnp.where(fire_c, now_c + rpc_to_ns, st.cp_to),
-            cp_dst=jnp.where(fire_c, st.pred, st.cp_dst),
-            cp_sent=jnp.where(fire_c, now_c, st.cp_sent),
-            t_cp=jnp.where(en_c, now_c + jnp.int64(
-                int(p.check_pred_delay * NS)), st.t_cp))
+        with scope("chord.ping"):
+            # predecessor check (handleCheckPredecessorTimerExpired)
+            en_c = (st.state == READY) & (st.t_cp < t_end)
+            now_c = jnp.maximum(st.t_cp, t0)
+            fire_c = en_c & (st.pred != NO_NODE) & (st.cp_to == T_INF)
+            ob.send(fire_c, now_c, st.pred, wire.PING_CALL,
+                    size_b=wire.BASE_CALL_B)
+            st = dataclasses.replace(
+                st,
+                cp_to=jnp.where(fire_c, now_c + rpc_to_ns, st.cp_to),
+                cp_dst=jnp.where(fire_c, st.pred, st.cp_dst),
+                cp_sent=jnp.where(fire_c, now_c, st.cp_sent),
+                t_cp=jnp.where(en_c, now_c + jnp.int64(
+                    int(p.check_pred_delay * NS)), st.t_cp))
 
         # app timer → start an app lookup (KBRTestApp::handleTimerEvent →
         # callRoute → iterative lookup, SURVEY §3.2)
@@ -1166,20 +1177,21 @@ class ChordLogic:
         new_lk, failed_nodes, _ = lk_mod.on_timeouts(st.lk, t_end, t0, lcfg)
         st = dataclasses.replace(st, lk=new_lk)
 
-        # stabilize / notify RPC timeout → failed successor
-        en = (st.stab_op != 0) & (st.stab_to < t_end)
-        stab_failed = jnp.where(en, st.stab_dst, NO_NODE)
-        st = dataclasses.replace(
-            st, stab_op=jnp.where(en, 0, st.stab_op),
-            stab_to=jnp.where(en, T_INF, st.stab_to))
+        with scope("chord.failed"):
+            # stabilize / notify RPC timeout → failed successor
+            en = (st.stab_op != 0) & (st.stab_to < t_end)
+            stab_failed = jnp.where(en, st.stab_dst, NO_NODE)
+            st = dataclasses.replace(
+                st, stab_op=jnp.where(en, 0, st.stab_op),
+                stab_to=jnp.where(en, T_INF, st.stab_to))
 
-        # predecessor ping timeout → the PINGED node failed (a predecessor
-        # adopted after the ping was sent is NOT dropped)
-        en = st.cp_to < t_end
-        cp_failed = jnp.where(en, st.cp_dst, NO_NODE)
-        st = dataclasses.replace(
-            st, cp_to=jnp.where(en, T_INF, st.cp_to),
-            cp_dst=jnp.where(en, NO_NODE, st.cp_dst))
+            # predecessor ping timeout → the PINGED node failed (a predecessor
+            # adopted after the ping was sent is NOT dropped)
+            en = st.cp_to < t_end
+            cp_failed = jnp.where(en, st.cp_dst, NO_NODE)
+            st = dataclasses.replace(
+                st, cp_to=jnp.where(en, T_INF, st.cp_to),
+                cp_dst=jnp.where(en, NO_NODE, st.cp_dst))
 
         # route-hop ACK timeouts: unresponsive next hops are failures too
         if self.rcfg is not None:
@@ -1231,10 +1243,11 @@ class ChordLogic:
         lksucc_cnt += jnp.sum((taken & suc_l).astype(I32))
         anyfail_cnt += jnp.sum((taken & ~suc_l).astype(I32))
 
-        # join: contact our successor directly (one vector send)
-        ob.send(taken & suc_l & (pur_l == P_JOIN), t0, res_l,
-                wire.CHORD_JOIN_CALL, key=me_key, a=node_idx,
-                size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
+        with scope("chord.join"):
+            # join: contact our successor directly (one vector send)
+            ob.send(taken & suc_l & (pur_l == P_JOIN), t0, res_l,
+                    wire.CHORD_JOIN_CALL, key=me_key, a=node_idx,
+                    size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
 
         # partition-merge probe completions (handleLookupResponse,
         # BootstrapList.cc:171-195): the candidate's key resolved to a
@@ -1259,15 +1272,16 @@ class ChordLogic:
             ob.send(foreign, t0, x_m, wire.CHORD_SUCC_HINT, a=node_idx,
                     size_b=wire.BASE_CALL_B + wire.NODEHANDLE_B)
 
-        # finger repair results (one scatter per field)
-        enf = taken & (pur_l == P_FINGER)
-        fi_l = jnp.clip(comp["aux"], 0, spec.bits - 1)
-        st = dataclasses.replace(
-            st,
-            finger=st.finger.at[jnp.where(enf & suc_l, fi_l, spec.bits)]
-            .set(res_l, mode="drop"),
-            finger_dirty=st.finger_dirty
-            .at[jnp.where(enf, fi_l, spec.bits)].set(False, mode="drop"))
+        with scope("chord.fix_fingers"):
+            # finger repair results (one scatter per field)
+            enf = taken & (pur_l == P_FINGER)
+            fi_l = jnp.clip(comp["aux"], 0, spec.bits - 1)
+            st = dataclasses.replace(
+                st,
+                finger=st.finger.at[jnp.where(enf & suc_l, fi_l, spec.bits)]
+                .set(res_l, mode="drop"),
+                finger_dirty=st.finger_dirty
+                .at[jnp.where(enf, fi_l, spec.bits)].set(False, mode="drop"))
 
         # app lookups → app completion hook (batched when supported)
         ena_l = taken & (pur_l == P_APP)
@@ -1296,25 +1310,26 @@ class ChordLogic:
                     ctx, st, ob, li, comp, taken[li], suc_l[li], res_l[li],
                     t0)
 
-        # -------------------------------------------- finger repair pump ---
-        dirty_any = (st.state == READY) & jnp.any(st.finger_dirty)
-        no_finger_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_FINGER))
-        fi = jnp.argmax(st.finger_dirty).astype(I32)
-        target = K.add(me_key, self._pow2[fi], spec)
-        nxt_f, sib_f = self._find_node(ctx, st, me_key, node_idx, target)
-        # responsible ourselves → no finger needed (covered by succ list)
-        self_fix = dirty_any & no_finger_lk & sib_f
-        st = dataclasses.replace(
-            st,
-            finger_dirty=jnp.where(self_fix,
-                                   st.finger_dirty.at[fi].set(False),
-                                   st.finger_dirty))
-        slot, have = lk_mod.free_slot(st.lk)
-        start_fix = dirty_any & no_finger_lk & ~sib_f & have & (
-            nxt_f != NO_NODE)
-        seed = jnp.full((lcfg.frontier,), NO_NODE, I32).at[0].set(nxt_f)
-        st = dataclasses.replace(st, lk=lk_mod.start(
-            st.lk, start_fix, slot, P_FINGER, fi, target, seed, t0, lcfg))
+        with scope("chord.fix_fingers"):
+            # -------------------------------------------- finger repair pump ---
+            dirty_any = (st.state == READY) & jnp.any(st.finger_dirty)
+            no_finger_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_FINGER))
+            fi = jnp.argmax(st.finger_dirty).astype(I32)
+            target = K.add(me_key, self._pow2[fi], spec)
+            nxt_f, sib_f = self._find_node(ctx, st, me_key, node_idx, target)
+            # responsible ourselves → no finger needed (covered by succ list)
+            self_fix = dirty_any & no_finger_lk & sib_f
+            st = dataclasses.replace(
+                st,
+                finger_dirty=jnp.where(self_fix,
+                                       st.finger_dirty.at[fi].set(False),
+                                       st.finger_dirty))
+            slot, have = lk_mod.free_slot(st.lk)
+            start_fix = dirty_any & no_finger_lk & ~sib_f & have & (
+                nxt_f != NO_NODE)
+            seed = jnp.full((lcfg.frontier,), NO_NODE, I32).at[0].set(nxt_f)
+            st = dataclasses.replace(st, lk=lk_mod.start(
+                st.lk, start_fix, slot, P_FINGER, fi, target, seed, t0, lcfg))
 
         # ------------------------------------------------------- pump ------
         # adaptive per-destination RPC timeouts from the RTT cache

@@ -84,6 +84,7 @@ from oversim_tpu.common import neighborcache as nc_mod
 from oversim_tpu.common import route as rt_mod
 from oversim_tpu.common import wire
 from oversim_tpu.core import keys as K
+from oversim_tpu.core.scopes import scope, scoped
 from oversim_tpu.engine.logic import Outbox, select_tree
 
 I32 = jnp.int32
@@ -312,6 +313,7 @@ class KademliaLogic:
         disp = jnp.where(was & ~still, sib, NO_NODE)
         return new_sib, disp
 
+    @scoped("kademlia.bucket_update")
     def _bucket_update_batch(self, ctx, st, me_key, cands, alive, now):
         """One-pass batched bucket update — the bucket half of routingAdd
         (Kademlia.cc:432-700) for ALL of a tick's C candidates at once.
@@ -435,6 +437,7 @@ class KademliaLogic:
                 return st, rc_ping
         return st, NO_NODE
 
+    @scoped("kademlia.routing_add")
     def _routing_add_batch(self, ctx, st, me_key, node_idx, cands, alive,
                            now, heard):
         """Batched routingAdd (Kademlia.cc:432) for a tick's whole
@@ -517,6 +520,7 @@ class KademliaLogic:
                                                key[None], rmax)
         return out[0], is_sib[0]
 
+    @scoped("kademlia.find_node")
     def _find_node_batch(self, ctx, st, me_key, node_idx, keys, rmax):
         """Batched findNode for T target keys at once ([T, KL] → ([T, rmax]
         slots, [T] is_sibling, [B, K] stale)) — ONE sort over the shared
@@ -577,6 +581,7 @@ class KademliaLogic:
         is_sib = ready & (n_sib < 1) | (ready & ~not_ours & ~closer_sib)
         return out, is_sib, stale.reshape(p.num_buckets, p.k)
 
+    @scoped("kademlia.failed")
     def _handle_failed(self, ctx, st, me_key, node_idx, failed, also=None):
         """handleFailedNode (Kademlia.cc:979): drop sibling / stale+evict.
 
@@ -762,38 +767,39 @@ class KademliaLogic:
                 nodes=res_atk,
                 size_b=wire.findnode_res_b(p.redundant_nodes))
 
-        # ping (generic liveness; b echoes the caller's generation so
-        # verification pongs can be stale-guarded, lookup.on_pongs)
-        ob.send(v_r & (msgs.kind == wire.PING_CALL), t_del_r, msgs.src,
-                wire.PING_RES, a=msgs.a, b=msgs.b, size_b=wire.BASE_CALL_B)
+        with scope("kademlia.pings"):
+            # ping (generic liveness; b echoes the caller's generation so
+            # verification pongs can be stale-guarded, lookup.on_pongs)
+            ob.send(v_r & (msgs.kind == wire.PING_CALL), t_del_r, msgs.src,
+                    wire.PING_RES, a=msgs.a, b=msgs.b, size_b=wire.BASE_CALL_B)
 
-        # S/Kademlia sibling-verification pongs (lookup engine pings its
-        # staged candidate, IterativeLookup.cc:295-340)
-        if lcfg.verify_siblings:
-            st = dataclasses.replace(st, lk=lk_mod.on_pongs(
-                st.lk, dataclasses.replace(
-                    msgs, valid=v_r & (msgs.kind == wire.PING_RES)), lcfg))
+            # S/Kademlia sibling-verification pongs (lookup engine pings its
+            # staged candidate, IterativeLookup.cc:295-340)
+            if lcfg.verify_siblings:
+                st = dataclasses.replace(st, lk=lk_mod.on_pongs(
+                    st.lk, dataclasses.replace(
+                        msgs, valid=v_r & (msgs.kind == wire.PING_RES)), lcfg))
 
-        # maintenance pings (bucket pings / replacement-cache pings /
-        # downlist verification, Kademlia.h bucketPingInterval &
-        # replacementCachePing): KAD_PING kinds keep their pongs separate
-        # from the lookup engine's verification pings
-        ob.send(v_r & (msgs.kind == wire.KAD_PING_CALL), t_del_r, msgs.src,
-                wire.KAD_PING_RES, a=msgs.a, size_b=wire.BASE_CALL_B)
-        en_kpr = v_r & (msgs.kind == wire.KAD_PING_RES)
-        pong_hit = jnp.any(
-            st.ping_dst[:, None] == jnp.where(en_kpr, msgs.src,
-                                              NO_NODE)[None, :], axis=1)
-        st = dataclasses.replace(
-            st,
-            ping_dst=jnp.where(pong_hit, NO_NODE, st.ping_dst),
-            ping_to=jnp.where(pong_hit, T_INF, st.ping_to))
+            # maintenance pings (bucket pings / replacement-cache pings /
+            # downlist verification, Kademlia.h bucketPingInterval &
+            # replacementCachePing): KAD_PING kinds keep their pongs separate
+            # from the lookup engine's verification pings
+            ob.send(v_r & (msgs.kind == wire.KAD_PING_CALL), t_del_r, msgs.src,
+                    wire.KAD_PING_RES, a=msgs.a, size_b=wire.BASE_CALL_B)
+            en_kpr = v_r & (msgs.kind == wire.KAD_PING_RES)
+            pong_hit = jnp.any(
+                st.ping_dst[:, None] == jnp.where(en_kpr, msgs.src,
+                                                  NO_NODE)[None, :], axis=1)
+            st = dataclasses.replace(
+                st,
+                ping_dst=jnp.where(pong_hit, NO_NODE, st.ping_dst),
+                ping_to=jnp.where(pong_hit, T_INF, st.ping_to))
 
-        # downlist receive (KademliaDownlistMessage, Kademlia.cc:1305-
-        # 1319): ping each reported-dead node before believing it —
-        # queued into the bounded ping table below
-        dl_cands = jnp.where(
-            v_r & (msgs.kind == wire.KAD_DOWNLIST), msgs.a, NO_NODE)
+            # downlist receive (KademliaDownlistMessage, Kademlia.cc:1305-
+            # 1319): ping each reported-dead node before believing it —
+            # queued into the bounded ping table below
+            dl_cands = jnp.where(
+                v_r & (msgs.kind == wire.KAD_DOWNLIST), msgs.a, NO_NODE)
 
         # app-owned message kinds (Common API deliver path)
         if hasattr(self.app, "on_msgs"):
@@ -805,114 +811,117 @@ class KademliaLogic:
                     st.app, msgs.slot(r), ctx, ob, ev, sib_b[r]))
 
         # ------------------------------------------------------- timers ----
-        # join (joinOverlay: lookup own key via bootstrap,
-        # Kademlia.cc:1027-1081)
-        en_j = (st.state == JOINING) & (st.t_join < t_end)
-        now_j = jnp.maximum(st.t_join, t0)
-        boot = ctx.sample_ready(rngs[1], node_idx)
-        no_join_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_JOIN))
-        alone_start = en_j & (boot == NO_NODE)
-        st = self._become_ready(ctx, st, alone_start, now_j, rngs[2])
-        joins_cnt += alone_start.astype(I32)
-        slot, have = lk_mod.free_slot(st.lk)
-        start_join = en_j & (boot != NO_NODE) & no_join_lk & have
-        seed = jnp.full((lcfg.frontier,), NO_NODE, I32).at[0].set(boot)
-        st = dataclasses.replace(st, lk=lk_mod.start(
-            st.lk, start_join, slot, P_JOIN, 0, me_key, seed, now_j, lcfg))
-        st = dataclasses.replace(st, t_join=jnp.where(
-            en_j & ~alone_start,
-            now_j + jnp.int64(int(p.join_delay * NS)), st.t_join))
+        with scope("kademlia.join"):
+            # join (joinOverlay: lookup own key via bootstrap,
+            # Kademlia.cc:1027-1081)
+            en_j = (st.state == JOINING) & (st.t_join < t_end)
+            now_j = jnp.maximum(st.t_join, t0)
+            boot = ctx.sample_ready(rngs[1], node_idx)
+            no_join_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_JOIN))
+            alone_start = en_j & (boot == NO_NODE)
+            st = self._become_ready(ctx, st, alone_start, now_j, rngs[2])
+            joins_cnt += alone_start.astype(I32)
+            slot, have = lk_mod.free_slot(st.lk)
+            start_join = en_j & (boot != NO_NODE) & no_join_lk & have
+            seed = jnp.full((lcfg.frontier,), NO_NODE, I32).at[0].set(boot)
+            st = dataclasses.replace(st, lk=lk_mod.start(
+                st.lk, start_join, slot, P_JOIN, 0, me_key, seed, now_j, lcfg))
+            st = dataclasses.replace(st, t_join=jnp.where(
+                en_j & ~alone_start,
+                now_j + jnp.int64(int(p.join_delay * NS)), st.t_join))
 
-        # periodic refresh tick: mark stale buckets dirty + sibling refresh
-        en_r = (st.state == READY) & (st.t_refresh < t_end)
-        now_r = jnp.maximum(st.t_refresh, t0)
-        refresh_ns = jnp.int64(int(p.bucket_refresh * NS))
-        # only buckets for prefixes we can actually populate: any bucket
-        # whose index <= index of the furthest sibling (reference refreshes
-        # buckets up to routingBucketIndex(siblingTable->back()),
-        # Kademlia.cc:1591 area)
-        far_sib = st.sib[-1]
-        has_sib = far_sib != NO_NODE
-        max_bi = jnp.where(
-            has_sib,
-            self._bucket_index(me_key, ctx.keys[jnp.maximum(far_sib, 0)]),
-            -1)
-        bi_range = jnp.arange(p.num_buckets, dtype=I32)
-        stale_bucket = st.b_used + refresh_ns < now_r
-        mark = en_r & (bi_range <= max_bi) & stale_bucket
-        st = dataclasses.replace(
-            st,
-            refresh_dirty=st.refresh_dirty | mark,
-            t_refresh=jnp.where(en_r, now_r + refresh_ns, st.t_refresh))
-        # sibling-table refresh timing: lookup own key when unused for the
-        # interval (start fires below, after the batched findNode)
-        sib_stale = en_r & (st.sib_used + jnp.int64(
-            int(p.sibling_refresh * NS)) < now_r)
+        with scope("kademlia.refresh"):
+            # periodic refresh tick: mark stale buckets dirty + sibling refresh
+            en_r = (st.state == READY) & (st.t_refresh < t_end)
+            now_r = jnp.maximum(st.t_refresh, t0)
+            refresh_ns = jnp.int64(int(p.bucket_refresh * NS))
+            # only buckets for prefixes we can actually populate: any bucket
+            # whose index <= index of the furthest sibling (reference refreshes
+            # buckets up to routingBucketIndex(siblingTable->back()),
+            # Kademlia.cc:1591 area)
+            far_sib = st.sib[-1]
+            has_sib = far_sib != NO_NODE
+            max_bi = jnp.where(
+                has_sib,
+                self._bucket_index(me_key, ctx.keys[jnp.maximum(far_sib, 0)]),
+                -1)
+            bi_range = jnp.arange(p.num_buckets, dtype=I32)
+            stale_bucket = st.b_used + refresh_ns < now_r
+            mark = en_r & (bi_range <= max_bi) & stale_bucket
+            st = dataclasses.replace(
+                st,
+                refresh_dirty=st.refresh_dirty | mark,
+                t_refresh=jnp.where(en_r, now_r + refresh_ns, st.t_refresh))
+            # sibling-table refresh timing: lookup own key when unused for the
+            # interval (start fires below, after the batched findNode)
+            sib_stale = en_r & (st.sib_used + jnp.int64(
+                int(p.sibling_refresh * NS)) < now_r)
 
-        # ----------------------------------------- maintenance pings ----
-        # ping timeouts: unresponsive pinged nodes are failures
-        ping_exp = (st.ping_dst != NO_NODE) & (st.ping_to < t_end)
-        ping_failed = jnp.where(ping_exp, st.ping_dst, NO_NODE)   # [Pp]
-        st = dataclasses.replace(
-            st,
-            ping_dst=jnp.where(ping_exp, NO_NODE, st.ping_dst),
-            ping_to=jnp.where(ping_exp, T_INF, st.ping_to))
+        with scope("kademlia.pings"):
+            # ----------------------------------------- maintenance pings ----
+            # ping timeouts: unresponsive pinged nodes are failures
+            ping_exp = (st.ping_dst != NO_NODE) & (st.ping_to < t_end)
+            ping_failed = jnp.where(ping_exp, st.ping_dst, NO_NODE)   # [Pp]
+            st = dataclasses.replace(
+                st,
+                ping_dst=jnp.where(ping_exp, NO_NODE, st.ping_dst),
+                ping_to=jnp.where(ping_exp, T_INF, st.ping_to))
 
-        # bucket-ping timer (bucketPingInterval): probe the oldest-seen
-        # routing-table entry so silent deaths surface between refreshes
-        if p.bucket_ping_interval > 0:
-            en_bp = (st.state == READY) & (st.t_bping < t_end)
-            now_bp = jnp.maximum(st.t_bping, t0)
-            seen_all = jnp.where(st.buckets != NO_NODE, st.b_seen, T_INF)
-            flat_bp = jnp.argmin(seen_all.reshape(-1))
-            bp_cand = jnp.where(en_bp, st.buckets.reshape(-1)[flat_bp],
-                                NO_NODE)
-            st = dataclasses.replace(st, t_bping=jnp.where(
-                en_bp,
-                now_bp + jnp.int64(int(p.bucket_ping_interval * NS)),
-                st.t_bping))
-        else:
-            bp_cand = NO_NODE
+            # bucket-ping timer (bucketPingInterval): probe the oldest-seen
+            # routing-table entry so silent deaths surface between refreshes
+            if p.bucket_ping_interval > 0:
+                en_bp = (st.state == READY) & (st.t_bping < t_end)
+                now_bp = jnp.maximum(st.t_bping, t0)
+                seen_all = jnp.where(st.buckets != NO_NODE, st.b_seen, T_INF)
+                flat_bp = jnp.argmin(seen_all.reshape(-1))
+                bp_cand = jnp.where(en_bp, st.buckets.reshape(-1)[flat_bp],
+                                    NO_NODE)
+                st = dataclasses.replace(st, t_bping=jnp.where(
+                    en_bp,
+                    now_bp + jnp.int64(int(p.bucket_ping_interval * NS)),
+                    st.t_bping))
+            else:
+                bp_cand = NO_NODE
 
-        # queue this tick's ping candidates (downlist verifications, the
-        # replacement-cache ping, the bucket ping) into free ping slots —
-        # the same rank trick as route.forward_batch; overflow lanes drop
-        # (retried next downlist/interval)
-        ping_cands = jnp.concatenate(
-            [dl_cands,
-             jnp.stack([jnp.asarray(rc_ping, I32),
-                        jnp.asarray(bp_cand, I32)])])            # [R+2]
-        # skip nodes already being pinged
-        dup_p = jnp.any(
-            ping_cands[:, None] == st.ping_dst[None, :], axis=1)
-        ping_cands = jnp.where(dup_p | K.dup_mask(ping_cands), NO_NODE,
-                               ping_cands)
-        en_p = ping_cands != NO_NODE
-        lane_rank = jnp.cumsum(en_p.astype(I32)) - 1
-        free_p = st.ping_dst == NO_NODE
-        slot_rank = jnp.cumsum(free_p.astype(I32)) - 1
-        n_free_p = jnp.sum(free_p.astype(I32))
-        pp = p.ping_slots
-        slot_of_rank = jnp.full((pp,), pp, I32).at[
-            jnp.where(free_p, slot_rank, pp)].set(
-            jnp.arange(pp, dtype=I32), mode="drop")
-        lane_slot = jnp.where(
-            en_p & (lane_rank < n_free_p),
-            slot_of_rank[jnp.clip(lane_rank, 0, pp - 1)], pp)
-        sent_p = lane_slot < pp
-        ob.send(sent_p, t0, ping_cands, wire.KAD_PING_CALL,
-                size_b=wire.BASE_CALL_B)
-        # a ping takes a FREE slot and every ping of the tick the same
-        # timeout, so the i64 ``ping_to`` is written by mask, not by a
-        # second (64-bit) scatter: the slots that were free and hold a
-        # node now
-        ping_dst = st.ping_dst.at[lane_slot].set(ping_cands, mode="drop")
-        st = dataclasses.replace(
-            st,
-            ping_dst=ping_dst,
-            ping_to=jnp.where(
-                free_p & (ping_dst != NO_NODE),
-                t0 + jnp.int64(int(p.rpc_timeout * NS)), st.ping_to))
+            # queue this tick's ping candidates (downlist verifications, the
+            # replacement-cache ping, the bucket ping) into free ping slots —
+            # the same rank trick as route.forward_batch; overflow lanes drop
+            # (retried next downlist/interval)
+            ping_cands = jnp.concatenate(
+                [dl_cands,
+                 jnp.stack([jnp.asarray(rc_ping, I32),
+                            jnp.asarray(bp_cand, I32)])])            # [R+2]
+            # skip nodes already being pinged
+            dup_p = jnp.any(
+                ping_cands[:, None] == st.ping_dst[None, :], axis=1)
+            ping_cands = jnp.where(dup_p | K.dup_mask(ping_cands), NO_NODE,
+                                   ping_cands)
+            en_p = ping_cands != NO_NODE
+            lane_rank = jnp.cumsum(en_p.astype(I32)) - 1
+            free_p = st.ping_dst == NO_NODE
+            slot_rank = jnp.cumsum(free_p.astype(I32)) - 1
+            n_free_p = jnp.sum(free_p.astype(I32))
+            pp = p.ping_slots
+            slot_of_rank = jnp.full((pp,), pp, I32).at[
+                jnp.where(free_p, slot_rank, pp)].set(
+                jnp.arange(pp, dtype=I32), mode="drop")
+            lane_slot = jnp.where(
+                en_p & (lane_rank < n_free_p),
+                slot_of_rank[jnp.clip(lane_rank, 0, pp - 1)], pp)
+            sent_p = lane_slot < pp
+            ob.send(sent_p, t0, ping_cands, wire.KAD_PING_CALL,
+                    size_b=wire.BASE_CALL_B)
+            # a ping takes a FREE slot and every ping of the tick the same
+            # timeout, so the i64 ``ping_to`` is written by mask, not by a
+            # second (64-bit) scatter: the slots that were free and hold a
+            # node now
+            ping_dst = st.ping_dst.at[lane_slot].set(ping_cands, mode="drop")
+            st = dataclasses.replace(
+                st,
+                ping_dst=ping_dst,
+                ping_to=jnp.where(
+                    free_p & (ping_dst != NO_NODE),
+                    t0 + jnp.int64(int(p.rpc_timeout * NS)), st.ping_to))
 
         # app timer
         # graceful-leave: hand app data to the closest sibling and stop
@@ -926,15 +935,16 @@ class KademliaLogic:
         app, req = self.app.on_timer(st.app, en_a, ctx, now_a, rngs[3], ev, node_idx)
         st = dataclasses.replace(st, app=app)
 
-        # bucket-refresh target (pump below): random key with
-        # sharedPrefixLength(me, target) == bi —
-        # delta = 2^(bits-1-bi) | (rand & (2^(bits-1-bi) - 1)); target=me^delta
-        bi_ref = jnp.argmax(st.refresh_dirty).astype(I32)
-        jbit = jnp.clip(spec.bits - 1 - bi_ref, 0, spec.bits - 1)
-        top = self._pow2[jbit]
-        mask = K.sub(top, K.from_int(1, spec), spec)
-        rnd = K.random_keys(rngs[5], (), spec)
-        target_ref = me_key ^ (top | (rnd & mask))
+        with scope("kademlia.refresh"):
+            # bucket-refresh target (pump below): random key with
+            # sharedPrefixLength(me, target) == bi —
+            # delta = 2^(bits-1-bi) | (rand & (2^(bits-1-bi) - 1)); target=me^delta
+            bi_ref = jnp.argmax(st.refresh_dirty).astype(I32)
+            jbit = jnp.clip(spec.bits - 1 - bi_ref, 0, spec.bits - 1)
+            top = self._pow2[jbit]
+            mask = K.sub(top, K.from_int(1, spec), spec)
+            rnd = K.random_keys(rngs[5], (), spec)
+            target_ref = me_key ^ (top | (rnd & mask))
 
         # ONE batched findNode for every timer consumer: sibling refresh
         # (own key), the app lookup seed, and the bucket-refresh seed
@@ -944,15 +954,16 @@ class KademliaLogic:
         res0, seed_a, seed_r = seeds3[0], seeds3[1], seeds3[2]
         sib_a = sib3[1]
 
-        # sibling refresh start
-        no_sib_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_SIB))
-        slot, have = lk_mod.free_slot(st.lk)
-        start_sib = sib_stale & no_sib_lk & have & (res0[0] != NO_NODE)
-        st = dataclasses.replace(st, lk=lk_mod.start(
-            st.lk, start_sib, slot, P_SIB, 0, me_key,
-            res0[:lcfg.frontier], now_r, lcfg))
-        st = dataclasses.replace(
-            st, sib_used=jnp.where(start_sib, now_r, st.sib_used))
+        with scope("kademlia.refresh"):
+            # sibling refresh start
+            no_sib_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_SIB))
+            slot, have = lk_mod.free_slot(st.lk)
+            start_sib = sib_stale & no_sib_lk & have & (res0[0] != NO_NODE)
+            st = dataclasses.replace(st, lk=lk_mod.start(
+                st.lk, start_sib, slot, P_SIB, 0, me_key,
+                res0[:lcfg.frontier], now_r, lcfg))
+            st = dataclasses.replace(
+                st, sib_used=jnp.where(start_sib, now_r, st.sib_used))
         # local responsibility → full sibling set (top-s of self ∪
         # siblings by XOR distance to the key), matching the responder-side
         # FINDNODE_RES payload so numReplica consumers get the replica set
@@ -1055,38 +1066,40 @@ class KademliaLogic:
         lksucc_cnt += jnp.sum((taken & suc_l).astype(I32))
         anyfail_cnt += jnp.sum((taken & ~suc_l).astype(I32))
 
-        # join completion → READY.  The reference becomes READY whenever
-        # the sibling table is non-empty (lookupFinished,
-        # Kademlia.cc:1543) — but its join lookup is exhaustive enough
-        # that the table then holds the true closest set.  A node going
-        # READY off a 1-2 entry table claims siblinghood for keys it
-        # does not own (isSiblingFor: not-full tables accept broadly,
-        # Kademlia.cc:888) and black-holes DHT traffic, so the
-        # vectorized build requires a SUCCESSFUL own-key lookup or a
-        # half-full sibling table before serving.
-        # At most one join lookup exists per node (no_join_lk gate above).
-        enj = taken & (pur_l == P_JOIN)
-        any_j = jnp.any(enj)
-        n_sib_j = jnp.sum((st.sib != NO_NODE).astype(I32))
-        got = any_j & (jnp.any(enj & suc_l)
-                       | (n_sib_j >= min(p.s, 4)))
-        joins_cnt += got.astype(I32)
-        st = self._become_ready(ctx, st, got, t0, rngs[4])
-        # join failed with nothing learned → retry via t_join
-        st = dataclasses.replace(st, t_join=jnp.where(
-            any_j & ~got, t0 + jnp.int64(int(p.join_delay * NS)),
-            st.t_join))
+        with scope("kademlia.join"):
+            # join completion → READY.  The reference becomes READY whenever
+            # the sibling table is non-empty (lookupFinished,
+            # Kademlia.cc:1543) — but its join lookup is exhaustive enough
+            # that the table then holds the true closest set.  A node going
+            # READY off a 1-2 entry table claims siblinghood for keys it
+            # does not own (isSiblingFor: not-full tables accept broadly,
+            # Kademlia.cc:888) and black-holes DHT traffic, so the
+            # vectorized build requires a SUCCESSFUL own-key lookup or a
+            # half-full sibling table before serving.
+            # At most one join lookup exists per node (no_join_lk gate above).
+            enj = taken & (pur_l == P_JOIN)
+            any_j = jnp.any(enj)
+            n_sib_j = jnp.sum((st.sib != NO_NODE).astype(I32))
+            got = any_j & (jnp.any(enj & suc_l)
+                           | (n_sib_j >= min(p.s, 4)))
+            joins_cnt += got.astype(I32)
+            st = self._become_ready(ctx, st, got, t0, rngs[4])
+            # join failed with nothing learned → retry via t_join
+            st = dataclasses.replace(st, t_join=jnp.where(
+                any_j & ~got, t0 + jnp.int64(int(p.join_delay * NS)),
+                st.t_join))
 
-        # bucket refresh completions → clear dirty bits (one scatter)
-        enr_l = taken & (pur_l == P_REFRESH)
-        rows_r = jnp.where(enr_l, jnp.clip(comp["aux"], 0,
-                                           p.num_buckets - 1),
-                           p.num_buckets)
-        st = dataclasses.replace(
-            st,
-            refresh_dirty=st.refresh_dirty.at[rows_r].set(
-                False, mode="drop"),
-            b_used=st.b_used.at[rows_r].set(t0, mode="drop"))
+        with scope("kademlia.refresh"):
+            # bucket refresh completions → clear dirty bits (one scatter)
+            enr_l = taken & (pur_l == P_REFRESH)
+            rows_r = jnp.where(enr_l, jnp.clip(comp["aux"], 0,
+                                               p.num_buckets - 1),
+                               p.num_buckets)
+            st = dataclasses.replace(
+                st,
+                refresh_dirty=st.refresh_dirty.at[rows_r].set(
+                    False, mode="drop"),
+                b_used=st.b_used.at[rows_r].set(t0, mode="drop"))
 
         # app lookups → app completion hook (batched when the app
         # supports it; per-slot fold otherwise)
@@ -1108,24 +1121,25 @@ class KademliaLogic:
                         t0=comp["t0"][li]),
                     ctx, ob, ev, t0, node_idx))
 
-        # ------------------------------------------- bucket refresh pump ---
-        # target/seed were computed in the batched findNode above; gate on
-        # the POST-completion dirty bit so a bucket whose refresh just
-        # finished is not immediately re-queried
-        dirty_now = st.refresh_dirty[jnp.minimum(bi_ref, p.num_buckets - 1)]
-        dirty_any = (st.state == READY) & dirty_now
-        no_ref_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_REFRESH))
-        slot, have = lk_mod.free_slot(st.lk)
-        start_ref = dirty_any & no_ref_lk & have & (seed_r[0] != NO_NODE)
-        # no candidates at all → just clear the bit
-        clear_only = dirty_any & no_ref_lk & (seed_r[0] == NO_NODE)
-        st = dataclasses.replace(
-            st,
-            refresh_dirty=jnp.where(clear_only,
-                                    st.refresh_dirty.at[bi_ref].set(False),
-                                    st.refresh_dirty),
-            lk=lk_mod.start(st.lk, start_ref, slot, P_REFRESH, bi_ref,
-                            target_ref, seed_r[:lcfg.frontier], t0, lcfg))
+        with scope("kademlia.refresh"):
+            # ------------------------------------------- bucket refresh pump ---
+            # target/seed were computed in the batched findNode above; gate on
+            # the POST-completion dirty bit so a bucket whose refresh just
+            # finished is not immediately re-queried
+            dirty_now = st.refresh_dirty[jnp.minimum(bi_ref, p.num_buckets - 1)]
+            dirty_any = (st.state == READY) & dirty_now
+            no_ref_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_REFRESH))
+            slot, have = lk_mod.free_slot(st.lk)
+            start_ref = dirty_any & no_ref_lk & have & (seed_r[0] != NO_NODE)
+            # no candidates at all → just clear the bit
+            clear_only = dirty_any & no_ref_lk & (seed_r[0] == NO_NODE)
+            st = dataclasses.replace(
+                st,
+                refresh_dirty=jnp.where(clear_only,
+                                        st.refresh_dirty.at[bi_ref].set(False),
+                                        st.refresh_dirty),
+                lk=lk_mod.start(st.lk, start_ref, slot, P_REFRESH, bi_ref,
+                                target_ref, seed_r[:lcfg.frontier], t0, lcfg))
 
         # ------------------------------------------------------- pump ------
         # adaptive per-destination RPC timeouts from the RTT cache

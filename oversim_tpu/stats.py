@@ -19,6 +19,7 @@ import dataclasses
 import math
 
 import jax.numpy as jnp
+from oversim_tpu.core.scopes import scoped
 
 F32 = jnp.float32
 F64 = jnp.float64  # accumulators: f32 would silently drop increments >2^24
@@ -45,6 +46,7 @@ def init_stats(spec: StatSpec) -> dict:
     return s
 
 
+@scoped("stats.record")
 def record(stats: dict, events: dict, gate) -> dict:
     """Fold one tick's events into the accumulators.
 

@@ -40,8 +40,8 @@ Host-side exporters (all dependency-free):
     recorder.py's writer (native vecwriter.c or the byte-identical
     Python fallback);
   * ``PerfettoTrace`` — Chrome-trace/Perfetto JSON (``traceEvents``)
-    for bench window dispatch/fetch spans, profiling.py per-tick phase
-    breakdowns (``add_profile``) and KPI counter tracks
+    for bench window dispatch/fetch spans, per-tick phase breakdowns
+    (``add_profile``) and KPI counter tracks
     (``add_series``); load in ui.perfetto.dev or chrome://tracing;
   * ``run_manifest`` — the unified RunManifest (config hash, mesh/
     sharding layout, HLO op-budget results, git rev, artifact paths)
@@ -345,10 +345,12 @@ class PerfettoTrace:
                             "args": {name: float(value)}})
 
     def add_profile(self, report: dict, *, t0_s: float = 0.0, tid=1):
-        """Lay a profiling.py report's per-tick phase durations out as
-        back-to-back spans (one track per call).  Uses the per-tick
-        ``phase_ticks_ms`` list when present, else one averaged tick
-        from ``phase_ms_per_tick``."""
+        """Lay per-tick phase durations out as back-to-back spans (one
+        track per call): ``report["phase_ticks_ms"]``, a list of
+        {phase: ms} dicts, one a tick, when present, else one averaged
+        tick from ``report["phase_ms_per_tick"]`` (the last line of
+        ``benchmark/phases.py`` carries one: a device trace reduced by
+        the tick program's named scopes)."""
         ticks = report.get("phase_ticks_ms")
         if not ticks:
             avg = report.get("phase_ms_per_tick")

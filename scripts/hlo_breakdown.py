@@ -26,9 +26,8 @@ Usage:
 
 The counting helpers (``hlo_op_counts`` / ``check_budget`` /
 ``check_telemetry_budget``) live in oversim_tpu/analysis/hlo_text.py and
-are re-exported here for back-compat (tests/test_hlo_budget.py,
-tests/test_engine.py, oversim_tpu/profiling.py import them from this
-module); both homes are import-safe (no jax at module level).
+are re-exported here for back-compat (tests/test_hlo_budget.py and
+tests/test_engine.py import them from this module); both homes are import-safe (no jax at module level).
 """
 
 import collections

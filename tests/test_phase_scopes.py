@@ -1,0 +1,150 @@
+"""The tick program names its phases (oversim_tpu/core/scopes.py; ISSUE 41).
+
+Read off the tick's jaxpr: ``jax.make_jaxpr(sim.step)`` and each
+equation's name stack, the sub-jaxprs of ``while``, ``cond``, ``scan``
+and ``pjit`` walked with their caller's stack in front.  Trace only:
+nothing is lowered, nothing compiled, nothing run but ``sim.init``.
+"""
+
+import os
+import re
+
+import jax
+import pytest
+
+from oversim_tpu import churn as churn_mod
+from oversim_tpu import hostcache
+from oversim_tpu.apps.kbrtest import KbrTestApp, KbrTestParams
+from oversim_tpu.core import scopes
+from oversim_tpu.engine.sim import EngineParams, Simulation
+
+N = 32
+CASES = [("kademlia", "dense"), ("kademlia", "sparse"),
+         ("chord", "dense"), ("chord", "sparse")]
+
+
+def _sim(overlay, tick_impl):
+    app = KbrTestApp(KbrTestParams(test_interval=6.0))
+    if overlay == "chord":
+        from oversim_tpu.overlay.chord import ChordLogic
+        logic = ChordLogic(app=app)
+    else:
+        from oversim_tpu.overlay.kademlia import KademliaLogic
+        logic = KademliaLogic(app=app)
+    cp = churn_mod.ChurnParams(model="lifetime", target_num=N,
+                               init_interval=0.2,
+                               lifetime_mean=60.0)
+    ep = EngineParams(window=0.1, inbox_slots=2, pool_factor=4,
+                      tick_impl=tick_impl)
+    return Simulation(logic, cp, engine_params=ep)
+
+
+def _subjaxprs(eqn):
+    for value in eqn.params.values():
+        for v in (value if isinstance(value, (tuple, list)) else (value,)):
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def scope_paths(jaxpr, above=()):
+    """Every equation of ``jaxpr`` and of what it calls as ``(primitive,
+    names)``: the ``jax.named_scope`` names it lies under, outermost
+    first (a sub-jaxpr's stacks are relative to the equation that holds
+    it).  Transforms (``vmap``, ``jit``) are no names."""
+    for eqn in jaxpr.eqns:
+        names = above + tuple(
+            e.name for e in eqn.source_info.name_stack.stack
+            if type(e).__name__ == "Scope")
+        yield eqn.primitive.name, names
+        for inner in _subjaxprs(eqn):
+            yield from scope_paths(inner, names)
+
+
+@pytest.fixture(scope="module", params=CASES, ids=lambda c: "-".join(c))
+def tick(request):
+    overlay, tick_impl = request.param
+    sim = _sim(overlay, tick_impl)
+    state = jax.eval_shape(sim.init_from_rng, jax.random.PRNGKey(1))
+    paths = list(scope_paths(jax.make_jaxpr(sim.step)(state).jaxpr))
+    assert len(paths) > 1000
+    return sim, paths
+
+
+def test_every_equation_lies_under_exactly_one_phase(tick):
+    _, paths = tick
+    bad = [(prim, names) for prim, names in paths
+           if sum(n.startswith("phase.") for n in names) != 1]
+    assert not bad, f"{len(bad)} of {len(paths)}: {bad[:5]}"
+    assert all(names[0].startswith("phase.") for _, names in paths)
+
+
+def test_the_phases_met_are_the_planes_own(tick):
+    sim, paths = tick
+    met = {names[0] for _, names in paths}
+    assert met == set(scopes.phases_for(sim.tick_impl))
+    assert scopes.phases_for("sparse") == scopes.PHASES_SPARSE
+    assert scopes.phases_for("dense") == scopes.PHASES
+
+
+def test_every_name_met_is_registered_under_its_phase(tick):
+    _, paths = tick
+    for _, names in paths:
+        for name in names[1:]:
+            assert name in scopes.PARTS[names[0]], (names[0], name)
+
+
+def test_the_overlays_parts_are_met(tick):
+    sim, paths = tick
+    met = {n for _, names in paths for n in names[1:]}
+    overlay = type(sim.logic).__name__.removesuffix("Logic").lower()
+    own = {p for p in scopes.PARTS["phase.node_step"]
+           if p.startswith(overlay + ".")}
+    assert own and own <= met, own - met
+    other = "chord." if overlay == "kademlia" else "kademlia."
+    assert not [n for n in met if n.startswith(other)]
+    for part in ("lookup.responses", "lookup.timeouts", "lookup.pump",
+                 "app.kbrtest", "pool.alloc", "stats.record", "churn.step"):
+        assert part in met, part
+    if sim.tick_impl == "sparse":
+        assert {"step.gather", "step.write_back", "inbox.rank"} <= met
+
+
+def test_no_registered_name_holds_scatter():
+    # analysis/hlo_text._SCATTER_WHILE tells a scatter's own loop by
+    # ``/scatter`` in its op_name
+    assert scopes.REGISTRY
+    assert not [n for n in scopes.REGISTRY if "scatter" in n]
+    every = set(scopes.PHASES + scopes.PHASES_SPARSE)
+    assert set(scopes.PARTS) <= every
+    for parts in scopes.PARTS.values():
+        assert not every & set(parts)
+
+
+def test_an_unregistered_name_is_refused():
+    with pytest.raises(KeyError, match="phase.nowhere"):
+        scopes.scope("phase.nowhere")
+    with pytest.raises(KeyError):
+        scopes.scoped("kademlia.nothing")
+
+
+def test_the_compile_cache_keys_by_metadata(tmp_path, monkeypatch):
+    """An executable out of the cache otherwise carries the scopes of
+    the tree that first compiled it (hostcache.enable)."""
+    flags = ("jax_compilation_cache_include_metadata_in_key",
+             "jax_hlo_source_file_canonicalization_regex",
+             "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs")
+    before = {f: getattr(jax.config, f) for f in flags}
+    monkeypatch.setenv(hostcache.CACHE_ENV, str(tmp_path))
+    try:
+        assert hostcache.enable(persistent=True) == str(tmp_path)
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        # and not by where the checkout lives
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert re.sub(jax.config.jax_hlo_source_file_canonicalization_regex,
+                      "", hostcache.__file__) == "oversim_tpu/hostcache.py"
+        assert hostcache.__file__.startswith(root)
+    finally:
+        for f, v in before.items():
+            jax.config.update(f, v)
