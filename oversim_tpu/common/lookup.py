@@ -45,6 +45,17 @@ whole per-node step); the L lookup slots of one node are a static axis.
 A lookup completion is recorded in the ``done/success/result`` fields and
 consumed by the owner (overlay logic) via ``take_completions`` — purpose
 dispatch (join / finger repair / app route) lives with the owner.
+
+The rule for writes: a write whose index is a lookup slot of the node
+(or a slot and an RPC, frontier or visited column of it) is a SELECT
+over the whole leaf by a one-hot mask (``_slot_mask``, ``_put``), never
+``leaf.at[slot].set``.  Under the node step's ``vmap`` an indexed write
+is one scatter of A updates a leaf, and on the chip a scatter costs by
+its updates, the dropped ones too: 52 to 66 ns each, so ``start``'s 27
+leaves at four call sites were 108 operations of 7.5 us in a tick of
+N=4096 whether or not one lookup started (PERF.md, PR 41 and PR 42).  A
+leaf is [L] or [L, <=16] with L <= 8: the select reads a few hundred
+words a lane and fuses with its neighbours.
 """
 
 from __future__ import annotations
@@ -206,6 +217,27 @@ def num_free(lk: LookupState):
     return jnp.sum((~lk.active).astype(I32))
 
 
+def _slot_mask(l_dim: int, slot, en):
+    """[L] bool, one-hot at ``slot`` where ``en``; a ``slot`` outside
+    [0, L) selects nothing."""
+    return (jnp.arange(l_dim, dtype=I32) == slot) & en
+
+
+def _col_mask(rows, width: int, col):
+    """[L, width] bool: row ``l`` one-hot at ``col[l]`` (or at the one
+    ``col`` of all rows) where ``rows[l]``."""
+    return rows[:, None] & (
+        jnp.arange(width, dtype=I32) == jnp.reshape(col, (-1, 1)))
+
+
+def _put(old, sel, new):
+    """``old`` with ``new`` where ``sel``: ``sel`` covers ``old``'s
+    leading axes ([L] or [L, C]) and ``new`` broadcasts against
+    ``old``."""
+    sel = sel.reshape(sel.shape + (1,) * (old.ndim - sel.ndim))
+    return jnp.where(sel, jnp.asarray(new, old.dtype), old)
+
+
 @scoped("lookup.start")
 def start(lk: LookupState, en, slot, purpose, aux, target, seed_nodes,
           now, cfg: LookupConfig, ext=None) -> LookupState:
@@ -217,48 +249,42 @@ def start(lk: LookupState, en, slot, purpose, aux, target, seed_nodes,
     lookup will fail at the next pump (reference: empty local findNode →
     path fails).
     """
-    f = lk.frontier.shape[1]
-    r = lk.pending_dst.shape[1]
-    slot = jnp.where(en, slot, jnp.int32(lk.active.shape[0]))  # OOB drop
-    seed = seed_nodes[:f]
+    l_dim, f = lk.frontier.shape
+    sel = _slot_mask(l_dim, slot, en)
+
+    def put(old, new):
+        return _put(old, sel, new)
+
     return dataclasses.replace(
         lk,
-        active=lk.active.at[slot].set(True, mode="drop"),
-        purpose=lk.purpose.at[slot].set(jnp.asarray(purpose, I32), mode="drop"),
-        aux=lk.aux.at[slot].set(jnp.asarray(aux, I32), mode="drop"),
-        target=lk.target.at[slot].set(target, mode="drop"),
-        gen=lk.gen.at[slot].add(1, mode="drop"),
-        frontier=lk.frontier.at[slot].set(seed, mode="drop"),
-        fr_flags=lk.fr_flags.at[slot].set(jnp.full((f,), F_NEW, I32),
-                                          mode="drop"),
-        fr_src=lk.fr_src.at[slot].set(
-            jnp.full((f,), NO_NODE, I32), mode="drop"),
-        visited=lk.visited.at[slot].set(
-            jnp.full((lk.visited.shape[1],), NO_NODE, I32), mode="drop"),
-        vis_n=lk.vis_n.at[slot].set(0, mode="drop"),
-        pending_dst=lk.pending_dst.at[slot].set(
-            jnp.full((r,), NO_NODE, I32), mode="drop"),
-        pend_prov=lk.pend_prov.at[slot].set(
-            jnp.full((r,), NO_NODE, I32), mode="drop"),
-        t_sent=lk.t_sent.at[slot].set(jnp.zeros((r,), I64), mode="drop"),
-        t_to=lk.t_to.at[slot].set(jnp.full((r,), T_INF, I64), mode="drop"),
-        retry=lk.retry.at[slot].set(jnp.zeros((r,), I32), mode="drop"),
-        refire=lk.refire.at[slot].set(jnp.zeros((r,), bool), mode="drop"),
-        deadline=lk.deadline.at[slot].set(now + cfg.deadline_ns, mode="drop"),
-        hops=lk.hops.at[slot].set(0, mode="drop"),
-        t0=lk.t0.at[slot].set(now, mode="drop"),
-        done=lk.done.at[slot].set(False, mode="drop"),
-        success=lk.success.at[slot].set(False, mode="drop"),
-        result=lk.result.at[slot].set(NO_NODE, mode="drop"),
-        results=lk.results.at[slot].set(
-            jnp.full((f,), NO_NODE, I32), mode="drop"),
-        res_n=lk.res_n.at[slot].set(0, mode="drop"),
-        t_done=lk.t_done.at[slot].set(T_INF, mode="drop"),
-        ext=lk.ext.at[slot].set(
-            jnp.zeros((cfg.ext_words,), I32) if ext is None else ext,
-            mode="drop"),
-        ver_dst=lk.ver_dst.at[slot].set(NO_NODE, mode="drop"),
-        ver_to=lk.ver_to.at[slot].set(T_INF, mode="drop"),
+        active=lk.active | sel,
+        purpose=put(lk.purpose, purpose),
+        aux=put(lk.aux, aux),
+        target=put(lk.target, target),
+        gen=lk.gen + sel.astype(I32),
+        frontier=put(lk.frontier, seed_nodes[:f]),
+        fr_flags=put(lk.fr_flags, F_NEW),
+        fr_src=put(lk.fr_src, NO_NODE),
+        visited=put(lk.visited, NO_NODE),
+        vis_n=put(lk.vis_n, 0),
+        pending_dst=put(lk.pending_dst, NO_NODE),
+        pend_prov=put(lk.pend_prov, NO_NODE),
+        t_sent=put(lk.t_sent, 0),
+        t_to=put(lk.t_to, T_INF),
+        retry=put(lk.retry, 0),
+        refire=put(lk.refire, False),
+        deadline=put(lk.deadline, now + cfg.deadline_ns),
+        hops=put(lk.hops, 0),
+        t0=put(lk.t0, now),
+        done=put(lk.done, False),
+        success=put(lk.success, False),
+        result=put(lk.result, NO_NODE),
+        results=put(lk.results, NO_NODE),
+        res_n=put(lk.res_n, 0),
+        t_done=put(lk.t_done, T_INF),
+        ext=put(lk.ext, 0 if ext is None else ext),
+        ver_dst=put(lk.ver_dst, NO_NODE),
+        ver_to=put(lk.ver_to, T_INF),
     )
 
 
@@ -296,40 +322,39 @@ def on_response(lk: LookupState, msg, metric_fn, cfg: LookupConfig):
     is_sib = (msg.c != 0) & has_nodes
 
     # clear the matched pending RPC; count the hop (IterativeLookup.cc:825)
-    row = jnp.where(ok, l, l_dim)
+    at_ok = _slot_mask(l_dim, l, ok)                            # [L]
+    hit = _col_mask(at_ok, match.shape[0], j)                   # [L, R]
     lk = dataclasses.replace(
         lk,
-        pending_dst=lk.pending_dst.at[row, j].set(NO_NODE, mode="drop"),
-        t_to=lk.t_to.at[row, j].set(T_INF, mode="drop"),
-        retry=lk.retry.at[row, j].set(0, mode="drop"),
-        refire=lk.refire.at[row, j].set(False, mode="drop"),
-        hops=lk.hops.at[row].add(1, mode="drop"))
+        pending_dst=_put(lk.pending_dst, hit, NO_NODE),
+        t_to=_put(lk.t_to, hit, T_INF),
+        retry=_put(lk.retry, hit, 0),
+        refire=_put(lk.refire, hit, False),
+        hops=lk.hops + at_ok.astype(I32))
 
     if cfg.verify_siblings and not cfg.exhaustive:
         # S/Kademlia: stage the head candidate for ping verification
         # instead of completing (IterativeLookup.cc:295-340); pump sends
         # the ping.  The response still merges into the frontier below so
         # a failed verification continues the lookup.
-        fin = ok & is_sib & (lk.ver_dst[l] == NO_NODE)
-        slot_fin = jnp.where(fin, l, l_dim)
+        at_fin = _slot_mask(l_dim, l, ok & is_sib & (lk.ver_dst[l] == NO_NODE))
         lk = dataclasses.replace(
             lk,
-            ver_dst=lk.ver_dst.at[slot_fin].set(resp_nodes[0], mode="drop"),
-            ver_to=lk.ver_to.at[slot_fin].set(T_INF, mode="drop"),
-            result=lk.result.at[slot_fin].set(resp_nodes[0], mode="drop"),
-            results=lk.results.at[slot_fin].set(resp_nodes, mode="drop"))
+            ver_dst=_put(lk.ver_dst, at_fin, resp_nodes[0]),
+            ver_to=_put(lk.ver_to, at_fin, T_INF),
+            result=_put(lk.result, at_fin, resp_nodes[0]),
+            results=_put(lk.results, at_fin, resp_nodes))
         upd = ok
     elif not cfg.exhaustive:
         # finished: responder was a sibling → result = first returned node
-        fin = ok & is_sib
-        slot_fin = jnp.where(fin, l, l_dim)
+        at_fin = _slot_mask(l_dim, l, ok & is_sib)
         lk = dataclasses.replace(
             lk,
-            done=lk.done.at[slot_fin].set(True, mode="drop"),
-            success=lk.success.at[slot_fin].set(True, mode="drop"),
-            result=lk.result.at[slot_fin].set(resp_nodes[0], mode="drop"),
-            results=lk.results.at[slot_fin].set(resp_nodes, mode="drop"),
-            t_done=lk.t_done.at[slot_fin].set(msg.t_deliver, mode="drop"))
+            done=lk.done | at_fin,
+            success=lk.success | at_fin,
+            result=_put(lk.result, at_fin, resp_nodes[0]),
+            results=_put(lk.results, at_fin, resp_nodes),
+            t_done=_put(lk.t_done, at_fin, msg.t_deliver))
         upd = ok & ~is_sib
     else:
         # exhaustive: accumulate the responder's sibling set and keep going
@@ -344,12 +369,12 @@ def on_response(lk: LookupState, msg, metric_fn, cfg: LookupConfig):
         sdist = jnp.where(dup[:, None], jnp.uint32(0xFFFFFFFF), sdist)
         _, (packed_full,) = keys_mod.sort_by_distance(sdist, (cur,), approx=True)
         packed = packed_full[:f]
-        slot_acc = jnp.where(acc, l, l_dim)
+        at_acc = _slot_mask(l_dim, l, acc)
         lk = dataclasses.replace(
             lk,
-            results=lk.results.at[slot_acc].set(packed, mode="drop"),
-            res_n=lk.res_n.at[slot_acc].set(
-                jnp.sum(packed != NO_NODE, dtype=I32), mode="drop"))
+            results=_put(lk.results, at_acc, packed),
+            res_n=_put(lk.res_n, at_acc,
+                       jnp.sum(packed != NO_NODE, dtype=I32)))
         upd = ok   # frontier always advances; exhaustion completes the lookup
 
     if cfg.merge:
@@ -382,17 +407,17 @@ def on_response(lk: LookupState, msg, metric_fn, cfg: LookupConfig):
         new_flags = jnp.where(has_nodes, new_flags, lk.fr_flags[l])
         new_src = jnp.where(has_nodes, new_src, lk.fr_src[l])
 
-    slot_upd = jnp.where(upd, l, l_dim)
+    at_upd = _slot_mask(l_dim, l, upd)
     lk = dataclasses.replace(
         lk,
-        frontier=lk.frontier.at[slot_upd].set(new_frontier, mode="drop"),
-        fr_flags=lk.fr_flags.at[slot_upd].set(new_flags, mode="drop"),
-        fr_src=lk.fr_src.at[slot_upd].set(new_src, mode="drop"))
+        frontier=_put(lk.frontier, at_upd, new_frontier),
+        fr_flags=_put(lk.fr_flags, at_upd, new_flags),
+        fr_src=_put(lk.fr_src, at_upd, new_src))
     ew = cfg.ext_words
     if ew:
         # responder-updated extension rides the response tail
-        lk = dataclasses.replace(lk, ext=lk.ext.at[slot_upd].set(
-            msg.nodes[-ew:], mode="drop"))
+        lk = dataclasses.replace(
+            lk, ext=_put(lk.ext, at_upd, msg.nodes[-ew:]))
     return lk
 
 
@@ -428,24 +453,27 @@ def on_responses(lk: LookupState, msgs, metric_fn, cfg: LookupConfig):
     ok = ok & ~jnp.any(same & earlier & ok[None, :], axis=1)
     j = jnp.argmax(match, axis=1).astype(I32)
 
+    def per_slot(pred):
+        """[R] bool → ([L] any, [L] first-r index, [R, L] the mask)."""
+        m_rl = pred[:, None] & (l_r[:, None] == lixs[None, :])
+        return jnp.any(m_rl, axis=0), jnp.argmax(m_rl, axis=0), m_rl
+
     # clear matched pending RPCs; count hops (IterativeLookup.cc:825)
-    rows = jnp.where(ok, l_r, l_dim)
+    _, _, m_ok = per_slot(ok)
+    hit = jnp.any(m_ok[:, :, None] & (
+        j[:, None, None] == jnp.arange(match.shape[1], dtype=I32)),
+        axis=0)                                                 # [L, Rrpc]
     lk = dataclasses.replace(
         lk,
-        pending_dst=lk.pending_dst.at[rows, j].set(NO_NODE, mode="drop"),
-        t_to=lk.t_to.at[rows, j].set(T_INF, mode="drop"),
-        retry=lk.retry.at[rows, j].set(0, mode="drop"),
-        refire=lk.refire.at[rows, j].set(False, mode="drop"),
-        hops=lk.hops.at[rows].add(1, mode="drop"))
+        pending_dst=_put(lk.pending_dst, hit, NO_NODE),
+        t_to=_put(lk.t_to, hit, T_INF),
+        retry=_put(lk.retry, hit, 0),
+        refire=_put(lk.refire, hit, False),
+        hops=lk.hops + jnp.sum(m_ok, axis=0, dtype=I32))
 
     resp_nodes = msgs.nodes[:, :f]                              # [R, F]
     has_nodes = jnp.any(resp_nodes != NO_NODE, axis=1)
     is_sib = (msgs.c != 0) & has_nodes
-
-    def per_slot(pred):
-        """[R] bool → ([L] any, [L] first-r index)."""
-        m_rl = pred[:, None] & (l_r[:, None] == lixs[None, :])
-        return jnp.any(m_rl, axis=0), jnp.argmax(m_rl, axis=0), m_rl
 
     if cfg.verify_siblings and not cfg.exhaustive:
         # S/Kademlia: stage head candidate for ping verification instead
@@ -628,10 +656,9 @@ def on_pongs(lk: LookupState, msgs, cfg: LookupConfig):
     ok = (msgs.valid & lk.active[l_r] & ~lk.done[l_r]
           & (lk.gen[l_r] == msgs.b)
           & (lk.ver_dst[l_r] == msgs.src) & (msgs.src != NO_NODE))
-    fin = jnp.zeros((l_dim,), bool).at[jnp.where(ok, l_r, l_dim)].set(
-        True, mode="drop")
-    win = jnp.zeros((l_dim,), I32).at[jnp.where(ok, l_r, l_dim)].set(
-        jnp.arange(msgs.valid.shape[0], dtype=I32), mode="drop")
+    # the lowest inbox slot wins a slot that two pongs answer
+    m_rl = ok[:, None] & (l_r[:, None] == jnp.arange(l_dim, dtype=I32))
+    fin, win = jnp.any(m_rl, axis=0), jnp.argmax(m_rl, axis=0)
     return dataclasses.replace(
         lk,
         done=lk.done | fin,
@@ -738,18 +765,21 @@ def pump(lk: LookupState, outbox, ctx, node_idx, now, rng,
         idle = lk.active & ~lk.done
         fire = idle & has_cand & has_free & (lk.hops < MAX_HOPS)
 
-        rows = jnp.where(fire, jnp.arange(l_dim, dtype=I32), l_dim)
-        vcol = vis_n % visited.shape[1]
-        visited = visited.at[rows, vcol].set(cand, mode="drop")
+        # a firing row writes ONE column of each leaf
+        v_dim = visited.shape[1]
+        at_rpc = _col_mask(fire, r_dim, col)
+        visited = _put(visited, _col_mask(fire, v_dim, vis_n % v_dim),
+                       cand[:, None])
         vis_n = vis_n + fire.astype(I32)
-        fr_flags = fr_flags.at[rows, first].set(F_PENDING, mode="drop")
-        pending_dst = pending_dst.at[rows, col].set(cand, mode="drop")
-        pend_prov = pend_prov.at[rows, col].set(prov, mode="drop")
-        t_sent_arr = t_sent_arr.at[rows, col].set(now, mode="drop")
+        fr_flags = _put(fr_flags, _col_mask(fire, f, first), F_PENDING)
+        pending_dst = _put(pending_dst, at_rpc, cand[:, None])
+        pend_prov = _put(pend_prov, at_rpc, prov[:, None])
+        t_sent_arr = _put(t_sent_arr, at_rpc, now)
         to_ns = (cfg.rpc_timeout_ns if timeout_fn is None
                  else timeout_fn(cand))
-        t_to = t_to.at[rows, col].set(now + to_ns, mode="drop")
-        retry = retry.at[rows, col].set(0, mode="drop")
+        t_to = _put(t_to, at_rpc,
+                    jnp.broadcast_to(now + to_ns, (l_dim,))[:, None])
+        retry = _put(retry, at_rpc, 0)
         fired_any = fired_any | fire
 
         outbox.send(

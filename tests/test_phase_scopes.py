@@ -110,6 +110,25 @@ def test_the_overlays_parts_are_met(tick):
         assert {"step.gather", "step.write_back", "inbox.rank"} <= met
 
 
+def test_the_lookup_engine_scatters_nothing(tick):
+    """A lookup slot is written by mask (common/lookup.py, ISSUE 42):
+    under the node step's vmap an indexed write is a scatter of A
+    updates a leaf, 108 of them under ``lookup.start`` before."""
+    _, paths = tick
+    under = {}
+    for prim, names in paths:
+        for part in names[1:]:
+            if part.startswith("lookup."):
+                under.setdefault(part, []).append(prim)
+    assert {"lookup.start", "lookup.pump", "lookup.responses"} <= set(under)
+    scatters = {part: [p for p in prims if p.startswith("scatter")]
+                for part, prims in under.items()}
+    assert not any(scatters.values()), scatters
+    # the writes are there, as selects
+    for part in ("lookup.start", "lookup.pump", "lookup.responses"):
+        assert under[part].count("select_n") >= 4, part
+
+
 def test_no_registered_name_holds_scatter():
     # analysis/hlo_text._SCATTER_WHILE tells a scatter's own loop by
     # ``/scatter`` in its op_name
