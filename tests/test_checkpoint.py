@@ -26,16 +26,20 @@ def _make_sim():
 
 def test_roundtrip_and_exact_resume(tmp_path):
     sim = _make_sim()
-    st = sim.init(seed=3)
-    st = sim.run_chunk(st, 150)
+    def run(st, n_chunks):
+        # ONE chunk length: run_chunk compiles again for every length
+        for _ in range(n_chunks):
+            st = sim.run_chunk(st, 50)
+        return st
+
+    st = run(sim.init(seed=3), 3)       # 150 ticks
     path = str(tmp_path / "ck.npz")
     ckpt.save(path, st)
 
-    # continue the original
-    a = sim.run_chunk(st, 100)
+    # continue the original: 100 ticks
+    a = run(st, 2)
     # restore and continue the copy
-    st2 = ckpt.load(path, sim.init(seed=0))
-    b = sim.run_chunk(st2, 100)
+    b = run(ckpt.load(path, sim.init(seed=0)), 2)
 
     import jax
     la, _ = jax.tree.flatten(a)

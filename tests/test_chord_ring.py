@@ -24,6 +24,9 @@ from oversim_tpu.overlay import chord
 from oversim_tpu.overlay.chord import ChordLogic
 
 
+CHUNK = 50     # 10 s of 0.2 s windows: a run ends ON the second it names
+
+
 def _sim(n, interval=0.1):
     logic = ChordLogic(app=KbrTestApp(KbrTestParams(test_interval=60.0)))
     cp = churn_mod.ChurnParams(model="none", target_num=n,
@@ -55,7 +58,7 @@ def sim64():
 
 @pytest.mark.parametrize("seed", [42, 7, 2147483659])
 def test_ring_closes_at_upstreams_fill_n64(sim64, seed):
-    st = sim64.run_until(sim64.init(seed=seed), 40.0, chunk=128)
+    st = sim64.run_until(sim64.init(seed=seed), 40.0, chunk=CHUNK)
     assert ring_faults(st) == (64, 0, 0)
     lost = {k: int(v) for k, v in st.counters.items()
             if k.endswith(("_lost", "_overflow")) and int(v)}
@@ -64,7 +67,7 @@ def test_ring_closes_at_upstreams_fill_n64(sim64, seed):
 
 def test_ring_closes_at_upstreams_fill_n256():
     s = _sim(256)
-    st = s.run_until(s.init(seed=42), 80.0, chunk=128)
+    st = s.run_until(s.init(seed=42), 80.0, chunk=CHUNK)
     assert ring_faults(st) == (256, 0, 0)
 
 

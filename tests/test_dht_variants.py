@@ -28,11 +28,13 @@ def run_variant(variant: str):
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=8,
                                init_interval=1.0)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=20.0,
+    ep = sim_mod.EngineParams(window=0.1, transition_time=20.0,
                               inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=29)
-    st = s.run_until(st, 180.0, chunk=128)
+    # measurement opens at second 28; 92 s and more of one put or get
+    # per node per 20 s from there
+    st = s.run_until(st, 120.0, chunk=128)
     return s, st, s.summary(st)
 
 

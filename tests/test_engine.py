@@ -742,7 +742,9 @@ def test_run_until_device_matches_host_loop_chord64():
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=64,
                                init_interval=0.1)
-    ep = EngineParams(window=0.2, transition_time=10.0)
+    # R=2 (engine default 8): the two programs hold the handler unrolled
+    # over the inbox slots, and the identity is the run loop's, at any R
+    ep = EngineParams(window=0.2, transition_time=10.0, inbox_slots=2)
     sim = Simulation(logic, cp, engine_params=ep)
 
     target = cp.init_finished_time + 8.0

@@ -15,33 +15,36 @@ from oversim_tpu.underlay import simple as underlay_mod
 
 
 def test_partition_and_heal():
-    """Split 16 nodes into two 8-node types at t=150s, heal at t=300s.
+    """Split 16 nodes into two 8-node types at t=80s, heal at t=160s.
     During the split cross-type traffic must drop (partition_lost > 0);
     after healing, deliveries must flow again."""
     up = underlay_mod.UnderlayParams(
         num_node_types=2, type_boundaries=(8,),
         partition_events=(
-            (150.0, 0, 1, False), (150.0, 1, 0, False),
-            (300.0, 0, 1, True), (300.0, 1, 0, True)))
+            (80.0, 0, 1, False), (80.0, 1, 0, False),
+            (160.0, 0, 1, True), (160.0, 1, 0, True)))
     cp = churn_mod.ChurnParams(model="none", target_num=16,
                                init_interval=0.5)
     logic = ChordLogic(app=KbrTestApp(KbrTestParams(test_interval=15.0)))
+    # the 16 nodes have joined by second 8, measurement opens at 38: each
+    # of the three stretches (whole, split, healed) holds 40 to 80 s of
+    # one test per node per 15 s
     s = sim_mod.Simulation(logic, cp, up,
-                           sim_mod.EngineParams(window=0.05,
-                                                transition_time=60.0,
+                           sim_mod.EngineParams(window=0.1,
+                                                transition_time=30.0,
                                                 inbox_slots=2))
     st = s.init(seed=9)
     # stop well short of the split: run_until overshoots by up to a chunk
-    st = s.run_until(st, 140.0, chunk=64)
-    assert float(st.t_now) / 1e9 < 150.0
+    st = s.run_until(st, 70.0, chunk=64)
+    assert float(st.t_now) / 1e9 < 80.0
     delivered_before = s.summary(st)["kbr_delivered"]
     assert s.summary(st)["_engine"]["partition_lost"] == 0
 
-    st = s.run_until(st, 300.0, chunk=64)
+    st = s.run_until(st, 160.0, chunk=64)
     mid = s.summary(st)
     assert mid["_engine"]["partition_lost"] > 0, mid["_engine"]
 
-    st = s.run_until(st, 450.0, chunk=64)
+    st = s.run_until(st, 240.0, chunk=64)
     after = s.summary(st)
     # healed: deliveries keep accumulating after the merge
     assert after["kbr_delivered"] > mid["kbr_delivered"] + 10, (
@@ -119,10 +122,11 @@ def test_malicious_sibling_attack_degrades_lookups():
                                init_interval=0.5)
     s = sim_mod.Simulation(logic, cp,
                            engine_params=sim_mod.EngineParams(
-                               window=0.05, transition_time=60.0,
+                               window=0.1, transition_time=30.0,
                                malicious=mp, inbox_slots=2))
     st = s.init(seed=8)
-    st = s.run_until(st, 300.0, chunk=256)
+    # measurement from second 38: 122 s of one test per node per 15 s
+    st = s.run_until(st, 160.0, chunk=256)
     out = s.summary(st)
     n_mal = int(np.asarray(st.malicious).sum())
     assert n_mal >= 2, n_mal

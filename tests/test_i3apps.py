@@ -142,11 +142,13 @@ def test_stacked_trigger_cross_server_keeps_payload_kind():
     app.rcfg = logic.rcfg
     cp = churn_mod.ChurnParams(model="none", target_num=N,
                                init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=40.0,
+    ep = sim_mod.EngineParams(window=0.1, transition_time=40.0,
                               inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=11)
-    st = s.run_until(st, 200.0, chunk=256)
+    # measurement opens at second 46; a ping per node per 10 s from there
+    # to 110 s and more is some 75 for the two > 5 below
+    st = s.run_until(st, 110.0, chunk=256)
     out = s.summary(st)
     assert out["i3_sent"] > 5, out
     assert out["i3_ping_survived"] > 5, out

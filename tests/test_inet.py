@@ -38,11 +38,13 @@ def test_chord_runs_over_inet_underlay():
     cp = churn_mod.ChurnParams(model="none", target_num=n,
                                init_interval=0.3)
     up = inet_mod.InetUnderlayParams(topology="rease", routers=8)
-    ep = sim_mod.EngineParams(window=0.050, transition_time=40.0,
+    ep = sim_mod.EngineParams(window=0.100, transition_time=40.0,
                               inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, up, ep, underlay_module=inet_mod)
     state = s.init(seed=2)
-    state = s.run_until(state, 240.0)
+    # measurement opens at second 44.8; a test per node per 10 s from
+    # there to 140 s and more is some 150 tests for the ratio below
+    state = s.run_until(state, 140.0)
     out = s.summary(state)
     sent = float(out["kbr_sent"])
     delivered = float(out["kbr_delivered"])

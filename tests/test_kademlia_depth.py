@@ -23,7 +23,7 @@ def run_sim(n=16, sim_s=240.0, seed=5, churn=None, **kw):
     logic = KademliaLogic(**kw)
     cp = churn or churn_mod.ChurnParams(model="none", target_num=n,
                                         init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.050, transition_time=30.0,
+    ep = sim_mod.EngineParams(window=0.100, transition_time=30.0,
                               inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=seed)
@@ -38,7 +38,7 @@ def depth_run():
     cp = churn_mod.ChurnParams(model="lifetime", target_num=16,
                                init_interval=0.5, lifetime_mean=120.0)
     return run_sim(
-        n=16, sim_s=420.0, churn=cp,
+        n=16, sim_s=260.0, churn=cp,
         params=KademliaParams(replacement_cands=4,
                               replacement_cache_ping=True,
                               bucket_ping_interval=30.0,

@@ -192,11 +192,13 @@ def test_gnp_hosted_by_chord():
     logic = ChordLogic(ncs_params=p)
     cp = churn_mod.ChurnParams(model="none", target_num=12,
                                init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.020, transition_time=20.0,
+    ep = sim_mod.EngineParams(window=0.100, transition_time=20.0,
                               inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=13)
-    st = s.run_until(st, 200.0, chunk=256)
+    # the 12 nodes have joined by second 6; 24 rounds of probes (one per
+    # 5 s) embed the four landmarks and then the layers over them
+    st = s.run_until(st, 120.0, chunk=256)
     layer = np.asarray(st.logic.ncs.layer)
     assert (layer[:4] == 0).all()
     assert (layer[4:] >= 1).all(), layer

@@ -6,30 +6,49 @@ multicast reaching every member (handleNiceMulticast fan-out)."""
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from oversim_tpu import churn as churn_mod
 from oversim_tpu.engine import sim as sim_mod
 from oversim_tpu.overlay.nice import NiceLogic, NiceParams, READY
 
 
+_EP = sim_mod.EngineParams(window=0.05, outbox_slots=64,
+                           transition_time=40.0, rmax=16, inbox_slots=2)
+
+
 def _run(n, t_sim, seed=3, **pkw):
     logic = NiceLogic(params=NiceParams(**pkw))
     cp = churn_mod.ChurnParams(model="none", target_num=n,
                                init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.05, outbox_slots=64,
-                              transition_time=40.0, rmax=16, inbox_slots=2)
-    s = sim_mod.Simulation(logic, cp, engine_params=ep)
+    s = sim_mod.Simulation(logic, cp, engine_params=_EP)
     state = s.init(seed=seed)
     state = s.run_until(state, t_sim)
     return s, state
 
 
-def test_all_nodes_ready_and_clustered():
-    s, state = _run(16, 120.0)
+@pytest.fixture(scope="module")
+def nice24():
+    """ONE 24-node simulation for the two structure tests: its state at
+    second 120 (where the first reads it; fetched, because the run
+    donates it) and at second 200."""
+    logic = NiceLogic(params=NiceParams())
+    cp = churn_mod.ChurnParams(model="none", target_num=24,
+                               init_interval=0.5)
+    s = sim_mod.Simulation(logic, cp, engine_params=_EP)
+    early = s.run_until(s.init(seed=3), 120.0)
+    at_120 = jax.device_get(early)
+    return s, at_120, s.run_until(early, 200.0)
+
+
+def test_all_nodes_ready_and_clustered(nice24):
+    _, state, _ = nice24
     st = state.logic
     alive = np.asarray(state.alive)
+    assert alive.sum() == 24
     ready = np.asarray(st.state) == READY
     assert (ready[alive]).all(), "every alive node must reach READY"
     # everyone alive is in a layer-0 cluster with a live leader
@@ -40,8 +59,8 @@ def test_all_nodes_ready_and_clustered():
     assert alive[leaders[alive]].all(), "layer-0 leaders must be alive"
 
 
-def test_cluster_size_invariants():
-    s, state = _run(24, 200.0)
+def test_cluster_size_invariants(nice24):
+    s, _, state = nice24
     st = state.logic
     alive = np.asarray(state.alive)
     in_layer = np.asarray(st.in_layer)
@@ -80,9 +99,7 @@ def test_survives_churn():
     logic = NiceLogic(params=NiceParams())
     cp = churn_mod.ChurnParams(model="lifetime", target_num=16,
                                lifetime_mean=120.0, init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.05, outbox_slots=64,
-                              transition_time=40.0, rmax=16, inbox_slots=2)
-    s = sim_mod.Simulation(logic, cp, engine_params=ep)
+    s = sim_mod.Simulation(logic, cp, engine_params=_EP)
     state = s.init(seed=5)
     state = s.run_until(state, 240.0)
     st = state.logic

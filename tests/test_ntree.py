@@ -22,15 +22,17 @@ def ntree_run():
                                event_interval=10.0))
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=N, init_interval=0.5)
-    # sized for XLA-CPU: window 0.05 and chunk 128 bound the tick count,
+    # sized for XLA-CPU: window 0.1 and chunk 128 bound the tick count,
     # inbox_slots 2 (engine default 8) shrinks the handler unrolled over
-    # the inbox slots — a third message in one 50 ms window is deferred
-    # to the next tick, never lost
-    ep = sim_mod.EngineParams(window=0.050, transition_time=80.0,
+    # the inbox slots — a third message in one 100 ms window is deferred
+    # to the next tick, never lost.  The 16 players have joined by second
+    # 8 and measurement opens at 48; an event per player per 10 s from
+    # there to 130 s and more is over a hundred for the > 20 below
+    ep = sim_mod.EngineParams(window=0.100, transition_time=40.0,
                               inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=41)
-    st = s.run_until(st, 220.0, chunk=128)
+    st = s.run_until(st, 130.0, chunk=128)
     return s, st
 
 

@@ -18,11 +18,16 @@ def p2pns_run():
                    num_slots=N)
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=N, init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.050, transition_time=80.0,
+    # the 16 nodes have joined by second 8 and measurement opens at 68
+    # (at 38 a sixth of the resolves went unanswered, 0.85 against the
+    # 0.8 below); 112 s and more from there hold a resolve per node per
+    # 15 s (the > 50 below) and a keepalive registration per node per
+    # 60 s
+    ep = sim_mod.EngineParams(window=0.100, transition_time=60.0,
                               inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=17)
-    st = s.run_until(st, 260.0, chunk=128)
+    st = s.run_until(st, 180.0, chunk=128)
     return s, st
 
 

@@ -17,13 +17,15 @@ def pastry32():
                                init_interval=0.4)
     # window 0.1: at N=32 nearly every 10 ms window holds an event, so
     # 10 ms ticks would be tens of thousands; the ACK timeout is 1.5 s.
-    # One test per node per 20 s from 60 s on: 140 s send 128 lookups
-    ep = sim_mod.EngineParams(window=0.100, transition_time=60.0,
+    # The 32 nodes have joined by second 12.8 and measurement opens at
+    # 42.8; one test per node per 20 s from there to 128 s (the end of
+    # the run's tenth chunk) sends 128 lookups
+    ep = sim_mod.EngineParams(window=0.100, transition_time=30.0,
                               inbox_slots=INBOX_SLOTS)
     app = KbrTestApp(KbrTestParams(test_interval=20.0))
     s = sim_mod.Simulation(PastryLogic(app=app), cp, engine_params=ep)
     st = s.init(seed=23)
-    st = s.run_until(st, 140.0, chunk=128)
+    st = s.run_until(st, 123.0, chunk=128)
     return s, st
 
 

@@ -33,12 +33,16 @@ N = 32
 # tick costs what its program holds; 2 (engine default 8).  A third
 # message for one node in one window is deferred a tick, never lost.
 INBOX_SLOTS = 2
-# measurement opens at 120 s; 80 s of it is four rounds of one test per
-# node per 20 s, 128 one-way and 128 RPC tests for the > 100 below
-RUN_S = 200.0
+# the 32 nodes have joined by second 6.4 and measurement opens
+# TRANSITION_S later; the 80 s and more from there to the end of the
+# run's last chunk are four rounds of one test per node per 20 s, 128
+# one-way and 128 RPC tests for the > 100 below
+TRANSITION_S = 30.0
+RUN_S = 120.0
 
 
-def run_mode(overlay: str, mode: str, seed: int = 11):
+def run_mode(overlay: str, mode: str, seed: int = 11,
+             transition_s: float = TRANSITION_S, run_s: float = RUN_S):
     rcfg = rt_mod.RouteConfig(mode=mode)
     app = KbrTestApp(KbrTestParams(test_interval=20.0, rpc_test=True),
                      rcfg=rcfg)
@@ -62,11 +66,11 @@ def run_mode(overlay: str, mode: str, seed: int = 11):
     # window 0.1: recursive ACK timeouts are 1.5 s — ordering
     # semantics are insensitive at this scale and the tick count (the
     # run cost on XLA-CPU) falls with the window
-    ep = sim_mod.EngineParams(window=0.100, transition_time=120.0,
+    ep = sim_mod.EngineParams(window=0.100, transition_time=transition_s,
                               inbox_slots=INBOX_SLOTS)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=seed)
-    st = s.run_until(st, RUN_S, chunk=128)
+    st = s.run_until(st, run_s, chunk=128)
     return s, st, s.summary(st)
 
 
@@ -139,7 +143,7 @@ def test_prox_aware_iterative():
     # window 0.1: recursive ACK timeouts are 1.5 s — ordering
     # semantics are insensitive at this scale and the tick count (the
     # run cost on XLA-CPU) falls with the window
-    ep = sim_mod.EngineParams(window=0.100, transition_time=120.0,
+    ep = sim_mod.EngineParams(window=0.100, transition_time=TRANSITION_S,
                               inbox_slots=INBOX_SLOTS)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=11)

@@ -19,11 +19,14 @@ def scribe_run():
                                  subscribe_refresh=15.0))
     logic = ChordLogic(app=app)
     cp = churn_mod.ChurnParams(model="none", target_num=N, init_interval=0.5)
-    ep = sim_mod.EngineParams(window=0.050, transition_time=80.0,
+    # the 16 nodes have joined by second 8 and measurement opens at 48;
+    # a publish per node per 20 s from there to 140 s and more is some
+    # 75 for the > 30 below
+    ep = sim_mod.EngineParams(window=0.100, transition_time=40.0,
                               inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=13)
-    st = s.run_until(st, 240.0, chunk=128)
+    st = s.run_until(st, 140.0, chunk=128)
     return s, st
 
 

@@ -26,11 +26,13 @@ def simmud_run():
     # MMOG multicast is bursty: each publish fans out through the region
     # tree in one tick — size the pool for the burst (counted, never
     # silent; engine/pool.py docstring)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=80.0,
+    # The 16 players have joined by second 8 and measurement opens at
+    # 48; 112 s and more from there hold a publish per player per 10 s
+    ep = sim_mod.EngineParams(window=0.1, transition_time=40.0,
                               pool_factor=16, outbox_slots=64, inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=43)
-    st = s.run_until(st, 280.0, chunk=128)
+    st = s.run_until(st, 160.0, chunk=128)
     return s, st
 
 

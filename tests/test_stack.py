@@ -28,7 +28,7 @@ def run_stack(overlay: str):
         logic = KademliaLogic(app=stack)
     cp = churn_mod.ChurnParams(model="none", target_num=N,
                                init_interval=1.0)
-    ep = sim_mod.EngineParams(window=0.05, transition_time=30.0,
+    ep = sim_mod.EngineParams(window=0.1, transition_time=30.0,
                               inbox_slots=2)
     s = sim_mod.Simulation(logic, cp, engine_params=ep)
     st = s.init(seed=17)

@@ -146,30 +146,52 @@ def assert_leaves_identical(a, b, label):
 # bit-identity: campaign slice r == solo run from replica_rng(r)
 # ---------------------------------------------------------------------------
 
+CHUNK = 32    # ONE chunk length a runner: run_chunk compiles for each
+
+
+def run_ticks(runner, state, ticks):
+    for _ in range(ticks // CHUNK):
+        state = runner.run_chunk(state, CHUNK)
+    return state
+
+
+@pytest.fixture(scope="module")
+def campaign_of():
+    """overlay -> its four-replica campaign, built (and its chunk
+    compiled) once for the tests that run it."""
+    made = {}
+
+    def get(overlay):
+        if overlay not in made:
+            made[overlay] = Campaign(make_overlay_sim(overlay),
+                                     CampaignParams(replicas=4, base_seed=3))
+        return made[overlay]
+
+    return get
+
+
 @pytest.mark.parametrize("overlay", ["chord", "kademlia"])
-def test_campaign_bit_identity_vs_solo_runs(overlay):
-    camp = Campaign(make_overlay_sim(overlay),
-                    CampaignParams(replicas=4, base_seed=3))
+def test_campaign_bit_identity_vs_solo_runs(campaign_of, overlay):
+    camp = campaign_of(overlay)
     # the campaign's own simulation: the vmapped step takes the dense
     # sweep where the engine's default would give these logics the
     # awake-set plane (same results, two more counters in the layout)
     sim = camp.sim
     assert sim.tick_impl == "dense"
-    cs = camp.run_chunk(camp.init(), 64)
+    cs = run_ticks(camp, camp.init(), 64)
     for r in range(camp.s):
         solo = sim_mod._dedupe_buffers(
             sim.init_from_rng(camp.replica_rng(r)))
-        solo = sim.run_chunk(solo, 64)
+        solo = run_ticks(sim, solo, 64)
         assert_leaves_identical(camp.replica_state(cs, r), solo,
                                 f"{overlay} replica {r}")
 
 
-def test_campaign_report_hop_hist_ensemble():
+def test_campaign_report_hop_hist_ensemble(campaign_of):
     """report()'s kbr_hop_hist carries cross-replica mean/stddev/CI that
     match a numpy recomputation from the per-replica counts."""
-    sim = make_overlay_sim("kademlia")
-    camp = Campaign(sim, CampaignParams(replicas=4, base_seed=3))
-    cs = camp.run_chunk(camp.init(), 160)   # past init (2.4 s) + lookups
+    camp = campaign_of("kademlia")
+    cs = run_ticks(camp, camp.init(), 160)  # past init (2.4 s) + lookups
     rep = camp.report(cs)
 
     hh = rep["kbr_hop_hist"]

@@ -2,7 +2,7 @@
 
 One recorded run serves the module: Kademlia under KBRTestApp and
 LifetimeChurn at target 128 (256 context slots), lifetimes of 60 s mean,
-680 ticks of 0.2 s (some two hundred deaths and as many rebirths, each
+480 ticks of 0.2 s (over a hundred deaths and as many rebirths, each
 under a fresh key), tick by tick, on the dense sweep and on the awake-set
 plane.  Pinned on it: the two planes end on the same state, every leaf
 (further cases of test_zz_sparse.py's identity test, whose helpers these
@@ -33,7 +33,7 @@ from oversim_tpu.overlay.kademlia import NO_NODE, KademliaLogic
 
 from test_zz_sparse import _assert_tree_equal, _strip_sparse
 
-TARGET, TICKS = 128, 680
+TARGET, TICKS = 128, 480
 T_INF = int(churn_mod.T_INF)
 
 
@@ -50,7 +50,7 @@ def _churn_sim(tick_impl):
 
 @pytest.fixture(scope="module")
 def churned():
-    """Both planes through the same 680 ticks; of each tick, who was
+    """Both planes through the same 480 ticks; of each tick, who was
     alive and who was under a leave notice after it."""
     out = {}
     for tick_impl in ("dense", "sparse"):
@@ -119,7 +119,7 @@ def _ids(node_keys):
 
 
 def test_tables_hold_no_reborn_slot_in_its_old_bucket(churned):
-    """After 680 churned ticks: an entry stands in another bucket than
+    """After 480 churned ticks: an entry stands in another bucket than
     its slot's CURRENT key earns only where the slot was born after
     anything its holder has seen (the holder has not stepped since, and
     puts it right when it next does); no bucket holds a slot twice."""

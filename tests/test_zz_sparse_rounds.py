@@ -139,7 +139,10 @@ def test_every_node_awake_takes_n_over_a_rounds():
         sim = _sim("chord", tick_impl=tick_impl, churn="none",
                    interval=0.05, n=n, active_cap=n // 8)
         assert sim.n == n
-        s = sim.run_chunk(sim.init(seed=3), warm)
+        # ONE chunk length a plane: run_chunk compiles again for each
+        s = sim.init(seed=3)
+        for _ in range(warm // meas):
+            s = sim.run_chunk(s, meas)
         if tick_impl == "sparse":
             assert sim.acap == n // 8
             marks = {k: int(v) for k, v in
