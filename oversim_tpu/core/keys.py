@@ -441,3 +441,26 @@ def sort_by_distance(dist, payload, num_keys: int | None = None, *,
     out = jax.lax.sort(operands, dimension=-1, num_keys=nk)
     sorted_dist = jnp.stack(out[:len(lanes)], axis=-1)
     return sorted_dist, tuple(out[len(lanes):])
+
+
+def argmin_by_distance(dist, *, approx: bool = False):
+    """Index [...] (i32) of the lexicographically smallest row of ``dist``
+    [..., C, KL], the LOWEST index among equal rows: for every input
+    ``sort_by_distance(dist, (iota,), approx=approx)[1][0][..., 0]``,
+    what a caller that keeps ONE candidate wants, found by reduction
+    (no sort, no scatter, no 64-bit intermediate): ``min`` of lane 0,
+    ``min`` of lane 1 among the rows that hold it, ..., ``min`` of the
+    index among those.  The comparator lanes are ``sort_by_distance``'s:
+    all KL, the top two under ``approx=True`` (the same caveat holds).
+    """
+    c, kl = dist.shape[-2:]
+    top = jnp.iinfo(dist.dtype).max
+    among = None
+    for i in range(min(2, kl) if approx else kl):
+        lane = dist[..., i]
+        if among is not None:
+            lane = jnp.where(among, lane, top)
+        hit = lane == jnp.min(lane, axis=-1, keepdims=True)
+        among = hit if among is None else among & hit
+    idx = jax.lax.broadcasted_iota(jnp.int32, among.shape, among.ndim - 1)
+    return jnp.min(jnp.where(among, idx, c), axis=-1)

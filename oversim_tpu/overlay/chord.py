@@ -144,10 +144,12 @@ def _sort_lanes(dist, payload):
 
 
 def _lex_argmin(dist):
-    """Index of the lexicographically smallest [C, KL] distance row."""
-    idx = jnp.arange(dist.shape[0], dtype=I32)
-    (best,) = _sort_lanes(dist, (idx,))
-    return best[0]
+    """Index of the lexicographically smallest [C, KL] distance row (by
+    ``_sort_lanes``' comparator, the lowest index among equal rows).
+    Both callers keep ONE candidate (findNode runs with
+    numRedundantNodes = 1, a node has one predecessor), so the index is
+    reduced, not sorted for."""
+    return K.argmin_by_distance(dist, approx=True)
 
 
 class ChordLogic:
@@ -313,7 +315,9 @@ class ChordLogic:
         """Chord::findNode (Chord.cc:548) with numRedundantNodes=1.
 
         Returns (next_hop i32 slot, is_sibling bool).  NO_NODE next hop
-        when not READY (reference returns an empty NodeVector).
+        when not READY (reference returns an empty NodeVector).  One next
+        hop is wanted, so the closest preceding node is an argmin over
+        the fingers and successors and nothing is sorted.
         """
         spec = self.key_spec
         ready = st.state == READY

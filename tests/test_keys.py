@@ -148,6 +148,56 @@ def test_sort_by_distance_topk():
     np.testing.assert_array_equal(np.asarray(order), np.array(expect, dtype=np.int32))
 
 
+def _argmin_rows(case):
+    """[A, R, C, KL] u32 distance rows for one case of the argmin test."""
+    rng = np.random.default_rng(44)
+    a, r, c, kl = 3, 4, 168, 5
+    umax = np.uint32(0xFFFFFFFF)
+    if case == "uniform":
+        return rng.integers(0, 1 << 32, (a, r, c, kl), dtype=np.uint32)
+    if case == "ties":
+        return rng.integers(0, 4, (a, r, c, kl), dtype=np.uint32)
+    if case == "mostly_umax":
+        d = rng.integers(0, 1 << 32, (a, r, c, kl), dtype=np.uint32)
+        return np.where(rng.random((a, r, c, 1)) < 0.9, umax, d)
+    if case == "all_umax":
+        return np.full((a, r, c, kl), umax)
+    if case == "one_row":
+        return rng.integers(0, 1 << 32, (a, r, 1, kl), dtype=np.uint32)
+    assert case == "tie_above_differ_below"
+    d = rng.integers(0, 1 << 32, (a, r, c, kl), dtype=np.uint32)
+    d[..., :2] = 7                  # every row ties in the top two lanes
+    d[..., 0, 2] = umax             # and row 0 is not the smallest below
+    return d
+
+
+@pytest.mark.parametrize("approx", [False, True], ids=["exact", "approx"])
+@pytest.mark.parametrize("case", ["uniform", "ties", "mostly_umax", "all_umax",
+                                  "one_row", "tie_above_differ_below"])
+def test_argmin_by_distance_is_the_sorts_first_index(case, approx):
+    dist = jnp.asarray(_argmin_rows(case))
+
+    def by_sort(d):
+        idx = jnp.arange(d.shape[0], dtype=jnp.int32)
+        return K.sort_by_distance(d, (idx,), approx=approx)[1][0][0]
+
+    def by_argmin(d):
+        return K.argmin_by_distance(d, approx=approx)
+
+    want = np.asarray(jax.vmap(jax.vmap(by_sort))(dist))
+    # one row at a time, under vmap over the leading [A, R], and batched
+    for got in (jax.vmap(jax.vmap(by_argmin))(dist), by_argmin(dist),
+                jnp.stack([by_argmin(dist[0, j]) for j in range(4)])[None]):
+        got = np.asarray(got)
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(got, want[:got.shape[0]])
+    if case == "tie_above_differ_below":
+        # the compressed comparator sees ties where the exact one does not
+        assert (want == 0).all() == approx
+    if case == "all_umax":
+        assert (want == 0).all()
+
+
 def test_log2_floor():
     spec = K.KeySpec(160)
     vals = [0, 1, 2, 3, 4, 1 << 80, (1 << 159) + 5]

@@ -129,6 +129,30 @@ def test_the_lookup_engine_scatters_nothing(tick):
         assert under[part].count("select_n") >= 4, part
 
 
+# ``sort`` equations in a tick (N = 32, R = 2) on either plane: Kademlia's
+# as ISSUE 44's parent had them, Chord's 13 less the three calls of
+# ``_find_node`` and the notify handler's closest notifier
+SORTS = {"KademliaLogic": 10, "ChordLogic": 9}
+
+
+def test_chord_find_node_sorts_nothing(tick):
+    """Chord's closest preceding node is an argmin (ISSUE 44:
+    ``K.argmin_by_distance``): findNode keeps one next hop, and a sort
+    of the 168 fingers and successors ran for each inbox slot of each
+    stepped lane."""
+    sim, paths = tick
+    sorts = [names for prim, names in paths if prim == "sort"]
+    assert len(sorts) == SORTS[type(sim.logic).__name__]
+    if type(sim.logic).__name__ == "KademliaLogic":
+        return
+    under = [prim for prim, names in paths if "chord.find_node" in names]
+    assert len(under) > 100 and "sort" not in under
+    # the reductions are there
+    assert under.count("reduce_min") >= 3
+    # the sorts that keep more than one candidate stay (the broadcast's)
+    assert [names for names in sorts if "chord.broadcast" in names]
+
+
 def test_no_registered_name_holds_scatter():
     # analysis/hlo_text._SCATTER_WHILE tells a scatter's own loop by
     # ``/scatter`` in its op_name
