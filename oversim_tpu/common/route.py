@@ -41,12 +41,30 @@ import jax
 import jax.numpy as jnp
 
 from oversim_tpu.common import wire
+from oversim_tpu.core.scopes import scoped
 
 I32 = jnp.int32
 I64 = jnp.int64
 U32 = jnp.uint32
 NO_NODE = jnp.int32(-1)
 T_INF = jnp.int64(2**62)
+
+# what a hop does, cumulative (stats "c:" counters of an overlay that
+# counts its routed path, gated like every counter on the measurement
+# phase): KBR_ROUTE hops sent (a payload's first hop, every forward,
+# every reroute), hops whose ACK came, hops whose ACK timed out, the
+# timed-out hops sent again to another candidate, hops sent with no ACK
+# slot free (un-ACKed: the message is never the price of a full table),
+# payloads decapsulated at the node that holds itself responsible, and
+# the payloads dropped, by cause: no candidate survived loop detection,
+# or the hop count reached ``hop_max``.  Every hop sent ends in exactly
+# one of acked, timed out, un-ACKed or still pending:
+#   route_forwarded = route_acked + route_ack_timeouts
+#                     + route_unacked_table_full + pending slots
+ROUTE_COUNTERS = (
+    "route_forwarded", "route_acked", "route_ack_timeouts",
+    "route_rerouted", "route_unacked_table_full", "route_delivered",
+    "route_dropped_no_candidate", "route_dropped_hop_bound")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,6 +140,7 @@ def init(cfg: RouteConfig, kl: int, visited_cap: int) -> RouteState:
     )
 
 
+@scoped("route.forward")
 def pick_next_hop(cands, visited, last_hop, src_node, self_idx, is_sib):
     """Loop-detection candidate scan (BaseOverlay.cc:1500-1521).
 
@@ -147,6 +166,7 @@ def _route_nonce(slot, gen, q: int):
     return 1 + slot + q * (gen & jnp.int32(0x003FFFFF))
 
 
+@scoped("route.forward")
 def forward(rt: RouteState, ob, en, now, next_hop, *, key, inner, a, b, c,
             hops, stamp, size_b, visited, cfg: RouteConfig):
     """Send one route hop; when ACKs are on, also park a copy in a free
@@ -194,6 +214,7 @@ def forward(rt: RouteState, ob, en, now, next_hop, *, key, inner, a, b, c,
                                       mode="drop"))
 
 
+@scoped("route.forward")
 def forward_batch(rt: RouteState, ob, en, now, next_hop, *, key, inner, a,
                   b, c, hops, stamp, size_b, visited, cfg: RouteConfig):
     """Vector-valued :func:`forward`: ``en``/``now``/``next_hop`` and every
@@ -252,6 +273,7 @@ def forward_batch(rt: RouteState, ob, en, now, next_hop, *, key, inner, a,
                                              mode="drop"))
 
 
+@scoped("route.acks")
 def on_acks(rt: RouteState, m):
     """Batched :func:`on_ack`: ``m`` fields carry an [R] inbox axis.  Each
     valid ACK addresses a distinct slot (the nonce encodes the slot), so
@@ -358,6 +380,7 @@ def reply(ob, cfg: RouteConfig, en, now, msgs, ctx, node_idx, inner_kind,
                 size_b=size_b)
 
 
+@scoped("route.acks")
 def on_ack(rt: RouteState, m):
     """Consume a KBR_ROUTE_ACK (NextHopResponse): free the matched slot."""
     q = rt.active.shape[0]
@@ -373,6 +396,7 @@ def on_ack(rt: RouteState, m):
         t_to=rt.t_to.at[sl].set(T_INF, mode="drop"))
 
 
+@scoped("route.timeouts")
 def on_timeouts(rt: RouteState, t_end, cfg: RouteConfig):
     """Expire pending ACKs due before ``t_end``.
 
@@ -395,6 +419,7 @@ def on_timeouts(rt: RouteState, t_end, cfg: RouteConfig):
     ), failed, can_retry
 
 
+@scoped("route.forward")
 def reforward(rt: RouteState, ob, slot: int, en, now, next_hop,
               cfg: RouteConfig):
     """Re-send slot ``slot``'s parked message to a new next hop (reroute
@@ -417,6 +442,7 @@ def reforward(rt: RouteState, ob, slot: int, en, now, next_hop,
         t_to=rt.t_to.at[sl].set(now + cfg.ack_timeout_ns, mode="drop"))
 
 
+@scoped("route.forward")
 def reforward_batch(rt: RouteState, ob, en, now, next_hop,
                     cfg: RouteConfig):
     """Vectorized :func:`reforward` over all Q slots at once: ``en`` [Q]
@@ -438,6 +464,7 @@ def reforward_batch(rt: RouteState, ob, en, now, next_hop,
         t_to=jnp.where(en, now + cfg.ack_timeout_ns, rt.t_to))
 
 
+@scoped("route.timeouts")
 def drop_slots(rt: RouteState, en):
     """Vectorized :func:`drop_slot`: free every slot marked in ``en`` [Q]."""
     return dataclasses.replace(
@@ -446,6 +473,7 @@ def drop_slots(rt: RouteState, en):
         t_to=jnp.where(en, T_INF, rt.t_to))
 
 
+@scoped("route.timeouts")
 def drop_slot(rt: RouteState, slot: int, en):
     q = rt.active.shape[0]
     sl = jnp.where(en, jnp.int32(slot), q)
@@ -456,7 +484,20 @@ def drop_slot(rt: RouteState, slot: int, en):
 
 
 def next_event(rt: RouteState):
+    """The earliest pending ACK's timeout.  Exact for the awake-set
+    plane: a slot is active only with a finite ``t_to`` (``forward`` and
+    ``reforward`` arm it, ``on_ack``, ``on_timeouts`` and ``drop_slot``
+    clear both or re-arm), and ``on_timeouts`` is the only reader of a
+    slot that no message addresses (tests/test_pastry_bamboo.py)."""
     return jnp.min(jnp.where(rt.active, rt.t_to, T_INF))
+
+
+def parks(rt: RouteState, en, cfg: RouteConfig):
+    """Whether ``forward(rt, ob, en, ...)`` finds an ACK slot for its
+    message (False with ACKs off: nothing is parked)."""
+    if not cfg.route_acks:
+        return jnp.bool_(False)
+    return en & jnp.any(~rt.active)
 
 
 # ---------------------------------------------------------------------------

@@ -86,6 +86,9 @@ KAD_DOWNLIST = 42       # KademliaDownlistMessage (Kademlia.cc:1567-1585):
 # --- Pastry / Bamboo (src/overlay/pastry, bamboo; PastryMessage.msg) ---
 PASTRY_STATE_CALL = 20  # RequestStateMessage / leafset push-pull
 PASTRY_STATE_RES = 21   # PastryStateMessage: leafset (+ self) payload
+PASTRY_ROW_CALL = 22    # Bamboo localTuning: a=the row asked for
+PASTRY_ROW_RES = 23     # that row of the responder's routing table, the
+                        # responder in its own digit's column
 
 # --- Broose (src/overlay/broose; BrooseMessage.msg) ---
 BROOSE_BUCKET_CALL = 70  # BucketCall: a=bucket type (BROTHER/LEFT),

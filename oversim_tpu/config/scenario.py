@@ -428,16 +428,35 @@ def build_simulation(ini: IniFile, config: str = "General",
                                                   True), ap, mparams=mp)
     elif "pastry" in overlay_type.lower() or "bamboo" in overlay_type.lower():
         from oversim_tpu.overlay.pastry import (BambooLogic, PastryLogic,
-                                                PastryParams)
+                                                PastryParams, bamboo_params)
         proto = ("bamboo" if "bamboo" in overlay_type.lower() else "pastry")
+        base = bamboo_params() if proto == "bamboo" else PastryParams()
+
+        def key(name, default):
+            return _get(ini, config, f"overlay.{proto}.{name}", default)
+
+        routing = str(key("routingType", base.routing_mode)).strip('"')
+        if routing not in ("semi-recursive", "iterative"):
+            raise ScenarioError(
+                f"overlay.{proto}.routingType = {routing!r}: "
+                "overlay/pastry.py routes \"semi-recursive\" or "
+                "\"iterative\"")
+        acks = key("routeMsgAcks", base.route_acks)
         params = PastryParams(
-            bits_per_digit=int(_get(
-                ini, config, f"overlay.{proto}.bitsPerDigit", 4)),
-            num_leaves=int(_get(
-                ini, config, f"overlay.{proto}.numberOfLeaves",
-                8 if proto == "bamboo" else 16)),
-            join_delay=int(_get(
-                ini, config, f"overlay.{proto}.joinTimeout", 20)),
+            bits_per_digit=int(key("bitsPerDigit", base.bits_per_digit)),
+            num_leaves=int(key("numberOfLeaves", base.num_leaves)),
+            join_delay=int(key("joinTimeout", 20)),
+            leafset_interval=float(key("leafsetMaintenanceInterval",
+                                       base.leafset_interval)),
+            local_tuning_interval=float(key("localTuningInterval",
+                                            base.local_tuning_interval)),
+            tuning_interval=float(key("globalTuningInterval",
+                                      base.tuning_interval)),
+            routing_mode=routing,
+            route_acks=(acks if isinstance(acks, bool)
+                        else str(acks).strip('"').lower() == "true"),
+            rec_redundant=int(key("recNumRedundantNodes",
+                                  base.rec_redundant)),
         )
         cls = BambooLogic if proto == "bamboo" else PastryLogic
         logic = cls(spec, params,

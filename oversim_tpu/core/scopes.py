@@ -42,6 +42,9 @@ PARTS = {
         "kademlia.refresh", "kademlia.pings",
         "chord.find_node", "chord.failed", "chord.join", "chord.stabilize",
         "chord.fix_fingers", "chord.ping", "chord.broadcast",
+        "pastry.find_node", "pastry.learn", "pastry.failed", "pastry.join",
+        "pastry.leafset_maint", "pastry.tuning",
+        "route.forward", "route.acks", "route.timeouts",
         "lookup.responses", "lookup.timeouts", "lookup.pump",
         "lookup.start", "lookup.completions", "app.kbrtest",
         "outbox.finish"),

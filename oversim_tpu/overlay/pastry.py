@@ -25,10 +25,20 @@ numberOfLeaves=16 (Bamboo 8)).  State is structure-of-arrays:
     leafset arrives from the responsible node and the routing table
     fills from exchanges + observed traffic — Bamboo's push-pull
     convergence, Bamboo.cc localTuning/leafsetMaintenance);
-  * maintenance (Bamboo-style, used for both variants): periodic
-    leafset push-pull with a random leaf (`leafsetMaintenanceInterval`),
-    periodic random-key lookup filling routing-table rows
-    (`globalTuningInterval`); Pastry's reactive leafset repair
+  * maintenance (Bamboo-style, used for both variants; Bamboo.cc's three
+    periodic tasks, one timer each): ``t_ls`` leafset push-pull with a
+    random leaf (`leafsetMaintenanceInterval`: the call carries the
+    caller's leaf set, the response the callee's, and each side merges
+    the other's), ``t_lt`` local tuning (`localTuningInterval`: a
+    random routing-table entry is asked for the row it sits in, the
+    reply merged through `_rt_add` with the measured RTT of the
+    responder; 0 switches the task off, Pastry's default), ``t_gt``
+    global tuning (`globalTuningInterval`: a random-key lookup whose
+    responses fill routing-table rows); a node that completes its join
+    announces itself to every member of its new leaf set in the same
+    exchange message (Pastry's doJoinUpdate; Bamboo pushes its leaf set
+    to its members on a change), so a newcomer's neighbours need no
+    gossip round to hear of it; Pastry's reactive leafset repair
     (handleFailedNode → state request to the farthest leaf) rides the
     same exchange message;
   * proximity neighbor selection (PNS, BasePastry.cc:439-570
@@ -45,6 +55,16 @@ routingType="semi-recursive"): application payloads hop node-to-node via
 common/route.py (findNode → loop-detect → forward, NextHop ACK, reroute
 on hop failure), while join/maintenance lookups stay iterative.
 ``routing_mode="iterative"`` restores lookup-then-direct-hop routing.
+
+Read from the ini (config/scenario.py, `**.overlay.pastry.*` or
+`**.overlay.bamboo.*`): bitsPerDigit, numberOfLeaves, joinTimeout,
+leafsetMaintenanceInterval, localTuningInterval, globalTuningInterval,
+routeMsgAcks, routingType and recNumRedundantNodes (``rec_redundant``
+follows the key; the default of 4 is this module's, upstream's
+default.ini:386 says 3).  An overlay that no node has started yet is
+started by ONE of a tick's due joiners (``ring_starter`` /
+``Ctx.starter``); the engine's awake-set plane may skip this logic's
+idle nodes (``awake_set_exact``).
 """
 
 from __future__ import annotations
@@ -62,6 +82,7 @@ from oversim_tpu.common import neighborcache as nc_mod
 from oversim_tpu.common import route as rt_mod
 from oversim_tpu.common import wire
 from oversim_tpu.core import keys as K
+from oversim_tpu.core.scopes import scope, scoped
 from oversim_tpu.engine.logic import Outbox, select_tree
 
 I32 = jnp.int32
@@ -77,22 +98,40 @@ DEAD, JOINING, READY = 0, 1, 2
 
 P_JOIN, P_TUNE, P_APP = 1, 2, 3
 
+# what the overlay's upkeep did, cumulative (stats "c:" counters, gated
+# like every counter on the measurement phase): leaf-set push-pulls the
+# ``t_ls`` timer started, local-tuning probes the ``t_lt`` timer sent,
+# global-tuning rounds the ``t_gt`` timer fired (a round whose random
+# key is the node's own ends where it starts and is counted), state
+# exchanges answered (leaf-set and routing-row responses sent), and the
+# application payloads handed to common/route.py at their sender
+UPKEEP_COUNTERS = (
+    "bamboo_ls_rounds", "bamboo_lt_probes", "bamboo_gt_lookups",
+    "bamboo_state_msgs", "bamboo_app_routes")
+
 
 @dataclasses.dataclass(frozen=True)
 class PastryParams:
-    """default.ini:226-267."""
+    """default.ini:226-267.  Every field with an ini key beside it is
+    read from the ini by config/scenario.py; the values here are the
+    defaults where the ini is silent."""
 
     bits_per_digit: int = 4       # bitsPerDigit
     num_leaves: int = 16          # numberOfLeaves (Bamboo: 8)
     rows: int = 16                # routing-table row cap (see module doc)
-    join_delay: float = 10.0
-    leafset_interval: float = 10.0   # Bamboo leafsetMaintenanceInterval
-    tuning_interval: float = 30.0    # Bamboo globalTuningInterval
+    join_delay: float = 10.0      # joinTimeout
+    # Bamboo's three periodic tasks (Bamboo.cc), one timer each
+    leafset_interval: float = 10.0   # leafsetMaintenanceInterval (t_ls)
+    local_tuning_interval: float = 0.0  # localTuningInterval (t_lt);
+                                     # 0 = off (Pastry has no such task)
+    tuning_interval: float = 30.0    # globalTuningInterval (t_gt)
     rpc_timeout: float = 1.5
     # reference default.ini:245-246: semi-recursive with per-hop ACKs
-    routing_mode: str = "semi-recursive"   # or "iterative"
+    routing_mode: str = "semi-recursive"   # routingType; or "iterative"
     route_acks: bool = True       # routeMsgAcks
-    rec_redundant: int = 4        # recNumRedundantNodes (default.ini:386: 3)
+    rec_redundant: int = 4        # recNumRedundantNodes (upstream's
+                                  # default.ini:386 says 3; this
+                                  # module's default stays 4)
     adaptive_timeouts: bool = False  # optimizeTimeouts (BaseRpc.cc:197-
                                   # 205): iterative-lookup RPC timeouts
                                   # from the NeighborCache estimator
@@ -119,6 +158,7 @@ class PastryState:
     t_join: jnp.ndarray     # [N] i64
     t_ls: jnp.ndarray       # [N] i64 leafset maintenance
     t_gt: jnp.ndarray       # [N] i64 global tuning
+    t_lt: jnp.ndarray       # [N] i64 local tuning (T_INF where off)
     lk: lk_mod.LookupState
     rr: rt_mod.RouteState   # [N, Q, ...] pending-ACK recursive routes
     nc: object              # nc_mod.NcState — RTT cache (adaptive timeouts)
@@ -151,6 +191,19 @@ class PastryLogic:
 
     # -- engine interface ---------------------------------------------------
 
+    @property
+    def awake_set_exact(self) -> bool:
+        """The engine may skip this logic's idle nodes (engine/sim.py
+        ``resolve_tick_impl``): a node with no inbox message, no due
+        ``next_event`` (the join, leaf-set and tuning timers, the
+        lookups' and the pending ACKs' timeouts, the app's timer) and no
+        churn is a fixed point of ``step``, for the overlay's own part
+        and, where the app says the same of itself, for the app's.
+        Pinned against the dense sweep by tests/test_pastry_bamboo.py
+        (semi-recursive with ACKs) and by hand for Pastry's defaults and
+        the iterative mode (benchmark/tests/test_bamboo_planes.py)."""
+        return bool(getattr(self.app, "awake_set_exact", False))
+
     def split(self, st: PastryState):
         return dataclasses.replace(st, app_glob=None), st.app_glob
 
@@ -168,7 +221,7 @@ class PastryLogic:
             hists=tuple(app["hists"]),
             counters=tuple(app["counters"]) + (
                 "pastry_joins", "lookup_success", "lookup_failed",
-                "route_dropped"),
+                "route_dropped") + UPKEEP_COUNTERS + rt_mod.ROUTE_COUNTERS,
         )
 
     def init(self, rng, n: int) -> PastryState:
@@ -182,6 +235,7 @@ class PastryLogic:
             t_join=jnp.full((n,), T_INF, I64),
             t_ls=jnp.full((n,), T_INF, I64),
             t_gt=jnp.full((n,), T_INF, I64),
+            t_lt=jnp.full((n,), T_INF, I64),
             lk=jax.vmap(lambda _: lk_mod.init(self.lcfg, self.key_spec.lanes))(
                 jnp.arange(n)),
             rr=jax.vmap(lambda _: rt_mod.init(
@@ -212,13 +266,27 @@ class PastryLogic:
         joining = st.state == JOINING
         ready = st.state == READY
         t = jnp.where(joining, st.t_join, T_INF)
-        for timer in (st.t_ls, st.t_gt):
+        for timer in (st.t_ls, st.t_gt, st.t_lt):
             t = jnp.minimum(t, jnp.where(ready, timer, T_INF))
         t = jnp.minimum(t, jnp.where(ready, self.app.next_event(st.app),
                                      T_INF))
         t = jnp.minimum(t, jax.vmap(lk_mod.next_event)(st.lk))
         t = jnp.minimum(t, jax.vmap(rt_mod.next_event)(st.rr))
         return t
+
+    def ring_starter(self, st: PastryState, alive, t_end):
+        """The one joiner that may start an overlay in a tick that finds
+        no node READY (``Ctx.starter``): of the nodes whose join timer
+        is due, the earliest, ties to the lowest slot; NO_NODE where
+        none is due.  Upstream's first node is READY inside its own
+        creation event, so its second node always finds it; two nodes
+        created inside one tick window (a fill of 0.1 s a join under a
+        0.2 s window) must not both see an empty overlay and start one
+        each: leaf sets only ever merge what they hear of, and two
+        overlays that never exchange a message stay two."""
+        due = alive & (st.state == JOINING) & (st.t_join < t_end)
+        first = jnp.argmin(jnp.where(due, st.t_join, T_INF)).astype(I32)
+        return jnp.where(jnp.any(due), first, NO_NODE)
 
     # -- internals (per-node slice) ------------------------------------------
 
@@ -252,28 +320,46 @@ class PastryLogic:
         neighbor selection (PastryRoutingTable::mergeNode + the PNS
         ping-before-adopt comparison, BasePastry.cc:439-570: a measured
         closer candidate replaces an occupied slot; unmeasured
-        candidates only fill empty slots)."""
-        p = self.p
-        rt, rt_rtt = st.rt, st.rt_rtt
-        for i in range(cands.shape[0]):
-            c = jnp.where(en[i] & (cands[i] != node_idx), cands[i], NO_NODE)
-            c_rtt = RTT_INF if rtt is None else rtt[i]
-            ck = ctx.keys[jnp.maximum(c, 0)]
-            row = jnp.minimum(
-                K.shared_prefix_digits(me_key, ck, p.bits_per_digit,
-                                       self.key_spec), p.rows - 1)
-            col = K.digit(ck, row, p.bits_per_digit, self.key_spec)
-            empty = rt[row, col] == NO_NODE
-            same = rt[row, col] == c
-            closer = c_rtt < rt_rtt[row, col]
-            do = (c != NO_NODE) & (empty | closer | same)
-            r = jnp.where(do, row, p.rows)
-            rt = rt.at[r, col].set(c, mode="drop")
-            rt_rtt = rt_rtt.at[r, col].set(
-                jnp.where(same & ~closer, rt_rtt[row, col],
-                          jnp.asarray(c_rtt, I32)), mode="drop")
-        return dataclasses.replace(st, rt=rt, rt_rtt=rt_rtt)
+        candidates only fill empty slots).
 
+        All candidates at once: of those that earn one cell the one
+        with the least RTT wins (the earliest where two are as near,
+        the entry the cell holds before any of them), which is what
+        taking them one after the other comes to; then ONE indexed
+        write of the winners, each to a cell of its own."""
+        p = self.p
+        n_c = cands.shape[0]
+        c = jnp.where(en & (cands != node_idx), cands, NO_NODE)
+        c_rtt = (jnp.full((n_c,), RTT_INF, I32) if rtt is None
+                 else jnp.asarray(rtt, I32))
+        ck = ctx.keys[jnp.maximum(c, 0)]
+        row = jnp.minimum(
+            K.shared_prefix_digits(jnp.broadcast_to(me_key, ck.shape), ck,
+                                   p.bits_per_digit, self.key_spec),
+            p.rows - 1)
+        col = K.digit(ck, row, p.bits_per_digit, self.key_spec)
+        cell = row * p.cols + col
+        valid = c != NO_NODE
+        idx = jnp.arange(n_c, dtype=I32)
+        # beaten by another candidate of the same cell
+        rival = (valid[None, :] & (cell[None, :] == cell[:, None])
+                 & ((c_rtt[None, :] < c_rtt[:, None])
+                    | ((c_rtt[None, :] == c_rtt[:, None])
+                       & (idx[None, :] < idx[:, None]))))
+        wins = valid & ~jnp.any(rival, axis=1)
+        held, held_rtt = st.rt[row, col], st.rt_rtt[row, col]
+        same = held == c
+        closer = c_rtt < held_rtt
+        do = wins & ((held == NO_NODE) | closer | same)
+        r = jnp.where(do, row, p.rows + idx)   # OOB, each its own: dropped
+        return dataclasses.replace(
+            st,
+            rt=st.rt.at[r, col].set(c, mode="drop", unique_indices=True),
+            rt_rtt=st.rt_rtt.at[r, col].set(
+                jnp.where(same & ~closer, held_rtt, c_rtt), mode="drop",
+                unique_indices=True))
+
+    @scoped("pastry.learn")
     def _learn(self, ctx, st, me_key, node_idx, cands, en, rtt=None):
         st = self._leaf_merge(ctx, st, me_key, node_idx, cands, en)
         return self._rt_add(ctx, st, me_key, node_idx, cands, en, rtt)
@@ -282,6 +368,7 @@ class PastryLogic:
         """Own state payload: self + both halves (PastryStateMessage)."""
         return jnp.concatenate([node_idx[None], st.leaf_cw, st.leaf_ccw])
 
+    @scoped("pastry.find_node")
     def _find_node(self, ctx, st, me_key, node_idx, key, rmax):
         """BasePastry::findNode (BasePastry.cc:1100).
 
@@ -345,9 +432,16 @@ class PastryLogic:
         pfx = K.shared_prefix_digits(me_key, key, p.bits_per_digit, spec)
         kpfx = K.shared_prefix_digits(kk, key_b, p.bits_per_digit, spec)
         ok = (known != NO_NODE) & closer & (kpfx >= pfx)
-        df = jnp.where(ok[:, None], dk, UMAX)
-        (fb_s,) = K.sort_by_distance(df, (known,), approx=True)[1]
-        fallback = jnp.where(jnp.any(ok), fb_s[0], NO_NODE)
+        # the closest few of them, each an argmin (nothing is sorted; a
+        # known node that is no closer is no candidate at any place)
+        fb = []
+        for _ in range(max(p.rec_redundant - 1, 1)):
+            at = K.argmin_by_distance(jnp.where(ok[:, None], dk, UMAX),
+                                      approx=True)
+            fb.append(jnp.where(jnp.any(ok), known[at], NO_NODE))
+            ok = ok & (known != known[at])
+        fb_s = jnp.stack(fb)
+        fallback = fb_s[0]
 
         # result set: sibling case → closest leafs (replica set); else hop
         nxt = jnp.where(in_span & (leaf_dest != node_idx), leaf_dest,
@@ -367,6 +461,7 @@ class PastryLogic:
         cands = jnp.where(ready, cands, NO_NODE)
         return res, is_sib, cands
 
+    @scoped("pastry.failed")
     def _handle_failed(self, ctx, st, me_key, node_idx, failed, ob, now):
         """BasePastry::handleFailedNode + Pastry leafset repair: drop the
         failed nodes everywhere; if a leafset half lost a member, request
@@ -407,6 +502,9 @@ class PastryLogic:
             t_ls=jnp.where(en, now, st.t_ls),
             t_gt=jnp.where(en, now + jnp.int64(
                 int(p.tuning_interval * NS)), st.t_gt),
+            t_lt=jnp.where(
+                en & (p.local_tuning_interval > 0), now + jnp.int64(
+                    int(p.local_tuning_interval * NS)), st.t_lt),
             app=self.app.on_ready(st.app, en, now, rng))
 
     # -- the per-node step ---------------------------------------------------
@@ -434,6 +532,19 @@ class PastryLogic:
         anyfail_cnt = jnp.int32(0)
         lksucc_cnt = jnp.int32(0)
         routedrop_cnt = jnp.int32(0)
+        # upkeep (UPKEEP_COUNTERS) and routed-path (rt_mod.ROUTE_COUNTERS)
+        # tallies of this step
+        cnt = {k: jnp.int32(0)
+               for k in UPKEEP_COUNTERS + rt_mod.ROUTE_COUNTERS}
+
+        def tally(name, what):
+            cnt[name] = cnt[name] + jnp.sum(jnp.asarray(what).astype(I32))
+
+        acks_on = self.rcfg.route_acks
+        # a completed join is announced to the new leaf set once, after
+        # the inbox (one vector send)
+        announce = jnp.bool_(False)
+        announce_t = t0
         old_leaf = jnp.concatenate([st.leaf_cw, st.leaf_ccw])
         # update() delta base (the leafset is Pastry's sibling set)
 
@@ -457,11 +568,10 @@ class PastryLogic:
             # enter leafsets: the reference only merges overlay members
             # (PastryStateMessage senders); adopting a joiner would route
             # its own-key join lookup straight back at it.
-            src_ready = ctx.ready[jnp.maximum(m.src, 0)]
-            st = select_tree(
-                v & src_ready,
-                self._learn(ctx, st, me_key, node_idx, m.src[None],
-                            jnp.ones((1,), bool)), st)
+            # (ONE merge a slot, below: the source beside what the
+            # message carries)
+            src0 = m.src
+            src_ready = v & ctx.ready[jnp.maximum(src0, 0)]
 
             # local findNode on this slot's key — shared by the FindNode
             # RPC server, the recursive forwarding pre-pass, and the app
@@ -470,9 +580,11 @@ class PastryLogic:
                                               m.key, rmax)
 
             # per-hop ACK bookkeeping (NextHopResponse)
-            st = dataclasses.replace(st, rr=rt_mod.on_ack(
+            rr_acked = rt_mod.on_ack(
                 st.rr, dataclasses.replace(
-                    m, valid=v & (m.kind == wire.KBR_ROUTE_ACK))))
+                    m, valid=v & (m.kind == wire.KBR_ROUTE_ACK)))
+            tally("route_acked", st.rr.active & ~rr_acked.active)
+            st = dataclasses.replace(st, rr=rr_acked)
 
             # recursive route pre-pass (sendToKey SEMI_RECURSIVE hop,
             # BaseOverlay.cc:1441-1581): ACK the last hop, then either
@@ -480,19 +592,30 @@ class PastryLogic:
             # surviving loop detection.  visitedHops ride m.nodes; the
             # originator is visited[0].
             en_rt = v & (m.kind == wire.KBR_ROUTE) & (st.state == READY)
-            ob.send(en_rt & (m.nonce > 0), now, m.src, wire.KBR_ROUTE_ACK,
-                    nonce=m.nonce, size_b=wire.BASE_CALL_B)
+            with scope("route.acks"):
+                ob.send(en_rt & (m.nonce > 0), now, m.src,
+                        wire.KBR_ROUTE_ACK, nonce=m.nonce,
+                        size_b=wire.BASE_CALL_B)
             deliver = en_rt & sib
             nxt_rt, found_rt = rt_mod.pick_next_hop(
                 cands, m.nodes, m.src, m.nodes[0], node_idx, sib)
-            fwd = en_rt & ~sib & found_rt & (m.hops < self.rcfg.hop_max)
+            in_bound = m.hops < self.rcfg.hop_max
+            fwd = en_rt & ~sib & found_rt & in_bound
             if hasattr(self.app, "forward"):
                 # Common API forward() veto (BaseApp.h:214)
                 fwd = fwd & ~self.app.forward(st.app, m, ctx)
-            vis_n = jnp.sum((m.nodes != NO_NODE).astype(I32))
-            visited2 = m.nodes.at[jnp.minimum(vis_n, rmax - 1)].set(
-                jnp.where(fwd, node_idx, m.nodes[jnp.minimum(
-                    vis_n, rmax - 1)]))
+            with scope("route.forward"):
+                vis_n = jnp.sum((m.nodes != NO_NODE).astype(I32))
+                visited2 = m.nodes.at[jnp.minimum(vis_n, rmax - 1)].set(
+                    jnp.where(fwd, node_idx, m.nodes[jnp.minimum(
+                        vis_n, rmax - 1)]))
+            tally("route_delivered", deliver)
+            tally("route_forwarded", fwd)
+            tally("route_unacked_table_full",
+                  fwd & acks_on & ~rt_mod.parks(st.rr, fwd, self.rcfg))
+            tally("route_dropped_no_candidate", en_rt & ~sib & ~found_rt)
+            tally("route_dropped_hop_bound",
+                  en_rt & ~sib & found_rt & ~in_bound)
             st = dataclasses.replace(st, rr=rt_mod.forward(
                 st.rr, ob, fwd, now, nxt_rt, key=m.key, inner=m.d,
                 a=m.a, b=m.b, c=m.c, hops=m.hops + 1, stamp=m.stamp,
@@ -519,34 +642,61 @@ class PastryLogic:
 
             # FindNodeResponse → lookup engine + learn payload
             en = v & (m.kind == wire.FINDNODE_RES)
-            st = dataclasses.replace(st, lk=lk_mod.on_response(
-                st.lk, dataclasses.replace(m, valid=en), metric_fn, lcfg))
-            learned = m.nodes[:lcfg.frontier]
-            st = select_tree(
-                en, self._learn(ctx, st, me_key, node_idx, learned,
-                                learned != NO_NODE), st)
+            with scope("lookup.responses"):
+                st = dataclasses.replace(st, lk=lk_mod.on_response(
+                    st.lk, dataclasses.replace(m, valid=en), metric_fn,
+                    lcfg))
+            en_found = en
 
-            # state exchange (leafset push-pull; PastryStateMessage)
-            en = v & (m.kind == wire.PASTRY_STATE_CALL) & (
-                st.state == READY)
-            ob.send(en, now, m.src, wire.PASTRY_STATE_RES,
-                    nodes=pad_nodes(self._leafset_nodes(st, node_idx)),
-                    stamp=m.stamp, size_b=wire.BASE_CALL_B
-                    + wire.NODEHANDLE_B * (p.num_leaves + 1))
-            en = v & (m.kind == wire.PASTRY_STATE_RES)
+            # state exchange (leafset push-pull; PastryStateMessage):
+            # the call carries the caller's leaf set, the response the
+            # callee's (before it merged the caller's)
+            with scope("pastry.leafset_maint"):
+                en_call = v & (m.kind == wire.PASTRY_STATE_CALL) & (
+                    st.state == READY)
+                ob.send(en_call, now, m.src, wire.PASTRY_STATE_RES,
+                        nodes=pad_nodes(self._leafset_nodes(st, node_idx)),
+                        stamp=m.stamp, size_b=wire.BASE_CALL_B
+                        + wire.NODEHANDLE_B * (p.num_leaves + 1))
+            # local tuning, served: the row asked for, with ourselves in
+            # the column our own digit leaves empty
+            with scope("pastry.tuning"):
+                en_row = v & (m.kind == wire.PASTRY_ROW_CALL) & (
+                    st.state == READY)
+                row_r = jnp.clip(m.a, 0, p.rows - 1)
+                own_row = st.rt[row_r].at[K.digit(
+                    me_key, row_r, p.bits_per_digit, spec)].set(node_idx)
+                ob.send(en_row, now, m.src, wire.PASTRY_ROW_RES, a=row_r,
+                        nodes=pad_nodes(own_row), stamp=m.stamp,
+                        size_b=wire.BASE_CALL_B + 1
+                        + wire.NODEHANDLE_B * p.cols)
+            tally("bamboo_state_msgs", en_call | en_row)
+            # what a message teaches, merged by ONE pass a slot: its
+            # READY source, a FindNode response's nodes, a READY
+            # caller's pushed leaf set, a response's leaf set or row;
+            # the responder's own entry carries the measured RTT
+            is_res = v & ((m.kind == wire.PASTRY_STATE_RES)
+                          | (m.kind == wire.PASTRY_ROW_RES))
+            taught = jnp.concatenate([src0[None], m.nodes[:rmax]])
+            en_taught = (taught != NO_NODE) & jnp.concatenate([
+                src_ready[None], jnp.broadcast_to(
+                    is_res | en_found | (en_call & src_ready), (rmax,))])
             rtt_ms = jnp.clip((now - m.stamp) // 1_000_000, 0,
                               RTT_INF - 1).astype(I32)
-            rtt_vec = jnp.full((rmax,), RTT_INF, I32).at[0].set(
-                jnp.where(m.stamp > 0, rtt_ms, RTT_INF))
+            rtt_vec = jnp.where(
+                is_res & (m.stamp > 0) & (taught == src0), rtt_ms, RTT_INF)
             st = select_tree(
-                en, self._learn(ctx, st, me_key, node_idx,
-                                m.nodes[:rmax], m.nodes[:rmax] != NO_NODE,
-                                rtt=rtt_vec),
-                st)
+                jnp.any(en_taught),
+                self._learn(ctx, st, me_key, node_idx, taught, en_taught,
+                            rtt=rtt_vec), st)
             # joining node: first state response completes the join
-            got_state = en & (st.state == JOINING)
-            joins_cnt += got_state.astype(I32)
-            st = self._become_ready(ctx, st, got_state, now, rngs[0])
+            with scope("pastry.join"):
+                got_state = v & (m.kind == wire.PASTRY_STATE_RES) & (
+                    st.state == JOINING)
+                joins_cnt += got_state.astype(I32)
+                st = self._become_ready(ctx, st, got_state, now, rngs[0])
+                announce = announce | got_state
+                announce_t = jnp.where(got_state, now, announce_t)
 
             # app-owned kinds (reuse the sibling flag computed for this
             # slot's FindNode handler — no app-kind handler above mutates
@@ -558,56 +708,102 @@ class PastryLogic:
             ob.send(v & (m.kind == wire.PING_CALL), now, m.src,
                     wire.PING_RES, a=m.a, size_b=wire.BASE_CALL_B)
 
+        # a node whose join ended in this tick tells every member of its
+        # new leaf set (the call carries that leaf set and is answered
+        # with the member's own)
+        state_b = wire.BASE_CALL_B + wire.NODEHANDLE_B * (p.num_leaves + 1)
+        with scope("pastry.join"):
+            leafs = jnp.concatenate([st.leaf_cw, st.leaf_ccw])
+            ob.send(announce & (leafs != NO_NODE), announce_t, leafs,
+                    wire.PASTRY_STATE_CALL,
+                    nodes=pad_nodes(self._leafset_nodes(st, node_idx)),
+                    stamp=announce_t, size_b=state_b)
+
         # ------------------------------------------------------- timers ----
         # join: lookup own key, then state request to the responsible node
-        en_j = (st.state == JOINING) & (st.t_join < t_end)
-        now_j = jnp.maximum(st.t_join, t0)
-        boot = ctx.sample_ready(rngs[1], node_idx)
-        no_join_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_JOIN))
-        alone_start = en_j & (boot == NO_NODE)
-        st = self._become_ready(ctx, st, alone_start, now_j, rngs[2])
-        joins_cnt += alone_start.astype(I32)
-        slot, have = lk_mod.free_slot(st.lk)
-        start_join = en_j & (boot != NO_NODE) & no_join_lk & have
-        seed = jnp.full((lcfg.frontier,), NO_NODE, I32).at[0].set(boot)
-        st = dataclasses.replace(st, lk=lk_mod.start(
-            st.lk, start_join, slot, P_JOIN, 0, me_key, seed, now_j, lcfg))
-        st = dataclasses.replace(st, t_join=jnp.where(
-            en_j & ~alone_start,
-            now_j + jnp.int64(int(p.join_delay * NS)), st.t_join))
+        with scope("pastry.join"):
+            en_j = (st.state == JOINING) & (st.t_join < t_end)
+            now_j = jnp.maximum(st.t_join, t0)
+            boot = ctx.sample_ready(rngs[1], node_idx)
+            no_join_lk = ~jnp.any(st.lk.active & (st.lk.purpose == P_JOIN))
+            # no node READY: ONE due joiner starts the overlay
+            # (ring_starter); the others keep their timer, so they stay
+            # due (and awake) and find the starter READY in the next tick
+            alone_start = en_j & (boot == NO_NODE) & (
+                ctx.starter == node_idx)
+            st = self._become_ready(ctx, st, alone_start, now_j, rngs[2])
+            joins_cnt += alone_start.astype(I32)
+            slot, have = lk_mod.free_slot(st.lk)
+            start_join = en_j & (boot != NO_NODE) & no_join_lk & have
+            seed = jnp.full((lcfg.frontier,), NO_NODE, I32).at[0].set(boot)
+            st = dataclasses.replace(st, lk=lk_mod.start(
+                st.lk, start_join, slot, P_JOIN, 0, me_key, seed, now_j,
+                lcfg))
+            st = dataclasses.replace(st, t_join=jnp.where(
+                en_j & (boot != NO_NODE),
+                now_j + jnp.int64(int(p.join_delay * NS)), st.t_join))
 
         # leafset maintenance: push-pull with a random leaf (Bamboo
         # leafsetMaintenance)
-        en_l = (st.state == READY) & (st.t_ls < t_end)
-        now_l = jnp.maximum(st.t_ls, t0)
-        leafs = jnp.concatenate([st.leaf_cw, st.leaf_ccw])
-        n_leafs = jnp.sum((leafs != NO_NODE).astype(I32))
-        pick = jax.random.randint(rngs[3], (), 0, jnp.maximum(n_leafs, 1),
-                                  dtype=I32)
-        order = jnp.argsort(jnp.where(leafs != NO_NODE, 0, 1))  # analysis: allow(sort-call)
-        tgt = leafs[order[jnp.minimum(pick, leafs.shape[0] - 1)]]
-        fire_l = en_l & (tgt != NO_NODE)
-        ob.send(fire_l, now_l, tgt, wire.PASTRY_STATE_CALL,
-                stamp=now_l, size_b=wire.BASE_CALL_B)
-        st = dataclasses.replace(st, t_ls=jnp.where(
-            en_l, now_l + jnp.int64(int(p.leafset_interval * NS)), st.t_ls))
+        with scope("pastry.leafset_maint"):
+            en_l = (st.state == READY) & (st.t_ls < t_end)
+            now_l = jnp.maximum(st.t_ls, t0)
+            leafs = jnp.concatenate([st.leaf_cw, st.leaf_ccw])
+            held_l = leafs != NO_NODE
+            n_leafs = jnp.sum(held_l.astype(I32))
+            pick = jax.random.randint(rngs[3], (), 0,
+                                      jnp.maximum(n_leafs, 1), dtype=I32)
+            # the pick-th held leaf, counted and not sorted
+            tgt = leafs[jnp.argmax(jnp.cumsum(held_l.astype(I32)) > pick)]
+            fire_l = en_l & (n_leafs > 0)
+            ob.send(fire_l, now_l, tgt, wire.PASTRY_STATE_CALL,
+                    nodes=pad_nodes(self._leafset_nodes(st, node_idx)),
+                    stamp=now_l, size_b=state_b)
+            tally("bamboo_ls_rounds", fire_l)
+            st = dataclasses.replace(st, t_ls=jnp.where(
+                en_l, now_l + jnp.int64(int(p.leafset_interval * NS)),
+                st.t_ls))
 
-        # global tuning: random-key lookup fills routing rows (Bamboo
-        # globalTuning)
-        en_g = (st.state == READY) & (st.t_gt < t_end)
-        now_g = jnp.maximum(st.t_gt, t0)
-        no_tune = ~jnp.any(st.lk.active & (st.lk.purpose == P_TUNE))
-        target = K.random_keys(rngs[4], (), spec)
-        seed_g, sib_g, _ = self._find_node(ctx, st, me_key, node_idx,
-                                           target, rmax)
-        slot, have = lk_mod.free_slot(st.lk)
-        start_g = en_g & no_tune & have & ~sib_g & (seed_g[0] != NO_NODE)
-        st = dataclasses.replace(
-            st,
-            lk=lk_mod.start(st.lk, start_g, slot, P_TUNE, 0, target,
-                            seed_g[:lcfg.frontier], now_g, lcfg),
-            t_gt=jnp.where(en_g, now_g + jnp.int64(
-                int(p.tuning_interval * NS)), st.t_gt))
+        with scope("pastry.tuning"):
+            # local tuning (Bamboo localTuning): a random routing-table
+            # entry is asked for the row it sits in
+            if p.local_tuning_interval > 0:
+                en_t = (st.state == READY) & (st.t_lt < t_end)
+                now_t = jnp.maximum(st.t_lt, t0)
+                flat = st.rt.reshape(-1)
+                held_t = flat != NO_NODE
+                n_held = jnp.sum(held_t.astype(I32))
+                pick_t = jax.random.randint(
+                    jax.random.fold_in(rngs[4], 1), (), 0,
+                    jnp.maximum(n_held, 1), dtype=I32)
+                at = jnp.argmax(jnp.cumsum(held_t.astype(I32)) > pick_t)
+                fire_t = en_t & (n_held > 0)
+                ob.send(fire_t, now_t, flat[at], wire.PASTRY_ROW_CALL,
+                        a=(at // p.cols).astype(I32), stamp=now_t,
+                        size_b=wire.BASE_CALL_B + 1)
+                tally("bamboo_lt_probes", fire_t)
+                st = dataclasses.replace(st, t_lt=jnp.where(
+                    en_t, now_t + jnp.int64(
+                        int(p.local_tuning_interval * NS)), st.t_lt))
+
+            # global tuning: random-key lookup fills routing rows (Bamboo
+            # globalTuning)
+            en_g = (st.state == READY) & (st.t_gt < t_end)
+            now_g = jnp.maximum(st.t_gt, t0)
+            no_tune = ~jnp.any(st.lk.active & (st.lk.purpose == P_TUNE))
+            target = K.random_keys(rngs[4], (), spec)
+            seed_g, sib_g, _ = self._find_node(ctx, st, me_key, node_idx,
+                                               target, rmax)
+            slot, have = lk_mod.free_slot(st.lk)
+            start_g = en_g & no_tune & have & ~sib_g & (
+                seed_g[0] != NO_NODE)
+            tally("bamboo_gt_lookups", en_g)
+            st = dataclasses.replace(
+                st,
+                lk=lk_mod.start(st.lk, start_g, slot, P_TUNE, 0, target,
+                                seed_g[:lcfg.frontier], now_g, lcfg),
+                t_gt=jnp.where(en_g, now_g + jnp.int64(
+                    int(p.tuning_interval * NS)), st.t_gt))
 
         # app timer
         # graceful-leave: hand app data to the clockwise leaf and stop
@@ -648,6 +844,12 @@ class PastryLogic:
                 cands_a, jnp.full((rmax,), NO_NODE, I32), NO_NODE,
                 node_idx, node_idx, sib_a)
             fire0 = req.want & ~sib_a & routable & found0
+            tally("bamboo_app_routes", fire0)
+            tally("route_forwarded", fire0)
+            tally("route_unacked_table_full",
+                  fire0 & acks_on & ~rt_mod.parks(st.rr, fire0, self.rcfg))
+            tally("route_dropped_no_candidate",
+                  req.want & ~sib_a & routable & ~found0)
             st = dataclasses.replace(st, rr=rt_mod.forward(
                 st.rr, ob, fire0, now_a, nxt0, key=req.key,
                 inner=inner_a, a=req.tag, b=jnp.int32(0),
@@ -686,6 +888,7 @@ class PastryLogic:
         # route-hop ACK timeouts: unresponsive next hops are failures too
         new_rr, rt_failed, rt_retry = rt_mod.on_timeouts(st.rr, t_end,
                                                          self.rcfg)
+        tally("route_ack_timeouts", rt_failed != NO_NODE)
         st = dataclasses.replace(st, rr=new_rr)
         st = self._handle_failed(
             ctx, st, me_key, node_idx,
@@ -705,7 +908,10 @@ class PastryLogic:
             # delivers (decap) next tick
             st = dataclasses.replace(st, rr=rt_mod.reforward(
                 st.rr, ob, qi, en_q & found_q, t0, nxt_q, self.rcfg))
+            tally("route_rerouted", en_q & found_q)
+            tally("route_forwarded", en_q & found_q)
             give_up = en_q & ~found_q
+            tally("route_dropped_no_candidate", give_up)
             st = dataclasses.replace(
                 st, rr=rt_mod.drop_slot(st.rr, qi, give_up))
             routedrop_cnt += give_up.astype(I32)
@@ -776,14 +982,17 @@ class PastryLogic:
             "c:route_dropped": routedrop_cnt,
             "s:lookup_hops": comp_hops_ev,
         }
+        events.update({"c:" + k: v for k, v in cnt.items()})
         ev.finish(events, self.app.hist_map)
         return st, ob, events
 
 
 def bamboo_params() -> PastryParams:
     """Bamboo defaults (default.ini:251-267): smaller leafset, periodic
-    push maintenance (already the maintenance style here)."""
-    return PastryParams(num_leaves=8)
+    push maintenance (already the maintenance style here), and the
+    third of Bamboo's periodic tasks, local tuning, at the leaf-set
+    task's interval."""
+    return PastryParams(num_leaves=8, local_tuning_interval=10.0)
 
 
 class BambooLogic(PastryLogic):

@@ -89,11 +89,12 @@ COST_ORDER = (
     "test_route_modes_epichord.py", "test_epichord.py",
     "test_route_modes.py", "test_zz_sparse.py", "test_engine.py",
     "test_mesh_dryrun.py", "test_route_modes_broose.py",
-    "test_chord_ring.py", "test_pastry_multihop.py",
+    "test_chord_ring.py", "test_pastry_bamboo.py",
+    "test_pastry_multihop.py",
     "test_zz_sparse_rounds.py", "test_zz_sparse_churn.py",
     "test_vmap_campaign.py", "test_mesh.py", "test_route_modes_koorde.py",
     "test_zz_service_resume.py", "test_kademlia_depth.py",
-    "test_mesh_2d.py", "test_pastry_bamboo.py",
+    "test_mesh_2d.py",
 )
 
 

@@ -47,19 +47,20 @@ def pastry_run():
 
 def test_all_ready(pastry_run):
     _, st = pastry_run
-    assert np.asarray(st.alive).sum() == 8
+    assert np.asarray(st.alive).sum() == st.alive.shape[0]
     assert (np.asarray(st.logic.state) == READY).all()
 
 
 def test_leafsets_are_ring_neighbors(pastry_run):
     """8 nodes, leafset >= 8: every node must know all others, and
-    leaf_cw[0] must be the ring successor."""
+    leaf_cw[0] must be the ring successor (at any N)."""
     _, st = pastry_run
+    n = st.alive.shape[0]
     keys_int = [K.to_int(k) for k in np.asarray(st.node_keys)]
-    order = sorted(range(8), key=lambda i: keys_int[i])
+    order = sorted(range(n), key=lambda i: keys_int[i])
     cw = np.asarray(st.logic.leaf_cw)
     for pos, i in enumerate(order):
-        assert cw[i, 0] == order[(pos + 1) % 8], f"node {i} cw successor"
+        assert cw[i, 0] == order[(pos + 1) % n], f"node {i} cw successor"
 
 
 def test_deliveries(pastry_run):

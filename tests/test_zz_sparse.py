@@ -239,9 +239,12 @@ def test_sparse_identity_r_overflow_pressure():
 # -- which plane a deployment gets ------------------------------------------
 
 
-PASTRY_INI = """
+# an overlay with no declaration of its own (Koorde inherits Chord's;
+# Pastry and Bamboo declare theirs since ISSUE 45,
+# tests/test_pastry_bamboo.py)
+UNDECLARED_INI = """
 [General]
-**.overlayType = "oversim.overlay.pastry.PastryModules"
+**.overlayType = "oversim.overlay.broose.BrooseModules"
 **.tier1Type = "oversim.applications.kbrtestapp.KBRTestAppModules"
 **.targetOverlayTerminalNum = 8
 """
@@ -270,19 +273,25 @@ def test_default_tick_plane_resolution():
     assert set(cell.counter_names) == set(
         ENGINE_COUNTERS + PLANE_COUNTERS) - set(CHURN_COUNTERS)
 
-    pastry = build_simulation(IniFile.loads(PASTRY_INI))
-    assert not getattr(pastry.logic, "awake_set_exact", False)
-    assert pastry.ep.tick_impl == "auto" and pastry.tick_impl == "dense"
-    assert pastry.counter_names == ENGINE_COUNTERS
-    twin = pastry.for_vmap()
-    assert twin.tick_impl == "dense" and twin.ep == pastry.ep
-    assert twin.inbox_lanes == pastry.ep.pool_factor * pastry.n
+    broose = build_simulation(IniFile.loads(UNDECLARED_INI))
+    assert type(broose.logic).__name__ == "BrooseLogic"
+    assert not getattr(broose.logic, "awake_set_exact", False)
+    assert broose.ep.tick_impl == "auto" and broose.tick_impl == "dense"
+    assert broose.counter_names == ENGINE_COUNTERS
+    twin = broose.for_vmap()
+    assert twin.tick_impl == "dense" and twin.ep == broose.ep
+    assert twin.inbox_lanes == broose.ep.pool_factor * broose.n
     assert twin.for_vmap() is twin
     with pytest.raises(ScenarioError, match="awake_set_exact"):
         build_simulation(IniFile.loads(
-            PASTRY_INI + '**.tickImpl = "sparse"\n'))
+            UNDECLARED_INI + '**.tickImpl = "sparse"\n'))
     with pytest.raises(ValueError, match="awake_set_exact"):
-        resolve_tick_impl("sparse", pastry.logic)
+        resolve_tick_impl("sparse", broose.logic)
+    # Pastry and Bamboo declare it: the same ini with the overlay line
+    # changed is given the awake-set plane
+    pastry = build_simulation(IniFile.loads(UNDECLARED_INI.replace(
+        "broose.BrooseModules", "pastry.PastryModules")))
+    assert pastry.logic.awake_set_exact and pastry.tick_impl == "sparse"
     with pytest.raises(ValueError, match="unsupported"):
         resolve_tick_impl("eager", cell.logic)
 
