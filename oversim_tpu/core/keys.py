@@ -464,3 +464,36 @@ def argmin_by_distance(dist, *, approx: bool = False):
         among = hit if among is None else among & hit
     idx = jax.lax.broadcasted_iota(jnp.int32, among.shape, among.ndim - 1)
     return jnp.min(jnp.where(among, idx, c), axis=-1)
+
+
+def closest_k_by_distance(dist, payload, k: int, *, approx: bool = False):
+    """The first ``k`` of ``payload`` (tuple of [..., C] numeric arrays) in
+    the order of ``dist`` [..., C, KL]: for every input
+    ``sort_by_distance(dist, payload, approx=approx)[1]`` cut to
+    ``[..., :k]``, what a caller that keeps a FEW of many candidates
+    wants, found by ``k`` passes of ``argmin_by_distance``'s lane-by-lane
+    reduction (no sort, no scatter, no gather, no 64-bit intermediate: a
+    pass reads the payload off by its winner's one-hot mask, a sum of
+    one term).  The winner leaves the running by that mask over the
+    INDEX, not by its distance: rows that tie, all-``UMAX`` padding among
+    them, then follow in index order as the stable sort leaves them.
+    ``k`` is static; the comparator lanes are ``sort_by_distance``'s (the
+    same caveat holds under ``approx=True``).
+    """
+    c, kl = dist.shape[-2:]
+    top = jnp.iinfo(dist.dtype).max
+    lanes = [dist[..., i] for i in range(min(2, kl) if approx else kl)]
+    idx = jax.lax.broadcasted_iota(jnp.int32, dist.shape[:-1], dist.ndim - 2)
+    free = jnp.ones(dist.shape[:-1], bool)
+    picks = [[] for _ in payload]
+    for _ in range(min(k, c)):
+        among = free
+        for lane in lanes:
+            lane = jnp.where(among, lane, top)
+            among = among & (lane == jnp.min(lane, axis=-1, keepdims=True))
+        hit = idx == jnp.min(jnp.where(among, idx, c), axis=-1, keepdims=True)
+        for out, p in zip(picks, payload):
+            out.append(jnp.sum(jnp.where(hit, p, 0), axis=-1, keepdims=True,
+                               dtype=p.dtype))
+        free = free & ~hit
+    return tuple(jnp.concatenate(out, axis=-1) for out in picks)

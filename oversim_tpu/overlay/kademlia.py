@@ -523,12 +523,23 @@ class KademliaLogic:
     @scoped("kademlia.find_node")
     def _find_node_batch(self, ctx, st, me_key, node_idx, keys, rmax):
         """Batched findNode for T target keys at once ([T, KL] → ([T, rmax]
-        slots, [T] is_sibling, [B, K] stale)) — ONE sort over the shared
-        candidate set per tick instead of one per unrolled call site.
+        slots, [T] is_sibling, [B, K] stale)) over ONE shared candidate
+        set per tick instead of one per unrolled call site.
 
         findNode: top-R by XOR distance over self ∪ siblings ∪ all buckets
         (Kademlia.cc:1101 walks best bucket → surrounding buckets →
         siblings; same result set).  isSiblingFor: Kademlia.cc:888.
+
+        Of the 1 + s + B·k candidates (265) it keeps
+        ``lookupRedundantNodes`` (8), so the closest are taken by that
+        many argmin passes (``K.closest_k_by_distance``: the stable
+        sort's prefix, ties and padding included) and nothing is sorted:
+        ordering all 265 cost ten times the passes on the chip, for
+        every key of every stepped lane (PERF.md section 5, "Kademlia's
+        closest k").  The module's other sorts stay sorts,
+        because they keep the whole order or the displaced set:
+        ``_sib_merge``'s, ``_handle_failed``'s re-sort of the sibling
+        table, ``_bucket_update_batch``'s, the local lookup seed's.
 
         ``stale`` marks the bucket entries whose slot's CURRENT key
         earns another bucket than the one they sit in: entries of a slot
@@ -556,14 +567,13 @@ class KademliaLogic:
             [cands[:1 + p.s], jnp.where(drop, NO_NODE, held)])
         d = ck[None, :, :] ^ keys[:, None, :]                      # [T, C, KL]
         d = jnp.where((cands == NO_NODE)[None, :, None], UMAX, d)
-        (c_s,) = K.sort_by_distance(
-            d, (jnp.broadcast_to(cands, (t_dim, cands.shape[0])),),
-            approx=True)[1]
+        r = min(p.redundant_nodes, rmax)
+        (near,) = K.closest_k_by_distance(
+            d, (jnp.broadcast_to(cands, (t_dim, cands.shape[0])),), r,
+            approx=True)
         ready = st.state == READY
-        out = jnp.where(ready, c_s[:, :rmax], NO_NODE)
-        r = p.redundant_nodes
-        if r < rmax:
-            out = out.at[:, r:].set(NO_NODE)
+        out = jnp.pad(jnp.where(ready, near, NO_NODE), ((0, 0), (0, rmax - r)),
+                      constant_values=NO_NODE)
 
         # isSiblingFor(self, key, numSiblings=1) (Kademlia.cc:888)
         n_sib = jnp.sum((st.sib != NO_NODE).astype(I32))

@@ -198,6 +198,75 @@ def test_argmin_by_distance_is_the_sorts_first_index(case, approx):
         assert (want == 0).all()
 
 
+def _closest_k_rows(case):
+    """([A, T, C, KL] u32 distance rows, k) for one case of the closest-k
+    test: the argmin test's rows at k = 8, and the cases only a k has."""
+    rng = np.random.default_rng(46)
+    a, t, kl = 3, 4, 5
+    umax = np.uint32(0xFFFFFFFF)
+    if case == "fewer_real_than_k":
+        # three real candidates a row among 265, the rest padding
+        d = np.full((a, t, 265, kl), umax)
+        at = rng.permuted(np.tile(np.arange(265), (a, t, 1)), axis=-1)[..., :3]
+        real = rng.integers(0, 1 << 32, (a, t, 3, kl), dtype=np.uint32)
+        np.put_along_axis(d, at[..., None], real, axis=2)
+        return d, 8
+    if case == "k_is_1":
+        return _argmin_rows("ties"), 1
+    if case == "k_is_c":
+        return rng.integers(0, 3, (a, t, 12, kl), dtype=np.uint32), 12
+    return _argmin_rows(case), 1 if case == "one_row" else 8
+
+
+@pytest.mark.parametrize("approx", [False, True], ids=["exact", "approx"])
+@pytest.mark.parametrize("case", ["uniform", "ties", "mostly_umax", "all_umax",
+                                  "fewer_real_than_k", "one_row", "k_is_1",
+                                  "k_is_c", "tie_above_differ_below"])
+def test_closest_k_is_the_sorts_prefix(case, approx):
+    rows, k = _closest_k_rows(case)
+    dist = jnp.asarray(rows)
+    c = dist.shape[-2]
+    # two payloads: the index, and data that repeats (a node held twice)
+    idx = jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32), dist.shape[:-1])
+    data = jnp.asarray(np.random.default_rng(7).integers(
+        -1, 5, dist.shape[:-1], dtype=np.int32))
+
+    def by_sort(d, i, x):
+        return tuple(s[..., :k] for s in K.sort_by_distance(
+            d, (i, x), approx=approx)[1])
+
+    def by_passes(d, i, x):
+        return K.closest_k_by_distance(d, (i, x), k, approx=approx)
+
+    want = jax.vmap(jax.vmap(by_sort))(dist, idx, data)
+    # under vmap over the leading [A, T], batched, and one row at a time
+    for got in (jax.vmap(jax.vmap(by_passes))(dist, idx, data),
+                by_passes(dist, idx, data),
+                tuple(g[None, None] for g in by_passes(
+                    dist[0, 0], idx[0, 0], data[0, 0]))):
+        assert len(got) == 2
+        for g, w in zip(got, want):
+            g, w = np.asarray(g), np.asarray(w)
+            assert g.dtype == np.int32 and g.shape[-1] == min(k, c)
+            np.testing.assert_array_equal(g, w[:g.shape[0], :g.shape[1]])
+    first = np.asarray(want[0])
+    if case in ("k_is_1", "one_row"):
+        np.testing.assert_array_equal(
+            first[..., 0], np.asarray(K.argmin_by_distance(dist, approx=approx)))
+    if case == "all_umax":
+        # padding follows in index order: a taken row is not picked twice
+        assert (first == np.arange(k)).all()
+    if case == "fewer_real_than_k":
+        real = np.sort(np.nonzero(rows[..., 0] != 0xFFFFFFFF)[-1].reshape(3, 4, 3))
+        np.testing.assert_array_equal(np.sort(first[..., :3]), real)
+        pad = np.stack([[np.setdiff1d(np.arange(c), real[i, j])[:k - 3]
+                         for j in range(4)] for i in range(3)])
+        np.testing.assert_array_equal(first[..., 3:], pad)
+    if case == "tie_above_differ_below":
+        # the compressed comparator sees ties where the exact one does not
+        assert (first == np.arange(k)).all() == approx
+
+
 def test_log2_floor():
     spec = K.KeySpec(160)
     vals = [0, 1, 2, 3, 4, 1 << 80, (1 << 159) + 5]

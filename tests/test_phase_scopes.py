@@ -136,9 +136,10 @@ def test_the_lookup_engine_scatters_nothing(tick):
 
 
 # ``sort`` equations in a tick (N = 32, R = 2) on either plane: Kademlia's
-# as ISSUE 44's parent had them, Chord's 13 less the three calls of
-# ``_find_node`` and the notify handler's closest notifier
-SORTS = {"KademliaLogic": 10, "ChordLogic": 9, "BambooLogic": None}
+# 10 less ``_find_node_batch``'s two (the inbox keys' and the timer
+# keys', ISSUE 46), Chord's 13 less the three calls of ``_find_node`` and
+# the notify handler's closest notifier
+SORTS = {"KademliaLogic": 8, "ChordLogic": 9, "BambooLogic": None}
 
 
 def test_chord_find_node_sorts_nothing(tick):
@@ -165,6 +166,31 @@ def test_chord_find_node_sorts_nothing(tick):
     assert under.count("reduce_min") >= 3
     # the sorts that keep more than one candidate stay (the broadcast's)
     assert [names for names in sorts if "chord.broadcast" in names]
+
+
+def test_kademlia_find_node_sorts_nothing(tick):
+    """Kademlia's findNode keeps ``lookupRedundantNodes`` of its 1 + s +
+    B k candidates (ISSUE 46: ``K.closest_k_by_distance``, that many
+    passes of the argmin), where a sort of all of them ran for each
+    inbox key and each timer key of each stepped lane."""
+    sim, paths = tick
+    under = [prim for prim, names in paths if "kademlia.find_node" in names]
+    if type(sim.logic).__name__ != "KademliaLogic":
+        assert not under
+        return
+    assert len(under) > 1000
+    assert "sort" not in under
+    assert not [prim for prim in under if prim.startswith("scatter")]
+    # the passes are there: a ``min`` a comparator lane and one of the
+    # index, and the payload by a masked sum
+    k = sim.logic.p.redundant_nodes
+    assert under.count("reduce_min") >= 3 * k
+    assert under.count("reduce_sum") >= k
+    # the sorts that keep the whole order or the displaced set stay
+    sorts = [names for prim, names in paths if prim == "sort"]
+    for part, least in (("kademlia.routing_add", 3),
+                        ("kademlia.bucket_update", 2), ("kademlia.failed", 1)):
+        assert sum(part in names for names in sorts) >= least, part
 
 
 def test_no_registered_name_holds_scatter():
