@@ -31,6 +31,20 @@ re-dispatching the message view with kind := d.
 
 All functions operate on a single node's slice (vmapped by the engine),
 mirroring common/lookup.py's structure.
+
+The rule for writes is common/lookup.py's: a write whose index is an ACK
+slot of the node (or a slot and a visited column of it) is a SELECT over
+the whole leaf by a one-hot mask (``_slot_mask``, ``_put``), never
+``leaf.at[slot].set``, and one slot's word at a traced index is read by
+a masked sum over the Q slots, never ``leaf[slot]``.  Under the node
+step's ``vmap`` an indexed write is one scatter of A updates a leaf, and
+on the chip a scatter costs by its updates, the dropped ones too: 61 ns
+each, so the Bamboo step's 170 such writes (``forward``'s fourteen leaves
+at nine call sites, ``on_ack``, ``reforward``, ``drop_slot``, the visited
+append) were 21.5 us each over 352 stepped lanes, a quarter of the tick
+of N=1000, whether or not one lane forwarded anything (PERF.md, PR 45
+and PR 47).  A leaf is [Q] or [Q, <=8] with Q = 4: the select reads a
+few dozen words a lane and fuses with its neighbours.
 """
 
 from __future__ import annotations
@@ -41,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from oversim_tpu.common import wire
+from oversim_tpu.common.lookup import _col_mask, _put, _slot_mask
 from oversim_tpu.core.scopes import scoped
 
 I32 = jnp.int32
@@ -166,6 +181,16 @@ def _route_nonce(slot, gen, q: int):
     return 1 + slot + q * (gen & jnp.int32(0x003FFFFF))
 
 
+def _park(rt: RouteState, sel, **new):
+    """``rt`` with a fresh hop's copy in the slots ``sel`` [Q] marks:
+    ``new`` names every leaf but ``active`` and ``retries``, each value
+    one slot's (broadcast over the slot axis) or a [Q, ...] row a slot."""
+    return dataclasses.replace(
+        rt, active=rt.active | sel, retries=_put(rt.retries, sel, 0),
+        **{name: _put(getattr(rt, name), sel, value)
+           for name, value in new.items()})
+
+
 @scoped("route.forward")
 def forward(rt: RouteState, ob, en, now, next_hop, *, key, inner, a, b, c,
             hops, stamp, size_b, visited, cfg: RouteConfig):
@@ -187,31 +212,17 @@ def forward(rt: RouteState, ob, en, now, next_hop, *, key, inner, a, b, c,
 
     free = ~rt.active
     slot = jnp.argmax(free).astype(I32)
-    have = jnp.any(free)
-    use = en & have
-    gen = rt.gen[jnp.minimum(slot, q - 1)] + 1
+    use = en & jnp.any(free)
+    sel = _slot_mask(q, slot, use)                         # [Q]
+    gen = jnp.sum(jnp.where(sel, rt.gen, 0), dtype=I32) + 1
     nonce = jnp.where(use, _route_nonce(slot, gen, q), 0)
     ob.send(en, now, next_hop, wire.KBR_ROUTE, key=key, nonce=nonce,
             hops=hops, a=a, b=b, c=c, d=inner, nodes=visited,
             stamp=stamp, size_b=size_b + cfg.overhead_b)
-    sl = jnp.where(use, slot, q)  # OOB drop
-    return dataclasses.replace(
-        rt,
-        active=rt.active.at[sl].set(True, mode="drop"),
-        gen=rt.gen.at[sl].set(gen, mode="drop"),
-        dst=rt.dst.at[sl].set(next_hop, mode="drop"),
-        t_to=rt.t_to.at[sl].set(now + cfg.ack_timeout_ns, mode="drop"),
-        retries=rt.retries.at[sl].set(0, mode="drop"),
-        key=rt.key.at[sl].set(key, mode="drop"),
-        inner=rt.inner.at[sl].set(jnp.asarray(inner, I32), mode="drop"),
-        a=rt.a.at[sl].set(jnp.asarray(a, I32), mode="drop"),
-        b=rt.b.at[sl].set(jnp.asarray(b, I32), mode="drop"),
-        c=rt.c.at[sl].set(jnp.asarray(c, I32), mode="drop"),
-        hops=rt.hops.at[sl].set(jnp.asarray(hops, I32), mode="drop"),
-        stamp=rt.stamp.at[sl].set(jnp.asarray(stamp, I64), mode="drop"),
-        size_b=rt.size_b.at[sl].set(jnp.asarray(size_b, I32), mode="drop"),
-        visited=rt.visited.at[sl].set(visited[:rt.visited.shape[1]],
-                                      mode="drop"))
+    return _park(rt, sel, gen=gen, dst=next_hop,
+                 t_to=now + cfg.ack_timeout_ns, key=key, inner=inner, a=a,
+                 b=b, c=c, hops=hops, stamp=stamp, size_b=size_b,
+                 visited=visited[:rt.visited.shape[1]])
 
 
 @scoped("route.forward")
@@ -231,65 +242,66 @@ def forward_batch(rt: RouteState, ob, en, now, next_hop, *, key, inner, a,
                 stamp=stamp, size_b=size_b + cfg.overhead_b)
         return rt
 
-    # rank of each enabled lane / each free slot
+    # rank of each enabled lane / each free slot; lane j parks in the
+    # free slot of its own rank, so an enabled lane holds a slot no other
+    # lane holds and a slot takes its one lane's value by a sum over R
     lane_rank = jnp.cumsum(en.astype(I32)) - 1            # [R]
     free = ~rt.active
     slot_rank = jnp.cumsum(free.astype(I32)) - 1          # [Q]
-    n_free = jnp.sum(free.astype(I32))
-    # lane j -> the free slot with rank lane_rank[j]
-    slot_of_rank = jnp.full((q,), q, I32).at[
-        jnp.where(free, slot_rank, q)].set(jnp.arange(q, dtype=I32),
-                                           mode="drop")  # [Q] rank->slot
-    lane_slot = jnp.where(en & (lane_rank < n_free),
-                          slot_of_rank[jnp.clip(lane_rank, 0, q - 1)], q)
-    parked = lane_slot < q                                 # [R]
-    gen = rt.gen[jnp.clip(lane_slot, 0, q - 1)] + 1
-    nonce = jnp.where(parked, _route_nonce(
-        jnp.clip(lane_slot, 0, q - 1), gen, q), 0)
+    hit = (en[:, None] & free[None, :]
+           & (lane_rank[:, None] == slot_rank[None, :]))  # [R, Q]
+    parked = jnp.any(hit, axis=1)                          # [R]
+    lane_slot = jnp.sum(jnp.where(hit, jnp.arange(q, dtype=I32), 0),
+                        axis=1, dtype=I32)
+    gen = jnp.sum(jnp.where(hit, rt.gen, 0), axis=1, dtype=I32) + 1
+    nonce = jnp.where(parked, _route_nonce(lane_slot, gen, q), 0)
     ob.send(en, now, next_hop, wire.KBR_ROUTE, key=key, nonce=nonce,
             hops=hops, a=a, b=b, c=c, d=inner, nodes=visited,
             stamp=stamp, size_b=size_b + cfg.overhead_b)
-    vis_cap = rt.visited.shape[1]
+
+    def of_slot(leaf, value):
+        """[Q, ...] of ``leaf``'s dtype: slot s's row is the value of the
+        lane that hits it (``value`` [R, ...] or one for all lanes)."""
+        value = jnp.asarray(value, leaf.dtype)
+        value = jnp.broadcast_to(value, (r,) + leaf.shape[1:])
+        at = hit.reshape(hit.shape + (1,) * (leaf.ndim - 1))
+        return jnp.sum(jnp.where(at, value[:, None], 0), axis=0,
+                       dtype=leaf.dtype)
+
+    new = dict(gen=gen, dst=next_hop, t_to=now + cfg.ack_timeout_ns,
+               key=key, inner=inner, a=a, b=b, c=c, hops=hops, stamp=stamp,
+               size_b=size_b, visited=visited[:, :rt.visited.shape[1]])
+    return _park(rt, jnp.any(hit, axis=0),
+                 **{name: of_slot(getattr(rt, name), value)
+                    for name, value in new.items()})
+
+
+def _ack_hit(rt: RouteState, m):
+    """[Q] bool (``m``'s fields scalars) or [R, Q] (an [R] inbox axis):
+    the pending slot that the KBR_ROUTE_ACK ``m`` answers (its nonce
+    names the slot and the slot's ``gen``; the slot awaits ``m.src``),
+    none for any other message."""
+    q = rt.active.shape[0]
+    nonce = jnp.asarray(m.nonce)[..., None]
+    ok = jnp.asarray(m.valid)[..., None] & (nonce > 0)
+    return (_slot_mask(q, (nonce - 1) % q, ok) & rt.active
+            & ((rt.gen & jnp.int32(0x003FFFFF)) == (nonce - 1) // q)
+            & (rt.dst == jnp.asarray(m.src)[..., None]))
+
+
+def _free(rt: RouteState, en):
+    """``rt`` with the slots ``en`` [Q] marks free and their timers off."""
     return dataclasses.replace(
         rt,
-        active=rt.active.at[lane_slot].set(True, mode="drop"),
-        gen=rt.gen.at[lane_slot].set(gen, mode="drop"),
-        dst=rt.dst.at[lane_slot].set(next_hop, mode="drop"),
-        t_to=rt.t_to.at[lane_slot].set(now + cfg.ack_timeout_ns,
-                                       mode="drop"),
-        retries=rt.retries.at[lane_slot].set(0, mode="drop"),
-        key=rt.key.at[lane_slot].set(key, mode="drop"),
-        inner=rt.inner.at[lane_slot].set(
-            jnp.broadcast_to(jnp.asarray(inner, I32), (r,)), mode="drop"),
-        a=rt.a.at[lane_slot].set(jnp.asarray(a, I32), mode="drop"),
-        b=rt.b.at[lane_slot].set(jnp.asarray(b, I32), mode="drop"),
-        c=rt.c.at[lane_slot].set(jnp.asarray(c, I32), mode="drop"),
-        hops=rt.hops.at[lane_slot].set(jnp.asarray(hops, I32), mode="drop"),
-        stamp=rt.stamp.at[lane_slot].set(jnp.asarray(stamp, I64),
-                                         mode="drop"),
-        size_b=rt.size_b.at[lane_slot].set(jnp.asarray(size_b, I32),
-                                           mode="drop"),
-        visited=rt.visited.at[lane_slot].set(visited[:, :vis_cap],
-                                             mode="drop"))
+        active=rt.active & ~en,
+        t_to=jnp.where(en, T_INF, rt.t_to))
 
 
 @scoped("route.acks")
 def on_acks(rt: RouteState, m):
-    """Batched :func:`on_ack`: ``m`` fields carry an [R] inbox axis.  Each
-    valid ACK addresses a distinct slot (the nonce encodes the slot), so
-    one scatter clears them all."""
-    q = rt.active.shape[0]
-    slot = (m.nonce - 1) % q                               # [R]
-    gen = (m.nonce - 1) // q
-    sc = jnp.clip(slot, 0, q - 1)
-    ok = (m.valid & (m.nonce > 0) & rt.active[sc]
-          & ((rt.gen[sc] & jnp.int32(0x003FFFFF)) == gen)
-          & (rt.dst[sc] == m.src))
-    sl = jnp.where(ok, sc, q)
-    return dataclasses.replace(
-        rt,
-        active=rt.active.at[sl].set(False, mode="drop"),
-        t_to=rt.t_to.at[sl].set(T_INF, mode="drop"))
+    """Batched :func:`on_ack`: ``m`` fields carry an [R] inbox axis.  A
+    slot is freed where any lane's ACK matches it."""
+    return _free(rt, jnp.any(_ack_hit(rt, m), axis=0))
 
 
 def append_visited(visited, self_idx, en):
@@ -297,11 +309,10 @@ def append_visited(visited, self_idx, en):
     to each enabled lane's [R, V] visited list (first NO_NODE slot; a full
     list keeps its prefix — bounded-width deviation, overflow harmless:
     loop detection just loses the oldest hops)."""
-    r, vcap = visited.shape
+    vcap = visited.shape[1]
     n_vis = jnp.sum((visited != NO_NODE).astype(I32), axis=1)   # [R]
-    pos = jnp.where(en, jnp.minimum(n_vis, vcap - 1), vcap)
-    return visited.at[jnp.arange(r), pos].set(
-        jnp.where(en, self_idx, NO_NODE), mode="drop")
+    at = _col_mask(en, vcap, jnp.minimum(n_vis, vcap - 1))
+    return _put(visited, at, self_idx)
 
 
 def sroute_send(ob, en, now, *, path, responder, inner, key, a, hops,
@@ -383,17 +394,7 @@ def reply(ob, cfg: RouteConfig, en, now, msgs, ctx, node_idx, inner_kind,
 @scoped("route.acks")
 def on_ack(rt: RouteState, m):
     """Consume a KBR_ROUTE_ACK (NextHopResponse): free the matched slot."""
-    q = rt.active.shape[0]
-    slot = (m.nonce - 1) % q
-    gen = (m.nonce - 1) // q
-    ok = (m.valid & (m.nonce > 0) & rt.active[slot]
-          & ((rt.gen[slot] & jnp.int32(0x003FFFFF)) == gen)
-          & (rt.dst[slot] == m.src))
-    sl = jnp.where(ok, slot, q)
-    return dataclasses.replace(
-        rt,
-        active=rt.active.at[sl].set(False, mode="drop"),
-        t_to=rt.t_to.at[sl].set(T_INF, mode="drop"))
+    return _free(rt, _ack_hit(rt, m))
 
 
 @scoped("route.timeouts")
@@ -434,12 +435,12 @@ def reforward(rt: RouteState, ob, slot: int, en, now, next_hop,
             c=rt.c[slot], d=rt.inner[slot], nodes=rt.visited[slot],
             stamp=rt.stamp[slot],
             size_b=rt.size_b[slot] + cfg.overhead_b)
-    sl = jnp.where(en, jnp.int32(slot), q)
+    sel = _slot_mask(q, slot, en)
     return dataclasses.replace(
         rt,
-        gen=rt.gen.at[sl].set(gen, mode="drop"),
-        dst=rt.dst.at[sl].set(next_hop, mode="drop"),
-        t_to=rt.t_to.at[sl].set(now + cfg.ack_timeout_ns, mode="drop"))
+        gen=_put(rt.gen, sel, gen),
+        dst=_put(rt.dst, sel, next_hop),
+        t_to=_put(rt.t_to, sel, now + cfg.ack_timeout_ns))
 
 
 @scoped("route.forward")
@@ -467,20 +468,12 @@ def reforward_batch(rt: RouteState, ob, en, now, next_hop,
 @scoped("route.timeouts")
 def drop_slots(rt: RouteState, en):
     """Vectorized :func:`drop_slot`: free every slot marked in ``en`` [Q]."""
-    return dataclasses.replace(
-        rt,
-        active=rt.active & ~en,
-        t_to=jnp.where(en, T_INF, rt.t_to))
+    return _free(rt, en)
 
 
 @scoped("route.timeouts")
 def drop_slot(rt: RouteState, slot: int, en):
-    q = rt.active.shape[0]
-    sl = jnp.where(en, jnp.int32(slot), q)
-    return dataclasses.replace(
-        rt,
-        active=rt.active.at[sl].set(False, mode="drop"),
-        t_to=rt.t_to.at[sl].set(T_INF, mode="drop"))
+    return _free(rt, _slot_mask(rt.active.shape[0], slot, en))
 
 
 def next_event(rt: RouteState):

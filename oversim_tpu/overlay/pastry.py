@@ -606,9 +606,10 @@ class PastryLogic:
                 fwd = fwd & ~self.app.forward(st.app, m, ctx)
             with scope("route.forward"):
                 vis_n = jnp.sum((m.nodes != NO_NODE).astype(I32))
-                visited2 = m.nodes.at[jnp.minimum(vis_n, rmax - 1)].set(
-                    jnp.where(fwd, node_idx, m.nodes[jnp.minimum(
-                        vis_n, rmax - 1)]))
+                visited2 = jnp.where(
+                    fwd & (jnp.arange(rmax, dtype=I32)
+                           == jnp.minimum(vis_n, rmax - 1)),
+                    node_idx, m.nodes)
             tally("route_delivered", deliver)
             tally("route_forwarded", fwd)
             tally("route_unacked_table_full",

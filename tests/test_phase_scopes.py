@@ -116,16 +116,22 @@ def test_the_overlays_parts_are_met(tick):
         assert {"step.gather", "step.write_back", "inbox.rank"} <= met
 
 
+def _parts_under(paths, prefix):
+    """The primitives of every part whose name starts with ``prefix``."""
+    under = {}
+    for prim, names in paths:
+        for part in names[1:]:
+            if part.startswith(prefix):
+                under.setdefault(part, []).append(prim)
+    return under
+
+
 def test_the_lookup_engine_scatters_nothing(tick):
     """A lookup slot is written by mask (common/lookup.py, ISSUE 42):
     under the node step's vmap an indexed write is a scatter of A
     updates a leaf, 108 of them under ``lookup.start`` before."""
     _, paths = tick
-    under = {}
-    for prim, names in paths:
-        for part in names[1:]:
-            if part.startswith("lookup."):
-                under.setdefault(part, []).append(prim)
+    under = _parts_under(paths, "lookup.")
     assert {"lookup.start", "lookup.pump", "lookup.responses"} <= set(under)
     scatters = {part: [p for p in prims if p.startswith("scatter")]
                 for part, prims in under.items()}
@@ -133,6 +139,28 @@ def test_the_lookup_engine_scatters_nothing(tick):
     # the writes are there, as selects
     for part in ("lookup.start", "lookup.pump", "lookup.responses"):
         assert under[part].count("select_n") >= 4, part
+
+
+def test_the_routed_path_scatters_nothing(tick):
+    """An ACK slot is written by mask (common/route.py, ISSUE 47): under
+    the node step's vmap an indexed write is a scatter of A updates a
+    leaf, some 170 of them under ``route.*`` in a Bamboo step of R = 8
+    before (fourteen leaves a ``forward``)."""
+    sim, paths = tick
+    under = _parts_under(paths, "route.")
+    if not under:
+        pytest.skip(f"{type(sim.logic).__name__}'s tick meets no route.* "
+                    "part (iterative routing)")
+    assert set(under) == {"route.forward", "route.acks", "route.timeouts"}
+    scatters = {part: [p for p in prims if p.startswith("scatter")]
+                for part, prims in under.items()}
+    assert not any(scatters.values()), scatters
+    # the writes are there, as selects: thirteen leaves a ``forward`` at
+    # R + 1 call sites, two an ACK, two a slot given up
+    r, q = sim.ep.inbox_slots, sim.logic.rcfg.slots
+    assert under["route.forward"].count("select_n") >= 13 * (r + 1)
+    assert under["route.acks"].count("select_n") >= r
+    assert under["route.timeouts"].count("select_n") >= q
 
 
 # ``sort`` equations in a tick (N = 32, R = 2) on either plane: Kademlia's
